@@ -12,63 +12,13 @@ Architecture (see SURVEY.md §7):
 """
 from __future__ import annotations
 
-# jax version compat: `shard_map` was promoted from jax.experimental to the
-# jax root; re-export it there on older installs so `from jax import
-# shard_map` (collective.py, pipeline.py, ring_attention.py, tests) works
-# against either generation.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *args, **kwargs):
-        # newer jax renamed check_rep -> check_vma
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # newer jax names the MANUAL axes (axis_names=); 0.4.37 takes the
-        # complement (auto=), the axes left to the compiler
-        if "axis_names" in kwargs:
-            manual = frozenset(kwargs.pop("axis_names"))
-            mesh = kwargs.get("mesh", args[0] if args else None)
-            if mesh is not None:
-                auto = frozenset(mesh.axis_names) - manual
-                if auto:
-                    kwargs["auto"] = auto
-        return _shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _compat_shard_map
-
-# `jax.lax.axis_size` landed after 0.4.37; `psum(1, axis)` of a Python int
-# constant-folds to a static int inside shard_map/pmap traces, which is all
-# the pipeline/ring call sites need (they use it for `range(n)` bounds).
-if not hasattr(_jax.lax, "axis_size"):
-    def _compat_axis_size(axis_name):
-        import jax.lax
-
-        return jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _compat_axis_size
-
-# `jax.distributed.is_initialized` also postdates 0.4.37: the coordination
-# client handle in jax._src.distributed.global_state is the ground truth
-# (probing via jax.process_count() would initialize the XLA backend, after
-# which jax.distributed.initialize() becomes illegal).
-if not hasattr(_jax.distributed, "is_initialized"):
-    def _compat_dist_is_initialized():
-        from jax._src import distributed as _jdist
-
-        return _jdist.global_state.client is not None
-
-    _jax.distributed.is_initialized = _compat_dist_is_initialized
-del _jax
-
 from . import framework
 
-# Persistent XLA compilation cache (FLAGS_jit_cache_dir, on by default
-# under ~/.cache/paddle_tpu/xla): compiled executables are reused across
+# Persistent XLA compilation cache: compiled executables are reused across
 # PROCESSES, so the second run of the same model skips XLA compilation.
-# Disable with FLAGS_JIT_CACHE_DIR="" in the environment or
-# paddle.set_flags({"FLAGS_jit_cache_dir": ""}).
+# It lives where JAX_COMPILATION_CACHE_DIR says, else in FLAGS_jit_cache_dir
+# (default <checkout>/.jax_cache; FLAGS_JIT_CACHE_DIR="" in the environment
+# or paddle.set_flags({"FLAGS_jit_cache_dir": ""}) turns it off).
 framework.flags.apply_jit_cache()
 
 from .framework import (

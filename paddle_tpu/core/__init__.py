@@ -6,13 +6,16 @@ Reference parity: the C++ platform layer that survives on TPU (SURVEY.md
 (platform/profiler.cc + tools/timeline.py), double-buffer ring handoff
 (operators/reader/buffered_reader.cc), parallel batch assembly
 (framework/data_feed.cc).  Device compute is XLA/Pallas; this is host-side
-runtime.  The library is compiled from csrc/core.cc on first import (g++,
-cached .so); every entry point has a pure-Python fallback so the package
-works without a toolchain.
+runtime.  The library is a generated file: it is compiled from csrc/core.cc
+on first use (g++) and again whenever the source is newer, and it is never
+loaded without that source beside it.  Every entry point has a pure-Python
+path so the package works without a toolchain; `available()` says which of
+the two is in use.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -28,9 +31,17 @@ _build_lock = threading.Lock()
 
 
 def _build():
+    # build beside the target and rename: another process importing the
+    # package meanwhile loads the old library or the whole new one
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-           "-shared", "-o", _SO, _SRC]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+           "-shared", "-o", tmp, _SRC]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load():
@@ -45,15 +56,16 @@ def _load():
         if _load_failed:
             return None
         try:
+            if not os.path.exists(_SRC):
+                raise OSError(f"{_SRC} is missing")
             if (not os.path.exists(_SO)
-                    or (os.path.exists(_SRC)
-                        and os.path.getmtime(_SRC) > os.path.getmtime(_SO))):
-                if not os.path.exists(_SRC):
-                    _load_failed = True
-                    return None
+                    or os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
                 _build()
             lib = ctypes.CDLL(_SO)
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
+            logging.getLogger("paddle_tpu.core").warning(
+                "native core unavailable, using the pure-Python path: %s%s",
+                e, getattr(e, "stderr", None) or "")
             _load_failed = True
             return None
         # signatures

@@ -2,7 +2,7 @@
 
 Reference parity: paddle/fluid/platform/enforce.h:388-640 (PADDLE_ENFORCE*
 macros, typed error codes from error_codes.proto) and platform/errors.cc.
-TPU-native: plain python exceptions with the same taxonomy; stack traces come
+TPU-native: plain python exceptions in the same classes; stack traces come
 for free from python, XLA compile errors pass through annotated.
 """
 from __future__ import annotations

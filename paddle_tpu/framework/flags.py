@@ -30,10 +30,15 @@ def define_flag(name: str, default: Any, help_: str = ""):
 
 # Mirrors of the reference's commonly used flags (platform/flags.cc:33-565).
 define_flag("FLAGS_jit_cache_dir",
-            os.path.join("~", ".cache", "paddle_tpu", "xla"),
+            os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache"),
             "persistent XLA compilation cache directory; '' disables. "
             "Compiled executables are reused ACROSS processes, so the "
-            "second run of the same model skips XLA compilation entirely")
+            "second run of the same model skips XLA compilation entirely. "
+            "The default is one fixed directory beside the package (the "
+            "path is part of the cache key, so it must not move).  Where "
+            "JAX_COMPILATION_CACHE_DIR is set, jax keeps its cache there "
+            "and this flag sets no directory")
 define_flag("FLAGS_jit_cache_min_compile_secs", 0.5,
             "only persist executables whose compile took at least this "
             "long (0 caches everything)")
@@ -227,40 +232,38 @@ _jit_cache_dir_applied = None
 
 
 def apply_jit_cache(force: bool = False):
-    """Point jax's persistent compilation cache at FLAGS_jit_cache_dir.
+    """Turn on jax's persistent compilation cache.
 
     Called once at paddle_tpu import (and again from set_flags when the
     flag changes).  With the cache on, every process that compiles the
     same jitted step (same HLO, same backend) after the first reads the
-    executable from disk instead of re-running XLA — this is what takes
-    `decode_first_call_seconds` / fit's first-step compile from seconds
-    to milliseconds on the second run.  Returns the resolved directory,
-    or None when disabled/unavailable."""
+    executable from disk instead of re-running XLA.
+
+    The directory can be placed from outside: where the environment sets
+    JAX_COMPILATION_CACHE_DIR, jax itself reads it and no directory is set
+    in code.  Otherwise it is FLAGS_jit_cache_dir.  Returns the directory
+    in use, or None when the cache is off."""
     global _jit_cache_dir_applied
 
-    d = _REGISTRY.get("FLAGS_jit_cache_dir") or ""
-    d = os.path.expanduser(d) if d else ""
+    import jax
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or ""
+    d = env_dir or os.path.expanduser(_REGISTRY.get("FLAGS_jit_cache_dir")
+                                      or "")
     if not force and d == _jit_cache_dir_applied:
         return d or None
-    try:
-        import jax
-
-        if not d:
-            jax.config.update("jax_compilation_cache_dir", None)
-            _jit_cache_dir_applied = ""
-            return None
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
+    if not env_dir:
+        if d:
+            os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d or None)
+    if d:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs",
             float(_REGISTRY.get("FLAGS_jit_cache_min_compile_secs", 0.5)))
         # no size floor: tiny-but-slow-to-compile entries still count
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _jit_cache_dir_applied = d
-        return d
-    except Exception:  # noqa: BLE001 - cache is an optimization, never fatal
-        _jit_cache_dir_applied = None
-        return None
+    _jit_cache_dir_applied = d
+    return d or None
 
 
 def get_flags(keys):

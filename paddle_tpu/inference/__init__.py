@@ -23,12 +23,8 @@ import pickle
 import numpy as np
 
 import jax
+import jax.export  # noqa: F401 - a submodule jax does not import by itself
 import jax.numpy as jnp
-
-try:  # newer jax exposes jax.export lazily; older needs the submodule import
-    import jax.export  # noqa: F401
-except ImportError:  # pragma: no cover - very old jax
-    pass
 
 from ..framework.dtype import convert_dtype
 from ..tensor import Tensor

@@ -75,8 +75,8 @@ PEAK_BW = {
 
 def peak_bw_per_device(device=None) -> float:
     """HBM bytes/s for one device: FLAGS_device_peak_bw when set, else
-    the longest device-kind match in PEAK_BW, else the v4 figure
-    (mirrors telemetry.peak_flops_per_device)."""
+    the longest device-kind match in PEAK_BW; an unknown device kind
+    raises (mirrors telemetry.peak_flops_per_device)."""
     override = float(_flags.flag("FLAGS_device_peak_bw") or 0.0)
     if override > 0:
         return override
@@ -87,7 +87,9 @@ def peak_bw_per_device(device=None) -> float:
     for k, v in sorted(PEAK_BW.items(), key=lambda kv: -len(kv[0])):
         if k in kind:
             return v
-    return 1228e9
+    raise KeyError(
+        f"no peak bytes/s known for device_kind {kind!r}; add it to "
+        f"PEAK_BW or set FLAGS_device_peak_bw")
 
 
 # ---------------------------------------------------------------------------

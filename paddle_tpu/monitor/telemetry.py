@@ -64,8 +64,8 @@ PEAK_FLOPS = {
 
 def peak_flops_per_device(device=None) -> float:
     """Peak FLOP/s for one device: FLAGS_device_peak_flops when set,
-    else the longest device-kind match in PEAK_FLOPS, else the v4
-    figure (same default as bench.py)."""
+    else the longest device-kind match in PEAK_FLOPS.  A device kind the
+    table does not know raises: no other chip's figure stands in."""
     override = float(_flags.flag("FLAGS_device_peak_flops") or 0.0)
     if override > 0:
         return override
@@ -76,7 +76,9 @@ def peak_flops_per_device(device=None) -> float:
     for k, v in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
         if k in kind:
             return v
-    return 275e12
+    raise KeyError(
+        f"no peak FLOP/s known for device_kind {kind!r}; add it to "
+        f"PEAK_FLOPS or set FLAGS_device_peak_flops")
 
 
 def device_memory_stats(device=None):

@@ -23,16 +23,9 @@ from ..framework.flags import flag
 from ..tensor import Tensor, apply, unwrap
 
 
-@functools.lru_cache(maxsize=1)
-def _tpu_available() -> bool:
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
-
-
 def _use_pallas() -> bool:
-    return bool(flag("FLAGS_use_pallas_kernels")) and _tpu_available()
+    return bool(flag("FLAGS_use_pallas_kernels")) \
+        and jax.default_backend() != "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -69,30 +62,36 @@ def _note_fallback(kernel: str, reason: str):
             f"/metrics)", RuntimeWarning, stacklevel=3)
 
 
-def _fallback_reason(exc: Exception) -> str:
-    if isinstance(exc, NotImplementedError):
-        return "mask_shape" if "mask" in str(exc) else "shape"
-    return type(exc).__name__
+def _kernel_or_none(kernel: str, call):
+    """The result of `call`, one Pallas kernel dispatch, or None (counted)
+    when the kernel itself says from the shapes that it does not tile
+    this call.  Nothing else chooses the composite: an error from
+    tracing, lowering or compiling the kernel propagates."""
+    from .pallas import DoesNotTile
+
+    try:
+        return call()
+    except DoesNotTile as e:
+        _note_fallback(kernel, "mask_shape" if "mask" in str(e) else "shape")
+        return None
 
 
 def _mesh_axes():
     """(mesh, batch_axes, tp_axis) for kernel shard_map composition:
     batch axes are the >1-sized data axes ('dp'/'fsdp'), tp is the
     >1-sized head/column axis under either naming scheme — the models'
-    in-layer 'mp' pin or SpecLayout's 'tp'."""
-    try:
-        from ..distributed.mesh import get_mesh
+    in-layer 'mp' pin or SpecLayout's 'tp'.  The mesh is None only when
+    no ambient mesh spans more than one device: a Mosaic kernel cannot be
+    partitioned by GSPMD, so under any wider mesh EVERY kernel call goes
+    through shard_map, with the axes that do not divide it left out."""
+    from ..distributed.mesh import get_mesh
 
-        mesh = get_mesh()
-    except Exception:  # noqa: BLE001 - no distributed state, solo jit
-        return None, (), None
-    if mesh is None:
+    mesh = get_mesh()
+    if mesh is None or mesh.size <= 1:
         return None, (), None
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     batch = tuple(a for a in ("dp", "fsdp") if sizes.get(a, 1) > 1)
     tp = next((a for a in ("mp", "tp") if sizes.get(a, 1) > 1), None)
-    if not batch and tp is None:
-        return None, (), None
     return mesh, batch, tp
 
 
@@ -104,8 +103,41 @@ def _axes_size(mesh, axes) -> int:
     return n
 
 
-def _rows_divisible(dim: int, mesh, axes) -> bool:
-    return dim % _axes_size(mesh, axes) == 0
+def _dividing(dim: int, mesh, axes) -> tuple:
+    """The leading axes of `axes` whose joint size divides `dim`."""
+    while axes and dim % _axes_size(mesh, axes) != 0:
+        axes = axes[:-1]
+    return axes
+
+
+def _axes_entry(axes):
+    """A PartitionSpec entry for a tuple of mesh axes (None if empty)."""
+    return (axes if len(axes) > 1 else axes[0]) if axes else None
+
+
+def _rows_entry(x, mesh, batch):
+    """The PartitionSpec entry for x's leading (row) dim: the batch axes
+    that divide it; None for a vector, whose only dim is the feature dim."""
+    return _axes_entry(_dividing(x.shape[0], mesh, batch)) \
+        if x.ndim >= 2 else None
+
+
+def _axis_if_divides(dim: int, mesh, axis):
+    """`axis` when the mesh has it and its size divides `dim`, else None."""
+    return axis if axis is not None \
+        and dim % _axes_size(mesh, (axis,)) == 0 else None
+
+
+def _rows_sharded(kernel, mesh, batch, x, *replicated):
+    """`kernel(x, *replicated)` under shard_map: x's rows split over the
+    batch axes that divide them, every other operand whole."""
+    from jax.sharding import PartitionSpec as P
+
+    xspec = P(_rows_entry(x, mesh, batch), *([None] * (x.ndim - 1)))
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(xspec,) + tuple(P() for _ in replicated),
+        out_specs=xspec, check_vma=False)(x, *replicated)
 
 
 # ---------------------------------------------------------------------------
@@ -115,11 +147,20 @@ def layer_norm(x, weight, bias, epsilon=1e-5):
     if _use_pallas():
         from .pallas import layer_norm as pln
 
-        try:
-            return apply(lambda v, w, b: pln.layer_norm(v, w, b, epsilon),
-                         x, weight, bias)
-        except Exception as e:  # noqa: BLE001 - counted, then composite
-            _note_fallback("layer_norm", _fallback_reason(e))
+        mesh, batch, _ = _mesh_axes()
+
+        def kernel(v, w, b):
+            return pln.layer_norm(v, w, b, epsilon)
+
+        def pf(v, w, b):
+            if mesh is not None:
+                return _rows_sharded(kernel, mesh, batch, v, w, b)
+            return kernel(v, w, b)
+
+        out = _kernel_or_none("layer_norm",
+                              lambda: apply(pf, x, weight, bias))
+        if out is not None:
+            return out
 
     def f(v, w, b):
         mean = jnp.mean(v, axis=-1, keepdims=True)
@@ -146,30 +187,28 @@ def softmax_cross_entropy(logits, label, ignore_index=-100):
     if _use_pallas():
         from .pallas import softmax_xent as sx
 
-        try:
-            mesh, batch, _ = _mesh_axes()
+        mesh, batch, _ = _mesh_axes()
 
-            def pf(z, l):
-                if mesh is not None and batch and z.ndim >= 2 \
-                        and _rows_divisible(z.shape[0], mesh, batch):
-                    from jax.experimental.shard_map import shard_map
-                    from jax.sharding import PartitionSpec as P
+        def pf(z, l):
+            if mesh is not None:
+                from jax.sharding import PartitionSpec as P
 
-                    bspec = batch if len(batch) > 1 else batch[0]
-                    li = l if l.ndim == z.ndim - 1 else jnp.squeeze(l, -1)
-                    body = functools.partial(sx.softmax_xent,
-                                             ignore_index=ignore_index)
-                    return shard_map(
-                        body, mesh=mesh,
-                        in_specs=(P(bspec, *([None] * (z.ndim - 1))),
-                                  P(bspec, *([None] * (li.ndim - 1)))),
-                        out_specs=P(bspec, *([None] * (z.ndim - 2))),
-                        check_rep=False)(z, li)
-                return sx.softmax_xent(z, l, ignore_index=ignore_index)
+                lead = _rows_entry(z, mesh, batch)
+                li = l if l.ndim == z.ndim - 1 else jnp.squeeze(l, -1)
+                body = functools.partial(sx.softmax_xent,
+                                         ignore_index=ignore_index)
+                return jax.shard_map(
+                    body, mesh=mesh,
+                    in_specs=(P(lead, *([None] * (z.ndim - 1))),
+                              P(lead, *([None] * (li.ndim - 1)))),
+                    out_specs=P(lead, *([None] * (z.ndim - 2))),
+                    check_vma=False)(z, li)
+            return sx.softmax_xent(z, l, ignore_index=ignore_index)
 
-            return apply(pf, logits, label)
-        except Exception as e:  # noqa: BLE001 - counted, then composite
-            _note_fallback("softmax_xent", _fallback_reason(e))
+        out = _kernel_or_none("softmax_xent",
+                              lambda: apply(pf, logits, label))
+        if out is not None:
+            return out
 
     def f(z, l):
         li = l.astype(jnp.int32)
@@ -305,32 +344,28 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         else:
             from .pallas import flash_attention as fa
 
-            try:
-                mesh, batch, tp = _mesh_axes()
+            mesh, batch, tp = _mesh_axes()
 
-                def pf(q, k, v, *mask):
-                    m = mask[0] if mask else None
-                    # an ambient mesh whose axes don't divide this call's
-                    # geometry must not knock it off the kernel path: shed
-                    # non-dividing axes and keep the (replicated) kernel
-                    ba, hx = batch, tp
-                    while ba and q.shape[0] % _axes_size(mesh, ba) != 0:
-                        ba = ba[:-1]
-                    if hx is not None and \
-                            q.shape[2] % _axes_size(mesh, (hx,)) != 0:
-                        hx = None
-                    if mesh is not None and (ba or hx):
-                        return fa.sharded_flash_attention(
-                            q, k, v, mesh, head_axis=hx, batch_axes=ba,
-                            causal=is_causal, mask=m)
+            def pf(q, k, v, *mask):
+                m = mask[0] if mask else None
+                if mesh is None:
                     return fa.flash_attention(q, k, v, causal=is_causal,
                                               mask=m)
+                # an ambient mesh whose axes don't divide this call's
+                # geometry must not knock it off the kernel path: shed
+                # non-dividing axes and keep the (replicated) kernel
+                return fa.sharded_flash_attention(
+                    q, k, v, mesh,
+                    head_axis=_axis_if_divides(q.shape[2], mesh, tp),
+                    batch_axes=_dividing(q.shape[0], mesh, batch),
+                    causal=is_causal, mask=m)
 
-                args = (query, key, value) + (
-                    (attn_mask,) if attn_mask is not None else ())
-                return apply(pf, *args)
-            except Exception as e:  # noqa: BLE001 - counted, then composite
-                _note_fallback("flash_attention", _fallback_reason(e))
+            args = (query, key, value) + (
+                (attn_mask,) if attn_mask is not None else ())
+            out = _kernel_or_none("flash_attention",
+                                  lambda: apply(pf, *args))
+            if out is not None:
+                return out
 
     from ..framework import random as _random
 
@@ -371,8 +406,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap,
                            tp_axis=None):
     """Pallas paged decode attention over one layer's KV pool plane, or
-    None when the kernel can't run (the caller keeps its dense-gather
-    reference path and this shows up in the fallback counter).
+    None when the kernels are off or the kernel says it does not tile this
+    geometry (the caller keeps its dense-gather reference path and the
+    refusal shows up in the fallback counter).
 
     q [slots, 1, nh, hd] (the step's query, post-scatter); k_pages/v_pages
     [num_pages, page_size, nh, hd]; rows [slots, pages_per_slot] int32
@@ -384,23 +420,20 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap,
         return None
     from .pallas import paged_attention as pa
 
-    try:
-        def pf(qv, kp, vp, rw, ps_):
-            q1 = qv[:, 0]
-            mesh = None
-            if tp_axis is not None:
-                mesh, _, _ = _mesh_axes()
-            if mesh is not None and tp_axis in mesh.axis_names:
-                out = pa.sharded_paged_decode_attention(
-                    q1, kp, vp, rw, ps_, seq_cap, mesh, tp_axis)
-            else:
-                out = pa.paged_decode_attention(q1, kp, vp, rw, ps_, seq_cap)
-            return out[:, None]
+    mesh, _, _ = _mesh_axes()
 
-        return apply(pf, q, k_pages, v_pages, rows, pos)
-    except Exception as e:  # noqa: BLE001 - counted, then dense gather
-        _note_fallback("paged_attention", _fallback_reason(e))
-        return None
+    def pf(qv, kp, vp, rw, ps_):
+        q1 = qv[:, 0]
+        if mesh is not None:
+            out = pa.sharded_paged_decode_attention(
+                q1, kp, vp, rw, ps_, seq_cap, mesh,
+                tp_axis if tp_axis in mesh.axis_names else None)
+        else:
+            out = pa.paged_decode_attention(q1, kp, vp, rw, ps_, seq_cap)
+        return out[:, None]
+
+    return _kernel_or_none(
+        "paged_attention", lambda: apply(pf, q, k_pages, v_pages, rows, pos))
 
 
 # ---------------------------------------------------------------------------
@@ -412,23 +445,17 @@ def _sharded_bias_gelu(v, b, mesh, batch, tp):
     """Pallas bias_gelu under shard_map so GSPMD keeps the FFN activation
     sharded (rows over dp/fsdp, feature columns over mp/tp) instead of
     gathering it around an opaque custom call."""
-    from .pallas import bias_gelu as bg
-
-    if batch and (v.ndim < 2 or not _rows_divisible(v.shape[0], mesh, batch)):
-        batch = ()
-    if tp is not None and v.shape[-1] % _axes_size(mesh, (tp,)) != 0:
-        tp = None
-    if not batch and tp is None:
-        return bg.bias_gelu(v, b)
-
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    bspec = (batch if len(batch) > 1 else batch[0]) if batch else None
-    vspec = P(bspec, *([None] * (v.ndim - 2)), tp)
-    return shard_map(bg.bias_gelu, mesh=mesh,
-                     in_specs=(vspec, P(tp)), out_specs=vspec,
-                     check_rep=False)(v, b)
+    from .pallas import bias_gelu as bg
+
+    tp = _axis_if_divides(v.shape[-1], mesh, tp)
+    lead = [_rows_entry(v, mesh, batch)] + [None] * (v.ndim - 2) \
+        if v.ndim >= 2 else []
+    vspec = P(*lead, tp)
+    return jax.shard_map(bg.bias_gelu, mesh=mesh,
+                         in_specs=(vspec, P(tp)), out_specs=vspec,
+                         check_vma=False)(v, b)
 
 
 def _dropout(y, dropout_p, training):
@@ -451,17 +478,16 @@ def bias_gelu(x, bias, dropout_p=0.0, training=True):
     if _use_pallas():
         from .pallas import bias_gelu as bg
 
-        try:
-            mesh, batch, tp = _mesh_axes()
+        mesh, batch, tp = _mesh_axes()
 
-            def pf(v, b):
-                if mesh is not None:
-                    return _sharded_bias_gelu(v, b, mesh, batch, tp)
-                return bg.bias_gelu(v, b)
+        def pf(v, b):
+            if mesh is not None:
+                return _sharded_bias_gelu(v, b, mesh, batch, tp)
+            return bg.bias_gelu(v, b)
 
-            return _dropout(apply(pf, x, bias), dropout_p, training)
-        except Exception as e:  # noqa: BLE001 - counted, then composite
-            _note_fallback("bias_gelu", _fallback_reason(e))
+        out = _kernel_or_none("bias_gelu", lambda: apply(pf, x, bias))
+        if out is not None:
+            return _dropout(out, dropout_p, training)
     y = apply(lambda v, b: jax.nn.gelu(v + b.astype(v.dtype),
                                        approximate=False), x, bias)
     return _dropout(y, dropout_p, training)

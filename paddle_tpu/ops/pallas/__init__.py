@@ -3,12 +3,29 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as _pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; kernels
-# import the resolved class from here so they compile against either name.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")
+
+class DoesNotTile(Exception):
+    """A kernel's own statement, made from shapes before anything is traced
+    into a pallas_call, that it does not tile this call.  It is the one
+    thing that lets ops/fused.py take the XLA composite: whatever tracing,
+    lowering or compiling raises is something else and propagates."""
+
+
+# float32 elements per block of a row-tiled kernel: such a kernel keeps
+# several block-sized float32 temporaries live, and Mosaic's scoped VMEM
+# limit on a v5e is 16 MiB
+_BLOCK_ELEMS = 256 * 1024
+
+
+def pick_block_rows(r: int, n: int) -> int:
+    """Rows per block for a kernel tiling an [r, n] array by rows: the
+    largest power of two from 256 down to 8 that divides r and keeps the
+    block within _BLOCK_ELEMS (8 rows at any width); 0 if none divides."""
+    for cand in (256, 128, 64, 32, 16, 8):
+        if r % cand == 0 and (cand * n <= _BLOCK_ELEMS or cand == 8):
+            return cand
+    return 0
 
 
 def interpret_default() -> bool:

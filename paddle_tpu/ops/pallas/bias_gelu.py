@@ -10,7 +10,10 @@ to emit dy * gelu'(u) in a single pass (db is the row-sum of dx, left to
 XLA's reduction).
 
 GeLU is the exact erf form (matches nn.functional.gelu's default
-approximate=False).  All math in float32.  Dropout is NOT in-kernel: the
+approximate=False).  Mosaic has no lowering for `lax.erf`, so the kernel
+evaluates erf with XLA's own float32 rational polynomial (max abs error
+4.5e-7 against float64 erf).  All math in float32.  Dropout is NOT
+in-kernel: the
 wrapper in ops/fused.py threads the per-step rng and applies the keep-mask
 as XLA elementwise ops, which fuse into the surrounding matmul anyway.
 """
@@ -25,15 +28,38 @@ from jax.experimental import pallas as pl
 _INV_SQRT2 = 0.7071067811865476
 _INV_SQRT_2PI = 0.3989422804014327
 
-from . import im as _im, interpret_default as _interpret_default
+from . import (DoesNotTile, im as _im,
+               interpret_default as _interpret_default, pick_block_rows)
+
+
+# erf(x) ~= x * P(x^2) / Q(x^2) on [-4, 4] (|erf| is 1 to float32 beyond)
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04,
+          -2.95459980854025e-03, -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _erf_f32(x):
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    return x * _horner(x2, _ERF_P) / _horner(x2, _ERF_Q)
 
 
 def _gelu_f32(u):
-    return 0.5 * u * (1.0 + jax.lax.erf(u * _INV_SQRT2))
+    return 0.5 * u * (1.0 + _erf_f32(u * _INV_SQRT2))
 
 
 def _dgelu_f32(u):
-    cdf = 0.5 * (1.0 + jax.lax.erf(u * _INV_SQRT2))
+    cdf = 0.5 * (1.0 + _erf_f32(u * _INV_SQRT2))
     pdf = jnp.exp(-0.5 * u * u) * _INV_SQRT_2PI
     return cdf + u * pdf
 
@@ -49,16 +75,9 @@ def _bwd_kernel(x_ref, b_ref, dy_ref, dx_ref):
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def _pick_block_rows(r: int) -> int:
-    for cand in (256, 128, 64, 32, 16, 8):
-        if r % cand == 0:
-            return cand
-    return 0
-
-
 def _row_call(kernel, outs, x2d, b, extra, interpret):
     r, n = x2d.shape
-    block_r = _pick_block_rows(r)
+    block_r = pick_block_rows(r, n)
     row_spec = pl.BlockSpec((block_r, n), _im(lambda i: (i, 0)))
     vec_spec = pl.BlockSpec((n,), _im(lambda i: (0,)))
     return pl.pallas_call(
@@ -94,17 +113,17 @@ _bg.defvjp(_bg_fwd, _bg_bwd)
 def bias_gelu(x, bias, interpret: bool | None = None):
     """gelu(x + bias) over the last dim; any leading shape.
 
-    x [..., F], bias [F].  Raises NotImplementedError for rows not
+    x [..., F], bias [F].  Raises DoesNotTile for rows not
     tileable to 8 sublanes (caller falls back to XLA).
     """
     n = x.shape[-1]
     if bias.shape != (n,):
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"bias_gelu: bias {bias.shape} must be 1D of size {n}")
     lead = x.shape[:-1]
     x2d = x.reshape(-1, n)
-    if _pick_block_rows(x2d.shape[0]) == 0:
-        raise NotImplementedError(
+    if pick_block_rows(x2d.shape[0], n) == 0:
+        raise DoesNotTile(
             f"bias_gelu: rows {x2d.shape[0]} not divisible by 8")
     if interpret is None:
         interpret = _interpret_default()

@@ -30,8 +30,8 @@ mesh's head (tp/mp) and batch (dp/fsdp) axes so GSPMD runs one kernel per
 shard with the LOCAL head count — attention has no cross-head or
 cross-batch reduction, so no collectives are needed inside the body.
 
-Falls back (by raising) to the XLA softmax path in ops/fused.py when shapes
-don't tile (seq not divisible by block) — the caller catches.
+Raises DoesNotTile when shapes don't tile (seq not divisible by block);
+ops/fused.py then takes the XLA softmax path and counts it.
 """
 from __future__ import annotations
 
@@ -47,7 +47,7 @@ DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
 
 
-from . import (CompilerParams as _CompilerParams, im as _im,
+from . import (DoesNotTile, im as _im,
                interpret_default as _interpret_default)
 
 
@@ -171,7 +171,7 @@ def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -295,7 +295,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, i, 0))),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*dq_operands)
@@ -327,7 +327,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*dkv_operands)
@@ -397,13 +397,13 @@ def _fold_mask(mask, b, h, s_q, s_k):
     while m.ndim < 4:
         m = m[None]
     if m.ndim != 4:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"flash_attention: mask rank {mask.ndim} unsupported")
     hm = h if m.shape[1] != 1 else 1
     try:
         m = jnp.broadcast_to(m, (b, hm, s_q, s_k))
     except ValueError:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"flash_attention: mask shape {mask.shape} does not broadcast "
             f"to ({b}, {h}, {s_q}, {s_k})")
     return m.reshape(b * hm, s_q, s_k)
@@ -417,7 +417,7 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
 
     ``mask`` is a bool (True = attend) or additive mask broadcastable to
     [B, H, S_q, S_k], composable with ``causal``.  Raises
-    NotImplementedError for shapes the kernel doesn't tile (caller falls
+    DoesNotTile for shapes the kernel doesn't tile (caller falls
     back to the XLA path).
     """
     b, s_q, h, d = q.shape
@@ -425,13 +425,13 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     if s_q % block_q or s_k % block_k:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"flash_attention: seq ({s_q},{s_k}) not divisible by blocks "
             f"({block_q},{block_k})")
     if min(block_q, block_k) < 8:
-        raise NotImplementedError("flash_attention: sequence too short")
+        raise DoesNotTile("flash_attention: sequence too short")
     if k.shape[2] != h:
-        raise NotImplementedError("flash_attention: GQA head mismatch")
+        raise DoesNotTile("flash_attention: GQA head mismatch")
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:
@@ -464,8 +464,7 @@ def sharded_flash_attention(q, k, v, mesh, head_axis=None, batch_axes=(),
     head-dim blocking inside each shard sees the LOCAL (sharded) head
     count, so `mesh3d` runs the kernel rather than falling back to one
     replicated call.  Axes absent from the mesh or not dividing the
-    operand raise NotImplementedError (caller falls back)."""
-    from jax.experimental.shard_map import shard_map
+    operand raise DoesNotTile (caller falls back)."""
     from jax.sharding import PartitionSpec as P
 
     b, s_q, h, d = q.shape
@@ -480,13 +479,9 @@ def sharded_flash_attention(q, k, v, mesh, head_axis=None, batch_axes=(),
     for a in batch_axes:
         nb *= sizes[a]
     if h % tp or b % nb:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"sharded flash_attention: heads {h} % tp {tp} or batch {b} % "
             f"dp {nb} != 0")
-    if not batch_axes and head_axis is None:
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               block_q=block_q, block_k=block_k,
-                               interpret=interpret, mask=mask)
     bspec = tuple(batch_axes) if len(batch_axes) > 1 else \
         (batch_axes[0] if batch_axes else None)
     qkv_spec = P(bspec, None, head_axis, None)
@@ -510,6 +505,6 @@ def sharded_flash_attention(q, k, v, mesh, head_axis=None, batch_axes=(),
                                interpret=interpret,
                                mask=rest[0] if rest else None)
 
-    f = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                  out_specs=qkv_spec, check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                      out_specs=qkv_spec, check_vma=False)
     return f(*operands)

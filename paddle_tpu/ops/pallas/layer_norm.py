@@ -14,8 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-from . import im as _im, interpret_default as _interpret_default
+from . import (DoesNotTile, im as _im,
+               interpret_default as _interpret_default, pick_block_rows)
 
 
 def _ln_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
@@ -30,19 +30,9 @@ def _ln_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
     rstd_ref[...] = jnp.broadcast_to(rstd, rstd_ref.shape)
 
 
-def _pick_block_rows(r: int) -> int:
-    for cand in (256, 128, 64, 32, 16, 8):
-        if r % cand == 0:
-            return cand
-    return 0
-
-
 def _ln_fwd_call(x2d, w, b, eps, interpret):
     r, n = x2d.shape
-    block_r = _pick_block_rows(r)
-    if block_r == 0:
-        raise NotImplementedError(f"layer_norm rows {r} not divisible by 8")
-
+    block_r = pick_block_rows(r, n)
     y, mean, rstd = pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
         grid=(r // block_r,),
@@ -99,10 +89,13 @@ def layer_norm(x, weight, bias, epsilon=1e-5, interpret: bool | None = None):
     """LN over the last dim; any leading shape."""
     n = x.shape[-1]
     if weight.shape != (n,) or bias is None or bias.shape != (n,):
-        raise NotImplementedError("pallas layer_norm needs 1D scale+shift")
+        raise DoesNotTile("pallas layer_norm needs 1D scale+shift")
     if interpret is None:
         interpret = _interpret_default()
     lead = x.shape[:-1]
     x2d = x.reshape(-1, n)
+    if pick_block_rows(x2d.shape[0], n) == 0:
+        raise DoesNotTile(
+            f"layer_norm rows {x2d.shape[0]} not divisible by 8")
     y = _ln(x2d, weight, bias, float(epsilon), interpret)
     return y.reshape(*lead, n)

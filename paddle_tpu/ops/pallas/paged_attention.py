@@ -38,7 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-from . import interpret_default as _interpret_default
+from . import DoesNotTile, interpret_default as _interpret_default
 
 
 def _kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -58,27 +58,25 @@ def _kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _body():
+        # One query token per head: the score and value products run on
+        # the VPU as multiply + reduce over the page's own [ps, nh, hd]
+        # layout.  Mosaic's matmul wants the batch (head) dimension leading
+        # in both operands, and the page has it second.
         q = q_ref[0].astype(jnp.float32) * sm_scale      # [nh, hd]
         k = k_ref[0].astype(jnp.float32)                 # [ps, nh, hd]
-        # per-head q . k over hd: batch nh, contract hd -> [nh, ps]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        s = jnp.sum(q[None] * k, axis=-1, keepdims=True)  # [ps, nh, 1]
         tok = p_idx * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, s.shape, 0)
         s = jnp.where(tok <= pos, s, _NEG_INF)
 
-        m_prev = m_ref[:, :1]
+        m_prev = m_ref[:, :1]                            # [nh, 1]
         l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                           # [nh, ps]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])                     # [ps, nh, 1]
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = alpha * l_prev + jnp.sum(p, axis=0)
         v = v_ref[0].astype(jnp.float32)                 # [ps, nh, hd]
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)          # [nh, hd]
+        pv = jnp.sum(p * v, axis=0)                      # [nh, hd]
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -100,22 +98,22 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
     table (-1 = unmapped); pos: [slots] int32 attention extent per lane
     (inclusive); seq_cap: STATIC max extent — only ceil(seq_cap /
     page_size) table columns are walked.  Returns [slots, nh, hd] in
-    q's dtype.  Raises NotImplementedError for untileable geometry
+    q's dtype.  Raises DoesNotTile for untileable geometry
     (caller falls back to the dense gather).
     """
     slots, nh, hd = q.shape
     num_pages, ps = k_pages.shape[0], k_pages.shape[1]
     if k_pages.shape[2] != nh or k_pages.shape[3] != hd:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"paged_decode_attention: pool heads {k_pages.shape[2:]} != "
             f"query heads ({nh}, {hd})")
     pages_walked = -(-int(seq_cap) // ps)
     if pages_walked > rows.shape[1]:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"paged_decode_attention: seq_cap {seq_cap} needs "
             f"{pages_walked} pages > table width {rows.shape[1]}")
     if ps < 8:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"paged_decode_attention: page_size {ps} < 8 sublanes")
     if sm_scale is None:
         sm_scale = 1.0 / (hd ** 0.5)
@@ -166,18 +164,15 @@ def sharded_paged_decode_attention(q, k_pages, v_pages, rows, pos,
     pin), the page table and positions are replicated, and each shard
     runs the kernel on its LOCAL heads — decode attention has no
     cross-head reduction, so no collectives are needed."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     nh = q.shape[1]
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     tp = sizes.get(head_axis, 1)
     if tp <= 1:
-        return paged_decode_attention(q, k_pages, v_pages, rows, pos,
-                                      seq_cap, sm_scale=sm_scale,
-                                      interpret=interpret)
+        head_axis = None     # whole heads on every device of the mesh
     if nh % tp:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"sharded paged_decode_attention: heads {nh} % tp {tp} != 0")
 
     def body(ql, kl, vl, rl, pl_):
@@ -185,11 +180,11 @@ def sharded_paged_decode_attention(q, k_pages, v_pages, rows, pos,
                                       sm_scale=sm_scale,
                                       interpret=interpret)
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, head_axis, None),
                   P(None, None, head_axis, None),
                   P(None, None, head_axis, None),
                   P(None, None), P(None)),
-        out_specs=P(None, head_axis, None), check_rep=False)
+        out_specs=P(None, head_axis, None), check_vma=False)
     return f(q, k_pages, v_pages, rows, pos)

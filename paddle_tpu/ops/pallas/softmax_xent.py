@@ -29,7 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-from . import (CompilerParams as _CompilerParams, im as _im,
+from . import (DoesNotTile, im as _im,
                interpret_default as _interpret_default)
 
 
@@ -44,7 +44,7 @@ def _fwd_kernel(z_ref, lab_ref, loss_ref, lse_ref, m_ref, l_ref, pick_ref,
         pick_ref[...] = jnp.zeros_like(pick_ref)
 
     z = z_ref[...].astype(jnp.float32)                 # [br, bc]
-    lab = lab_ref[...]                                 # [br] int32
+    lab = lab_ref[:, :1]                               # [br, 1] int32
     col = c_idx * block_c + jax.lax.broadcasted_iota(
         jnp.int32, z.shape, 1)
     m_prev = m_ref[:, :1]
@@ -55,7 +55,7 @@ def _fwd_kernel(z_ref, lab_ref, loss_ref, lse_ref, m_ref, l_ref, pick_ref,
         jnp.sum(jnp.exp(z - m_new), axis=-1, keepdims=True)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-    picked = jnp.sum(jnp.where(col == lab[:, None], z, 0.0),
+    picked = jnp.sum(jnp.where(col == lab, z, 0.0),
                      axis=-1, keepdims=True)
     pick_ref[...] += jnp.broadcast_to(picked, pick_ref.shape)
 
@@ -63,7 +63,7 @@ def _fwd_kernel(z_ref, lab_ref, loss_ref, lse_ref, m_ref, l_ref, pick_ref,
     def _finish():
         lse = m_ref[:, :1] + jnp.log(l_ref[:, :1])
         loss = lse - pick_ref[:, :1]
-        loss = jnp.where((lab == ignore_index)[:, None], 0.0, loss)
+        loss = jnp.where(lab == ignore_index, 0.0, loss)
         loss_ref[...] = jnp.broadcast_to(loss, loss_ref.shape)
         lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
 
@@ -72,15 +72,15 @@ def _bwd_kernel(z_ref, lab_ref, lse_ref, g_ref, dz_ref, *, block_c,
                 ignore_index):
     c_idx = pl.program_id(1)
     z = z_ref[...].astype(jnp.float32)
-    lab = lab_ref[...]
+    lab = lab_ref[:, :1]
     lse = lse_ref[:, :1]
     g = g_ref[:, :1]
     col = c_idx * block_c + jax.lax.broadcasted_iota(
         jnp.int32, z.shape, 1)
     p = jnp.exp(z - lse)
-    onehot = (col == lab[:, None]).astype(jnp.float32)
+    onehot = (col == lab).astype(jnp.float32)
     dz = (p - onehot) * g
-    dz = jnp.where((lab == ignore_index)[:, None], 0.0, dz)
+    dz = jnp.where(lab == ignore_index, 0.0, dz)
     dz_ref[...] = dz.astype(dz_ref.dtype)
 
 
@@ -89,6 +89,12 @@ def _pick_block(n: int, cands) -> int:
         if n % c == 0:
             return c
     return 0
+
+
+def _lanes(col):
+    """A per-row vector as a lane-replicated [n, 128] operand: Mosaic has
+    no layout for a 1-D block cast to a column inside the kernel."""
+    return jnp.broadcast_to(col[:, None], (col.shape[0], 128))
 
 
 def _fwd_call(z, lab, ignore_index, interpret):
@@ -102,7 +108,7 @@ def _fwd_call(z, lab, ignore_index, interpret):
         grid=(num_r, num_c),
         in_specs=[
             pl.BlockSpec((block_r, block_c), _im(lambda i, j: (i, j))),
-            pl.BlockSpec((block_r,), _im(lambda i, j: (i,))),
+            pl.BlockSpec((block_r, 128), _im(lambda i, j: (i, 0))),
         ],
         out_specs=[
             pl.BlockSpec((block_r, 128), _im(lambda i, j: (i, 0))),
@@ -117,10 +123,10 @@ def _fwd_call(z, lab, ignore_index, interpret):
             pltpu.VMEM((block_r, 128), jnp.float32),
             pltpu.VMEM((block_r, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(z, lab)
+    )(z, _lanes(lab))
     return loss[:, 0], lse[:, 0]
 
 
@@ -128,24 +134,24 @@ def _bwd_call(z, lab, lse, g, ignore_index, interpret):
     n, v = z.shape
     block_r = _pick_block(n, (128, 64, 32, 16, 8))
     block_c = _pick_block(v, (1024, 512, 256, 128))
-    lse_r = jnp.broadcast_to(lse[:, None], (n, 128))
-    g_r = jnp.broadcast_to(g.astype(jnp.float32)[:, None], (n, 128))
+    lse_r = _lanes(lse)
+    g_r = _lanes(g.astype(jnp.float32))
     dz = pl.pallas_call(
         functools.partial(_bwd_kernel, block_c=block_c,
                           ignore_index=ignore_index),
         grid=(n // block_r, v // block_c),
         in_specs=[
             pl.BlockSpec((block_r, block_c), _im(lambda i, j: (i, j))),
-            pl.BlockSpec((block_r,), _im(lambda i, j: (i,))),
+            pl.BlockSpec((block_r, 128), _im(lambda i, j: (i, 0))),
             pl.BlockSpec((block_r, 128), _im(lambda i, j: (i, 0))),
             pl.BlockSpec((block_r, 128), _im(lambda i, j: (i, 0))),
         ],
         out_specs=pl.BlockSpec((block_r, block_c), _im(lambda i, j: (i, j))),
         out_shape=jax.ShapeDtypeStruct((n, v), z.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(z, lab, lse_r, g_r)
+    )(z, _lanes(lab), lse_r, g_r)
     return dz
 
 
@@ -175,7 +181,7 @@ def softmax_xent(logits, labels, ignore_index: int = -100,
 
     logits [..., V]; labels int [...] (a trailing size-1 axis is
     squeezed).  Returns per-token loss with logits' leading shape, in
-    logits' dtype.  Raises NotImplementedError for geometry the kernel
+    logits' dtype.  Raises DoesNotTile for geometry the kernel
     can't tile even after padding (caller falls back to XLA).
     """
     v = logits.shape[-1]
@@ -183,7 +189,7 @@ def softmax_xent(logits, labels, ignore_index: int = -100,
     if labels.ndim == logits.ndim:
         labels = jnp.squeeze(labels, -1)
     if labels.shape != lead:
-        raise NotImplementedError(
+        raise DoesNotTile(
             f"softmax_xent: labels {labels.shape} vs logits lead {lead}")
     if interpret is None:
         interpret = _interpret_default()

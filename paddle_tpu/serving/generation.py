@@ -362,12 +362,18 @@ class GenerationEngine:
                 draft_layers=dcfg.num_layers,
                 draft_num_heads=dcfg.num_heads,
                 draft_head_dim=dcfg.hidden_size // dcfg.num_heads)
+        # the KV pool holds what the model computes: bf16 weights, bf16 pool
+        import jax.numpy as jnp
+
+        kv_dtype = next((str(p.dtype) for p in model.parameters()
+                         if jnp.issubdtype(p.dtype, jnp.floating)),
+                        "float32")
         self.geometry = CacheGeometry(
             num_layers=cfg.num_layers, max_slots=self.max_slots,
             max_seq_len=self.max_seq_len, num_heads=cfg.num_heads,
             head_dim=cfg.hidden_size // cfg.num_heads,
             vocab_size=cfg.vocab_size, page_size=page_size,
-            num_pages=int(num_pages), **draft_kw)
+            num_pages=int(num_pages), dtype=kv_dtype, **draft_kw)
         self.metrics = GenerationMetrics(
             max_slots=self.max_slots, num_pages=self.geometry.num_pages)
         self._prefix = (PrefixCache(page_size) if prefix_cache else None)
@@ -805,7 +811,6 @@ class GenerationEngine:
         f32 = sds((), np.float32)
         b1 = sds((), np.bool_)
         pvec = sds((pps,), np.int32)
-        kv_dt = np.dtype(geometry.dtype)
         out_state = state_sh if mesh is not None else None
 
         def outs(*tail):
@@ -839,19 +844,16 @@ class GenerationEngine:
             dpre = (dpspec,) if draft is not None else ()
             for sp in self.prompt_buckets:
                 ids = sds((1, sp), np.int32)
-                kv = sds((geom.num_layers, sp, geom.num_heads,
-                          geom.head_dim), kv_dt, kv_sh)
-                lg = sds((V,), np.float32)
-                dkv_in = ()
-                if draft is not None:
-                    dkv = sds((geom.draft_layers, sp,
-                               geom.draft_num_heads,
-                               geom.draft_head_dim), kv_dt)
-                    dkv_in = (dkv, dkv)
                 self._prefill_execs[sp] = inference.aot_compile(
                     prefill_step, (pspec,) + dpre + (ids, i32),
                     out_shardings=(kv_sh, kv_sh, rep)
                     if mesh is not None else None)
+                # insert takes what prefill gives, in the model's own
+                # dtypes (bf16 weights hand over bf16 K/V and logits)
+                pre = self._prefill_execs[sp].out_info
+                kv = sds(pre[0].shape, pre[0].dtype, kv_sh)
+                lg = sds(pre[2].shape, pre[2].dtype)
+                dkv_in = tuple(sds(a.shape, a.dtype) for a in pre[3:])
                 self._insert_execs[sp] = inference.aot_compile(
                     insert_step,
                     (sspec, i32, kv, kv, lg, i32, i32, i32, b1, f32, i32,

@@ -2,7 +2,8 @@
 reference + gradient comparison, SURVEY.md §4 op unit tests).
 
 On CPU the kernels run in pallas interpret mode; the same code compiles via
-Mosaic on TPU (validated by bench/driver runs)."""
+Mosaic on TPU (tests/test_mosaic_compile.py compiles them for a described
+v5e; chip_smoke.py runs them on one)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +11,7 @@ import pytest
 
 import paddle_tpu as paddle
 
+from paddle_tpu.ops.pallas import DoesNotTile
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.ops.pallas.layer_norm import layer_norm
 
@@ -56,7 +58,7 @@ def test_flash_attention_jit_and_bf16():
 
 def test_flash_attention_fallback_shapes():
     q = jnp.zeros((1, 129, 2, 64))  # 129 % 128 != 0
-    with pytest.raises(NotImplementedError):
+    with pytest.raises(DoesNotTile):
         flash_attention(q, q, q)
 
 
@@ -83,7 +85,7 @@ def test_layer_norm_fwd_bwd():
 
 
 def test_fused_op_dispatch_falls_back_cleanly(monkeypatch):
-    """ops.fused attempts pallas, hits NotImplementedError on an untileable
+    """ops.fused attempts pallas, hits DoesNotTile on an untileable
     shape, and falls back to the XLA path with a correct result."""
     import paddle_tpu as paddle
     from paddle_tpu.ops import fused
@@ -245,7 +247,7 @@ def test_flash_attention_mask_shapes_and_fallback():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
     # non-broadcastable mask raises (dispatch falls back, counted)
-    with pytest.raises(NotImplementedError):
+    with pytest.raises(DoesNotTile):
         flash_attention(q, k, v, mask=jnp.zeros((3, 1, 128, 128)))
 
 
@@ -286,7 +288,7 @@ def test_sharded_flash_attention_tp2_parity():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
     # heads not divisible by tp -> clean refusal for the dispatch gate
-    with pytest.raises(NotImplementedError):
+    with pytest.raises(DoesNotTile):
         sharded_flash_attention(q[:, :, :1], k[:, :, :1], v[:, :, :1],
                                 mesh, head_axis="tp")
 
@@ -406,9 +408,9 @@ def test_paged_decode_attention_refusals():
     kp = jnp.zeros((4, 8, 2, 16))
     rows = jnp.zeros((2, 2), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
-    with pytest.raises(NotImplementedError):  # table too narrow
+    with pytest.raises(DoesNotTile):  # table too narrow
         paged_decode_attention(q, kp, kp, rows, pos, seq_cap=64)
-    with pytest.raises(NotImplementedError):  # head mismatch
+    with pytest.raises(DoesNotTile):  # head mismatch
         paged_decode_attention(q, kp[:, :, :1], kp[:, :, :1], rows, pos, 16)
 
 
@@ -545,7 +547,7 @@ def test_bias_gelu_fwd_bwd_parity():
     for a, bb in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                    rtol=1e-4, atol=1e-4)
-    with pytest.raises(NotImplementedError):  # rows % 8 != 0 -> dispatch
+    with pytest.raises(DoesNotTile):  # rows % 8 != 0 -> dispatch
         bias_gelu(jnp.zeros((7, 256)), jnp.zeros((256,)))
 
 

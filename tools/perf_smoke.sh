@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
-# Perf smoke: proves the persistent XLA compilation cache
-# (FLAGS_jit_cache_dir) works process-over-process, then runs the
-# perf-marked pytest suite.
+# Perf smoke: proves the persistent XLA compilation cache, placed from
+# outside with JAX_COMPILATION_CACHE_DIR, works process-over-process, then
+# runs the perf-marked pytest suite.
 #
 # Runs the bert and ernie CPU smoke benches TWICE each in fresh
 # processes against a fresh cache directory and asserts the second
@@ -17,10 +17,13 @@ if [ "${PADDLE_SKIP_LINT:-0}" != "1" ]; then
 fi
 
 export JAX_PLATFORMS=cpu
-CACHE_DIR="$(mktemp -d /tmp/paddle_perf_cache.XXXXXX)"
+# one fixed directory inside the checkout (.gitignore lists .jax_cache/),
+# emptied so that each first run below compiles
+CACHE_DIR="$PWD/.jax_cache/perf_smoke"
+rm -rf "$CACHE_DIR"
 OUT_DIR="$(mktemp -d /tmp/paddle_perf_out.XXXXXX)"
 trap 'rm -rf "$CACHE_DIR" "$OUT_DIR"' EXIT
-export FLAGS_JIT_CACHE_DIR="$CACHE_DIR"       # flags.py env override
+export JAX_COMPILATION_CACHE_DIR="$CACHE_DIR"
 export FLAGS_JIT_CACHE_MIN_COMPILE_SECS=0     # cache every executable
 
 compile_seconds() {  # run one bench config, print its compile_seconds
