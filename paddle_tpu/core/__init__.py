@@ -196,15 +196,22 @@ def stat_list() -> dict:
 # ---------------------------------------------------------------------------
 # Profiler events (host scopes; complements jax.profiler device traces)
 # ---------------------------------------------------------------------------
+_profiler_on = False    # mirrors the native flag, which only we set
+
+
 def profiler_enable(on: bool = True) -> None:
+    global _profiler_on
     lib = _load()
     if lib:
         lib.pt_profiler_enable(1 if on else 0)
+        _profiler_on = bool(on)
 
 
 def profiler_enabled() -> bool:
-    lib = _load()
-    return bool(lib and lib.pt_profiler_enabled())
+    """Read from Python: every RecordEvent asks, and a ctypes call would
+    release the GIL each time, handing the hot loop's interpreter to
+    whichever thread waits for it."""
+    return _profiler_on
 
 
 def event_push(name: str) -> None:

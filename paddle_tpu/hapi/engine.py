@@ -62,6 +62,7 @@ from ..framework.transfer import (fetch_floats, host_fetch, in_host_fetch,
                                   shard_batch)
 from ..nn.layer_base import functional_call
 from ..tensor import Tensor
+from ..utils.profiler import StepTimers
 
 __all__ = ["TrainEngine", "build_pure_train_step", "host_fetch",
            "in_host_fetch", "fetch_floats", "resolve_mesh", "mesh_meta"]
@@ -231,6 +232,9 @@ class TrainEngine:
         self._recompute = None
         self._accum = 1
         self.batch_axes = "dp"  # str or tuple — shard_batch's split axes
+        # phases of step(); Model.fit hands in its own recorder, whose
+        # `dispatch` scope they then run under
+        self.timers = StepTimers()
 
     @property
     def active(self):
@@ -622,34 +626,42 @@ class TrainEngine:
         """Dispatch one donated train step WITHOUT syncing.  The loss
         lands in the ring; returns the (device-resident) model outputs
         for metric computation."""
+        scope = self.timers.scope
         opt = self.model._optimizer
-        lr = opt.get_lr()
-        if lr != self._lr_host:
-            # host-side LRScheduler advanced: refresh the device scalar
-            # (an async host→device upload, not a sync)
-            self._lr_host = lr
-            new_lr = jnp.asarray(lr, jnp.float32)
-            if self._state_sharding is not None:
-                new_lr = jax.device_put(new_lr, self._state_sharding["lr"])
-            self.state["lr"] = new_lr
-        rng = _random.split_key()
+        with scope("dispatch/lr"):
+            lr = opt.get_lr()
+            if lr != self._lr_host:
+                # host-side LRScheduler advanced: refresh the device
+                # scalar (an async host→device upload, not a sync)
+                self._lr_host = lr
+                new_lr = jnp.asarray(lr, jnp.float32)
+                if self._state_sharding is not None:
+                    new_lr = jax.device_put(new_lr,
+                                            self._state_sharding["lr"])
+                self.state["lr"] = new_lr
+        with scope("dispatch/rng"):
+            rng = _random.split_key()
         if self.mesh is not None:
             # the DataLoader prefetch thread normally pre-shards batches
             # (io.DataLoader.placement); this is the idempotent fallback
             # for direct engine callers and odd-sized tail batches
             # (device_put onto the sharding an array already has is free)
-            inputs = shard_batch(inputs, self.mesh, axis=self.batch_axes)
-            labels = shard_batch(labels, self.mesh, axis=self.batch_axes)
+            with scope("dispatch/shard"):
+                inputs = shard_batch(inputs, self.mesh,
+                                     axis=self.batch_axes)
+                labels = shard_batch(labels, self.mesh,
+                                     axis=self.batch_axes)
             from ..distributed.mesh import mesh_guard
 
             # ambient mesh during trace/dispatch so in-model
             # shard_constraint / eager collectives resolve axis names
-            with mesh_guard(self.mesh):
+            with scope("dispatch/call"), mesh_guard(self.mesh):
                 self.state, loss_val, outs = self._step_fn(
                     self.state, rng, inputs, labels)
         else:
-            self.state, loss_val, outs = self._step_fn(self.state, rng,
-                                                       inputs, labels)
+            with scope("dispatch/call"):
+                self.state, loss_val, outs = self._step_fn(
+                    self.state, rng, inputs, labels)
         self.ring.append(loss_val)
         self._host_step += 1
         opt._step_count = self._host_step  # host mirror of state["step"]

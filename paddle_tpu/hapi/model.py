@@ -635,7 +635,12 @@ class Model:
             loader.placement = _partial(shard_batch, mesh=engine.mesh,
                                         axis=engine.batch_axes)
         eager_sync = user_cbs or bool(self._metrics)
-        timers = StepTimers()
+        # the loop's phases: top level data, prepare, dispatch, sync,
+        # metrics, callbacks, ckpt, write_back, eval; nothing between
+        # two `data` scopes is unnamed, so the totals sum to fit's wall
+        # time.  engine.step() records dispatch/lr, /rng, /shard, /call
+        # under `dispatch` on the same recorder.
+        timers = engine.timers = StepTimers()
         self._last_fit_timers = timers
         _END = object()
 
@@ -655,19 +660,21 @@ class Model:
         try:
             cbks.on_train_begin({})
             for epoch in range(epochs):
-                self.network.train()
-                for m in self._metrics:
-                    m.reset()
-                cbks.on_epoch_begin(epoch, {})
-                if _fit_span is not None:
-                    _epoch_span = _fit_span.child("train.epoch",
-                                                  epoch=epoch)
-                # fold user writes to Layer params/buffers (epoch-end
-                # callbacks: SWA/EMA write-back, re-init, pruning) back
-                # into the device-resident state
-                engine.refresh_from_layers()
-                losses = []
-                data_iter = iter(loader)
+                with timers.scope("prepare"):
+                    self.network.train()
+                    for m in self._metrics:
+                        m.reset()
+                    cbks.on_epoch_begin(epoch, {})
+                    if _fit_span is not None:
+                        _epoch_span = _fit_span.child("train.epoch",
+                                                      epoch=epoch)
+                    # fold user writes to Layer params/buffers (epoch-end
+                    # callbacks: SWA/EMA write-back, re-init, pruning)
+                    # back into the device-resident state
+                    engine.refresh_from_layers()
+                    losses = []
+                with timers.scope("data"):
+                    data_iter = iter(loader)
                 step_i = -1
                 while True:
                     with timers.scope("data"):
@@ -681,54 +688,63 @@ class Model:
                         # aligned with the uninterrupted run.  A SIGTERM
                         # here exits immediately — nothing new to save,
                         # the restored checkpoint is still the newest
-                        if guard is not None and guard.preempted:
-                            _flightrec.dump("preempt")
-                            raise SystemExit(_res.PREEMPTED_EXIT_CODE)
-                        _random.split_key()
-                        it_count += 1
-                        if telem is not None:
-                            # fast-forwarded batches dispatched nothing:
-                            # they must not count into a step window
-                            _win_t0 = time.perf_counter()
-                            _win_it0 = it_count
+                        with timers.scope("prepare"):
+                            if guard is not None and guard.preempted:
+                                _flightrec.dump("preempt")
+                                raise SystemExit(_res.PREEMPTED_EXIT_CODE)
+                            _random.split_key()
+                            it_count += 1
+                            if telem is not None:
+                                # fast-forwarded batches dispatched
+                                # nothing: they must not count into a
+                                # step window
+                                _win_t0 = time.perf_counter()
+                                _win_it0 = it_count
                         continue
-                    if telem is not None:
-                        # start/advance/stop an armed jax.profiler capture
-                        # — on the training thread, at a step boundary
-                        telem.poll_trace()
-                    cbks.on_train_batch_begin(step_i, {})
-                    if ft_mgr is not None:
-                        # fault-injection hook (crash/preempt/slow) so the
-                        # fit() recovery paths are chaos-testable too
-                        _chaos.on_step(it_count + 1)
-                    elif pod is not None:
-                        _pod_chaos.on_step(it_count + 1)
-                    batch = _to_list(batch)
-                    inputs, labels = self._split_batch(batch)
-                    inputs = [_as_tensor(x) for x in inputs]
-                    labels = [_as_tensor(x) for x in labels]
-                    if pod is not None:
-                        # every rank holds the FULL global batch; the pod
-                        # runtime strides it over the live membership (and
-                        # re-strides on replay after a shrink)
-                        _pod_raw = (inputs, labels)
-                        inputs = pod.stride(inputs)
-                        labels = pod.stride(labels)
-                    if user_cbs:
-                        # per-batch weight mutations (WGAN-style clipping
-                        # callbacks) only possible with user callbacks —
-                        # identity-scan for them before dispatching
-                        engine.refresh_from_layers()
-                    if telem is not None:
-                        # idempotent anchor so the FIRST interval (the
-                        # one containing the compile) is measured too
-                        telem.mark_start()
-                    _sp_step = (_epoch_span.child("train.step",
-                                                  step=it_count + 1)
-                                if _epoch_span is not None else None)
-                    if pod is not None:
-                        # in-memory rollback point for a mid-step shrink
-                        pod.before_step(engine, it_count)
+                    with timers.scope("prepare"):
+                        if telem is not None:
+                            # start/advance/stop an armed jax.profiler
+                            # capture — on the training thread, at a
+                            # step boundary
+                            telem.poll_trace()
+                        cbks.on_train_batch_begin(step_i, {})
+                        if ft_mgr is not None:
+                            # fault-injection hook (crash/preempt/slow)
+                            # so the fit() recovery paths are
+                            # chaos-testable too
+                            _chaos.on_step(it_count + 1)
+                        elif pod is not None:
+                            _pod_chaos.on_step(it_count + 1)
+                        batch = _to_list(batch)
+                        inputs, labels = self._split_batch(batch)
+                        inputs = [_as_tensor(x) for x in inputs]
+                        labels = [_as_tensor(x) for x in labels]
+                        if pod is not None:
+                            # every rank holds the FULL global batch; the
+                            # pod runtime strides it over the live
+                            # membership (and re-strides on replay after
+                            # a shrink)
+                            _pod_raw = (inputs, labels)
+                            inputs = pod.stride(inputs)
+                            labels = pod.stride(labels)
+                        if user_cbs:
+                            # per-batch weight mutations (WGAN-style
+                            # clipping callbacks) only possible with user
+                            # callbacks — identity-scan for them before
+                            # dispatching
+                            engine.refresh_from_layers()
+                        if telem is not None:
+                            # idempotent anchor so the FIRST interval
+                            # (the one containing the compile) is
+                            # measured too
+                            telem.mark_start()
+                        _sp_step = (_epoch_span.child("train.step",
+                                                      step=it_count + 1)
+                                    if _epoch_span is not None else None)
+                        if pod is not None:
+                            # in-memory rollback point for a mid-step
+                            # shrink
+                            pod.before_step(engine, it_count)
                     with timers.scope("dispatch"):
                         outs = engine.step(inputs, labels)
                     if pod is not None:
@@ -740,12 +756,14 @@ class Model:
                                 engine, _pod_raw[0], _pod_raw[1],
                                 it_count + 1)
                             losses.extend(_pod_losses)
-                    if telem is not None:
-                        telem.step_mark()
-                    if _sp_step is not None:
-                        # covers dispatch only: the async engine returns
-                        # futures, so device time lands in the sync scope
-                        _sp_step.end()
+                    with timers.scope("callbacks"):
+                        if telem is not None:
+                            telem.step_mark()
+                        if _sp_step is not None:
+                            # covers dispatch only: the async engine
+                            # returns futures, so device time lands in
+                            # the sync scope
+                            _sp_step.end()
                     it_count += 1
                     log_step = bool(log_freq) and step_i % log_freq == 0
                     if eager_sync or log_step:
@@ -757,51 +775,57 @@ class Model:
                         # old loop wrote back every batch; vanilla runs
                         # keep the async no-copy path).  Opt slots sync
                         # only at boundaries — callbacks observe weights
-                        engine.write_back(copy=True, sync_opt=False)
+                        with timers.scope("write_back"):
+                            engine.write_back(copy=True, sync_opt=False)
                     if self._metrics:
-                        with host_fetch():
+                        with timers.scope("metrics"), host_fetch():
                             for m in self._metrics:
                                 m.update(unwrap(m.compute(
                                     *(_to_list(outs) + labels))))
-                    logs = {"loss": losses[-1] if losses else float("nan"),
-                            "batch_size": batch_size}
-                    if user_cbs or log_step:
-                        for m in self._metrics:
-                            logs[m._name] = np.mean(
-                                _to_list(m.accumulate()))
-                    cbks.on_train_batch_end(step_i, logs)
-                    if telem is not None and log_step \
-                            and it_count > _win_it0:
-                        _win_t0, _win_it0, _win_totals, _win_counts = \
-                            self._telemetry_window(
-                                telem, engine, timers, epoch, it_count,
-                                batch_size, losses, inputs, labels,
-                                _win_t0, _win_it0, _win_totals,
-                                _win_counts)
+                    with timers.scope("callbacks"):
+                        logs = {"loss": (losses[-1] if losses
+                                         else float("nan")),
+                                "batch_size": batch_size}
+                        if user_cbs or log_step:
+                            for m in self._metrics:
+                                logs[m._name] = np.mean(
+                                    _to_list(m.accumulate()))
+                        cbks.on_train_batch_end(step_i, logs)
+                        if telem is not None and log_step \
+                                and it_count > _win_it0:
+                            _win_t0, _win_it0, _win_totals, _win_counts \
+                                = self._telemetry_window(
+                                    telem, engine, timers, epoch,
+                                    it_count, batch_size, losses, inputs,
+                                    labels, _win_t0, _win_it0,
+                                    _win_totals, _win_counts)
                     if ft_mgr is not None:
-                        if (checkpoint_interval
-                                and it_count % checkpoint_interval == 0):
-                            self._ft_save(ft_mgr, ft_saver, it_count)
-                        if ((ft_saver is not None and ft_saver.fatal)
-                                or self._ft_sync_failures
-                                >= max(1, self._ft_max_failures)):
-                            # degrade-then-escalate: K consecutive failed
-                            # generations means the job has been training
-                            # WITHOUT durability — abort with the
-                            # distinct code so the launcher alerts
-                            # instead of restarting blindly
-                            _flightrec.dump("durability")
-                            raise SystemExit(_res.DURABILITY_EXIT_CODE)
-                        if guard is not None and guard.preempted:
-                            # in-flight batch done: emergency checkpoint
-                            # (synchronous — we are about to exit), then
-                            # the distinct "preempted" exit so the
-                            # launcher restarts us
-                            self._ft_save(ft_mgr, ft_saver, it_count,
-                                          force=True, sync=True)
-                            ft_mgr.wait()
-                            _flightrec.dump("preempt")
-                            raise SystemExit(_res.PREEMPTED_EXIT_CODE)
+                        with timers.scope("ckpt"):
+                            if (checkpoint_interval
+                                    and it_count % checkpoint_interval == 0):
+                                self._ft_save(ft_mgr, ft_saver, it_count)
+                            if ((ft_saver is not None and ft_saver.fatal)
+                                    or self._ft_sync_failures
+                                    >= max(1, self._ft_max_failures)):
+                                # degrade-then-escalate: K consecutive
+                                # failed generations means the job has
+                                # been training WITHOUT durability —
+                                # abort with the distinct code so the
+                                # launcher alerts instead of restarting
+                                # blindly
+                                _flightrec.dump("durability")
+                                raise SystemExit(
+                                    _res.DURABILITY_EXIT_CODE)
+                            if guard is not None and guard.preempted:
+                                # in-flight batch done: emergency
+                                # checkpoint (synchronous — we are about
+                                # to exit), then the distinct "preempted"
+                                # exit so the launcher restarts us
+                                self._ft_save(ft_mgr, ft_saver, it_count,
+                                              force=True, sync=True)
+                                ft_mgr.wait()
+                                _flightrec.dump("preempt")
+                                raise SystemExit(_res.PREEMPTED_EXIT_CODE)
                     if num_iters is not None and it_count >= num_iters:
                         break
                 with timers.scope("sync"):
@@ -810,49 +834,60 @@ class Model:
                     # close the epoch's partial window (inputs/labels are
                     # the last dispatched batch — it_count > _win_it0
                     # guarantees one exists)
-                    _win_t0, _win_it0, _win_totals, _win_counts = \
-                        self._telemetry_window(
-                            telem, engine, timers, epoch, it_count,
-                            batch_size, losses, inputs, labels,
-                            _win_t0, _win_it0, _win_totals, _win_counts)
+                    with timers.scope("callbacks"):
+                        _win_t0, _win_it0, _win_totals, _win_counts = \
+                            self._telemetry_window(
+                                telem, engine, timers, epoch, it_count,
+                                batch_size, losses, inputs, labels,
+                                _win_t0, _win_it0, _win_totals,
+                                _win_counts)
                 # epoch-boundary write-back: the Layer tree gets device
                 # COPIES so checkpoints/eval/user inspection see current
                 # values while the engine keeps donating its own buffers
-                engine.write_back(copy=True)
+                with timers.scope("write_back"):
+                    engine.write_back(copy=True)
                 if ft_mgr is not None and not checkpoint_interval \
                         and it_count > start_it:
-                    self._ft_save(ft_mgr, ft_saver, it_count, force=True)
-                # losses can be empty when resume fast-forwarded the epoch
-                history["loss"].append(
-                    float(np.mean(losses)) if losses else float("nan"))
-                epoch_logs = {"loss": history["loss"][-1]}
-                for m in self._metrics:
-                    epoch_logs[m._name] = np.mean(_to_list(m.accumulate()))
+                    with timers.scope("ckpt"):
+                        self._ft_save(ft_mgr, ft_saver, it_count,
+                                      force=True)
+                with timers.scope("metrics"):
+                    # losses can be empty when resume fast-forwarded the
+                    # epoch
+                    history["loss"].append(
+                        float(np.mean(losses)) if losses else float("nan"))
+                    epoch_logs = {"loss": history["loss"][-1]}
+                    for m in self._metrics:
+                        epoch_logs[m._name] = np.mean(
+                            _to_list(m.accumulate()))
                 if eval_data is not None and (epoch + 1) % eval_freq == 0:
-                    cbks.on_eval_begin({})
-                    eval_res = self.evaluate(eval_data,
-                                             batch_size=batch_size,
-                                             verbose=0)
-                    history.setdefault("eval_loss", []).append(
-                        eval_res.get("loss"))
-                    epoch_logs.update({f"eval_{k}": v
-                                       for k, v in eval_res.items()})
-                    cbks.on_eval_end(eval_res)
-                cbks.on_epoch_end(epoch, epoch_logs)
-                if _epoch_span is not None:
-                    _epoch_span.end(status="ok")
-                    _epoch_span = None
+                    with timers.scope("eval"):
+                        cbks.on_eval_begin({})
+                        eval_res = self.evaluate(eval_data,
+                                                 batch_size=batch_size,
+                                                 verbose=0)
+                        history.setdefault("eval_loss", []).append(
+                            eval_res.get("loss"))
+                        epoch_logs.update({f"eval_{k}": v
+                                           for k, v in eval_res.items()})
+                        cbks.on_eval_end(eval_res)
+                with timers.scope("callbacks"):
+                    cbks.on_epoch_end(epoch, epoch_logs)
+                    if _epoch_span is not None:
+                        _epoch_span.end(status="ok")
+                        _epoch_span = None
                 # SIGTERM during epoch-end eval/callbacks must still turn
                 # into a clean preempted exit (not a SIGKILL after the
                 # grace window); a final-epoch latch just finishes the run
                 if guard is not None and guard.preempted \
                         and epoch + 1 < epochs:
-                    if it_count > start_it:
-                        self._ft_save(ft_mgr, ft_saver, it_count,
-                                      force=True, sync=True)
-                        ft_mgr.wait()
-                    _flightrec.dump("preempt")
-                    raise SystemExit(_res.PREEMPTED_EXIT_CODE)
+                    with timers.scope("ckpt"):
+                        if it_count > start_it:
+                            self._ft_save(ft_mgr, ft_saver, it_count,
+                                          force=True, sync=True)
+                            ft_mgr.wait()
+                        _flightrec.dump("preempt")
+                        raise SystemExit(_res.PREEMPTED_EXIT_CODE)
                 if self.stop_training:
                     break
                 if num_iters is not None and it_count >= num_iters:
@@ -878,15 +913,17 @@ class Model:
             # the Layer tree's state again (single source of truth for
             # train_batch/save/parameters after fit returns) — even when
             # fit is unwinding on an exception/preemption
-            if fit_ok:
-                # success path: a failed final write-back means the Layer
-                # tree holds stale weights — that must surface, not pass
-                engine.finish()
-            else:
-                try:
+            with timers.scope("write_back"):
+                if fit_ok:
+                    # success path: a failed final write-back means the
+                    # Layer tree holds stale weights — that must
+                    # surface, not pass
                     engine.finish()
-                except Exception:  # noqa: BLE001 - don't mask the real error
-                    pass
+                else:
+                    try:
+                        engine.finish()
+                    except Exception:  # noqa: BLE001 - don't mask the
+                        pass           # real error
             if engine.mesh is not None:
                 loader.placement = prev_placement
             # a crash mid-fit must still flush/close callback resources

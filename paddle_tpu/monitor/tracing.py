@@ -10,9 +10,15 @@ recorder is a bounded in-process ring, and the export is the same
 chrome://tracing / perfetto JSON the timeline tool produced.
 
 Dependency-free by design (stdlib only, no jax, no OpenTelemetry): a
-`Span` is a dict-sized object stamped with `time.monotonic()`; ending it
-appends one summary dict to the tracer's ring and notifies listeners
-(the crash flight recorder subscribes).  Sampling is *head* sampling
+`Span` is a dict-sized object; ending it appends one summary dict to the
+tracer's ring and notifies listeners (the crash flight recorder
+subscribes).  A span starts at `ts_ns` on `time.time_ns()`, the clock a
+jax.profiler trace stamps its host plane with (PERF.md, section 3): an
+event of the `.xplane.pb` at `start_ns` happened at the trace's
+`profile_start_time` (a stat of its `Task Environment` plane) plus
+`start_ns`, so a request's spans can be laid over the device's events.
+`ts_ms` is the same instant in milliseconds; durations and event offsets
+come from `time.perf_counter()`.  Sampling is *head* sampling
 decided from the trace_id itself —
 
     int(trace_id[:8], 16) < FLAGS_trace_sample_rate * 2**32
@@ -97,7 +103,7 @@ class Span:
     marks), `end()` exactly once (idempotent)."""
 
     __slots__ = ("_tracer", "trace_id", "span_id", "parent_id", "name",
-                 "attrs", "events", "t0_wall", "t0", "dur_ms", "tid",
+                 "attrs", "events", "t0_ns", "t0", "dur_ms", "tid",
                  "_ended")
 
     sampled = True
@@ -110,7 +116,7 @@ class Span:
         self.name = name
         self.attrs = dict(attrs) if attrs else {}
         self.events = []          # (name, t_ms offset, attrs-or-None)
-        self.t0_wall = time.time()
+        self.t0_ns = time.time_ns()   # the profiler trace's clock
         self.t0 = time.perf_counter()
         self.dur_ms = 0.0
         self.tid = threading.get_ident()
@@ -264,7 +270,8 @@ class Tracer:
             "trace_id": span.trace_id,
             "span_id": span.span_id,
             "parent_id": span.parent_id,
-            "ts_ms": round(span.t0_wall * 1e3, 3),
+            "ts_ms": round(span.t0_ns / 1e6, 3),
+            "ts_ns": span.t0_ns,
             "dur_ms": round(span.dur_ms, 3),
             "tid": span.tid,
             "attrs": span.attrs,
@@ -292,14 +299,6 @@ class Tracer:
         if limit is not None and limit >= 0:
             out = out[-limit:]
         return out
-
-    def trace_ids(self) -> list[str]:
-        """Distinct trace ids present in the ring, oldest first."""
-        seen = []
-        for s in self.spans():
-            if s["trace_id"] not in seen:
-                seen.append(s["trace_id"])
-        return seen
 
     def clear(self):
         with self._lock:

@@ -75,13 +75,14 @@ def _bwd_kernel(x_ref, b_ref, dy_ref, dx_ref):
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def _row_call(kernel, outs, x2d, b, extra, interpret):
+def _row_call(name, kernel, outs, x2d, b, extra, interpret):
     r, n = x2d.shape
     block_r = pick_block_rows(r, n)
     row_spec = pl.BlockSpec((block_r, n), _im(lambda i: (i, 0)))
     vec_spec = pl.BlockSpec((n,), _im(lambda i: (0,)))
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=(r // block_r,),
         in_specs=[row_spec, vec_spec] + [row_spec] * len(extra),
         out_specs=row_spec,
@@ -92,7 +93,8 @@ def _row_call(kernel, outs, x2d, b, extra, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _bg(x2d, b, interpret):
-    return _row_call(_fwd_kernel, x2d.dtype, x2d, b, (), interpret)
+    return _row_call("paddle_bias_gelu_fwd", _fwd_kernel, x2d.dtype, x2d, b,
+                     (), interpret)
 
 
 def _bg_fwd(x2d, b, interpret):
@@ -101,7 +103,8 @@ def _bg_fwd(x2d, b, interpret):
 
 def _bg_bwd(interpret, res, dy):
     x2d, b = res
-    dx = _row_call(_bwd_kernel, x2d.dtype, x2d, b, (dy,), interpret)
+    dx = _row_call("paddle_bias_gelu_bwd", _bwd_kernel, x2d.dtype, x2d, b,
+                   (dy,), interpret)
     # d/db == d/dx elementwise (y = gelu(x + b)), so db is dx's row-sum
     db = jnp.sum(dx.astype(jnp.float32), axis=0).astype(b.dtype)
     return dx, db

@@ -156,6 +156,7 @@ def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
 
     out, lse = pl.pallas_call(
         kernel,
+        name="paddle_flash_fwd",
         grid=(bh, num_q, num_k),
         in_specs=in_specs,
         out_specs=[
@@ -290,6 +291,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
                           has_bias=has_bias, block_q=block_q,
                           block_k=block_k, num_k=num_k),
+        name="paddle_flash_dq",
         grid=(bh, num_q, num_k),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, i, 0))),
@@ -317,6 +319,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           has_bias=has_bias, block_q=block_q,
                           block_k=block_k, num_q=num_q),
+        name="paddle_flash_dkv",
         grid=(bh, num_k, num_q),
         in_specs=dkv_in_specs,
         out_specs=[

@@ -35,6 +35,7 @@ def _ln_fwd_call(x2d, w, b, eps, interpret):
     block_r = pick_block_rows(r, n)
     y, mean, rstd = pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
+        name="paddle_layer_norm_fwd",
         grid=(r // block_r,),
         in_specs=[
             pl.BlockSpec((block_r, n), _im(lambda i: (i, 0))),

@@ -148,6 +148,7 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
     out = pl.pallas_call(
         functools.partial(_kernel, sm_scale=float(sm_scale), page_size=ps,
                           pages_walked=pages_walked),
+        name="paddle_paged_decode_fwd",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, nh, hd), q.dtype),
         interpret=interpret,

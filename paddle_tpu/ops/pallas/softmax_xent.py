@@ -105,6 +105,7 @@ def _fwd_call(z, lab, ignore_index, interpret):
     loss, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_c=block_c, num_c=num_c,
                           ignore_index=ignore_index),
+        name="paddle_softmax_xent_fwd",
         grid=(num_r, num_c),
         in_specs=[
             pl.BlockSpec((block_r, block_c), _im(lambda i, j: (i, j))),
@@ -139,6 +140,7 @@ def _bwd_call(z, lab, lse, g, ignore_index, interpret):
     dz = pl.pallas_call(
         functools.partial(_bwd_kernel, block_c=block_c,
                           ignore_index=ignore_index),
+        name="paddle_softmax_xent_bwd",
         grid=(n // block_r, v // block_c),
         in_specs=[
             pl.BlockSpec((block_r, block_c), _im(lambda i, j: (i, j))),

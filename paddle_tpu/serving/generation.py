@@ -56,7 +56,7 @@ from ..framework import flags as _flags
 from ..framework.transfer import host_fetch
 from ..monitor import tracing as _tracing
 from ..utils import chaos
-from ..utils.profiler import RecordEvent
+from ..utils.profiler import RecordEvent, StepTimers
 from .engine import (DeadlineExceededError, EngineStoppedError,
                      QueueFullError)
 from .kv_cache import (CacheGeometry, admit_slot, make_state, push_pages,
@@ -410,6 +410,9 @@ class GenerationEngine:
         self._idle = threading.Event()
         self._idle.set()
         self._iter = 0
+        # the decode loop's phases (see _run): annotations under
+        # paddle.genserve/ in a profiler trace, totals in /metrics
+        self.timers = StepTimers("paddle.genserve")
         self.compile_count = 0
         self._state = None
         self._params = None
@@ -916,9 +919,8 @@ class GenerationEngine:
         ca = exe.cost_analysis()
         ca = ca[0] if isinstance(ca, (list, tuple)) else (ca or {})
         if measured_step_ms is None:
-            gaps = sorted(self.metrics._gaps)
-            if gaps:
-                measured_step_ms = gaps[len(gaps) // 2] * 1e3
+            # the gaps are kept in milliseconds; 0.0 means none yet
+            measured_step_ms = self.metrics._gaps.quantile(0.50) or None
         from ..monitor import perf as _perf
 
         return _perf.build_report(exe, name="decode",
@@ -1031,32 +1033,47 @@ class GenerationEngine:
 
     def _run(self):
         try:
+            # Every statement of an iteration lies in one top-level
+            # phase of self.timers (wait, pull, sweep, admit, chunk,
+            # decode | spec_decode, fetch, distribute), so the phases'
+            # totals sum to the loop's wall time; README "Reading a
+            # trace" lists them with their children.
+            scope = self.timers.scope
             while True:
                 self._pull_requests()
-                self._sweep_backlog()
+                with scope("sweep"):
+                    self._sweep_backlog()
                 self._admit_ready()
-                self._preempt_swept()
-                occupied = self._sched.occupied
-                self.metrics.set_occupancy(len(occupied))
-                self.metrics.set_page_occupancy(
-                    self.geometry.num_pages - self._sched.pages_available)
+                with scope("sweep"):
+                    self._preempt_swept()
+                    occupied = self._sched.occupied
+                    self.metrics.set_occupancy(len(occupied))
+                    self.metrics.set_page_occupancy(
+                        self.geometry.num_pages
+                        - self._sched.pages_available)
+                    self.metrics.observe_loop(self.timers.totals)
                 if occupied and not self._stopped:
                     # at most ONE prefill chunk per iteration, then a
                     # decode step for the armed lanes — a long prompt
                     # streams in without stalling in-flight streams
-                    self._advance_chunk()
+                    if self._sched.prefilling():
+                        with scope("chunk"):
+                            self._advance_chunk()
                     if len(self._sched.occupied) > self._sched.prefilling():
                         if self._spec_exec is not None:
                             outs, emitted, fin = self.step_spec()
-                            self._distribute_spec(outs, emitted, fin)
+                            with scope("distribute"):
+                                self._distribute_spec(outs, emitted, fin)
                         else:
                             toks, fin = self.step()
-                            self._distribute(toks, fin)
+                            with scope("distribute"):
+                                self._distribute(toks, fin)
                     continue
-                if self._queue.empty() and not self._backlog:
-                    self._idle.set()
-                    if self._draining or self._stopped:
-                        return
+                with scope("pull"):
+                    if self._queue.empty() and not self._backlog:
+                        self._idle.set()
+                        if self._draining or self._stopped:
+                            return
         except BaseException as e:  # pragma: no cover - last-resort:
             # never die silently
             logger.exception("generation decode loop crashed")
@@ -1078,21 +1095,22 @@ class GenerationEngine:
 
     def _pull_requests(self):
         """Move queued requests to the backlog; block only when idle."""
-        block = (not self._sched.occupied and not self._backlog
-                 and not (self._draining or self._stopped))
-        try:
-            req = self._queue.get(block=block)
-        except queue.Empty:
-            return
-        if req is not _WAKE:
-            self._backlog.append(req)
-        while True:
-            try:
-                r2 = self._queue.get_nowait()
-            except queue.Empty:
-                return
-            if r2 is not _WAKE:
-                self._backlog.append(r2)
+        scope = self.timers.scope
+        if (not self._sched.occupied and not self._backlog
+                and not (self._draining or self._stopped)):
+            # no lane waits for a token: time here is nobody's latency
+            with scope("wait"):
+                req = self._queue.get()
+            if req is not _WAKE:
+                self._backlog.append(req)
+        with scope("pull"):
+            while True:
+                try:
+                    req = self._queue.get_nowait()
+                except queue.Empty:
+                    return
+                if req is not _WAKE:
+                    self._backlog.append(req)
 
     def _sweep_backlog(self):
         now = time.monotonic()
@@ -1112,7 +1130,20 @@ class GenerationEngine:
         self._backlog = keep
 
     def _admit_ready(self):
-        while self._backlog and not self._stopped:
+        # with no free lane nothing can be admitted, so the head of the
+        # backlog is not even looked up: an `admit` phase is an
+        # admission (or, rarely, a head that pages cannot hold yet)
+        while (self._backlog and not self._stopped
+               and self._sched.has_free()):
+            with self.timers.scope("admit"):
+                if not self._admit_head():
+                    return
+
+    def _admit_head(self) -> bool:
+        """Admit the head of the backlog if the pool can hold it; False
+        when it has to wait for pages."""
+        scope = self.timers.scope
+        with scope("admit/lookup"):
             req = self._backlog[0]
             j_hit, shared = (self._prefix.lookup(req.prompt)
                              if self._prefix is not None else (0, ()))
@@ -1125,35 +1156,34 @@ class GenerationEngine:
                 # head's reservation fits — otherwise a stream of
                 # distinct prompts parks one-reader prefixes over the
                 # whole pool and the backlog never drains
-                if (self._prefix is not None and self._sched.has_free()
-                        and len(self._prefix)
+                if (self._prefix is not None and len(self._prefix)
                         and need > self._sched.pages_available):
                     short = need - self._sched.pages_available
                     self._reclaim(self._prefix.evict_idle(short))
                     self._sched.set_shared_resident(
                         self._prefix.resident_pages)
                 if not self._sched.can_admit(need):
-                    # no free lane, or the pool cannot reserve the
-                    # worst case even after eviction — FIFO
-                    # head-of-line wait until a retirement frees
-                    # lanes/pages (admit-and-crash is not an option)
-                    break
+                    # the pool cannot reserve the worst case even
+                    # after eviction — FIFO head-of-line wait until a
+                    # retirement frees pages (admit-and-crash is not
+                    # an option)
+                    return False
             self._backlog.popleft()
             slot = self._sched.admit(req, n_pages=need)
-            try:
-                suffix_len = len(req.prompt) \
-                    - j_hit * self.geometry.page_size
-                if self.prefill_chunk and suffix_len > self.prefill_chunk:
-                    self._admit_chunked(req, slot, j_hit, shared)
-                else:
-                    self._admit(req, slot, j_hit, shared)
-            except Exception as e:  # noqa: BLE001 - fail THIS request,
-                # keep the decode loop alive for the others
-                logger.exception("generation admission failed")
-                self.metrics.count("errors")
-                self._host_retire(slot)
-                req.end_spans("error")
-                req.handle._finish(e)
+        try:
+            suffix_len = len(req.prompt) - j_hit * self.geometry.page_size
+            if self.prefill_chunk and suffix_len > self.prefill_chunk:
+                self._admit_chunked(req, slot, j_hit, shared)
+            else:
+                self._admit(req, slot, j_hit, shared)
+        except Exception as e:  # noqa: BLE001 - fail THIS request,
+            # keep the decode loop alive for the others
+            logger.exception("generation admission failed")
+            self.metrics.count("errors")
+            self._host_retire(slot)
+            req.end_spans("error")
+            req.handle._finish(e)
+        return True
 
     def _admit(self, req: _GenRequest, slot: int, j_hit: int, shared):
         """Prefill + insert: map the slot's cache pages (reusing any
@@ -1170,12 +1200,13 @@ class GenerationEngine:
         pinned = max(j_hit, j_reg)
         sp_prefill = (req.span.child("gen.prefill", bucket=req.bucket,
                                      prompt_len=L, slot=slot,
-                                     prefix_pages=j_hit)
+                                     prefix_pages=j_hit, iter=self._iter)
                       if req.span is not None else None)
         stop = np.int32(L + req.max_new_tokens)
         dpre = ((self._draft_params,)
                 if self.draft_model is not None else ())
-        with RecordEvent("paddle.genserve/prefill"):
+        scope = self.timers.scope
+        with scope("prefill"):
             if j_hit > 0:
                 # prefix hit: prefill ONLY the suffix
                 suffix = req.prompt[j_hit * geom.page_size:]
@@ -1205,24 +1236,33 @@ class GenerationEngine:
                     np.int32(req.top_k), stop, np.int32(req.eos),
                     np.int32(pinned), *out[3:])
         self._state = state
-        with host_fetch():
+        with scope("admit/fetch"), host_fetch():
+            # blocks until the device has run the prefill and insert
             t1 = int(np.array(tok1, copy=True))
             row_np = np.array(row, copy=True)
         if self._prefix is not None:
-            self.metrics.count_prefix(hit=j_hit > 0)
-            pin_pages = [int(p) for p in row_np[:pinned]]
-            self._prefix.pin(pin_pages)
-            self._slot_pins[slot] = pin_pages
-            self._reclaim(self._prefix.register(req.prompt, row_np,
-                                                j_hit, j_reg))
-            self._sched.set_shared_resident(self._prefix.resident_pages)
-        if sp_prefill is not None:
-            sp_prefill.end(status="ok")
+            with scope("admit/register"):
+                self.metrics.count_prefix(hit=j_hit > 0)
+                pin_pages = [int(p) for p in row_np[:pinned]]
+                self._prefix.pin(pin_pages)
+                self._slot_pins[slot] = pin_pages
+                self._reclaim(self._prefix.register(req.prompt, row_np,
+                                                    j_hit, j_reg))
+                self._sched.set_shared_resident(
+                    self._prefix.resident_pages)
+        with scope("admit/push"):
+            if sp_prefill is not None:
+                sp_prefill.end(status="ok")
+            self._push_first(req, slot, t1)
+
+    def _push_first(self, req: _GenRequest, slot: int, t1: int):
+        """The first token goes to the handle (TTFT observed); the lane
+        retires at once on eos / max_new_tokens == 1."""
         now = time.monotonic()
         req.t_last_token = now
         req.handle._push(t1)
         if req.span is not None:
-            req.span.event("first_token", slot=slot)
+            req.span.event("first_token", slot=slot, iter=self._iter)
         self.metrics.observe_ttft(now - req.handle.t_submit)
         self.metrics.observe_tokens(1)
         if req.max_new_tokens == 1 or t1 == req.eos:
@@ -1257,17 +1297,21 @@ class GenerationEngine:
             row[:j_hit] = shared[:j_hit]
         req.chunk_row = row
         if self._prefix is not None:
-            self.metrics.count_prefix(hit=j_hit > 0)
-            # pin the cache-shared head NOW: it must stay resident for
-            # every later chunk's prefix gather (LRU cannot evict it)
-            pin_pages = [int(p) for p in row[:j_hit]]
-            self._prefix.pin(pin_pages)
-            self._slot_pins[slot] = pin_pages
-            self._sched.set_shared_resident(self._prefix.resident_pages)
+            with self.timers.scope("admit/register"):
+                self.metrics.count_prefix(hit=j_hit > 0)
+                # pin the cache-shared head NOW: it must stay resident
+                # for every later chunk's prefix gather (LRU cannot
+                # evict it)
+                pin_pages = [int(p) for p in row[:j_hit]]
+                self._prefix.pin(pin_pages)
+                self._slot_pins[slot] = pin_pages
+                self._sched.set_shared_resident(
+                    self._prefix.resident_pages)
         if req.span is not None:
             req.span_decode = req.span.child(
                 "gen.prefill", bucket=req.bucket, prompt_len=L,
-                slot=slot, prefix_pages=j_hit, chunked=True)
+                slot=slot, prefix_pages=j_hit, chunked=True,
+                iter=self._iter)
 
     def _advance_chunk(self):
         """Advance ONE prefilling slot by one chunk — bounded work per
@@ -1294,7 +1338,8 @@ class GenerationEngine:
         shared_vec = np.array(req.chunk_row, np.int32)
         dpre = ((self._draft_params,)
                 if self.draft_model is not None else ())
-        with RecordEvent("paddle.genserve/prefill_chunk"):
+        scope = self.timers.scope
+        with scope("prefill_chunk"):
             state, tok1, row = self._chunk_execs[sb](
                 self._params, *dpre, self._state, np.int32(slot), ids,
                 shared_vec, np.int32(cur // geom.page_size),
@@ -1306,14 +1351,14 @@ class GenerationEngine:
                 np.int32(req.j_hit), np.int32(req.pin_final),
                 np.bool_(arm))
         self._state = state
-        with host_fetch():
+        with scope("chunk/fetch"), host_fetch():
             t1 = int(np.array(tok1, copy=True))
             row_np = np.array(row, copy=True)
         req.chunk_row = row_np
         req.prefill_cursor = end
         self.metrics.count_chunk()
         if req.span_decode is not None:
-            req.span_decode.event("chunk", end=end)
+            req.span_decode.event("chunk", end=end, iter=self._iter)
         if arm:
             self._arm_chunked(req, slot, row_np, t1)
 
@@ -1336,21 +1381,7 @@ class GenerationEngine:
         if req.span_decode is not None:
             req.span_decode.end(status="ok")
             req.span_decode = None
-        now = time.monotonic()
-        req.t_last_token = now
-        req.handle._push(t1)
-        if req.span is not None:
-            req.span.event("first_token", slot=slot)
-        self.metrics.observe_ttft(now - req.handle.t_submit)
-        self.metrics.observe_tokens(1)
-        if req.max_new_tokens == 1 or t1 == req.eos:
-            self._release([slot])
-            self._host_retire(slot)
-            self.metrics.count("retired")
-            req.end_spans("ok")
-            req.handle._finish()
-        elif req.span is not None:
-            req.span_decode = req.span.child("gen.decode", slot=slot)
+        self._push_first(req, slot, t1)
 
     def _release(self, slots):
         mask = np.zeros((self.max_slots,), np.bool_)
@@ -1403,12 +1434,13 @@ class GenerationEngine:
         page pool is rewritten on device, never fetched); only the
         sampled token ids and finished mask cross to host, under
         host_fetch()."""
-        self._iter += 1
-        chaos.on_step(self._iter)   # fault-injection seam (utils/chaos)
-        with RecordEvent("paddle.genserve/decode"):
+        with self.timers.scope("decode"):
+            self._iter += 1
+            chaos.on_step(self._iter)   # fault-injection seam (utils/chaos)
             state, toks, fin = self._decode_exec(self._params, self._state)
         self._state = state
-        with host_fetch():
+        with self.timers.scope("fetch"), host_fetch():
+            # blocks until the device has run the step
             toks_np = np.array(toks, copy=True)
             fin_np = np.array(fin, copy=True)
         return toks_np, fin_np
@@ -1418,13 +1450,13 @@ class GenerationEngine:
         verify, compiled as a single executable): every armed lane
         advances 1..spec_tokens+1 tokens.  Returns (outs [slots, K+1],
         emitted [slots, K+1] prefix mask, finished [slots])."""
-        self._iter += 1
-        chaos.on_step(self._iter)
-        with RecordEvent("paddle.genserve/spec_decode"):
+        with self.timers.scope("spec_decode"):
+            self._iter += 1
+            chaos.on_step(self._iter)
             state, outs, emitted, fin = self._spec_exec(
                 self._params, self._draft_params, self._state)
         self._state = state
-        with host_fetch():
+        with self.timers.scope("fetch"), host_fetch():
             outs_np = np.array(outs, copy=True)
             emitted_np = np.array(emitted, copy=True)
             fin_np = np.array(fin, copy=True)
@@ -1454,7 +1486,8 @@ class GenerationEngine:
                 req.handle._push(int(outs_np[slot, i]))
                 if req.span_decode is not None:
                     req.span_decode.event("token",
-                                          i=len(req.handle.tokens))
+                                          i=len(req.handle.tokens),
+                                          iter=self._iter)
             req.t_last_token = now
             if bool(fin_np[slot]):
                 self._host_retire(slot)
@@ -1478,7 +1511,8 @@ class GenerationEngine:
             req.handle._push(tok)
             if req.span_decode is not None:
                 # host ints only — toks/fin were fetched in step()
-                req.span_decode.event("token", i=len(req.handle.tokens))
+                req.span_decode.event("token", i=len(req.handle.tokens),
+                                      iter=self._iter)
             if bool(fin_np[slot]):
                 # the decode step already pushed the lane's private
                 # pages back in-graph; this drops the host bookkeeping

@@ -18,7 +18,7 @@ from __future__ import annotations
 import collections
 import time
 
-from ..utils.metrics import Histogram, MetricsRegistry
+from ..utils.metrics import Histogram, MetricsRegistry, Reservoir
 
 __all__ = ["Histogram", "ServingMetrics", "GenerationMetrics",
            "RouterMetrics"]
@@ -177,11 +177,16 @@ class GenerationMetrics:
     engine (same private-registry pattern as ServingMetrics, so several
     engines coexist in one process).
 
-    Exposes (scraped by tools/serve_smoke.sh, read by bench.py genserve):
+    Exposes (scraped by tools/serve_smoke.sh; `snapshot()` is read by
+    bench.py's genserve body and, for counters and slot occupancy, by
+    benchmarks/adapters/gpt.py; the quantiles are an operator's view —
+    the benchmark times at the client).  "window" is the trailing
+    WINDOW_S seconds, so a scrape after warm-up stops reporting it:
       paddle_genserve_decode_tokens_per_sec  tokens streamed / s (window)
-      paddle_genserve_ttft_p50_ms / _p99_ms  time-to-first-token
+      paddle_genserve_ttft_p50_ms / _p99_ms  time-to-first-token (window)
       paddle_genserve_inter_token_p50_ms / _p99_ms
                                              gap between a slot's tokens
+                                             (window)
       paddle_genserve_slot_occupancy         occupied / max_slots
       paddle_genserve_page_occupancy         KV pages in use / num_pages
       paddle_genserve_tokens_total           generated tokens
@@ -192,6 +197,13 @@ class GenerationMetrics:
       paddle_genserve_spec_accept_ratio      accepted / proposed drafts
       paddle_genserve_prefill_chunks_total   chunked-prefill slices run
       paddle_genserve_compile_count          executables built at warmup
+      paddle_genserve_loop_seconds_total{phase}
+                                             the decode thread's seconds
+                                             by phase of its loop (the
+                                             engine's StepTimers; top-level
+                                             phases sum to its wall time,
+                                             `a/b` ran under `a`)
+      paddle_genserve_loop_iterations_total  decode-loop iterations
     """
 
     WINDOW_S = 60.0
@@ -209,16 +221,16 @@ class GenerationMetrics:
                   fn=self._tps_locked)
         reg.gauge("paddle_genserve_ttft_p50_ms",
                   "time-to-first-token p50 in milliseconds",
-                  fn=lambda: self._quantile_locked(self._ttft, 0.50))
+                  fn=lambda: self._ttft.quantile_locked(0.50))
         reg.gauge("paddle_genserve_ttft_p99_ms",
                   "time-to-first-token p99 in milliseconds",
-                  fn=lambda: self._quantile_locked(self._ttft, 0.99))
+                  fn=lambda: self._ttft.quantile_locked(0.99))
         reg.gauge("paddle_genserve_inter_token_p50_ms",
                   "inter-token latency p50 in milliseconds",
-                  fn=lambda: self._quantile_locked(self._gaps, 0.50))
+                  fn=lambda: self._gaps.quantile_locked(0.50))
         reg.gauge("paddle_genserve_inter_token_p99_ms",
                   "inter-token latency p99 in milliseconds",
-                  fn=lambda: self._quantile_locked(self._gaps, 0.99))
+                  fn=lambda: self._gaps.quantile_locked(0.99))
         reg.gauge("paddle_genserve_slot_occupancy",
                   "occupied decode slots / max_slots",
                   fn=lambda: self._occupied / self.max_slots)
@@ -262,8 +274,17 @@ class GenerationMetrics:
         self._spec_proposed = reg.counter(
             "paddle_genserve_spec_proposed_total",
             "draft proposals offered to target verification")
-        self._ttft = collections.deque(maxlen=self.RESERVOIR)
-        self._gaps = collections.deque(maxlen=self.RESERVOIR)
+        self._loop_seconds = reg.counter(
+            "paddle_genserve_loop_seconds_total",
+            "decode-thread seconds by phase of its loop (top-level "
+            "phases sum to the loop's wall time; a/b ran under a)",
+            label="phase")
+        self._loop_iterations = reg.counter(
+            "paddle_genserve_loop_iterations_total",
+            "iterations of the decode loop")
+        # the last RESERVOIR samples of the trailing WINDOW_S seconds
+        self._ttft = Reservoir(self.RESERVOIR, self._lock, self.WINDOW_S)
+        self._gaps = Reservoir(self.RESERVOIR, self._lock, self.WINDOW_S)
         self._token_stamps = collections.deque()   # (monotonic, count)
         self._occupied = 0
         self._pages_in_use = 0
@@ -287,12 +308,17 @@ class GenerationMetrics:
                 self._token_stamps.popleft()
 
     def observe_ttft(self, seconds: float):
-        with self._lock:
-            self._ttft.append(seconds * 1e3)
+        self._ttft.observe(seconds * 1e3)
 
     def observe_inter_token(self, seconds: float):
+        self._gaps.observe(seconds * 1e3)
+
+    def observe_loop(self, totals: dict):
+        """Once an iteration of the decode loop: its StepTimers totals
+        ({phase: seconds since the engine started})."""
         with self._lock:
-            self._gaps.append(seconds * 1e3)
+            self._loop_seconds.set_totals(totals)
+            self._loop_iterations.inc()
 
     def set_occupancy(self, occupied: int):
         with self._lock:
@@ -326,13 +352,6 @@ class GenerationMetrics:
         proposed = self._spec_proposed.value
         return self._spec_accepted.value / proposed if proposed else 0.0
 
-    def _quantile_locked(self, deque_, q: float):
-        if not deque_:
-            return 0.0
-        xs = sorted(deque_)
-        idx = min(len(xs) - 1, max(0, int(round(q * (len(xs) - 1)))))
-        return xs[idx]
-
     def _tps_locked(self, now=None):
         now = time.monotonic() if now is None else now
         if not self._token_stamps:
@@ -348,13 +367,13 @@ class GenerationMetrics:
             return {
                 "decode_tokens_per_sec": round(self._tps_locked(), 2),
                 "ttft_p50_ms": round(
-                    self._quantile_locked(self._ttft, 0.50), 3),
+                    self._ttft.quantile_locked(0.50), 3),
                 "ttft_p99_ms": round(
-                    self._quantile_locked(self._ttft, 0.99), 3),
+                    self._ttft.quantile_locked(0.99), 3),
                 "inter_token_p50_ms": round(
-                    self._quantile_locked(self._gaps, 0.50), 3),
+                    self._gaps.quantile_locked(0.50), 3),
                 "inter_token_p99_ms": round(
-                    self._quantile_locked(self._gaps, 0.99), 3),
+                    self._gaps.quantile_locked(0.99), 3),
                 "slot_occupancy": round(self._occupied / self.max_slots, 3),
                 "page_occupancy": round(
                     self._pages_in_use / self.num_pages, 3),

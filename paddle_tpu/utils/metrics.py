@@ -98,6 +98,17 @@ class Counter:
             self.values[key] += 1 if n is None else \
                 (float(n) if isinstance(n, float) else int(n))
 
+    def set_totals(self, totals: dict):
+        """Labeled: mirror running totals {label value: total} that are
+        accumulated elsewhere (the decode loop's StepTimers) and only
+        ever grow."""
+        values = self.values
+        with self._lock:
+            for key, total in totals.items():
+                if key not in values:
+                    self._order.append(key)
+                values[key] = total
+
     def get(self, key=None) -> int:
         with self._lock:
             return self.value if key is None else self.values[key]

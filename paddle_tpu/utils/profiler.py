@@ -48,32 +48,59 @@ class RecordEvent:
 
 
 class StepTimers:
-    """Per-step phase timing for the fit hot loop.
+    """Phase timing for a host loop: `Model.fit` (prefix `paddle.fit`)
+    and the generation engine's decode loop (`paddle.genserve`).
 
-    Each `scope(name)` is a RecordEvent — so `data` / `dispatch` / `sync`
-    phases appear as named spans inside jax.profiler / host chrome traces
-    — plus a host-side accumulator cheap enough to run every step, so
-    `summary()` answers "where does step time go" without a trace viewer.
-    Note that under the async engine `dispatch` measures enqueue cost
-    only; device execution overlaps and is paid for inside `sync`."""
+    Each `scope(name)` is a RecordEvent named `<prefix>/<name>`, so the
+    phase lies in a jax.profiler trace on the trace's own clock beside
+    the device's events, plus a host-side accumulator cheap enough to
+    leave on, so `summary()` answers "where does the loop's time go"
+    without a trace viewer.  Scopes nest: `parents[name]` is the scope
+    a phase ran under (None at the top level), and by convention a
+    child is named `parent/child`; a phase's self time is its total
+    less its children's (`self_seconds`).  The top-level phases of a
+    loop that is wholly covered sum to its wall time.  One thread
+    drives a recorder; another may read `totals` at any time.  Under
+    the async train engine `dispatch` measures enqueue cost only;
+    device execution overlaps and is paid for inside `sync`."""
 
-    def __init__(self):
+    def __init__(self, prefix: str = "paddle.fit"):
+        self.prefix = prefix
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
+        self.parents: dict[str, str | None] = {}
+        self._open: list[str] = []
 
     def reset(self):
         """Zero the accumulators: per-epoch phase summaries should
         describe that epoch, not the whole process lifetime."""
         self.totals.clear()
         self.counts.clear()
+        self.parents.clear()
 
     @contextlib.contextmanager
     def scope(self, name: str):
-        ev = RecordEvent(f"paddle.fit/{name}")
-        with ev:
-            yield
-        self.totals[name] = self.totals.get(name, 0.0) + ev.elapsed
-        self.counts[name] = self.counts.get(name, 0) + 1
+        # the clock is read around the annotation, so that what the
+        # annotation itself costs counts into the phase and not into
+        # the time between phases
+        begin = time.perf_counter()
+        self.parents[name] = self._open[-1] if self._open else None
+        self._open.append(name)
+        try:
+            with RecordEvent(f"{self.prefix}/{name}"):
+                yield
+        finally:
+            self._open.pop()
+            self.totals[name] = (self.totals.get(name, 0.0)
+                                 + time.perf_counter() - begin)
+            self.counts[name] = self.counts.get(name, 0) + 1
+
+    def self_seconds(self, name: str) -> float:
+        """A phase's total less the totals of the phases that ran
+        directly under it."""
+        return self.totals.get(name, 0.0) - sum(
+            t for child, t in self.totals.items()
+            if self.parents.get(child) == name)
 
     def summary(self) -> dict:
         """{phase: {total_s, count, mean_ms}} for every recorded phase."""
@@ -211,10 +238,6 @@ class ProfilerOptions:
         if options:
             self._options.update(options)
 
-    def with_state(self, state):
-        self._options["state"] = state
-        return self
-
     def __getitem__(self, name):
         if name not in self._options:
             raise ValueError(f"ProfilerOptions does not have an option "
@@ -248,41 +271,6 @@ class Profiler:
     def reset(self):
         from .. import core as _native
         _native.trace_clear()
-
-    def export_chrome_tracing(self, path: str,
-                              include_spans: bool = True) -> int:
-        """Chrome-trace export with the monitor tracer's request/fit
-        spans merged in: native host RecordEvent scopes AND
-        monitor/tracing.py spans land in ONE perfetto-loadable file
-        (the /debug/spans?format=chrome document, offline).  Returns
-        the total event count."""
-        import json
-        import os
-
-        from .. import core as _native
-
-        doc = {"traceEvents": [], "displayTimeUnit": "ms"}
-        if _native.available() and _native.trace_export(path) > 0:
-            try:
-                with open(path) as fh:
-                    loaded = json.load(fh)
-                doc = ({"traceEvents": loaded, "displayTimeUnit": "ms"}
-                       if isinstance(loaded, list) else loaded)
-            except (OSError, ValueError):
-                pass
-        if include_spans:
-            from ..monitor.tracing import default_tracer
-
-            span_doc = default_tracer().chrome_trace()
-            doc.setdefault("traceEvents", []).extend(
-                span_doc.get("traceEvents", ()))
-            if span_doc.get("metadata"):
-                doc.setdefault("metadata", {}).update(span_doc["metadata"])
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "w") as fh:
-            json.dump(doc, fh)
-        os.replace(tmp, path)
-        return len(doc.get("traceEvents", ()))
 
     def __enter__(self):
         return self.start()

@@ -1,0 +1,325 @@
+"""The host loops name their own time: `StepTimers` scopes cover every
+statement of the decode loop (`serving/generation.py` `_run`) and of the
+fit loop (`hapi/model.py`, `hapi/engine.py`), so that the top-level
+phases sum to the loop's wall time, a child never outlasts its parent,
+and the names documented in README.md "Reading a trace" are the names a
+profiler trace, `/metrics` and the benchmark's readers see.  The four
+executable names the benchmark matches on the trace's `XLA Modules` line
+are a contract pinned here."""
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import Model
+from paddle_tpu.framework import flags as _flags
+from paddle_tpu.io import TensorDataset
+from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+from paddle_tpu.monitor import tracing
+from paddle_tpu.serving import GenerationEngine
+from paddle_tpu.serving.metrics import GenerationMetrics
+from paddle_tpu.utils.profiler import StepTimers
+
+DECODE_TOP = {"wait", "pull", "sweep", "admit", "decode", "fetch",
+              "distribute"}
+ADMIT_CHILDREN = {"admit/lookup", "prefill", "admit/fetch",
+                  "admit/register", "admit/push"}
+FIT_TOP = {"data", "prepare", "dispatch", "sync", "metrics", "callbacks",
+           "write_back"}
+DISPATCH_CHILDREN = {"dispatch/lr", "dispatch/rng", "dispatch/call"}
+
+
+def top_level(timers):
+    return {n: t for n, t in timers.totals.items()
+            if timers.parents[n] is None}
+
+
+def assert_children_within_parents(timers):
+    for name in timers.totals:
+        assert -1e-9 <= timers.self_seconds(name) <= timers.totals[name]
+    for child, parent in timers.parents.items():
+        if parent is not None:
+            assert timers.totals[child] <= timers.totals[parent]
+
+
+class TestStepTimers:
+    def test_scopes_nest_under_a_prefix(self):
+        t = StepTimers("paddle.genserve")
+        with t.scope("admit"):
+            with t.scope("admit/lookup"):
+                time.sleep(0.002)
+            with t.scope("prefill"):
+                time.sleep(0.001)
+            time.sleep(0.001)
+        with t.scope("decode"):
+            pass
+        assert t.prefix == "paddle.genserve"
+        assert t.parents == {"admit": None, "admit/lookup": "admit",
+                             "prefill": "admit", "decode": None}
+        assert t.self_seconds("admit") == pytest.approx(
+            t.totals["admit"] - t.totals["admit/lookup"]
+            - t.totals["prefill"])
+        assert 0.001 <= t.self_seconds("admit") < t.totals["admit"]
+        assert t.self_seconds("decode") == t.totals["decode"]
+        assert_children_within_parents(t)
+
+    def test_a_scope_that_raises_still_closes(self):
+        t = StepTimers()
+        with pytest.raises(ValueError):
+            with t.scope("dispatch"):
+                with t.scope("dispatch/call"):
+                    raise ValueError("boom")
+        with t.scope("sync"):
+            pass
+        assert t.parents["sync"] is None          # the stack unwound
+        assert t.counts == {"dispatch/call": 1, "dispatch": 1, "sync": 1}
+
+    def test_annotation_carries_prefix_and_name(self, monkeypatch):
+        import jax
+
+        seen = []
+
+        class Spy:
+            def __init__(self, name):
+                seen.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+        with StepTimers("paddle.genserve").scope("admit/fetch"):
+            pass
+        with StepTimers().scope("data"):
+            pass
+        assert seen == ["paddle.genserve/admit/fetch", "paddle.fit/data"]
+
+
+@pytest.fixture(scope="module")
+def gpt():
+    paddle.seed(0)
+    m = GPTForCausalLM(GPTConfig(
+        vocab_size=211, hidden_size=48, num_layers=2, num_heads=4,
+        max_position_embeddings=64, dropout=0.0, attn_dropout=0.0))
+    m.eval()
+    return m
+
+
+@pytest.fixture()
+def tracer_on():
+    import paddle_tpu.monitor as monitor
+
+    old = _flags.flag("FLAGS_trace_sample_rate")
+    _flags.set_flags({"FLAGS_trace_sample_rate": 1.0})
+    monitor.reset()
+    yield tracing.default_tracer()
+    _flags.set_flags({"FLAGS_trace_sample_rate": old})
+    monitor.reset()
+
+
+@pytest.fixture(scope="module")
+def served(gpt):
+    """A tiny engine that served a plain request, a prefix hit and a
+    chunked prompt, drained; with the wall time of its loop."""
+    eng = GenerationEngine(gpt, max_slots=3, max_seq_len=40,
+                           prompt_buckets="8,16", page_size=4,
+                           prefix_cache=True, prefill_chunk=8)
+    run, life = eng._run, {}
+
+    def timed_run():
+        t0 = time.perf_counter()
+        try:
+            run()
+        finally:
+            life["wall"] = time.perf_counter() - t0
+
+    eng._run = timed_run            # the decode thread's target
+    eng.start()
+    prompt = list(range(50, 58))                        # two full pages
+    assert len(eng.generate([5, 9, 2], 6, timeout=60)) == 6     # plain
+    assert len(eng.generate(prompt, 6, timeout=60)) == 6        # miss
+    hits = eng.metrics.snapshot()["prefix_cache_hits"]
+    assert len(eng.generate(prompt, 6, timeout=60)) == 6        # hit
+    assert eng.metrics.snapshot()["prefix_cache_hits"] == hits + 1
+    assert len(eng.generate(list(range(60, 74)), 4, timeout=60)) == 4
+    assert eng.metrics.snapshot()["prefill_chunks"] >= 2        # chunked
+    assert eng.drain(timeout=60)
+    return eng, life["wall"]
+
+
+class TestDecodeLoop:
+    def test_documented_phases_exist(self, served):
+        eng, _ = served
+        names = set(eng.timers.totals)
+        assert DECODE_TOP | ADMIT_CHILDREN <= names
+        assert {"chunk", "prefill_chunk", "chunk/fetch"} <= names
+        top = set(top_level(eng.timers))
+        assert top == DECODE_TOP | {"chunk"}
+        assert {n for n, p in eng.timers.parents.items()
+                if p == "admit"} == ADMIT_CHILDREN
+        assert eng.timers.parents["prefill_chunk"] == "chunk"
+        assert eng.timers.prefix == "paddle.genserve"
+
+    def test_top_level_phases_cover_the_loops_wall_time(self, served):
+        eng, wall = served
+        covered = sum(top_level(eng.timers).values())
+        assert 0.95 * wall <= covered <= 1.001 * wall
+        assert_children_within_parents(eng.timers)
+        # one decode, fetch and distribute an iteration that stepped
+        c = eng.timers.counts
+        assert c["decode"] == c["fetch"] == c["distribute"] == eng._iter
+
+    def test_loop_seconds_are_in_the_prometheus_text(self, served):
+        eng, _ = served
+        text = eng.metrics.prometheus_text()
+        assert "# TYPE paddle_genserve_loop_seconds_total counter" in text
+        for phase in sorted(DECODE_TOP | ADMIT_CHILDREN):
+            assert f'paddle_genserve_loop_seconds_total{{phase="{phase}"}}' \
+                in text
+        rows = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                    if line.startswith("paddle_genserve_loop_"))
+        n = int(rows["paddle_genserve_loop_iterations_total"])
+        assert n >= eng._iter
+        fetch = float(
+            rows['paddle_genserve_loop_seconds_total{phase="fetch"}'])
+        assert 0 < fetch <= eng.timers.totals["fetch"] * 1.0001
+
+    def test_executable_names_the_benchmark_matches(self, served):
+        """`benchmarks/layer_metrics/*_device_ms.json` and the new idle
+        metrics anchor on these module names, letter for letter."""
+        eng, _ = served
+
+        def module(exe):
+            return exe.as_text().split("HloModule ", 1)[1].split(",")[0]
+
+        assert module(eng._decode_exec) == "jit_decode_step"
+        assert {module(e) for e in eng._prefill_execs.values()} \
+            == {"jit_target_prefill"}
+        assert {module(e) for e in eng._insert_prefix_execs.values()} \
+            == {"jit_insert_prefix_step"}
+
+    def test_token_events_carry_the_iteration(self, gpt, tracer_on):
+        eng = GenerationEngine(gpt, max_slots=2, max_seq_len=40,
+                               prompt_buckets="8,16").start()
+        try:
+            assert len(eng.generate([5, 9, 2], 5, timeout=60)) == 5
+            it0 = eng._iter
+            assert len(eng.generate([7, 7, 3], 4, timeout=60)) == 4
+        finally:
+            eng.stop()
+        spans = [s for s in tracer_on.spans() if s["name"] == "gen.decode"]
+        assert len(spans) == 2
+        iters = [e["iter"] for e in spans[1]["events"]
+                 if e["name"] == "token"]
+        # the second request's tokens came from consecutive decode steps,
+        # numbered on from where the first left the loop
+        assert iters == list(range(it0 + 1, it0 + 4))
+        prefill = [s for s in tracer_on.spans()
+                   if s["name"] == "gen.prefill"][1]
+        assert prefill["attrs"]["iter"] == it0
+
+
+class TestFitLoop:
+    def _fit(self):
+        """A tiny warm `fit` of two epochs; its loop's wall time is read
+        by a logger (which `fit` does not take for a user callback)
+        between on_train_begin and on_train_end."""
+        paddle.seed(0)
+        net = paddle.nn.Sequential(paddle.nn.Linear(4, 8), paddle.nn.ReLU(),
+                                   paddle.nn.Linear(8, 2))
+        rs = np.random.RandomState(0)
+        x = rs.randn(64, 4).astype("float32")
+        y = (x.sum(1) > 0).astype("int64")
+        model = Model(net)
+        model.prepare(
+            paddle.optimizer.Adam(learning_rate=0.01,
+                                  parameters=net.parameters()),
+            paddle.nn.CrossEntropyLoss(),
+            metrics=paddle.metric.Accuracy())
+        clock = {}
+
+        class Clock(paddle.callbacks.ProgBarLogger):
+            def on_train_begin(self, logs=None):
+                clock["t0"] = time.perf_counter()
+
+            def on_train_end(self, logs=None):
+                clock["wall"] = time.perf_counter() - clock["t0"]
+
+        for epochs in (1, 2):           # the first compiles
+            model.fit(TensorDataset([x, y]), batch_size=8, epochs=epochs,
+                      shuffle=False, verbose=0,
+                      callbacks=[Clock(log_freq=4, verbose=0)])
+        return model, clock["wall"]
+
+    def test_documented_phases_exist_and_nest(self):
+        model, _ = self._fit()
+        timers = model._last_fit_timers
+        assert set(top_level(timers)) == FIT_TOP
+        assert {n for n, p in timers.parents.items()
+                if p == "dispatch"} == DISPATCH_CHILDREN
+        assert timers.counts["dispatch"] == timers.counts["dispatch/call"] \
+            == 16
+        assert timers is model._engine.timers
+        assert_children_within_parents(timers)
+
+    def test_top_level_phases_cover_the_fit_loop(self):
+        model, wall = self._fit()
+        covered = sum(top_level(model._last_fit_timers).values())
+        assert 0.95 * wall <= covered <= wall
+
+    def test_the_train_step_is_named_jit_step(self):
+        model, _ = self._fit()
+        eng = model._engine.begin()
+        try:
+            x = paddle.to_tensor(np.zeros((8, 4), "float32"))
+            y = paddle.to_tensor(np.zeros((8,), "int64"))
+            text = eng.lower_step([x], [y]).as_text()
+        finally:
+            eng.finish()
+        assert "module @jit_step " in text
+
+
+class TestSpanClock:
+    def test_ts_ns_and_ts_ms_agree(self):
+        tracer = tracing.Tracer(sample_rate=1.0, max_spans=8)
+        before = time.time_ns()
+        with tracer.start_span("gen.request") as root:
+            with root.child("gen.queued"):
+                pass
+        after = time.time_ns()
+        spans = tracer.spans()
+        assert len(spans) == 2
+        for s in spans:
+            assert before <= s["ts_ns"] <= after
+            assert s["ts_ms"] == pytest.approx(s["ts_ns"] / 1e6, abs=1e-3)
+        assert spans[0]["ts_ns"] >= spans[1]["ts_ns"]   # child ends first
+
+
+class TestGenerationMetricsWindow:
+    def test_quantiles_cover_the_trailing_window_only(self, monkeypatch):
+        """Warm-up samples leave `/metrics` once they are WINDOW_S old."""
+        from paddle_tpu.utils import metrics as um
+
+        now = [1000.0]
+        monkeypatch.setattr(um.time, "monotonic", lambda: now[0])
+        m = GenerationMetrics(max_slots=2)
+        for _ in range(50):                 # warm-up: slow
+            m.observe_ttft(2.0)
+            m.observe_inter_token(0.5)
+        now[0] += m.WINDOW_S / 2
+        for _ in range(10):                 # steady state: fast
+            m.observe_ttft(0.040)
+            m.observe_inter_token(0.030)
+        snap = m.snapshot()
+        assert snap["ttft_p99_ms"] == 2000.0
+        assert snap["inter_token_p50_ms"] == 500.0
+        now[0] += m.WINDOW_S / 2 + 1        # the warm-up is now too old
+        snap = m.snapshot()
+        assert snap["ttft_p50_ms"] == snap["ttft_p99_ms"] == 40.0
+        assert snap["inter_token_p99_ms"] == 30.0
+        assert "paddle_genserve_ttft_p99_ms 40" in m.prometheus_text()
+        now[0] += m.WINDOW_S                # and then nothing is recent
+        assert m.snapshot()["ttft_p50_ms"] == 0.0

@@ -111,14 +111,53 @@ CASES = {
 }
 
 
+_TEXT = {}      # case -> the compiled HLO text, compiled once a module
+
+
+def _compiled_text(v5e, name):
+    if name not in _TEXT:
+        f, args = CASES[name]
+        one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+        shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                  for s, d in args]
+        _TEXT[name] = jax.jit(f).lower(*shapes).compile().as_text()
+    return _TEXT[name]
+
+
 @pytest.mark.kernels
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(v5e, name):
-    f, args = CASES[name]
-    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
-    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
-    compiled = jax.jit(f).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in _compiled_text(v5e, name)
+
+
+# every `pallas_call` of ops/pallas has a `name=`: the compiled
+# custom-call instruction carries it (`%jvp_paddle_flash_fwd_.1 = ...`),
+# and that instruction's text names the kernel's events on a profiler
+# trace's `XLA Ops` line (README.md "Reading a trace")
+KERNEL_NAMES = {
+    "paddle_flash_fwd": "flash_causal_fwd",
+    "paddle_flash_dq": "flash_causal_bwd",
+    "paddle_flash_dkv": "flash_causal_bwd",
+    "paddle_softmax_xent_fwd": "softmax_xent_bf16_fwd",
+    "paddle_softmax_xent_bwd": "softmax_xent_bf16_bwd",
+    "paddle_layer_norm_fwd": "layer_norm_f32_fwd",
+    "paddle_bias_gelu_fwd": "bias_gelu_fwd",
+    "paddle_bias_gelu_bwd": "bias_gelu_bwd",
+    "paddle_paged_decode_fwd": "paged_decode",
+}
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("kernel", list(KERNEL_NAMES))
+def test_kernel_name_is_on_the_compiled_call(v5e, kernel):
+    import re
+
+    calls = [line.split(" = ", 1)[0].strip().removeprefix("ROOT ")
+             for line in _compiled_text(v5e, KERNEL_NAMES[kernel]).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # jax wraps the name in the transformations it traced the call under
+    assert any(re.fullmatch(rf"%(\w+_)?{kernel}_*(\.\d+)?", c)
+               for c in calls), calls
 
 
 @pytest.mark.kernels
