@@ -1593,8 +1593,10 @@ def body_kernels(on_tpu):
     nhp, hdp = (8, 64) if on_tpu else (2, 16)
     npages, cap = slots * pps + 2, pps * ps
     qd = jnp.asarray(rs.randn(slots, nhp, hdp), jnp.float32) * scale
-    kp = jnp.asarray(rs.randn(npages, ps, nhp, hdp), jnp.float32) * scale
-    vp = jnp.asarray(rs.randn(npages, ps, nhp, hdp), jnp.float32) * scale
+    # the engine's stacked pools; the kernel reads plane `layer` of them
+    layer = 1
+    kp = jnp.asarray(rs.randn(2, npages, ps, nhp, hdp), jnp.float32) * scale
+    vp = jnp.asarray(rs.randn(2, npages, ps, nhp, hdp), jnp.float32) * scale
     rows_np = np.full((slots, pps), -1, np.int32)
     perm = rs.permutation(npages - 1) + 1
     pos_np = np.zeros(slots, np.int32)
@@ -1608,15 +1610,15 @@ def body_kernels(on_tpu):
 
     def paged_ref():
         gidx = jnp.clip(rows, 0, npages - 1)
-        kg = kp[gidx].reshape(slots, cap, nhp, hdp)
-        vg = vp[gidx].reshape(slots, cap, nhp, hdp)
+        kg = kp[layer, gidx].reshape(slots, cap, nhp, hdp)
+        vg = vp[layer, gidx].reshape(slots, cap, nhp, hdp)
         s = jnp.einsum("bnd,bsnd->bns", qd, kg) \
             * jnp.float32(1.0 / np.sqrt(hdp))
         valid = jnp.arange(cap)[None, :] <= pos[:, None]
         s = jnp.where(valid[:, None, :], s, jnp.float32(-1e30))
         return jnp.einsum("bns,bsnd->bnd", jax.nn.softmax(s, -1), vg)
 
-    out_pd = jax.jit(lambda *a: paged_decode_attention(*a, cap))(
+    out_pd = jax.jit(lambda *a: paged_decode_attention(*a, cap, layer))(
         qd, kp, vp, rows, pos)
     errs["paged"] = _err(out_pd, jax.jit(paged_ref)())
 

@@ -158,6 +158,7 @@ def phase_kernels(args, jax, sizes):
                                ("B", "S", "NH", "HD", "H", "FFN", "V"))
     slots, page = k["slots"], k["page"]
     pps = S // page
+    PLANE = 1   # the paged kernel reads one plane of the stacked pools
     f32, bf16 = jnp.float32, jnp.bfloat16
     rs = np.random.RandomState(args.seed)
 
@@ -192,7 +193,7 @@ def phase_kernels(args, jax, sizes):
         return -jnp.take_along_axis(lp, lab[:, None], 1)[:, 0]
 
     def ref_paged(q, kp, vp, rows, pos):
-        q, kp, vp = up(q, kp, vp)
+        q, kp, vp = up(q, kp[PLANE], vp[PLANE])
         kg = kp[jnp.clip(rows, 0)].reshape(slots, pps * page, NH, HD)
         vg = vp[jnp.clip(rows, 0)].reshape(slots, pps * page, NH, HD)
         s = jnp.einsum("bnd,bsnd->bns", q, kg) / math.sqrt(HD)
@@ -213,8 +214,8 @@ def phase_kernels(args, jax, sizes):
         rows[lane, :used] = perm[lane * pps:lane * pps + used]
         pos[lane] = used * page - 1 - rs.randint(page)
     paged_args = (rand((slots, NH, HD), bf16),
-                  rand((n_pages, page, NH, HD), bf16),
-                  rand((n_pages, page, NH, HD), bf16),
+                  rand((3, n_pages, page, NH, HD), bf16),
+                  rand((3, n_pages, page, NH, HD), bf16),
                   jnp.asarray(rows), jnp.asarray(pos))
     labels = jnp.asarray(rs.randint(0, V, (B * S,)), jnp.int32)
 
@@ -240,7 +241,7 @@ def phase_kernels(args, jax, sizes):
             softmax_xent, ref_xent,
             [rand((B * S, V), bf16), labels], (0,), 2e-2),
         "paged_decode": (
-            lambda *a: paged_decode_attention(*a, S), ref_paged,
+            lambda *a: paged_decode_attention(*a, S, PLANE), ref_paged,
             list(paged_args), (), 2e-2),
     }
 

@@ -151,14 +151,17 @@ class GPTAttention(Layer):
         return out, Tensor(k_cache), Tensor(v_cache)
 
     def decode_pages(self, x, k_pages, v_pages, rows, pos, active,
-                     seq_cap):
+                     seq_cap, layer):
         """Paged continuous-batching decode: like ``decode_slots`` but
         each lane's KV lives in fixed-size pool pages indirected through
         its page-table row (serving/kv_cache.py) instead of a dense
         ``[slots, S_max]`` stripe.
 
-        x: [slots, 1, H]; k_pages/v_pages: [num_pages, page_size, nh,
-        hd] (this layer's pool plane); rows: [slots, pages_per_slot]
+        x: [slots, 1, H]; k_pages/v_pages: [layers, num_pages,
+        page_size, nh, hd] (the WHOLE pools; this layer writes and reads
+        plane ``layer``, a static int, and returns the whole pools, so a
+        donated pool is rewritten in place and no plane is ever sliced
+        out or stacked back); rows: [slots, pages_per_slot]
         int32 page table (-1 = unmapped); pos: [slots] write index;
         active: [slots]; seq_cap: STATIC attention extent (the engine's
         S_max) — the gathered view is sliced to it so the softmax
@@ -184,31 +187,32 @@ class GPTAttention(Layer):
         active = jnp.asarray(unwrap(active), bool)
         k_pages, v_pages = unwrap(k_pages), unwrap(v_pages)
         rows = jnp.asarray(unwrap(rows), jnp.int32)
-        num_pages, ps = k_pages.shape[0], k_pages.shape[1]
+        num_pages, ps = k_pages.shape[1], k_pages.shape[2]
         lane = jnp.arange(B)
         # per-lane scatter: lane b writes its token's K/V at
-        # (rows[b, pos[b]//ps], pos[b]%ps); inactive lanes target
+        # (layer, rows[b, pos[b]//ps], pos[b]%ps); inactive lanes target
         # one-past-the-pool and are dropped
         page = rows[lane, jnp.clip(pos // ps, 0, rows.shape[1] - 1)]
         page = jnp.where(active, page, num_pages)
         off = pos % ps
-        k_pages = k_pages.at[page, off].set(k.astype(k_pages.dtype),
-                                            mode="drop")
-        v_pages = v_pages.at[page, off].set(v.astype(v_pages.dtype),
-                                            mode="drop")
+        k_pages = k_pages.at[layer, page, off].set(
+            k.astype(k_pages.dtype), mode="drop")
+        v_pages = v_pages.at[layer, page, off].set(
+            v.astype(v_pages.dtype), mode="drop")
         # hot path: the Pallas ragged kernel walks each lane's page-table
-        # row and reads the pool in place — no dense [slots, seq_cap]
-        # gather is materialized.  None => flag off / untileable geometry
+        # row and reads plane `layer` of the pool in place — no dense
+        # [slots, seq_cap] gather and no copy of the plane is
+        # materialized.  None => flag off / untileable geometry
         # (counted in paddle_pallas_fallbacks_total); the dense gather
         # below stays as the reference and fallback.
         ctx = fused.paged_decode_attention(
-            q, k_pages, v_pages, rows, pos, seq_cap,
+            q, k_pages, v_pages, rows, pos, seq_cap, layer,
             tp_axis="mp" if cfg.tensor_parallel else None)
         if ctx is None:
             # gather each lane's pages into a contiguous [seq_cap] view
             gidx = jnp.clip(rows, 0, num_pages - 1)
-            kg = k_pages[gidx].reshape(B, rows.shape[1] * ps, nh, hd)
-            vg = v_pages[gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+            kg = k_pages[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+            vg = v_pages[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
             kg, vg = kg[:, :seq_cap], vg[:, :seq_cap]
             scores = jnp.einsum("bqnd,bsnd->bnqs", q, kg) \
                 * (1.0 / float(hd) ** 0.5)
@@ -225,14 +229,15 @@ class GPTAttention(Layer):
         return out, Tensor(k_pages), Tensor(v_pages)
 
     def verify_pages(self, x, k_pages, v_pages, rows, positions, active,
-                     seq_cap):
+                     seq_cap, layer):
         """Speculative-decode verification attention: like
         ``decode_pages`` but each lane carries a CHUNK of C candidate
         tokens at consecutive positions instead of one — the target
         model scores every draft proposal in a single batched step.
 
-        x: [slots, C, H]; k_pages/v_pages: [num_pages, page_size, nh,
-        hd] (this layer's pool plane); rows: [slots, pages_per_slot]
+        x: [slots, C, H]; k_pages/v_pages: [layers, num_pages,
+        page_size, nh, hd] (the whole pools, plane ``layer`` written and
+        read, as in ``decode_pages``); rows: [slots, pages_per_slot]
         int32 page table; positions: [slots, C] absolute write index
         per candidate (consecutive per lane, clamped by the engine so
         they never run past the slot's reserved extent); active:
@@ -260,10 +265,10 @@ class GPTAttention(Layer):
         active = jnp.asarray(unwrap(active), bool)
         k_pages, v_pages = unwrap(k_pages), unwrap(v_pages)
         rows = jnp.asarray(unwrap(rows), jnp.int32)
-        num_pages, ps = k_pages.shape[0], k_pages.shape[1]
+        num_pages, ps = k_pages.shape[1], k_pages.shape[2]
         lane = jnp.arange(B)
-        # per-element scatter: candidate (b, i) writes its K/V at
-        # (rows[b, positions[b,i]//ps], positions[b,i]%ps); inactive
+        # per-element scatter: candidate (b, i) writes its K/V at (layer,
+        # rows[b, positions[b,i]//ps], positions[b,i]%ps); inactive
         # lanes target one-past-the-pool and are dropped.  Clamped
         # duplicate positions (end-of-budget) may collide — whichever
         # write wins is garbage no emitted query's mask ever exposes.
@@ -271,16 +276,16 @@ class GPTAttention(Layer):
                     jnp.clip(positions // ps, 0, rows.shape[1] - 1)]
         page = jnp.where(active[:, None], page, num_pages)
         off = positions % ps
-        k_pages = k_pages.at[page, off].set(k.astype(k_pages.dtype),
-                                            mode="drop")
-        v_pages = v_pages.at[page, off].set(v.astype(v_pages.dtype),
-                                            mode="drop")
+        k_pages = k_pages.at[layer, page, off].set(
+            k.astype(k_pages.dtype), mode="drop")
+        v_pages = v_pages.at[layer, page, off].set(
+            v.astype(v_pages.dtype), mode="drop")
         # dense per-lane gather (the decode_pages fallback math with a
         # C-wide query dim); no Pallas path — verification is one step
         # per K drafted tokens, off the per-token hot loop
         gidx = jnp.clip(rows, 0, num_pages - 1)
-        kg = k_pages[gidx].reshape(B, rows.shape[1] * ps, nh, hd)
-        vg = v_pages[gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+        kg = k_pages[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+        vg = v_pages[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
         kg, vg = kg[:, :seq_cap], vg[:, :seq_cap]
         scores = jnp.einsum("bqnd,bsnd->bnqs", q, kg) \
             * (1.0 / float(hd) ** 0.5)
@@ -449,18 +454,19 @@ class GPTBlock(Layer):
         return x, k_cache, v_cache
 
     def decode_pages(self, x, k_pages, v_pages, rows, pos, active,
-                     seq_cap):
+                     seq_cap, layer):
         a, k_pages, v_pages = self.attn.decode_pages(
-            self.ln_1(x), k_pages, v_pages, rows, pos, active, seq_cap)
+            self.ln_1(x), k_pages, v_pages, rows, pos, active, seq_cap,
+            layer)
         x = x + a
         x = x + self.mlp(self.ln_2(x))
         return x, k_pages, v_pages
 
     def verify_pages(self, x, k_pages, v_pages, rows, positions, active,
-                     seq_cap):
+                     seq_cap, layer):
         a, k_pages, v_pages = self.attn.verify_pages(
             self.ln_1(x), k_pages, v_pages, rows, positions, active,
-            seq_cap)
+            seq_cap, layer)
         x = x + a
         x = x + self.mlp(self.ln_2(x))
         return x, k_pages, v_pages
@@ -680,7 +686,11 @@ class GPTForCausalLM(Layer):
         table, seq_cap the static attention extent (engine S_max).
         Returns (logits [slots, V], k_pages', v_pages') — ONE
         fixed-shape program regardless of which lanes are live or how
-        pages are scattered through the pool.
+        pages are scattered through the pool.  The two pools are threaded
+        whole through the blocks: each writes its token's rows into its
+        own plane and reads that plane where it lies, so with the pools
+        donated the step rewrites them in place and holds no copy of a
+        plane (tests/test_mosaic_compile.py reads the compiled step).
         """
         import jax.numpy as jnp
 
@@ -695,14 +705,11 @@ class GPTForCausalLM(Layer):
         k_pages, v_pages = unwrap(k_pages), unwrap(v_pages)
         x = gpt.wte(Tensor(tokens[:, None])) \
             + gpt.wpe(T.reshape(Tensor(unwrap(pos)), [-1, 1]))
-        ks, vs = [], []
         for i, blk in enumerate(gpt.h):
-            x, kp, vp = blk.decode_pages(x, k_pages[i], v_pages[i], rows,
-                                         pos, active, seq_cap)
-            ks.append(unwrap(kp))
-            vs.append(unwrap(vp))
+            x, k_pages, v_pages = blk.decode_pages(
+                x, k_pages, v_pages, rows, pos, active, seq_cap, i)
         logits = self._head(gpt.ln_f(x))             # [slots, 1, V]
-        return unwrap(logits)[:, 0], jnp.stack(ks), jnp.stack(vs)
+        return unwrap(logits)[:, 0], unwrap(k_pages), unwrap(v_pages)
 
     def slot_verify_paged(self, tokens, positions, active, k_pages,
                           v_pages, rows, seq_cap):
@@ -734,14 +741,11 @@ class GPTForCausalLM(Layer):
         # turns into output tokens)
         pos_emb = jnp.clip(positions, 0, cfg.max_position_embeddings - 1)
         x = gpt.wte(Tensor(tokens)) + gpt.wpe(Tensor(pos_emb))
-        ks, vs = [], []
         for i, blk in enumerate(gpt.h):
-            x, kp, vp = blk.verify_pages(x, k_pages[i], v_pages[i], rows,
-                                         positions, active, seq_cap)
-            ks.append(unwrap(kp))
-            vs.append(unwrap(vp))
+            x, k_pages, v_pages = blk.verify_pages(
+                x, k_pages, v_pages, rows, positions, active, seq_cap, i)
         logits = self._head(gpt.ln_f(x))             # [slots, C, V]
-        return unwrap(logits), jnp.stack(ks), jnp.stack(vs)
+        return unwrap(logits), unwrap(k_pages), unwrap(v_pages)
 
     def slot_prefill_prefix(self, input_ids, prefix_k, prefix_v,
                             prefix_len, length):

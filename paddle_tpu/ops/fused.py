@@ -403,18 +403,20 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 # paged decode attention (fused_multi_transformer's masked decode analog):
 # ragged Pallas kernel walking each lane's page-table row over the KV pool
 # ---------------------------------------------------------------------------
-def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap,
+def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
                            tp_axis=None):
-    """Pallas paged decode attention over one layer's KV pool plane, or
-    None when the kernels are off or the kernel says it does not tile this
-    geometry (the caller keeps its dense-gather reference path and the
-    refusal shows up in the fallback counter).
+    """Pallas paged decode attention over plane `layer` of the whole KV
+    pool, or None when the kernels are off or the kernel says it does not
+    tile this geometry (the caller keeps its dense-gather reference path
+    and the refusal shows up in the fallback counter).
 
     q [slots, 1, nh, hd] (the step's query, post-scatter); k_pages/v_pages
-    [num_pages, page_size, nh, hd]; rows [slots, pages_per_slot] int32
-    (-1 = unmapped); pos [slots] int32 inclusive extent; seq_cap static.
-    Returns [slots, 1, nh, hd].  `tp_axis` names the mesh axis the pool's
-    head dim is sharded over (the models' "mp" pin), if any.
+    [layers, num_pages, page_size, nh, hd] (the stacked pools, never a
+    slice of them: a sliced operand is a copied plane); rows [slots,
+    pages_per_slot] int32 (-1 = unmapped); pos [slots] int32 inclusive
+    extent; seq_cap and layer static.  Returns [slots, 1, nh, hd].
+    `tp_axis` names the mesh axis the pool's head dim is sharded over (the
+    models' "mp" pin), if any.
     """
     if not _use_pallas():
         return None
@@ -426,10 +428,11 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap,
         q1 = qv[:, 0]
         if mesh is not None:
             out = pa.sharded_paged_decode_attention(
-                q1, kp, vp, rw, ps_, seq_cap, mesh,
+                q1, kp, vp, rw, ps_, seq_cap, layer, mesh,
                 tp_axis if tp_axis in mesh.axis_names else None)
         else:
-            out = pa.paged_decode_attention(q1, kp, vp, rw, ps_, seq_cap)
+            out = pa.paged_decode_attention(q1, kp, vp, rw, ps_, seq_cap,
+                                            layer)
         return out[:, None]
 
     return _kernel_or_none(

@@ -7,11 +7,16 @@ serving/kv_cache.py directly).
 
 One query token per lane attends over that lane's pages, walked through its
 int32 page-table row — the pool is never gathered into a dense
-``[slots, S_max]`` view.  The page table and per-lane positions ride in as
-scalar-prefetch operands (pltpu.PrefetchScalarGridSpec), so the KV
-BlockSpec index maps pick each grid step's page straight from the table
-and Mosaic can start the HBM->VMEM fetch of page ``rows[lane, p]`` while
-the previous page is still being processed.
+``[slots, S_max]`` view, and never sliced to one layer either: the operand
+is the WHOLE stacked pool ``[layers, num_pages, page_size, nh, hd]`` and a
+static ``layer`` picks the plane inside the BlockSpec index maps.  (A
+``k_pages[layer]`` outside the call is a copy of the plane: the operand of
+a Mosaic custom call cannot be a fused slice.)  The page table and
+per-lane positions ride in as scalar-prefetch operands
+(pltpu.PrefetchScalarGridSpec), so the KV index maps pick each grid
+step's page straight from the table and Mosaic can start the HBM->VMEM
+fetch of page ``(layer, rows[lane, p])`` while the previous page is still
+being processed.
 
 Grid is (slots, pages_walked): for each lane the kernel runs the flash
 running-softmax (m/l/acc in VMEM scratch) across its pages; pages that are
@@ -23,9 +28,12 @@ exactly, token by token.
 
 Used by GPTAttention.decode_pages through ops/fused.py when
 FLAGS_use_pallas_kernels is on; the dense-gather path stays as the
-fallback and parity reference.  The kernel only READS the pool (the
-current token's K/V scatter stays an XLA `.at[].set` before the call), so
-it composes with the engine's buffer donation untouched.
+fallback and parity reference.  The kernel only READS the pool: the
+current token's K/V rows are scattered by XLA before the call
+(``k_pages.at[layer, page, off].set``), in place into the donated pool,
+and the call then reads that same buffer — no plane and no pool is copied
+around it (tests/test_mosaic_compile.py holds the compiled decode step to
+that).
 """
 from __future__ import annotations
 
@@ -89,23 +97,35 @@ def _kernel(rows_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
-                           sm_scale=None, interpret: bool | None = None):
-    """Ragged decode attention over the paged KV pool.
+                           layer: int, sm_scale=None,
+                           interpret: bool | None = None):
+    """Ragged decode attention over plane ``layer`` of the paged KV pool.
 
     q: [slots, nh, hd] (one token per lane); k_pages/v_pages:
-    [num_pages, page_size, nh, hd] (one layer's pool plane, AFTER the
+    [layers, num_pages, page_size, nh, hd] (the WHOLE pool, AFTER the
     current token's scatter); rows: [slots, pages_per_slot] int32 page
     table (-1 = unmapped); pos: [slots] int32 attention extent per lane
     (inclusive); seq_cap: STATIC max extent — only ceil(seq_cap /
-    page_size) table columns are walked.  Returns [slots, nh, hd] in
-    q's dtype.  Raises DoesNotTile for untileable geometry
-    (caller falls back to the dense gather).
+    page_size) table columns are walked; layer: STATIC plane of the pool
+    (a Python int, closed over by the index maps: it is no operand).
+    Returns [slots, nh, hd] in q's dtype.  Raises DoesNotTile for
+    untileable geometry (caller falls back to the dense gather).
     """
     slots, nh, hd = q.shape
-    num_pages, ps = k_pages.shape[0], k_pages.shape[1]
-    if k_pages.shape[2] != nh or k_pages.shape[3] != hd:
+    if k_pages.ndim != 5 or v_pages.shape != k_pages.shape:
+        raise ValueError(
+            "paged_decode_attention takes the whole pools [layers, "
+            f"num_pages, page_size, nh, hd], got {k_pages.shape} and "
+            f"{v_pages.shape}")
+    layer = int(layer)
+    if not 0 <= layer < k_pages.shape[0]:
+        raise ValueError(
+            f"paged_decode_attention: layer {layer} outside a pool of "
+            f"{k_pages.shape[0]} layers")
+    ps = k_pages.shape[2]
+    if k_pages.shape[3] != nh or k_pages.shape[4] != hd:
         raise DoesNotTile(
-            f"paged_decode_attention: pool heads {k_pages.shape[2:]} != "
+            f"paged_decode_attention: pool heads {k_pages.shape[3:]} != "
             f"query heads ({nh}, {hd})")
     pages_walked = -(-int(seq_cap) // ps)
     if pages_walked > rows.shape[1]:
@@ -122,20 +142,21 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
 
     rows = jnp.asarray(rows, jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
+    # the layer axis is squeezed out of the block, so the kernel's body
+    # sees the [1, ps, nh, hd] page it always saw; dead (unmapped /
+    # past-pos) pages clamp to page 0: the fetch target must be in-bounds
+    # even though pl.when skips the math
+    page_spec = pl.BlockSpec(
+        (None, 1, ps, nh, hd),
+        lambda l, p, rows, pos: (layer, jnp.maximum(rows[l, p], 0), 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(slots, pages_walked),
         in_specs=[
             pl.BlockSpec((1, nh, hd),
                          lambda l, p, rows, pos: (l, 0, 0)),
-            # dead (unmapped / past-pos) pages clamp to page 0: the fetch
-            # target must be in-bounds even though pl.when skips the math
-            pl.BlockSpec((1, ps, nh, hd),
-                         lambda l, p, rows, pos:
-                         (jnp.maximum(rows[l, p], 0), 0, 0, 0)),
-            pl.BlockSpec((1, ps, nh, hd),
-                         lambda l, p, rows, pos:
-                         (jnp.maximum(rows[l, p], 0), 0, 0, 0)),
+            page_spec,
+            page_spec,
         ],
         out_specs=pl.BlockSpec((1, nh, hd),
                                lambda l, p, rows, pos: (l, 0, 0)),
@@ -157,8 +178,8 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
 
 
 def sharded_paged_decode_attention(q, k_pages, v_pages, rows, pos,
-                                   seq_cap: int, mesh, head_axis,
-                                   sm_scale=None,
+                                   seq_cap: int, layer: int, mesh,
+                                   head_axis, sm_scale=None,
                                    interpret: bool | None = None):
     """paged_decode_attention under shard_map: the pool's head axis is
     sharded over ``head_axis`` (layout.kv_page_spec() / the models' "mp"
@@ -177,15 +198,15 @@ def sharded_paged_decode_attention(q, k_pages, v_pages, rows, pos,
             f"sharded paged_decode_attention: heads {nh} % tp {tp} != 0")
 
     def body(ql, kl, vl, rl, pl_):
-        return paged_decode_attention(ql, kl, vl, rl, pl_, seq_cap,
+        return paged_decode_attention(ql, kl, vl, rl, pl_, seq_cap, layer,
                                       sm_scale=sm_scale,
                                       interpret=interpret)
 
     f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, head_axis, None),
-                  P(None, None, head_axis, None),
-                  P(None, None, head_axis, None),
+                  P(None, None, None, head_axis, None),
+                  P(None, None, None, head_axis, None),
                   P(None, None), P(None)),
         out_specs=P(None, head_axis, None), check_vma=False)
     return f(q, k_pages, v_pages, rows, pos)

@@ -418,6 +418,7 @@ class GenerationEngine:
         self._params = None
         self._buffers = None
         self._decode_exec = None
+        self.decode_temp_bytes = None   # set by start(), from the compiler
         self._spec_exec = None
         self._release_exec = None
         self._reclaim_exec = None
@@ -881,12 +882,21 @@ class GenerationEngine:
                         out_shardings=outs(rep, rep))
                     self.compile_count += 1
         self.metrics.set_compile_count(self.compile_count)
+        # the decode step rewrites the donated pools in place: what it
+        # holds beside them stays far under one layer's plane of one pool
+        # (a copied plane or pool would show here before any chip run)
+        mem = (self._spec_exec or self._decode_exec).memory_analysis()
+        self.decode_temp_bytes = (int(mem.temp_size_in_bytes)
+                                  if mem is not None else None)
         logger.info(
             "generation warmup compiled %d executable(s): slots=%d "
-            "S_max=%d prompt buckets=%s pages=%dx%d cache=%.1f MB%s",
+            "S_max=%d prompt buckets=%s pages=%dx%d cache=%.1f MB "
+            "decode temps=%s MB%s",
             self.compile_count, self.max_slots, self.max_seq_len,
             self.prompt_buckets, geom.num_pages, geom.page_size,
             geom.kv_bytes() / 1048576,
+            "n/a" if self.decode_temp_bytes is None
+            else f"{self.decode_temp_bytes / 1048576:.1f}",
             f" mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}"
             if mesh is not None else "")
 

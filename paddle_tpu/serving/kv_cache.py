@@ -12,7 +12,16 @@ per-slot PRNG keys, pinned shared-page count).
 Every jitted transition (insert / decode / release / reclaim) takes the
 state as its first state-argument with ``donate_argnums`` — the
 TrainEngine donation contract from hapi/engine.py — so XLA rewrites the
-pool in place and the KV bytes NEVER round-trip to host.  Page
+pool in place and the KV bytes NEVER round-trip to host.  Donation alone
+does not make it so: the program must also keep the pool whole.  The
+decode and verify steps (models/gpt.py ``slot_decode_paged`` /
+``slot_verify_paged``) scatter each layer's rows with
+``pool.at[layer, page, off].set`` and hand the paged kernel the whole
+pool with a static ``layer``: a per-layer ``pool[i]`` handed to the
+kernel, or a ``jnp.stack`` of planes at the end, compiles to a copy of
+every plane and of both pools each step, whatever is donated (PERF.md,
+PR 25).  ``GenerationEngine.start()`` logs the decode executable's
+temporaries beside the cache size.  Page
 allocation happens IN-GRAPH: admission maps ``ceil(len/page_size)``
 pages off the free stack, decode pops a fresh tail page the iteration a
 lane's write position crosses a page boundary, and retirement pushes a

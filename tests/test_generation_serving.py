@@ -187,6 +187,28 @@ class TestLifecycle:
             eng.submit(PROMPT_A, 2)
         eng.stop()
 
+    def test_warmup_logs_the_decode_steps_temporaries(self, model, caplog):
+        """start() reports what the compiled decode step holds beside the
+        donated pools (the compiler's `temp_size_in_bytes`), next to the
+        cache's size: a copied plane or pool shows there before any run."""
+        import logging
+
+        paddle.seed(0)
+        with caplog.at_level(logging.INFO, logger="paddle_tpu.serving"):
+            eng = GenerationEngine(model, max_slots=2, max_seq_len=40,
+                                   prompt_buckets="8").start()
+        try:
+            assert isinstance(eng.decode_temp_bytes, int)
+            assert eng.decode_temp_bytes == int(
+                eng._decode_exec.memory_analysis().temp_size_in_bytes)
+            line = next(r.getMessage() for r in caplog.records
+                        if "generation warmup compiled" in r.getMessage())
+            assert (f"decode temps={eng.decode_temp_bytes / 1048576:.1f} MB"
+                    in line), line
+            assert "cache=" in line
+        finally:
+            eng.stop()
+
     def test_stop_fails_inflight(self, model):
         paddle.seed(0)
         eng = GenerationEngine(model, max_slots=2, max_seq_len=40,

@@ -323,3 +323,27 @@ class TestGenerationMetricsWindow:
         assert "paddle_genserve_ttft_p99_ms 40" in m.prometheus_text()
         now[0] += m.WINDOW_S                # and then nothing is recent
         assert m.snapshot()["ttft_p50_ms"] == 0.0
+
+
+def test_trace_ops_sums_a_recorded_trace_by_kind(capsys):
+    """`tools/trace_ops.py` over the benchmark's small recorded TPU trace:
+    every device operation of the window summed by kind, those whose text
+    holds a given shape apart (how PERF.md's chat table is made)."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "trace_ops", os.path.join(root, "tools", "trace_ops.py"))
+    trace_ops = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_ops)
+    trace_ops.main(os.path.join(root, "benchmarks", "tests", "data",
+                                "small_phases.xplane.pb"), ["f32[]"])
+    head, *rows = capsys.readouterr().out.splitlines()
+    head = json.loads(head)
+    assert head["decode_steps"] == 5
+    assert 0 < head["shaped_s"] < head["ops_s"] <= head["busy_s"] * 1.001
+    shaped = rows[rows.index("WITH A SHAPE") + 1:
+                  next(i for i, r in enumerate(rows) if r.startswith("TOP"))]
+    assert len(shaped) == 1 and "%convert_reduce_fusion = f32[]" in shaped[0]

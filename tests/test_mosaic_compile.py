@@ -59,9 +59,10 @@ LN_BF16 = [((SLOTS, 1, H), BF16), ((H,), BF16), ((H,), BF16)]  # bf16 decode
 GELU_ARGS = [((B, S, FFN), BF16), ((FFN,), BF16)]
 XENT_BF16 = [((B * S, V), BF16), ((B * S,), I32)]
 XENT_F32 = [((2048, 50257), F32), ((2048,), I32)]   # BERT/HF vocab, padded
-PAGED_ARGS = [((SLOTS, NH, HD), BF16),
-              ((SLOTS * PAGES_PER_SLOT + 1, PAGE, NH, HD), BF16),
-              ((SLOTS * PAGES_PER_SLOT + 1, PAGE, NH, HD), BF16),
+# the engine's stacked pool, all layers of it: the kernel reads one plane
+POOL_LAYERS, PAGED_LAYER = 12, 5
+POOL = ((POOL_LAYERS, SLOTS * PAGES_PER_SLOT + 1, PAGE, NH, HD), BF16)
+PAGED_ARGS = [((SLOTS, NH, HD), BF16), POOL, POOL,
               ((SLOTS, PAGES_PER_SLOT), I32), ((SLOTS,), I32)]
 
 
@@ -85,7 +86,7 @@ def _xent(z, lab):
 
 
 def _paged(q, kp, vp, rows, pos):
-    return pa.paged_decode_attention(q, kp, vp, rows, pos, S,
+    return pa.paged_decode_attention(q, kp, vp, rows, pos, S, PAGED_LAYER,
                                      interpret=False)
 
 
@@ -187,3 +188,149 @@ def test_kernels_compose_with_a_2x2_mesh(v5e):
         jax.jit(_ln).lower(*args[:3])
     compiled = jax.jit(jax.grad(step, argnums=(0, 3))).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the decode step holds its KV pools in place -----------------------------
+# `GPTForCausalLM.slot_decode_paged` threads the two stacked pools through
+# its blocks: each scatters its token's rows into its own plane of the
+# donated pool and the paged kernel reads that plane where it lies.  A
+# `k_pages[i]` handed to the kernel, or a `jnp.stack` of the planes at the
+# end, compiles to copies of planes and pools (72% of the chat cell's
+# decode step on a v5e, PERF.md PR 25).  Read the compiled step for them.
+def _decode_step_compiled(device, layers, slots, seq, page, nh, hd, pages,
+                          dtype, backend=None, pallas=None):
+    """`slot_decode_paged` of a small GPT, the pools donated, compiled for
+    `device`.  Returns (compiled, pool shape)."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.nn.layer_base import functional_call
+    from paddle_tpu.ops import fused
+    from paddle_tpu.tensor import unwrap
+
+    net = GPTForCausalLM(GPTConfig(
+        vocab_size=512, hidden_size=nh * hd, num_layers=layers,
+        num_heads=nh, max_position_embeddings=seq, dropout=0.0,
+        attn_dropout=0.0))
+    net.eval()
+    pool = (layers, pages, page, nh, hd)
+    sharding = jax.sharding.SingleDeviceSharding(device)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    def step(params, tok, pos, active, kp, vp, rows):
+        out, _ = functional_call(
+            net, params, (tok, pos, active, kp, vp, rows, seq),
+            mutable=False, method="slot_decode_paged")
+        return out
+
+    # the step is traced over shapes: the weights it is given are of the
+    # served dtype whatever the constructor drew
+    args = ({n: sds(unwrap(p).shape, jnp.dtype(dtype))
+             for n, p in net.named_parameters()},
+            sds((slots,), I32), sds((slots,), I32), sds((slots,), jnp.bool_),
+            sds(pool, jnp.dtype(dtype)), sds(pool, jnp.dtype(dtype)),
+            sds((slots, seq // page), I32))
+    # the program asks the process which backend and flag it runs under;
+    # the test answers in their place while the step is traced
+    real_backend, real_flag = jax.default_backend, fused._use_pallas
+    if backend is not None:
+        jax.default_backend = lambda: backend
+    if pallas is not None:
+        fused._use_pallas = lambda: pallas
+    try:
+        lowered = jax.jit(step, donate_argnums=(4, 5)).lower(*args)
+    finally:
+        jax.default_backend, fused._use_pallas = real_backend, real_flag
+    return lowered.compile(), pool
+
+
+def _pool_shaped_outputs(text, pool):
+    """(opcode, line) of every instruction of the optimised HLO whose
+    result (or an element of whose tuple result) is a whole pool or one
+    layer's plane of it."""
+    import re
+
+    dims = ",".join(map(str, pool))
+    shapes = (f"[{dims}]", f"[{dims.split(',', 1)[1]}]")
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
+        if m and any(s in m.group(1) for s in shapes):
+            found.append((m.group(2), line.strip()))
+    return found
+
+
+def _fusion_root_opcode(text, fusion_line):
+    """The opcode of the root of the computation a fusion calls."""
+    import re
+
+    called = re.search(r"calls=(%[\w.-]+)", fusion_line).group(1)
+    body = text.split(f"\n{called} (", 1)[1].split("\n}", 1)[0]
+    return re.search(r"\n\s*ROOT %\S+ = .*? ([\w-]+)\(", body).group(1)
+
+
+def _assert_pools_stay_in_place(compiled, pool, itemsize):
+    layers = pool[0]
+    text = compiled.as_text()
+    outputs = _pool_shaped_outputs(text, pool)
+    # the two donated pools come in as parameters (of the entry and of the
+    # scatters' fusions) and go out through the scatters; beside what only
+    # names a buffer, nothing else may produce a pool or a plane: no
+    # slice, copy, dynamic-update-slice, concatenate or broadcast of one
+    names_a_buffer = ("parameter", "tuple", "get-tuple-element", "bitcast")
+    extra = [line for op, line in outputs
+             if op not in names_a_buffer + ("scatter", "fusion")]
+    assert not extra, [line[:200] for line in extra]
+    for line in (ln for op, ln in outputs if op == "fusion"):
+        assert _fusion_root_opcode(text, line) == "scatter", line[:200]
+    scatters = [line for op, line in outputs if op == "scatter"]
+    assert len(scatters) == 2 * layers                  # K and V, a layer
+    mem = compiled.memory_analysis()
+    plane = itemsize
+    for d in pool[1:]:
+        plane *= d
+    # both pools are rewritten where they lie ...
+    assert mem.alias_size_in_bytes >= 2 * layers * plane
+    # ... and the step holds less than one plane beside them (sliced and
+    # stacked, this 4-layer step holds 3.25 planes of temporaries)
+    assert mem.temp_size_in_bytes < plane, (mem.temp_size_in_bytes, plane)
+
+
+@pytest.mark.kernels
+def test_decode_step_keeps_the_donated_pools_in_place_on_v5e(v5e):
+    """Four layers at the chat cell's head geometry (heads of 128, pages
+    of 16 tokens, 16 slots x 1024, a plane of 16.8 MB): the compiled step
+    has one paged kernel a layer, each reading the whole pool, and the
+    only instructions that output a pool are the in-place scatters."""
+    compiled, pool = _decode_step_compiled(
+        v5e.devices[0], layers=4, slots=16, seq=1024, page=16, nh=4, hd=128,
+        pages=16 * 64, dtype="bfloat16", backend="tpu", pallas=True)
+    text = compiled.as_text()
+    dims = ",".join(map(str, pool))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "paddle_paged_decode_fwd" in line.split(" = ", 1)[0]]
+    assert len(calls) == 4
+    # rows, pos, q, then the two WHOLE pools (what the benchmark's
+    # `paged_decode_roofline` pattern matches on a trace), no plane
+    operands = ("operand_layout_constraints={s32[16,64]{1,0}, s32[16]{0}, "
+                "bf16[16,4,128]{2,1,0}, "
+                f"bf16[{dims}]{{4,3,2,1,0}}, bf16[{dims}]{{4,3,2,1,0}}}}")
+    for call in calls:
+        assert operands in call, call[:160]
+        assert call.lstrip().startswith(
+            "%paddle_paged_decode_fwd"), call[:80]
+    _assert_pools_stay_in_place(compiled, pool, 2)
+
+
+def test_decode_step_keeps_the_donated_pools_in_place_on_cpu():
+    """The twin of the v5e test where no TPU compiler can be described: the
+    CPU backend at tiny sizes, the dense-gather attention (no kernel here),
+    a pool four times what the slots can map so that a lane's gathered view
+    is not the shape of a plane."""
+    slots, seq, page = 2, 64, 8
+    compiled, pool = _decode_step_compiled(
+        jax.devices("cpu")[0], layers=4, slots=slots, seq=seq, page=page,
+        nh=2, hd=16, pages=4 * slots * seq // page, dtype="float32",
+        pallas=False)
+    _assert_pools_stay_in_place(compiled, pool, 4)

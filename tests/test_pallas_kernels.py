@@ -359,20 +359,45 @@ def test_fallback_counter_and_warn_once(monkeypatch):
            ',reason="dropout"}' in text
 
 
+# the pool is the engine's stacked one, [layers, pages, page_size, nh, hd],
+# with every plane different: a kernel that read another plane than the
+# one asked for would not match the dense gather of that plane
+_POOL_LAYERS = 5
+_PLANES = [0, _POOL_LAYERS // 2, _POOL_LAYERS - 1]
+
+
+def _dense_paged_ref(q, kp, vp, rows, pos, seq_cap, layer):
+    """The dense gather of plane `layer` (decode_pages' fallback math)."""
+    slots, nh, hd = q.shape
+    num_pages, ps = kp.shape[1], kp.shape[2]
+    gidx = jnp.clip(rows, 0, num_pages - 1)
+    kg = kp[layer, gidx].reshape(slots, -1, nh, hd)[:, :seq_cap]
+    vg = vp[layer, gidx].reshape(slots, -1, nh, hd)[:, :seq_cap]
+    s = jnp.einsum("bnd,bsnd->bns", q, kg) / np.sqrt(hd)
+    valid = jnp.arange(seq_cap)[None, :] <= pos[:, None]
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    w = jax.nn.softmax(s, -1)
+    return jnp.einsum("bns,bsnd->bnd", w, vg)
+
+
 @pytest.mark.kernels
-def test_paged_decode_attention_ragged_parity():
+@pytest.mark.parametrize("layer", _PLANES)
+def test_paged_decode_attention_ragged_parity(layer):
     """Ragged page-table rows (different lengths, -1 tails, one lane
     exactly at a page boundary, one mid-page) vs the dense-gather
-    reference decode_pages used before this kernel."""
+    reference decode_pages used before this kernel, on plane `layer` of
+    a pool whose planes differ."""
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
     rs = np.random.RandomState(8)
-    slots, pps, ps, nh, hd = 4, 4, 8, 2, 16
+    slots, ps, nh, hd = 4, 8, 2, 16
     num_pages = 12
     seq_cap = 32
     q = jnp.asarray(rs.randn(slots, nh, hd), jnp.float32)
-    kp = jnp.asarray(rs.randn(num_pages, ps, nh, hd), jnp.float32)
-    vp = jnp.asarray(rs.randn(num_pages, ps, nh, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(_POOL_LAYERS, num_pages, ps, nh, hd),
+                     jnp.float32)
+    vp = jnp.asarray(rs.randn(_POOL_LAYERS, num_pages, ps, nh, hd),
+                     jnp.float32)
     rows = jnp.asarray([[2, 5, -1, -1],    # two pages, mid-page pos
                         [7, 1, 3, 9],      # full table
                         [4, -1, -1, -1],   # single page
@@ -380,24 +405,37 @@ def test_paged_decode_attention_ragged_parity():
                        jnp.int32)
     pos = jnp.asarray([11, 26, 3, 15], jnp.int32)
 
-    def dense_ref():
-        gidx = jnp.clip(rows, 0, num_pages - 1)
-        kg = kp[gidx].reshape(slots, pps * ps, nh, hd)[:, :seq_cap]
-        vg = vp[gidx].reshape(slots, pps * ps, nh, hd)[:, :seq_cap]
-        s = jnp.einsum("bnd,bsnd->bns", q, kg) / np.sqrt(hd)
-        valid = jnp.arange(seq_cap)[None, :] <= pos[:, None]
-        s = jnp.where(valid[:, None, :], s, -1e30)
-        w = jax.nn.softmax(s, -1)
-        return jnp.einsum("bns,bsnd->bnd", w, vg)
-
-    out = paged_decode_attention(q, kp, vp, rows, pos, seq_cap)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense_ref()),
+    ref = _dense_paged_ref(q, kp, vp, rows, pos, seq_cap, layer)
+    out = paged_decode_attention(q, kp, vp, rows, pos, seq_cap, layer)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     # jit (engine decode executables wrap it) — same result
-    out_j = jax.jit(lambda *a: paged_decode_attention(*a, seq_cap))(
+    out_j = jax.jit(lambda *a: paged_decode_attention(*a, seq_cap, layer))(
         q, kp, vp, rows, pos)
     np.testing.assert_allclose(np.asarray(out_j), np.asarray(out),
                                rtol=0, atol=0)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("layer", _PLANES)
+def test_paged_decode_attention_unmapped_tail(layer):
+    """A lane whose table ends in unmapped (-1) entries inside the walked
+    extent, and one with nothing but its first page: the dead pages clamp
+    to page 0 OF PLANE `layer` and contribute nothing."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    rs = np.random.RandomState(18)
+    slots, ps, nh, hd = 3, 8, 2, 16
+    q = jnp.asarray(rs.randn(slots, nh, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(_POOL_LAYERS, 7, ps, nh, hd), jnp.float32)
+    vp = jnp.asarray(rs.randn(_POOL_LAYERS, 7, ps, nh, hd), jnp.float32)
+    rows = jnp.asarray([[3, -1, -1, -1], [6, 2, -1, -1], [1, 4, 5, -1]],
+                       jnp.int32)
+    pos = jnp.asarray([0, 9, 23], jnp.int32)
+    out = paged_decode_attention(q, kp, vp, rows, pos, 32, layer)
+    ref = _dense_paged_ref(q, kp, vp, rows, pos, 32, layer)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.kernels
@@ -405,17 +443,23 @@ def test_paged_decode_attention_refusals():
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
     q = jnp.zeros((2, 2, 16))
-    kp = jnp.zeros((4, 8, 2, 16))
+    kp = jnp.zeros((3, 4, 8, 2, 16))
     rows = jnp.zeros((2, 2), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     with pytest.raises(DoesNotTile):  # table too narrow
-        paged_decode_attention(q, kp, kp, rows, pos, seq_cap=64)
+        paged_decode_attention(q, kp, kp, rows, pos, seq_cap=64, layer=1)
     with pytest.raises(DoesNotTile):  # head mismatch
-        paged_decode_attention(q, kp[:, :, :1], kp[:, :, :1], rows, pos, 16)
+        paged_decode_attention(q, kp[..., :1, :], kp[..., :1, :], rows, pos,
+                               16, 1)
+    with pytest.raises(ValueError, match="whole pools"):  # a sliced plane
+        paged_decode_attention(q, kp[1], kp[1], rows, pos, 16, 0)
+    with pytest.raises(ValueError, match="outside a pool of 3"):
+        paged_decode_attention(q, kp, kp, rows, pos, 16, 3)
 
 
 @pytest.mark.kernels
-def test_sharded_paged_decode_tp2_parity():
+@pytest.mark.parametrize("layer", _PLANES)
+def test_sharded_paged_decode_tp2_parity(layer):
     from paddle_tpu.distributed.mesh import build_mesh
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_decode_attention, sharded_paged_decode_attention)
@@ -423,16 +467,50 @@ def test_sharded_paged_decode_tp2_parity():
     rs = np.random.RandomState(9)
     slots, ps, nh, hd = 2, 8, 4, 16
     q = jnp.asarray(rs.randn(slots, nh, hd), jnp.float32)
-    kp = jnp.asarray(rs.randn(6, ps, nh, hd), jnp.float32)
-    vp = jnp.asarray(rs.randn(6, ps, nh, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(_POOL_LAYERS, 6, ps, nh, hd), jnp.float32)
+    vp = jnp.asarray(rs.randn(_POOL_LAYERS, 6, ps, nh, hd), jnp.float32)
     rows = jnp.asarray([[1, 3], [5, -1]], jnp.int32)
     pos = jnp.asarray([12, 5], jnp.int32)
     mesh = build_mesh({"dp": 4, "tp": 2})
-    out = sharded_paged_decode_attention(q, kp, vp, rows, pos, 16, mesh,
-                                         "tp")
-    ref = paged_decode_attention(q, kp, vp, rows, pos, 16)
+    out = sharded_paged_decode_attention(q, kp, vp, rows, pos, 16, layer,
+                                         mesh, "tp")
+    ref = paged_decode_attention(q, kp, vp, rows, pos, 16, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_dense_paged_ref(q, kp, vp, rows, pos, 16, layer)),
+        rtol=1e-5, atol=1e-5)
+
+
+def _decode_pages_case(layers=3):
+    from paddle_tpu.models.gpt import GPTAttention, GPTConfig
+
+    cfg = GPTConfig(hidden_size=32, num_heads=2, num_layers=1,
+                    vocab_size=64, dropout=0.0, attn_dropout=0.0)
+    attn = GPTAttention(cfg)
+    attn.eval()
+    rs = np.random.RandomState(10)
+    ps, nh, hd = 8, 2, 16
+    return attn, dict(
+        x=rs.randn(2, 1, 32).astype("f"),
+        kp=rs.randn(layers, 6, ps, nh, hd).astype("f"),
+        vp=rs.randn(layers, 6, ps, nh, hd).astype("f"),
+        rows=np.asarray([[1, 4], [2, -1]], np.int32),
+        pos=np.asarray([9, 3], np.int32),
+        active=np.asarray([True, True]))
+
+
+def _run_decode_pages(attn, c, layer, method="decode_pages", **over):
+    from paddle_tpu.tensor import Tensor, unwrap
+
+    c = {**c, **over}
+    o, kk, vv = getattr(attn, method)(
+        Tensor(jnp.asarray(c["x"])), Tensor(jnp.asarray(c["kp"].copy())),
+        Tensor(jnp.asarray(c["vp"].copy())), Tensor(jnp.asarray(c["rows"])),
+        Tensor(jnp.asarray(c["pos"])), Tensor(jnp.asarray(c["active"])),
+        16, layer)
+    return [np.asarray(unwrap(t)) for t in (o, kk, vv)]
 
 
 @pytest.mark.kernels
@@ -441,46 +519,59 @@ def test_decode_pages_kernel_vs_dense_token_path(monkeypatch):
     context (to f32 tolerance) and the SAME page-pool contents as the
     dense-gather path, and the kernel call does not add steady-state
     recompiles (same jitted callable serves different table contents)."""
-    from paddle_tpu.models.gpt import GPTAttention, GPTConfig
     from paddle_tpu.ops import fused
     from paddle_tpu.tensor import Tensor, unwrap
 
-    cfg = GPTConfig(hidden_size=32, num_heads=2, num_layers=1,
-                    vocab_size=64, dropout=0.0, attn_dropout=0.0)
-    attn = GPTAttention(cfg)
-    attn.eval()
-    rs = np.random.RandomState(10)
-    slots, pps, ps, nh, hd = 2, 2, 8, 2, 16
-    x = rs.randn(slots, 1, 32).astype("f")
-    kp = rs.randn(6, ps, nh, hd).astype("f")
-    vp = rs.randn(6, ps, nh, hd).astype("f")
-    rows = np.asarray([[1, 4], [2, -1]], np.int32)
-    pos = np.asarray([9, 3], np.int32)
-    active = np.asarray([True, True])
-
-    def run():
-        o, kk, vv = attn.decode_pages(
-            Tensor(jnp.asarray(x)), Tensor(jnp.asarray(kp.copy())),
-            Tensor(jnp.asarray(vp.copy())), Tensor(jnp.asarray(rows)),
-            Tensor(jnp.asarray(pos)), Tensor(jnp.asarray(active)), 16)
-        return [np.asarray(unwrap(t)) for t in (o, kk, vv)]
-
-    o_ref, k_ref, v_ref = run()
+    attn, c = _decode_pages_case()
+    x, kp, vp, rows, pos, active = (c[k] for k in
+                                    ("x", "kp", "vp", "rows", "pos", "active"))
+    o_ref, k_ref, v_ref = _run_decode_pages(attn, c, 1)
     monkeypatch.setattr(fused, "_use_pallas", lambda: True)
-    o_pal, k_pal, v_pal = run()
+    o_pal, k_pal, v_pal = _run_decode_pages(attn, c, 1)
     np.testing.assert_allclose(o_pal, o_ref, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(k_pal, k_ref)  # scatter untouched
     np.testing.assert_array_equal(v_pal, v_ref)
+    assert k_pal.shape == kp.shape               # the whole pool comes back
 
     # compile tripwire: one jitted decode fn serves changed rows/pos
     calls = jax.jit(lambda r, p: unwrap(attn.decode_pages(
         Tensor(jnp.asarray(x)), Tensor(jnp.asarray(kp)),
         Tensor(jnp.asarray(vp)), Tensor(r), Tensor(p),
-        Tensor(jnp.asarray(active)), 16)[0]))
+        Tensor(jnp.asarray(active)), 16, 1)[0]))
     calls(jnp.asarray(rows), jnp.asarray(pos))
     calls(jnp.asarray([[0, 5], [3, -1]], jnp.int32),
           jnp.asarray([14, 7], jnp.int32))
     assert calls._cache_size() == 1
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["dense", "kernel"])
+@pytest.mark.parametrize("method", ["decode_pages", "verify_pages"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_pages_of_other_layers_come_back_untouched(monkeypatch, layer,
+                                                   method, use_kernel):
+    """The step writes plane `layer` and nothing else: the other planes of
+    both pools come back bitwise as they went in, plane `layer` differs
+    from its input in exactly the rows the live lanes wrote, and an
+    inactive lane (it aims one past the pool) writes nowhere."""
+    from paddle_tpu.ops import fused
+
+    monkeypatch.setattr(fused, "_use_pallas", lambda: use_kernel)
+    attn, c = _decode_pages_case()
+    over = {"active": np.asarray([True, False])}
+    if method == "verify_pages":        # a chunk of 2 candidates a lane
+        over["x"] = np.concatenate([c["x"], c["x"] * 0.5], axis=1)
+        over["pos"] = np.stack([c["pos"], c["pos"] + 1], axis=1)
+    _, kk, vv = _run_decode_pages(attn, c, layer, method, **over)
+    ps = c["kp"].shape[2]
+    for got, was in ((kk, c["kp"]), (vv, c["vp"])):
+        others = [i for i in range(was.shape[0]) if i != layer]
+        np.testing.assert_array_equal(got[others], was[others])
+        changed = np.argwhere((got[layer] != was[layer]).any(axis=(-1, -2)))
+        want = [[c["rows"][0, p // ps], p % ps]
+                for p in np.atleast_1d(over.get("pos", c["pos"])[0])]
+        assert changed.tolist() == sorted(want), (changed, want)
 
 
 @pytest.mark.kernels
