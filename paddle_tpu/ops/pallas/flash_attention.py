@@ -8,14 +8,28 @@ BASELINE.json's fused_attention).
 
 Design (flash attention v2 style):
 - public entry takes paddle layout [B, S, H, D]; internally folds to
-  [B*H, S, D] and tiles the MXU with (block_q x D) @ (D x block_k) matmuls.
-- forward: grid (BH, num_q, num_k) with the KV dimension innermost;
-  running max `m`, normalizer `l`, and the output accumulator live in VMEM
-  scratch across KV steps; output + logsumexp written on the last KV step.
-- backward: two kernels — dq (grid over KV innermost) and dkv (grid over Q
-  innermost) — recomputing p = exp(s - lse) per tile, FLOPs ~ 2.5x fwd.
-- causal: fully-masked tiles are skipped with pl.when (no FLOPs), the
-  diagonal tile is masked with a broadcasted iota comparison.
+  [B*H, S, D].  The unit of work is a score tile (block_q x block_k),
+  (block_q x D) @ (D x block_k) on the MXU; `pick_blocks` sizes it from
+  the call's shapes (s_q, s_k, D, mask or none, itemsize), and
+  `block_q=`/`block_k=` override it.
+- a grid step owns one block of one sequence axis and walks the other
+  axis INSIDE the kernel, a `fori_loop` over tile-sized sub-blocks of a
+  long resident block (`_span`: as much of the walked axis as the VMEM
+  budget holds, the whole head at GPT-2's 1024 x 64).  The grid is
+  (BH, blocks owned, resident spans), the last axis 1 unless the walked
+  sequence outgrows VMEM; accumulators live in VMEM scratch across it.
+- forward: a step owns block_q queries and walks the keys; the running
+  max `m` and normalizer `l` are [block_q, 1] loop carries, the output
+  accumulator is scratch; output + logsumexp written once a step.
+- backward: two kernels recomputing p = exp(s - lse) per tile, FLOPs
+  ~ 2.5x fwd.  dq owns block_q queries and walks the keys; dkv owns
+  block_k keys and walks the queries, so the lane-broadcast lse/delta
+  rows are fetched once a key block.
+- causal: the loop bounds come from the step's place on the diagonal, so
+  tiles above it are neither stepped over nor computed, and a resident
+  span wholly above it is not fetched (its index map repeats the last
+  span that has work).  Only sub-blocks the diagonal crosses build the
+  iota mask; those wholly under it run the unmasked body.
 - mask: an additive bias broadcastable to [B, H, S_q, S_k] (bool masks are
   converted to 0 / -1e30 by the wrapper) streamed tile-by-tile into the
   score matmul of all three kernels — the padding / attention-mask path of
@@ -36,94 +50,223 @@ ops/fused.py then takes the XLA softmax path and counts it.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
-_NEG_INF = -1e30
-
-
 from . import (DoesNotTile, im as _im,
                interpret_default as _interpret_default)
 
+_NEG_INF = -1e30
+_log = logging.getLogger(__name__)
 
+# sides of a score tile, largest first.  Measured on a v5e at 16 x 1024 x
+# 12 x 64 causal (PERF.md, PR 28): the time follows the count of tiles
+# more than the scores computed above the diagonal, so 512 x 512 (2.83 ms
+# a layer) beats 256 x 256 (4.00) and 128 x 128 (7.95); 1024 x 1024 (2.93)
+# computes the whole square and gains nothing.
+_TILE_SIDES = (512, 256, 128)
+# what one grid step may keep in VMEM by `_vmem_bytes`: Mosaic's scoped
+# limit on a v5e is 16 MiB, and the estimate leaves the compiler a quarter
+_VMEM_BUDGET = 12 * 1024 * 1024
+# bytes a row of a lane-broadcast float32 row vector takes
+_ROW_BYTES = 128 * 4
+
+
+# ---------------------------------------------------------------------------
+# blocks from shapes
+# ---------------------------------------------------------------------------
+def _vmem_bytes(own, tile, span, d, has_bias, itemsize):
+    """An upper bound, over the three kernels, of the VMEM of one grid step
+    that owns `own` rows of one sequence axis and keeps `span` rows of the
+    other resident, walking them `tile` rows at a time: per row two
+    [*, d] operands and two row vectors (double-buffered, as are the
+    outputs and the bias block), the float32 accumulators and statistics,
+    and six live float32 score tiles."""
+    per_row = 2 * d * itemsize + 2 * _ROW_BYTES
+    pipelined = 2 * (2 * own + span) * per_row
+    if has_bias:
+        pipelined += 2 * own * span * 4
+    scratch = own * (2 * d * 4 + 2 * _ROW_BYTES)
+    return pipelined + scratch + 6 * own * tile * 4
+
+
+def _sides(s):
+    """The tile sides a sequence of s can take, largest first: those of
+    _TILE_SIDES that divide it, else the whole of a sequence shorter than
+    the smallest (one that none divides gets one that does not tile)."""
+    return [c for c in _TILE_SIDES if s % c == 0] or [min(_TILE_SIDES[-1], s)]
+
+
+def pick_blocks(s_q, s_k, d, has_bias, itemsize):
+    """(block_q, block_k) of a call, from its shapes alone: the score tile
+    all three kernels compute at a time.  The largest tile, and of two as
+    large the one with more keys, for which a grid step that keeps just
+    one tile's worth of the other axis resident fits the VMEM budget, as
+    owner of its queries (forward, dQ) and of its keys (dK/dV)."""
+    def fits(bq, bk):
+        return max(_vmem_bytes(bq, bk, bk, d, has_bias, itemsize),
+                   _vmem_bytes(bk, bq, bq, d, has_bias, itemsize)
+                   ) <= _VMEM_BUDGET
+
+    tiles = [(bq, bk) for bq in _sides(s_q) for bk in _sides(s_k)]
+    return max([t for t in tiles if fits(*t)] or tiles[-1:],
+               key=lambda t: (t[0] * t[1], t[1]))
+
+
+def _span(s, own, tile, d, has_bias, itemsize):
+    """Rows of the walked axis (length `s`, walked `tile` at a time) that a
+    grid step owning `own` rows of the other axis keeps resident: the
+    largest multiple of `tile` that divides `s` and fits the budget."""
+    n = s // tile
+    for g in range(n, 0, -1):
+        if n % g == 0 and _vmem_bytes(own, tile, g * tile, d, has_bias,
+                                      itemsize) <= _VMEM_BUDGET:
+            return g * tile
+    return tile
+
+
+# ---------------------------------------------------------------------------
+# what the three kernels share
+# ---------------------------------------------------------------------------
 def _dot(a, b, contract):
     return jax.lax.dot_general(a, b, (contract, ((), ())),
                                preferred_element_type=jnp.float32)
 
 
-def _causal_mask(q_idx, k_idx, block_q, block_k):
-    q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return q_pos >= k_pos
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
 
 
-def _scores(q, k, bias_ref, q_idx, k_idx, *, sm_scale, causal,
-            block_q, block_k):
-    """The shared score tile: scale, additive mask, causal mask."""
-    s = _dot(q, k, ((1,), (1,))) * sm_scale        # [bq, bk] f32
-    if bias_ref is not None:
-        s = s + bias_ref[0].astype(jnp.float32)
-    if causal:
-        s = jnp.where(_causal_mask(q_idx, k_idx, block_q, block_k),
-                      s, _NEG_INF)
+def _rows(ref, t, size):
+    """Rows [t*size, (t+1)*size) of the block in `ref` ([1, rows, n])."""
+    if ref.shape[1] == size:
+        return ref[0]
+    return ref[0, pl.ds(pl.multiple_of(t * size, size), size), :]
+
+
+def _lanes(x, n):
+    """[rows, n] from a row vector held lane-broadcast as [rows, 128].
+    Whole vregs are reused, so this moves nothing; a [rows, 1] column
+    would cost a cross-lane broadcast every time it met a tile."""
+    if n <= 128:
+        return x[:, :n]
+    if n % 128 == 0:
+        return jnp.tile(x, (1, n // 128))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _cols(ref, t, size):
+    """Columns [t*size, (t+1)*size) of the block in `ref` ([1, rows, n])."""
+    if ref.shape[2] == size:
+        return ref[0]
+    return ref[0, :, pl.ds(pl.multiple_of(t * size, size), size)]
+
+
+def _scores(q, k, bias, q0, k0, masked, sm_scale, keys_first=False):
+    """The score tile [queries, keys], or transposed if `keys_first`:
+    scale, additive mask ([queries, keys] either way), and on a tile the
+    diagonal crosses the causal mask (q0, k0: the sequence positions of
+    the tile's first query and key)."""
+    s = (_dot(k, q, _NT) if keys_first else _dot(q, k, _NT)) * sm_scale
+    if bias is not None:
+        bias = bias.astype(jnp.float32)
+        s = s + (bias.T if keys_first else bias)
+    if masked:
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                              int(keys_first))
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                              int(not keys_first))
+        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
     return s
+
+
+def _walk(tile, carry, ranges):
+    """Run `tile(t, carry, masked)` over the sub-blocks t of a resident
+    span: `ranges` lists (first, end, masked) in the order to walk them."""
+    for lo, hi, masked in ranges:
+        carry = jax.lax.fori_loop(
+            lo, hi, lambda t, c, masked=masked: tile(t, c, masked), carry)
+    return carry
+
+
+def _k_ranges(causal, q0, block_q, k0, span, block_k):
+    """Sub-blocks of the keys [k0, k0+span) that the queries
+    [q0, q0+block_q) walk.  Causal: the keys the first query sees are
+    wholly under the diagonal, the keys only the last query sees end the
+    sub-blocks the diagonal crosses, and the rest are not walked."""
+    if not causal:
+        return [(0, span // block_k, False)]
+    seen_by_first = jnp.clip(q0 + 1 - k0, 0, span)
+    seen_by_last = jnp.clip(q0 + block_q - k0, 0, span)
+    clear = seen_by_first // block_k
+    return [(0, clear, False),
+            (clear, (seen_by_last + block_k - 1) // block_k, True)]
+
+
+def _q_ranges(causal, k0, block_k, q0, span, block_q):
+    """Sub-blocks of the queries [q0, q0+span) that the keys
+    [k0, k0+block_k) walk.  Causal: queries before the first key see none
+    of them and are not walked, queries before the last key see some (the
+    diagonal crosses their sub-blocks), the rest see all."""
+    if not causal:
+        return [(0, span // block_q, False)]
+    before_first = jnp.clip(k0 - q0, 0, span)
+    before_last = jnp.clip(k0 + block_k - 1 - q0, 0, span)
+    clear = (before_last + block_q - 1) // block_q
+    return [(before_first // block_q, clear, True),
+            (clear, span // block_q, False)]
+
+
+def _split(refs, n_in, has_bias):
+    """(inputs, bias ref or None, outputs and scratch)."""
+    return (refs[:n_in], refs[n_in] if has_bias else None,
+            refs[n_in + has_bias:])
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k, num_k):
-    if has_bias:
-        q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, acc_ref, m_ref, \
-            l_ref = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-        bias_ref = None
-    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
+def _fwd_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
+    (q_ref, k_ref, v_ref), bias_ref, \
+        (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split(refs, 3, has_bias)
+    span = k_ref.shape[1]
+    q0, k0 = pl.program_id(1) * block_q, pl.program_id(2) * span
 
-    @pl.when(k_idx == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # causal: tiles entirely above the diagonal contribute nothing
-    run = (q_idx + 1) * block_q > k_idx * block_k if causal else True
+    def tile(t, carry, masked):
+        m_prev, l_prev = carry      # [bq, 128] float32, every lane the same
+        bias = _cols(bias_ref, t, block_k) if has_bias else None
+        s = _scores(q_ref[0], _rows(k_ref, t, block_k), bias,
+                    q0, k0 + t * block_k, masked, sm_scale)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, block_k))    # [bq, bk]
+        alpha = jnp.exp(m_prev - m_new)
+        v = _rows(v_ref, t, block_k)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) + _dot(
+            p.astype(v.dtype), v, _NN)
+        return m_new, alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = _scores(q, k, bias_ref, q_idx, k_idx, sm_scale=sm_scale,
-                    causal=causal, block_q=block_q, block_k=block_k)
+    m, l = _walk(tile, (m_ref[...], l_ref[...]),
+                 _k_ranges(causal, q0, block_q, k0, span, block_k))
+    m_ref[...] = m
+    l_ref[...] = l
 
-        m_prev = m_ref[:, :1]                      # [bq, 1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                     # [bq, bk]
-        alpha = jnp.exp(m_prev - m_new)            # [bq, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        acc_ref[...] = acc_ref[...] * alpha + _dot(
-            p.astype(v_ref.dtype), v_ref[0], ((1,), (0,)))
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(k_idx == num_k - 1)
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
-        l = l_ref[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, ...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, ...] = (acc_ref[...] / _lanes(l_safe, acc_ref.shape[1])
+                         ).astype(o_ref.dtype)
         # lse broadcast over a 128-lane minor dim (TPU tiling-friendly)
-        lse_ref[0, ...] = m_ref[...] + jnp.log(l_safe)
+        lse_ref[0, ...] = m + jnp.log(l_safe)
 
 
 def _bias_group(bh: int, bias) -> int:
@@ -132,37 +275,71 @@ def _bias_group(bh: int, bias) -> int:
     return bh // bias.shape[0]
 
 
+def _layout(name, bh, s_own, own, s_walked, tile, d, has_bias, itemsize,
+            causal, from_diagonal):
+    """Grid and block specs of a kernel whose grid step (b, i, j) owns
+    block i (`own` rows) of one sequence axis and walks resident span j of
+    the other, `tile` rows at a time.  Returns (grid, span, the index map's
+    span for (i, j), spec(width, walked)).  Causal steps wholly above the
+    diagonal repeat the span of the nearest step that has work (the last
+    one, or the first when the walk starts from the diagonal as the keys'
+    does), so nothing is fetched for them."""
+    span = _span(s_walked, own, tile, d, has_bias, itemsize)
+    grid = (bh, s_own // own, s_walked // span)
+    _log.debug("%s: owns %d of %d rows, walks %d by %d in spans of %d, "
+               "grid %s", name, own, s_own, s_walked, tile, span, grid)
+    if not causal:
+        def walked_j(i, j):
+            return j
+    elif from_diagonal:
+        def walked_j(i, j):
+            return jnp.minimum(jnp.maximum(j, i * own // span), grid[2] - 1)
+    else:
+        def walked_j(i, j):
+            return jnp.minimum(j, (i * own + own - 1) // span)
+
+    def spec(width, walked=False):
+        if walked:
+            return pl.BlockSpec(
+                (1, span, width), _im(lambda b, i, j: (b, walked_j(i, j), 0)))
+        return pl.BlockSpec((1, own, width), _im(lambda b, i, j: (b, i, 0)))
+
+    return grid, span, walked_j, spec
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+# The two calls below are jitted so that a model of many layers traces and
+# lowers each kernel once, not once a layer: the layers share one inner
+# computation, which XLA inlines.  `Model.fit` traces its step twice (the
+# step, and its cost analysis for the MFU gauge), so at 12 layers this is
+# seconds of every start-up.
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "block_q", "block_k", "interpret"))
 def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
     bh, s_q, d = q.shape
-    s_k = k.shape[1]
-    num_q, num_k = s_q // block_q, s_k // block_k
     has_bias = bias is not None
-
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, has_bias=has_bias,
-        block_q=block_q, block_k=block_k, num_k=num_k)
-
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, i, 0))),
-        pl.BlockSpec((1, block_k, d), _im(lambda b, i, j: (b, j, 0))),
-        pl.BlockSpec((1, block_k, d), _im(lambda b, i, j: (b, j, 0))),
-    ]
+    grid, span, kj, spec = _layout(
+        "paddle_flash_fwd", bh, s_q, block_q, k.shape[1], block_k, d,
+        has_bias, q.dtype.itemsize, causal, False)
+    in_specs = [spec(d), spec(d, True), spec(d, True)]
     operands = [q, k, v]
     if has_bias:
         g = _bias_group(bh, bias)
         in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), _im(lambda b, i, j: (b // g, i, j))))
+            (1, block_q, span), _im(lambda b, i, j: (b // g, i, kj(i, j)))))
         operands.append(bias)
 
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
+                          has_bias=has_bias, block_q=block_q,
+                          block_k=block_k),
         name="paddle_flash_fwd",
-        grid=(bh, num_q, num_k),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, i, 0))),
-            pl.BlockSpec((1, block_q, 128), _im(lambda b, i, j: (b, i, 0))),
-        ],
+        out_specs=[spec(d), spec(128)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s_q, 128), jnp.float32),
@@ -172,8 +349,7 @@ def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(*operands)
     # keep only one lane as the residual (128x smaller in HBM; the lane
@@ -184,89 +360,76 @@ def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _dq_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k, num_k):
-    if has_bias:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref, \
-            dq_ref, acc_ref = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, \
-            acc_ref = refs
-        bias_ref = None
-    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
+def _dq_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
+        (dq_ref, acc_ref) = _split(refs, 6, has_bias)
+    span = k_ref.shape[1]
+    q0, k0 = pl.program_id(1) * block_q, pl.program_id(2) * span
 
-    @pl.when(k_idx == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    run = (q_idx + 1) * block_q > k_idx * block_k if causal else True
+    def tile(t, carry, masked):
+        bias = _cols(bias_ref, t, block_k) if has_bias else None
+        k = _rows(k_ref, t, block_k)
+        s = _scores(q_ref[0], k, bias, q0, k0 + t * block_k, masked,
+                    sm_scale)
+        p = jnp.exp(s - _lanes(lse_ref[0], block_k))   # [bq, bk] float32
+        dp = _dot(do_ref[0], _rows(v_ref, t, block_k), _NT)
+        ds = p * (dp - _lanes(delta_ref[0], block_k)) * sm_scale
+        acc_ref[...] += _dot(ds.astype(k.dtype), k, _NN)
+        return carry
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]                    # [bq, 1]
-        delta = delta_ref[0][:, :1]
+    _walk(tile, None, _k_ranges(causal, q0, block_q, k0, span, block_k))
 
-        s = _scores(q, k, bias_ref, q_idx, k_idx, sm_scale=sm_scale,
-                    causal=causal, block_q=block_q, block_k=block_k)
-        p = jnp.exp(s - lse)                       # [bq, bk] f32
-        dp = _dot(do, v, ((1,), (1,)))             # [bq, bk]
-        ds = p * (dp - delta) * sm_scale
-        acc_ref[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,)))
-
-    @pl.when(k_idx == num_k - 1)
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0, ...] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k, num_q):
-    if has_bias:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref, \
-            dk_ref, dv_ref, dk_acc, dv_acc = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, \
-            dk_acc, dv_acc = refs
-        bias_ref = None
-    k_idx, q_idx = pl.program_id(1), pl.program_id(2)
+def _dkv_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
+        (dk_ref, dv_ref, dk_acc, dv_acc) = _split(refs, 6, has_bias)
+    span = q_ref.shape[1]
+    k0, q0 = pl.program_id(1) * block_k, pl.program_id(2) * span
 
-    @pl.when(q_idx == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = (q_idx + 1) * block_q > k_idx * block_k if causal else True
+    # scores transposed, [keys, queries], so that no product contracts
+    # over rows; the queries' row vectors become rows of lanes
+    def tile(t, carry, masked):
+        q = _rows(q_ref, t, block_q)
+        do = _rows(do_ref, t, block_q)
+        bias = _rows(bias_ref, t, block_q) if has_bias else None
+        s = _scores(q, k_ref[0], bias, q0 + t * block_q, k0, masked,
+                    sm_scale, keys_first=True)         # [bk, bq]
+        p = jnp.exp(s - _rows(lse_ref, t, block_q).T[:1])
+        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
+        dp = _dot(v_ref[0], do, _NT)
+        ds = p * (dp - _rows(delta_ref, t, block_q).T[:1]) * sm_scale
+        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
+        return carry
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
+    _walk(tile, None, _q_ranges(causal, k0, block_k, q0, span, block_q))
 
-        s = _scores(q, k, bias_ref, q_idx, k_idx, sm_scale=sm_scale,
-                    causal=causal, block_q=block_q, block_k=block_k)
-        p = jnp.exp(s - lse)
-        dv_acc[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
-        dp = _dot(do, v, ((1,), (1,)))
-        ds = p * (dp - delta) * sm_scale           # [bq, bk]
-        dk_acc[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,)))
-
-    @pl.when(q_idx == num_q - 1)
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0, ...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, ...] = dv_acc[...].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "block_q", "block_k", "interpret"))
 def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
               interpret):
     bh, s_q, d = q.shape
     s_k = k.shape[1]
-    num_q, num_k = s_q // block_q, s_k // block_k
     has_bias = bias is not None
+    itemsize = q.dtype.itemsize
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                       # [bh, s_q]
     # Mosaic requires >=8 sublanes on row blocks, so row vectors enter the
@@ -274,66 +437,55 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
     # the saved fwd residual is the compact [bh, s_q]).
     lse_r = jnp.broadcast_to(lse[..., None], (bh, s_q, 128))
     delta_r = jnp.broadcast_to(delta[..., None], (bh, s_q, 128))
+    operands = [q, k, v, do, lse_r, delta_r] + ([bias] if has_bias else [])
+    g = _bias_group(bh, bias) if has_bias else 1
+    kwargs = dict(sm_scale=sm_scale, causal=causal, has_bias=has_bias,
+                  block_q=block_q, block_k=block_k)
 
-    q_spec = pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, i, 0)))
-    k_spec_j = pl.BlockSpec((1, block_k, d), _im(lambda b, i, j: (b, j, 0)))
-    row_spec = pl.BlockSpec((1, block_q, 128), _im(lambda b, i, j: (b, i, 0)))
-
-    dq_in_specs = [q_spec, k_spec_j, k_spec_j, q_spec, row_spec, row_spec]
-    dq_operands = [q, k, v, do, lse_r, delta_r]
+    # dq: a step owns block_q queries and walks the keys
+    grid, span, kj, spec = _layout(
+        "paddle_flash_dq", bh, s_q, block_q, s_k, block_k, d, has_bias,
+        itemsize, causal, False)
+    in_specs = [spec(d), spec(d, True), spec(d, True), spec(d), spec(128),
+                spec(128)]
     if has_bias:
-        g = _bias_group(bh, bias)
-        dq_in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), _im(lambda b, i, j: (b // g, i, j))))
-        dq_operands.append(bias)
-
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, span), _im(lambda b, i, j: (b // g, i, kj(i, j)))))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          has_bias=has_bias, block_q=block_q,
-                          block_k=block_k, num_k=num_k),
+        functools.partial(_dq_kernel, **kwargs),
         name="paddle_flash_dq",
-        grid=(bh, num_q, num_k),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, i, 0))),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=spec(d),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(*dq_operands)
+    )(*operands)
 
-    # dkv: grid is (bh, num_k, num_q) — q innermost
-    q_spec_j = pl.BlockSpec((1, block_q, d), _im(lambda b, i, j: (b, j, 0)))
-    k_spec_i = pl.BlockSpec((1, block_k, d), _im(lambda b, i, j: (b, i, 0)))
-    row_spec_j = pl.BlockSpec((1, block_q, 128), _im(lambda b, i, j: (b, j, 0)))
-    dkv_in_specs = [q_spec_j, k_spec_i, k_spec_i, q_spec_j, row_spec_j,
-                    row_spec_j]
-    dkv_operands = [q, k, v, do, lse_r, delta_r]
+    # dk, dv: a step owns block_k keys and walks the queries, so the
+    # lane-broadcast rows are fetched once a key block, not once a tile
+    grid, span, qj, spec = _layout(
+        "paddle_flash_dkv", bh, s_k, block_k, s_q, block_q, d, has_bias,
+        itemsize, causal, True)
+    in_specs = [spec(d, True), spec(d), spec(d), spec(d, True),
+                spec(128, True), spec(128, True)]
     if has_bias:
-        g = _bias_group(bh, bias)
-        # grid here is (b, k_idx=i, q_idx=j): bias tile rows follow j
-        dkv_in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), _im(lambda b, i, j: (b // g, j, i))))
-        dkv_operands.append(bias)
+        in_specs.append(pl.BlockSpec(
+            (1, span, block_k), _im(lambda b, i, j: (b // g, qj(i, j), i))))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          has_bias=has_bias, block_q=block_q,
-                          block_k=block_k, num_q=num_q),
+        functools.partial(_dkv_kernel, **kwargs),
         name="paddle_flash_dkv",
-        grid=(bh, num_k, num_q),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), _im(lambda b, i, j: (b, i, 0))),
-            pl.BlockSpec((1, block_k, d), _im(lambda b, i, j: (b, i, 0))),
-        ],
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[spec(d), spec(d)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(*dkv_operands)
+    )(*operands)
     return dq, dk, dv
 
 
@@ -413,20 +565,21 @@ def _fold_mask(mask, b, h, s_q, s_k):
 
 
 def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: int | None = None, block_k: int | None = None,
                     interpret: bool | None = None, mask=None):
     """Flash attention over paddle layout [B, S, H, D] -> [B, S, H, D].
 
     ``mask`` is a bool (True = attend) or additive mask broadcastable to
-    [B, H, S_q, S_k], composable with ``causal``.  Raises
-    DoesNotTile for shapes the kernel doesn't tile (caller falls
-    back to the XLA path).
+    [B, H, S_q, S_k], composable with ``causal``.  ``block_q`` x
+    ``block_k`` is the score tile; None takes it from the shapes
+    (`pick_blocks`).  Raises DoesNotTile for shapes the kernel doesn't
+    tile (caller falls back to the XLA path).
     """
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    block_q = min(block_q, s_q)
-    block_k = min(block_k, s_k)
+    picked = pick_blocks(s_q, s_k, d, mask is not None, q.dtype.itemsize)
+    block_q = min(block_q or picked[0], s_q)
+    block_k = min(block_k or picked[1], s_k)
     if s_q % block_q or s_k % block_k:
         raise DoesNotTile(
             f"flash_attention: seq ({s_q},{s_k}) not divisible by blocks "
@@ -459,8 +612,8 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
 # ---------------------------------------------------------------------------
 def sharded_flash_attention(q, k, v, mesh, head_axis=None, batch_axes=(),
                             causal: bool = False, sm_scale=None,
-                            block_q: int = DEFAULT_BLOCK_Q,
-                            block_k: int = DEFAULT_BLOCK_K,
+                            block_q: int | None = None,
+                            block_k: int | None = None,
                             interpret: bool | None = None, mask=None):
     """flash_attention under shard_map over ``mesh``: heads split over
     ``head_axis`` (tp/mp), batch over ``batch_axes`` (dp/fsdp) — the

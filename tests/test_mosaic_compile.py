@@ -1,6 +1,7 @@
 """The Pallas kernels of the main path, compiled by Mosaic for a described
 TPU v5e at GPT-2 124M shapes (B=8, S=1024, 12 heads x 64, H=768, FFN=3072,
-V=50304; 16 slots x 16-token pages).
+V=50304; 16 slots x 16-token pages), flash attention at the benchmark
+cells' own sizes (the train cell's B=16; the chat cell's prefill buckets).
 
 Nothing runs: the chip is described, not attached (`on-chip-measurement`
 guide, section 2), so a pass says the chip's compiler accepts the kernel
@@ -52,8 +53,13 @@ def _sum32(f):
     return lambda *a: f(*a).astype(F32).sum()
 
 
-QKV = [((B, S, NH, HD), BF16)] * 3
-PAD_MASK = ((B, 1, 1, S), jnp.bool_)
+# flash attention as the cells call it: gpt2-124m.train (16 x 1024, 12
+# heads of 64) and cerebras-gpt-1.3b.chat's `jit_target_prefill` (one
+# prompt padded to a bucket, 16 heads of 128)
+FLASH_B = 16
+QKV = [((FLASH_B, S, NH, HD), BF16)] * 3
+PAD_MASK = ((FLASH_B, 1, 1, S), jnp.bool_)
+CHAT_BUCKETS, CHAT_NH, CHAT_HD = (256, 512, 768), 16, 128
 LN_F32 = [((B, S, H), F32), ((H,), F32), ((H,), F32)]      # fit, autocast
 LN_BF16 = [((SLOTS, 1, H), BF16), ((H,), BF16), ((H,), BF16)]  # bf16 decode
 GELU_ARGS = [((B, S, FFN), BF16), ((FFN,), BF16)]
@@ -99,6 +105,9 @@ CASES = {
     "flash_causal_bwd": (_bwd(_flash(True), 3), QKV),
     "flash_masked_fwd": (_flash(False), QKV + [PAD_MASK]),
     "flash_masked_bwd": (_bwd(_flash(False), 3), QKV + [PAD_MASK]),
+    **{f"flash_chat_prefill_{s}": (
+        _flash(True), [((1, s, CHAT_NH, CHAT_HD), BF16)] * 3)
+       for s in CHAT_BUCKETS},
     "layer_norm_f32_fwd": (_ln, LN_F32),
     "layer_norm_f32_bwd": (_bwd(_ln, 3), LN_F32),
     "layer_norm_bf16_decode_rows": (_ln, LN_BF16),
@@ -112,17 +121,21 @@ CASES = {
 }
 
 
-_TEXT = {}      # case -> the compiled HLO text, compiled once a module
+_COMPILED = {}      # case -> the compiled executable, compiled once a module
 
 
-def _compiled_text(v5e, name):
-    if name not in _TEXT:
+def _compiled(v5e, name):
+    if name not in _COMPILED:
         f, args = CASES[name]
         one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
         shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                   for s, d in args]
-        _TEXT[name] = jax.jit(f).lower(*shapes).compile().as_text()
-    return _TEXT[name]
+        _COMPILED[name] = jax.jit(f).lower(*shapes).compile()
+    return _COMPILED[name]
+
+
+def _compiled_text(v5e, name):
+    return _compiled(v5e, name).as_text()
 
 
 @pytest.mark.kernels
@@ -132,7 +145,7 @@ def test_kernel_compiles_for_v5e(v5e, name):
 
 
 # every `pallas_call` of ops/pallas has a `name=`: the compiled
-# custom-call instruction carries it (`%jvp_paddle_flash_fwd_.1 = ...`),
+# custom-call instruction carries it (`%jvp_paddle_softmax_xent_fwd_.1 = ...`),
 # and that instruction's text names the kernel's events on a profiler
 # trace's `XLA Ops` line (README.md "Reading a trace")
 KERNEL_NAMES = {
@@ -159,6 +172,48 @@ def test_kernel_name_is_on_the_compiled_call(v5e, kernel):
     # jax wraps the name in the transformations it traced the call under
     assert any(re.fullmatch(rf"%(\w+_)?{kernel}_*(\.\d+)?", c)
                for c in calls), calls
+
+
+@pytest.mark.kernels
+def test_flash_roofline_patterns_find_the_compiled_calls(v5e):
+    """The benchmark's `flash_roofline` knows the three flash calls by the
+    text of their instructions as a trace's `XLA Ops` line prints it
+    (operand shapes and all), cut by `benchmarks.trace.short_name`.  Each
+    of its patterns, with the train cell's sizes filled in, has to match
+    exactly one of the calls compiled for that cell's shape, and between
+    them they match all three."""
+    import json
+    import pathlib
+    import re
+
+    from benchmarks import trace
+
+    try:
+        from jax._src.lib import _jax
+        options = _jax.HloPrintOptions()
+        options.print_operand_shape = True
+        options.include_layout_in_shapes = True
+    except (ImportError, AttributeError) as e:
+        pytest.skip(f"this jaxlib prints no operand shapes: {e}")
+    # the gradient's executable holds the forward call beside dQ and dK/dV
+    module, = _compiled(v5e, "flash_causal_bwd").runtime_executable(
+        ).hlo_modules()
+    calls = [trace.short_name(line.strip().removeprefix("ROOT "))
+             for line in module.to_string(options).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3, calls
+    metric = json.loads((pathlib.Path(trace.__file__).parent / "layer_metrics"
+                         / "flash_roofline.json").read_text())
+    sizes = {"BH": FLASH_B * NH, "S": S, "HD": HD}
+    matched = set()
+    for part in metric["args"]["parts"]:
+        pattern = part["pattern"]
+        for key, value in sizes.items():
+            pattern = pattern.replace("{" + key + "}", str(value))
+        hits = [c for c in calls if re.search(pattern, c)]
+        assert len(hits) == 1, (part["what"], calls)
+        matched.add(hits[0])
+    assert len(matched) == 3, calls
 
 
 @pytest.mark.kernels

@@ -236,6 +236,116 @@ def test_flash_attention_masked_fwd_bwd(causal, kind):
                                    rtol=1e-3, atol=1e-4)
 
 
+# the shapes `pick_blocks` tells apart: (s_q, s_k, d, causal, padding mask,
+# explicit block_q = block_k or None)
+FLASH_SHAPES = {
+    "s1024_d64_causal": (1024, 1024, 64, True, False, None),
+    "s768_d128_causal_512_does_not_divide": (768, 768, 128, True, False,
+                                             None),
+    "s512_d64_padding_mask": (512, 512, 64, False, True, None),
+    "s64_d64_shorter_than_any_block": (64, 64, 64, False, False, None),
+    "s64_d64_causal": (64, 64, 64, True, False, None),
+    "sq256_sk512": (256, 512, 64, False, False, None),
+    "sq512_sk256_causal": (512, 256, 64, True, False, None),
+    "s512_d64_causal_blocks_of_128": (512, 512, 64, True, False, 128),
+    "s384_d64_causal_mask_blocks_of_128": (384, 384, 64, True, True, 128),
+}
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
+def test_flash_attention_parity_over_block_choices(shape):
+    """Forward, dQ, dK and dV against the plain float32 reference at each
+    kind of shape the block function separates, one head a call."""
+    s_q, s_k, d, causal, padded, block = FLASH_SHAPES[shape]
+    rs = np.random.RandomState(11)
+    q = jnp.asarray(rs.randn(1, s_q, 1, d), jnp.float32)
+    k, v = [jnp.asarray(rs.randn(1, s_k, 1, d), jnp.float32)
+            for _ in range(2)]
+    mask = None
+    if padded:
+        mask = jnp.asarray(rs.rand(1, 1, 1, s_k) > 0.3)
+        mask = mask.at[:, :, :, :8].set(True)  # no fully-masked rows
+    w = jnp.asarray(rs.randn(1, s_q, 1, d), jnp.float32)
+
+    def kernel(*a):
+        return flash_attention(*a, causal=causal, mask=mask, block_q=block,
+                               block_k=block)
+
+    def ref(*a):
+        return _attn_ref_masked(*a, causal, mask)
+
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               rtol=1e-4, atol=1e-5)
+    g1 = jax.grad(lambda *a: (kernel(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (ref(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    for name, a, bb in zip(("dq", "dk", "dv"), g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
+                                   rtol=1e-3, atol=1e-4, err_msg=name)
+
+
+@pytest.mark.kernels
+def test_flash_block_function():
+    """`pick_blocks` and `_span` are arithmetic on shapes: the blocks
+    divide their sequences, one grid step's estimate fits the budget, and
+    no shape that tiled with blocks of min(128, s) stops tiling."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    seqs = (8, 64, 100, 128, 256, 384, 512, 640, 768, 1024, 1152, 2048,
+            4096, 8192, 32768)
+    for s_q in seqs:
+        for s_k in seqs:
+            for d in (64, 128, 256):
+                for has_bias in (False, True):
+                    for itemsize in (2, 4):
+                        bq, bk = fa.pick_blocks(s_q, s_k, d, has_bias,
+                                                itemsize)
+                        args = (d, has_bias, itemsize)
+                        assert bq >= 8 and bk >= 8
+                        assert s_q % bq == 0 and s_k % bk == 0
+                        # the old rule's blocks still divide the new ones
+                        assert bq % min(128, s_q) == 0
+                        assert bk % min(128, s_k) == 0
+                        for own, tile, s in ((bq, bk, s_k), (bk, bq, s_q)):
+                            span = fa._span(s, own, tile, *args)
+                            assert span % tile == 0 and s % span == 0
+                            assert fa._vmem_bytes(own, tile, span, *args) \
+                                <= fa._VMEM_BUDGET, (s_q, s_k, *args)
+    # the train cell's call keeps a whole head resident
+    bq, bk = fa.pick_blocks(1024, 1024, 64, False, 2)
+    assert fa._span(1024, bq, bk, 64, False, 2) == 1024
+    # 129 tiled with no block and still does not
+    with pytest.raises(DoesNotTile):
+        flash_attention(jnp.zeros((1, 129, 2, 64)), jnp.zeros((1, 129, 2, 64)),
+                        jnp.zeros((1, 129, 2, 64)))
+
+
+@pytest.mark.kernels
+def test_flash_layers_of_one_shape_trace_their_kernel_once(monkeypatch):
+    """A model's layers call the kernel at one shape: the jitted inner
+    call is traced (and lowered) for the first and reused by the rest, so
+    start-up does not pay for the kernel once a layer."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    traced = []
+    kernel = fa._fwd_kernel
+    monkeypatch.setattr(
+        fa, "_fwd_kernel",
+        lambda *a, **kw: (traced.append(1), kernel(*a, **kw))[1])
+
+    def three_layers(x):
+        for _ in range(3):
+            x = flash_attention(x, x, x, causal=True)
+        return x
+
+    # a shape no other test of this file uses: nothing is cached for it
+    out = jax.jit(three_layers)(jnp.ones((1, 128, 3, 32), jnp.float32))
+    assert out.shape == (1, 128, 3, 32)
+    assert len(traced) == 1
+
+
 @pytest.mark.kernels
 def test_flash_attention_mask_shapes_and_fallback():
     rs = np.random.RandomState(4)
