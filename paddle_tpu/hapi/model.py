@@ -1186,8 +1186,8 @@ class Model:
         device-resident PAGED KV cache, one donated decode executable
         advances every in-flight request a token per iteration, and POST
         /generate streams tokens as they decode (SSE).  The network must
-        expose the slot-batched decode path (``slot_prefill`` /
-        ``slot_decode_paged``, e.g. models.GPTForCausalLM).
+        expose the serving protocol (``slot_prefill`` / ``slot_step`` over
+        a KV source, e.g. models.GPTForCausalLM).
 
         ``page_size`` / ``num_pages`` size the KV page pool (0 pages =
         dense-equivalent), ``prefix_cache`` shares identical tokenized

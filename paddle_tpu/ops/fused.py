@@ -399,6 +399,26 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return apply(f, *args)
 
 
+def masked_attention(q, keys, values, valid):
+    """Explicit softmax attention of new tokens over a cached key/value
+    view — the one arithmetic every KV source (serving/kv_cache.py,
+    models/gpt.py ``DenseKV``) reduces to, so an engine lane, a verified
+    chunk and a solo ``generate`` run agree bitwise.
+
+    q [B, Q, nh, hd]; keys/values [B, S, nh, hd]; valid bool [B|1, Q|1, S]
+    (True = query row may see key column).  Raw jax arrays in and out;
+    returns [B, Q, nh, hd].  Masked columns get ``finfo.min``, so
+    their ``exp`` underflows to exactly 0.
+    """
+    scores = jnp.einsum("bqnd,bsnd->bnqs", q, keys) \
+        * (1.0 / float(q.shape[-1]) ** 0.5)
+    scores = jnp.where(valid[:, None], scores, jnp.finfo(scores.dtype).min)
+    probs = jnp.exp(scores - jax.lax.stop_gradient(
+        scores.max(axis=-1, keepdims=True)))
+    probs = probs / probs.sum(axis=-1, keepdims=True)
+    return jnp.einsum("bnqs,bsnd->bqnd", probs, values)
+
+
 # ---------------------------------------------------------------------------
 # paged decode attention (fused_multi_transformer's masked decode analog):
 # ragged Pallas kernel walking each lane's page-table row over the KV pool

@@ -75,6 +75,10 @@ def _bwd_kernel(x_ref, b_ref, dy_ref, dx_ref):
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
+# jitted for the same reason as layer_norm's call: one traced computation
+# per shape, shared by every layer and executable of the process
+@functools.partial(jax.jit, static_argnames=(
+    "name", "kernel", "outs", "interpret"))
 def _row_call(name, kernel, outs, x2d, b, extra, interpret):
     r, n = x2d.shape
     block_r = pick_block_rows(r, n)

@@ -30,6 +30,11 @@ def _ln_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
     rstd_ref[...] = jnp.broadcast_to(rstd, rstd_ref.shape)
 
 
+# jitted so that the layers of a model share one traced computation per
+# shape, which XLA inlines: a kernel body traced once a layer and once an
+# executable was most of the host's time in `GenerationEngine.start()`
+# (PERF.md, PR 29), as for the flash kernels (flash_attention.py, PR 28)
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def _ln_fwd_call(x2d, w, b, eps, interpret):
     r, n = x2d.shape
     block_r = pick_block_rows(r, n)

@@ -26,14 +26,14 @@ out-of-bounds fetch is issued).  Within the last live page, tokens beyond
 ``pos`` are masked to -1e30 — matching the dense reference's validity mask
 exactly, token by token.
 
-Used by GPTAttention.decode_pages through ops/fused.py when
-FLAGS_use_pallas_kernels is on; the dense-gather path stays as the
-fallback and parity reference.  The kernel only READS the pool: the
-current token's K/V rows are scattered by XLA before the call
-(``k_pages.at[layer, page, off].set``), in place into the donated pool,
-and the call then reads that same buffer — no plane and no pool is copied
-around it (tests/test_mosaic_compile.py holds the compiled decode step to
-that).
+Used by serving/kv_cache.py ``PagedKV.attend`` (the one-token-a-lane case)
+through ops/fused.py when FLAGS_use_pallas_kernels is on; the dense-gather
+path there stays as the fallback and parity reference.  The kernel only
+READS the pool: the current token's K/V rows are scattered by XLA before
+the call (``k_pages.at[layer, page, off].set``), in place into the donated
+pool, and the call then reads that same buffer — no plane and no pool is
+copied around it (tests/test_mosaic_compile.py holds the compiled decode
+step to that).
 """
 from __future__ import annotations
 

@@ -49,6 +49,7 @@ import logging
 import queue
 import threading
 import time
+from dataclasses import replace
 
 import numpy as np
 
@@ -59,9 +60,9 @@ from ..utils import chaos
 from ..utils.profiler import RecordEvent, StepTimers
 from .engine import (DeadlineExceededError, EngineStoppedError,
                      QueueFullError)
-from .kv_cache import (CacheGeometry, admit_slot, make_state, push_pages,
-                       reclaim_pages, release_slots, state_specs,
-                       take_pages, write_prompt)
+from .kv_cache import (CacheGeometry, PagedKV, PrefixKV, admit_slot,
+                       make_state, push_pages, reclaim_pages, release_slots,
+                       state_specs, take_pages, write_prompt)
 from .metrics import GenerationMetrics
 from .prefix_cache import PrefixCache
 from .scheduler import SlotScheduler
@@ -218,10 +219,11 @@ class GenerationEngine:
     """Continuous-batching decode over a device-resident paged KV cache.
 
     Args:
-      model: a causal-LM Layer exposing ``slot_prefill`` /
-        ``slot_decode_paged`` (models/gpt.py GPTForCausalLM) and a
-        ``cfg`` with num_layers / num_heads / hidden_size / vocab_size /
-        max_position_embeddings.
+      model: a causal-LM Layer exposing ``slot_prefill(ids, length)`` and
+        ``slot_step(tokens, positions, kv, last=None)`` (models/gpt.py
+        GPTForCausalLM; ``kv`` is a KV source of serving/kv_cache.py) and
+        a ``cfg`` with num_layers / num_heads / hidden_size / vocab_size
+        / max_position_embeddings.  A ``draft_model`` answers the same.
       max_slots: in-flight sequences per decode iteration
         (``FLAGS_genserve_max_slots``).
       max_seq_len: per-slot sequence cap S_max >= prompt + new tokens
@@ -263,13 +265,16 @@ class GenerationEngine:
 
         if isinstance(model, _HapiModel):
             model = model.network
-        for req_attr in ("slot_prefill", "slot_decode_paged", "cfg"):
-            if not hasattr(model, req_attr):
-                raise TypeError(
-                    f"GenerationEngine needs a model with `{req_attr}` "
-                    "(a causal LM with the slot-batched paged KV-cache "
-                    "decode path, e.g. models.GPTForCausalLM); got "
-                    f"{type(model).__name__}")
+        if isinstance(draft_model, _HapiModel):
+            draft_model = draft_model.network
+        for what, m in (("model", model), ("draft_model", draft_model)):
+            for req_attr in ("slot_prefill", "slot_step", "cfg"):
+                if m is not None and not hasattr(m, req_attr):
+                    raise TypeError(
+                        f"GenerationEngine needs a {what} with "
+                        f"`{req_attr}` (a causal LM that steps over a KV "
+                        "source, e.g. models.GPTForCausalLM); got "
+                        f"{type(m).__name__}")
         self.model = model
         cfg = model.cfg
         self.max_slots = int(max_slots
@@ -306,15 +311,7 @@ class GenerationEngine:
 
         # speculative decode: a draft model proposes spec_tokens per
         # iteration, the target verifies them in one batched step
-        if isinstance(draft_model, _HapiModel):
-            draft_model = draft_model.network
         if draft_model is not None:
-            for req_attr in ("slot_prefill", "slot_decode_paged",
-                             "slot_prefill_prefix", "cfg"):
-                if not hasattr(draft_model, req_attr):
-                    raise TypeError(
-                        f"draft_model needs `{req_attr}`; got "
-                        f"{type(draft_model).__name__}")
             dcfg = draft_model.cfg
             if dcfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
@@ -446,7 +443,7 @@ class GenerationEngine:
         V = geom.vocab_size
         k_max = min(self.max_top_k, V)
         ps, pps = geom.page_size, geom.pages_per_slot
-        num_pages, seq_cap = geom.num_pages, geom.max_seq_len
+        seq_cap = geom.max_seq_len
         # static prefix extent of the hit-path executables: the largest
         # full-page prefix any admitted prompt can share
         pfx_pages = min(pps, -(-self.prompt_buckets[-1] // ps))
@@ -548,34 +545,32 @@ class GenerationEngine:
 
         def suffix_prefill(params, dparams, state, ids, shared_ids,
                            shared_n, length):
-            # gather the already-resident prefix K/V from the pool(s)
-            # and prefill ONLY the suffix, attending over it — shared
-            # by the prefix-hit admission path and every prefill chunk
-            gidx = jnp.clip(shared_ids[:pfx_pages], 0, num_pages - 1)
-            pk = state["kp"][:, gidx].reshape(
-                geometry.num_layers, pfx_pages * ps, geometry.num_heads,
-                geometry.head_dim)
-            pv = state["vp"][:, gidx].reshape(
-                geometry.num_layers, pfx_pages * ps, geometry.num_heads,
-                geometry.head_dim)
-            (k_suf, v_suf, logits), _ = functional_call(
-                model, params,
-                (Tensor(ids), pk, pv, shared_n * ps, length),
-                buffers=buffers, mutable=False,
-                method="slot_prefill_prefix")
+            # prefill ONLY the suffix, attending over the prefix already
+            # resident in the pool(s) — shared by the prefix-hit admission
+            # path and every prefill chunk.  The suffix tokens sit at
+            # prefix_len + i; only the last real one's logits are wanted.
+            prefix_len = shared_n * ps
+            positions = (prefix_len
+                         + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+            last = jnp.asarray(length, jnp.int32) - prefix_len - 1
+
+            def suffix(m, p, b, k_pool, v_pool):
+                (lg, kv), _ = functional_call(
+                    m, p,
+                    (ids, positions,
+                     PrefixKV.gather(k_pool, v_pool,
+                                     shared_ids[:pfx_pages], prefix_len),
+                     last),
+                    buffers=b, mutable=False, method="slot_step")
+                return kv.suffix_kv(), lg[0, 0]
+
+            (k_suf, v_suf), logits = suffix(model, params, buffers,
+                                            state["kp"], state["vp"])
             if draft is None:
                 return k_suf, v_suf, logits, ()
-            dL, _, _, dnh, dhd = geometry.draft_pool_shape
-            dpk = state["dkp"][:, gidx].reshape(dL, pfx_pages * ps,
-                                                dnh, dhd)
-            dpv = state["dvp"][:, gidx].reshape(dL, pfx_pages * ps,
-                                                dnh, dhd)
-            (dk_suf, dv_suf, _), _ = functional_call(
-                draft, dparams,
-                (Tensor(ids), dpk, dpv, shared_n * ps, length),
-                buffers=dbuffers, mutable=False,
-                method="slot_prefill_prefix")
-            return k_suf, v_suf, logits, (dk_suf, dv_suf)
+            draft_kv, _ = suffix(draft, dparams, dbuffers,
+                                 state["dkp"], state["dvp"])
+            return k_suf, v_suf, logits, draft_kv
 
         def _insert_prefix(params, dparams, state, slot, ids, shared_ids,
                            shared_n, length, seed, resume_pos, do_sample,
@@ -645,11 +640,13 @@ class GenerationEngine:
                                            state["free_count"], need)
             ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
             # (2) one paged-attention token per lane
-            (logits, kp, vp), _ = functional_call(
+            (logits, kv), _ = functional_call(
                 model, params,
-                (state["tok"], pos, active, state["kp"], state["vp"],
-                 ptab, seq_cap),
-                buffers=buffers, mutable=False, method="slot_decode_paged")
+                (state["tok"][:, None], pos[:, None],
+                 PagedKV(state["kp"], state["vp"], ptab, pos, active,
+                         seq_cap)),
+                buffers=buffers, mutable=False, method="slot_step")
+            logits, kp, vp = logits[:, 0], kv.k_pages, kv.v_pages
             pair = jax.vmap(jax.random.split)(state["rng"])
             new_keys, subs = pair[:, 0], pair[:, 1]
             toks = jax.vmap(sample_token)(
@@ -711,30 +708,30 @@ class GenerationEngine:
             # writes chain token c_i's draft K/V at pos+i and (i < K)
             # proposes c_{i+1} = argmax; step K only closes the draft
             # cache for a fully accepted run (its logits are discarded).
-            dkp, dvp = state["dkp"], state["dvp"]
+            dkv = PagedKV(state["dkp"], state["dvp"], ptab, pos, active,
+                          seq_cap)
             t = state["tok"]
             chain = [t]
             for i in range(K + 1):
                 p_i = jnp.minimum(pos + i, stop_pos - 1)
-                (dlg, dkp, dvp), _ = functional_call(
+                (dlg, dkv), _ = functional_call(
                     draft, dparams,
-                    (t, p_i, active, dkp, dvp, ptab, seq_cap),
-                    buffers=dbuffers, mutable=False,
-                    method="slot_decode_paged")
+                    (t[:, None], p_i[:, None], replace(dkv, positions=p_i)),
+                    buffers=dbuffers, mutable=False, method="slot_step")
                 if i < K:
-                    t = jnp.argmax(dlg, axis=-1).astype(jnp.int32)
+                    t = jnp.argmax(dlg[:, 0], axis=-1).astype(jnp.int32)
                     chain.append(t)
             tokens = jnp.stack(chain, axis=1)        # [slots, K+1]
             # (3) target verification: score all K+1 candidates at once
             P = jnp.minimum(
                 pos[:, None] + jnp.arange(K + 1, dtype=jnp.int32)[None],
                 (stop_pos - 1)[:, None])
-            (logits, kp, vp), _ = functional_call(
+            (logits, kv), _ = functional_call(
                 model, params,
-                (tokens, P, active, state["kp"], state["vp"], ptab,
-                 seq_cap),
-                buffers=buffers, mutable=False,
-                method="slot_verify_paged")
+                (tokens, P,
+                 PagedKV(state["kp"], state["vp"], ptab, P, active,
+                         seq_cap)),
+                buffers=buffers, mutable=False, method="slot_step")
             # (4) accept/emit: outs[:, i] is what the target generates
             # after consuming c_0..c_i; position 0 goes through the
             # full sampling path (== argmax for greedy lanes) so the
@@ -775,7 +772,8 @@ class GenerationEngine:
                 state["free_stack"], free_count,
                 jnp.where(freeable, ptab, -1).reshape(-1))
             ptab = jnp.where(finished[:, None], -1, ptab)
-            new_state = dict(state, kp=kp, vp=vp, dkp=dkp, dvp=dvp,
+            new_state = dict(state, kp=kv.k_pages, vp=kv.v_pages,
+                             dkp=dkv.k_pages, dvp=dkv.v_pages,
                              ptab=ptab, free_stack=free_stack,
                              free_count=free_count, tok=new_tok,
                              pos=new_pos, rng=new_keys,

@@ -14,10 +14,10 @@ state as its first state-argument with ``donate_argnums`` — the
 TrainEngine donation contract from hapi/engine.py — so XLA rewrites the
 pool in place and the KV bytes NEVER round-trip to host.  Donation alone
 does not make it so: the program must also keep the pool whole.  The
-decode and verify steps (models/gpt.py ``slot_decode_paged`` /
-``slot_verify_paged``) scatter each layer's rows with
-``pool.at[layer, page, off].set`` and hand the paged kernel the whole
-pool with a static ``layer``: a per-layer ``pool[i]`` handed to the
+decode and verify steps hand the model a ``PagedKV`` source (below): each
+attention layer calls its ``attend``, which scatters that layer's rows
+with ``pool.at[layer, page, off].set`` and hands the paged kernel the
+whole pool with a static ``layer``: a per-layer ``pool[i]`` handed to the
 kernel, or a ``jnp.stack`` of planes at the end, compiles to a copy of
 every plane and of both pools each step, whatever is donated (PERF.md,
 PR 25).  ``GenerationEngine.start()`` logs the decode executable's
@@ -37,15 +37,24 @@ admits only requests whose worst-case page demand is reserved
 
 This module is layout + traced transitions only; scheduling policy lives
 in serving/scheduler.py and the compiled-executable lifecycle in
-serving/generation.py.
+serving/generation.py.  The pool's layout is indexed here and in
+ops/pallas/paged_attention.py and nowhere else: a model is handed a KV
+source (``PagedKV``, ``PrefixKV``) and calls ``attend(layer, q, k, v)``.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field, replace
+from typing import Any
 
-__all__ = ["CacheGeometry", "make_state", "state_specs", "take_pages",
-           "push_pages", "write_prompt", "admit_slot", "release_slots",
-           "reclaim_pages"]
+import jax
+import jax.numpy as jnp
+
+from ..ops import fused
+from ..tensor import unwrap
+
+__all__ = ["CacheGeometry", "PagedKV", "PrefixKV", "make_state",
+           "state_specs", "take_pages", "push_pages", "write_prompt",
+           "admit_slot", "release_slots", "reclaim_pages"]
 
 
 @dataclass(frozen=True)
@@ -132,9 +141,6 @@ def make_state(geom: CacheGeometry):
     ``stop_pos`` (stop_pos = prompt_len + max_new_tokens; a lane retires
     when its next write position would reach it, or on eos).
     """
-    import jax
-    import jax.numpy as jnp
-
     S = geom.max_slots
     key_shape = jax.random.PRNGKey(0).shape  # (2,) for threefry
     state = {
@@ -169,14 +175,144 @@ def state_specs(state, shardings=None):
     ``shardings``: optional matching pytree of NamedShardings — attached
     so the layout-aware engine lowers its executables with the page
     pool's head axis pinned over tp."""
-    import jax
-
     if shardings is None:
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state)
     return jax.tree_util.tree_map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         state, shardings)
+
+
+# -- KV sources: what a model's attention layers attend over ----------------
+# One protocol, ``attend(layer, q, k, v, head_axis=None) -> (ctx, source')``:
+# q/k/v [B, C, nh, hd] are the new tokens' projections (raw jax arrays),
+# ``layer`` a static int, ``head_axis`` the mesh axis the model pins its
+# heads to (or None); ctx [B, C, nh, hd] is the attention output and
+# source' the source with the new rows taken in.  Pytrees, so a source
+# passes through ``jit`` and ``functional_call`` like the arrays it holds.
+
+@jax.tree_util.register_dataclass
+@dataclass
+class PagedKV:
+    """The page pools as one step of the engine sees them: decode (one
+    token a lane, ``positions`` [slots]) and speculative verification (a
+    chunk of C candidates a lane at consecutive positions, ``positions``
+    [slots, C]).
+
+    k_pages/v_pages: [layers, num_pages, page_size, nh, hd], the WHOLE
+    pools: ``attend`` writes and reads plane ``layer`` and returns the
+    whole pools, so a donated pool is rewritten in place and no plane is
+    ever sliced out or stacked back.  rows: [slots, pages_per_slot] int32
+    page table (-1 = unmapped); active: [slots] bool, inactive lanes
+    write nowhere; seq_cap: STATIC attention extent (the engine's S_max):
+    the gathered view is sliced to it so the softmax reduction shape
+    matches ``generate``'s dense cache exactly, which keeps an engine
+    lane bitwise-equal to a solo run.  Unmapped (-1) table entries gather
+    an arbitrary resident page whose positions sit past the validity
+    mask, so they contribute exactly 0 to the softmax.
+
+    Causality inside a chunk falls out of the position mask: candidate
+    i's query admits exactly the keys at slots <= positions[b, i], the
+    committed history plus candidates 0..i: the reduction extent the
+    one-token step would have seen, so accepted tokens stay bitwise-equal
+    to the sequential path.
+    """
+    k_pages: Any
+    v_pages: Any
+    rows: Any
+    positions: Any
+    active: Any
+    seq_cap: int = field(metadata=dict(static=True))
+
+    def attend(self, layer, q, k, v, head_axis=None):
+        kp, vp, rows, pos = self.k_pages, self.v_pages, self.rows, \
+            self.positions
+        B, _, nh, hd = q.shape
+        num_pages, ps = kp.shape[1], kp.shape[2]
+        lane, active = jnp.arange(B), self.active
+        one = pos.ndim == 1
+        if one:
+            k, v = k[:, 0], v[:, 0]                  # [slots, nh, hd]
+        else:
+            lane, active = lane[:, None], active[:, None]
+        # token (b, i) writes its K/V at (layer, rows[b, pos // ps],
+        # pos % ps); inactive lanes target one-past-the-pool and are
+        # dropped.  Clamped duplicate positions of a chunk (end of budget)
+        # may collide: whichever write wins is garbage no emitted query's
+        # mask ever exposes.
+        page = rows[lane, jnp.clip(pos // ps, 0, rows.shape[1] - 1)]
+        page = jnp.where(active, page, num_pages)
+        off = pos % ps
+        kp = kp.at[layer, page, off].set(k.astype(kp.dtype), mode="drop")
+        vp = vp.at[layer, page, off].set(v.astype(vp.dtype), mode="drop")
+        # hot path, one token a lane: the Pallas ragged kernel walks each
+        # lane's page-table row and reads plane `layer` of the pool in
+        # place.  None => flag off / untileable geometry (counted in
+        # paddle_pallas_fallbacks_total).  The dense gather below is the
+        # reference, the fallback, and the chunk's path (verification is
+        # one step per K drafted tokens, off the per-token hot loop).
+        ctx = fused.paged_decode_attention(
+            q, kp, vp, rows, pos, self.seq_cap, layer,
+            tp_axis=head_axis) if one else None
+        if ctx is None:
+            gidx = jnp.clip(rows, 0, num_pages - 1)
+            kg = kp[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+            vg = vp[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+            valid = jnp.arange(self.seq_cap)[None, None, :] \
+                <= pos.reshape(B, -1)[:, :, None]
+            ctx = fused.masked_attention(
+                q, kg[:, :self.seq_cap], vg[:, :self.seq_cap], valid)
+        return unwrap(ctx), replace(self, k_pages=kp, v_pages=vp)
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class PrefixKV:
+    """A prompt's already-resident prefix, for a suffix-only prefill (a
+    prefix-cache hit, or one chunk of a chunked prefill): queries are the
+    suffix tokens (absolute positions ``prefix_len + i``), keys are
+    [prefix ++ suffix] with the prefix entries valid below ``prefix_len``
+    and the suffix causal, so the shared pages are never recomputed.
+
+    prefix_k/prefix_v: [layers, C, nh, hd] gathered from the pool (C
+    static, entries >= prefix_len garbage the mask hides); prefix_len:
+    traced scalar; suffix: the (k, v) [Ss, nh, hd] each layer attended
+    so far, which ``write_prompt`` pages in at the (page-aligned) prefix
+    boundary.  Token- (not bitwise-) equivalent to a full prefill: the
+    math matches up to float reassociation of the explicit softmax
+    against the fused causal kernel.
+    """
+    prefix_k: Any
+    prefix_v: Any
+    prefix_len: Any
+    suffix: tuple = ()
+
+    @classmethod
+    def gather(cls, k_pages, v_pages, page_ids, prefix_len):
+        """The prefix held by pool pages ``page_ids`` [n] (-1 entries
+        gather an arbitrary page past ``prefix_len``)."""
+        L, num_pages, ps, nh, hd = k_pages.shape
+        gidx = jnp.clip(page_ids, 0, num_pages - 1)
+        return cls(k_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nh, hd),
+                   v_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nh, hd),
+                   jnp.asarray(prefix_len, jnp.int32))
+
+    def attend(self, layer, q, k, v, head_axis=None):
+        S = q.shape[1]
+        pk, pv = self.prefix_k[layer][None], self.prefix_v[layer][None]
+        C = pk.shape[1]
+        i = jnp.arange(S)[:, None]
+        j = jnp.arange(C + S)[None, :]
+        ok = (j < self.prefix_len) | ((j >= C) & (j - C <= i))
+        ctx = fused.masked_attention(
+            q, jnp.concatenate([pk.astype(k.dtype), k], axis=1),
+            jnp.concatenate([pv.astype(v.dtype), v], axis=1), ok[None])
+        return ctx, replace(self, suffix=self.suffix + ((k[0], v[0]),))
+
+    def suffix_kv(self):
+        """(k, v) [layers, Ss, nh, hd] of the suffix, for ``write_prompt``."""
+        return (jnp.stack([k for k, _ in self.suffix]),
+                jnp.stack([v for _, v in self.suffix]))
 
 
 # -- in-graph free-list register ops ----------------------------------------
@@ -187,8 +323,6 @@ def take_pages(free_stack, free_count, need):
     stack array itself is untouched (entries above free_count are
     stale); the host guarantees free_count never underflows by
     reserving worst-case demand at admission."""
-    import jax.numpy as jnp
-
     need = need.astype(bool)
     ranks = jnp.cumsum(need.astype(jnp.int32)) - 1
     idx = jnp.clip(free_count - 1 - ranks, 0, free_stack.shape[0] - 1)
@@ -199,8 +333,6 @@ def take_pages(free_stack, free_count, need):
 def push_pages(free_stack, free_count, pages):
     """Push the valid (>= 0) entries of ``pages`` onto the free stack;
     -1 entries are skipped.  Returns (free_stack', free_count')."""
-    import jax.numpy as jnp
-
     valid = pages >= 0
     ranks = jnp.cumsum(valid.astype(jnp.int32)) - 1
     # invalid entries target one-past-the-end and are dropped
@@ -232,8 +364,6 @@ def write_prompt(state, slot, k_new, v_new, length, shared_ids, shared_n,
     prefill K/V for the same positions, scattered into ``dkp``/``dvp``
     at the same page ids — the shared table row keeps both pools'
     extents in lockstep."""
-    import jax.numpy as jnp
-
     kp, vp = state["kp"], state["vp"]
     L, num_pages, ps = kp.shape[0], kp.shape[1], kp.shape[2]
     pps = state["ptab"].shape[1]
@@ -286,8 +416,6 @@ def admit_slot(state, slot, tok, length, rng_key, do_sample, temp, top_k,
     scalar args.  ``active`` (traced bool) lets chunked prefill run the
     same executable for every chunk while only the FINAL chunk arms the
     lane — earlier chunks keep it parked with the registers staged."""
-    import jax.numpy as jnp
-
     slot = jnp.asarray(slot, jnp.int32)
     return dict(
         state,
@@ -313,8 +441,6 @@ def release_slots(state, mask):
     register) back onto the free stack; shared prefix pages stay
     resident for the prefix cache, returned later via
     ``reclaim_pages`` when their host refcount drops to zero."""
-    import jax.numpy as jnp
-
     ptab = state["ptab"]
     col = jnp.arange(ptab.shape[1], dtype=jnp.int32)[None, :]
     freeable = mask[:, None] & (ptab >= 0) & (col >= state["pinned"][:, None])

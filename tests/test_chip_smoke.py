@@ -248,13 +248,17 @@ def _tiny_gpt():
 
 
 def _dispatch_paged():
-    # through the model, which keeps the dense gather beside the kernel
+    # through the model and its KV source, which keeps the dense gather
+    # beside the kernel
+    from paddle_tpu.serving.kv_cache import PagedKV
+
     net = _tiny_gpt()
     pool = jnp.zeros((1, 4, 8, 2, 8), jnp.float32)
     rows = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
-    return net.slot_decode_paged(
-        jnp.asarray([1, 2]), jnp.asarray([3, 9]), jnp.asarray([True, True]),
-        pool, pool, rows, 16)[0]
+    pos = jnp.asarray([3, 9])
+    return net.slot_step(
+        jnp.asarray([[1], [2]]), pos[:, None],
+        PagedKV(pool, pool, rows, pos, jnp.asarray([True, True]), 16))[0]
 
 
 def _dispatch_bias_gelu():

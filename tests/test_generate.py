@@ -135,9 +135,9 @@ def test_training_mode_prefill_raises(model):
     model.train()
     try:
         with pytest.raises(RuntimeError, match="eval-only"):
-            model.gpt.prefill(
+            model.gpt(
                 paddle.to_tensor(rs.randint(0, 211, (1, 4)).astype(np.int32)),
-                cache_len=8)
+                return_kv=True)
     finally:
         model.eval()
 

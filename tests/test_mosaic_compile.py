@@ -246,19 +246,21 @@ def test_kernels_compose_with_a_2x2_mesh(v5e):
 
 
 # -- the decode step holds its KV pools in place -----------------------------
-# `GPTForCausalLM.slot_decode_paged` threads the two stacked pools through
-# its blocks: each scatters its token's rows into its own plane of the
-# donated pool and the paged kernel reads that plane where it lies.  A
+# `GPTForCausalLM.slot_step` threads a `PagedKV` source (the two stacked
+# pools) through its blocks: each scatters its token's rows into its own
+# plane of the donated pool and the paged kernel reads that plane where it
+# lies.  A
 # `k_pages[i]` handed to the kernel, or a `jnp.stack` of the planes at the
 # end, compiles to copies of planes and pools (72% of the chat cell's
 # decode step on a v5e, PERF.md PR 25).  Read the compiled step for them.
 def _decode_step_compiled(device, layers, slots, seq, page, nh, hd, pages,
                           dtype, backend=None, pallas=None):
-    """`slot_decode_paged` of a small GPT, the pools donated, compiled for
-    `device`.  Returns (compiled, pool shape)."""
+    """The decode step (`slot_step` over a `PagedKV`) of a small GPT, the
+    pools donated, compiled for `device`.  Returns (compiled, pool shape)."""
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
     from paddle_tpu.nn.layer_base import functional_call
     from paddle_tpu.ops import fused
+    from paddle_tpu.serving.kv_cache import PagedKV
     from paddle_tpu.tensor import unwrap
 
     net = GPTForCausalLM(GPTConfig(
@@ -273,10 +275,12 @@ def _decode_step_compiled(device, layers, slots, seq, page, nh, hd, pages,
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
     def step(params, tok, pos, active, kp, vp, rows):
-        out, _ = functional_call(
-            net, params, (tok, pos, active, kp, vp, rows, seq),
-            mutable=False, method="slot_decode_paged")
-        return out
+        (logits, kv), _ = functional_call(
+            net, params,
+            (tok[:, None], pos[:, None],
+             PagedKV(kp, vp, rows, pos, active, seq)),
+            mutable=False, method="slot_step")
+        return logits[:, 0], kv.k_pages, kv.v_pages
 
     # the step is traced over shapes: the weights it is given are of the
     # served dtype whatever the constructor drew

@@ -477,7 +477,7 @@ _PLANES = [0, _POOL_LAYERS // 2, _POOL_LAYERS - 1]
 
 
 def _dense_paged_ref(q, kp, vp, rows, pos, seq_cap, layer):
-    """The dense gather of plane `layer` (decode_pages' fallback math)."""
+    """The dense gather of plane `layer` (PagedKV.attend's fallback math)."""
     slots, nh, hd = q.shape
     num_pages, ps = kp.shape[1], kp.shape[2]
     gidx = jnp.clip(rows, 0, num_pages - 1)
@@ -495,7 +495,7 @@ def _dense_paged_ref(q, kp, vp, rows, pos, seq_cap, layer):
 def test_paged_decode_attention_ragged_parity(layer):
     """Ragged page-table rows (different lengths, -1 tails, one lane
     exactly at a page boundary, one mid-page) vs the dense-gather
-    reference decode_pages used before this kernel, on plane `layer` of
+    reference PagedKV.attend used before this kernel, on plane `layer` of
     a pool whose planes differ."""
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
@@ -611,26 +611,34 @@ def _decode_pages_case(layers=3):
         active=np.asarray([True, True]))
 
 
-def _run_decode_pages(attn, c, layer, method="decode_pages", **over):
-    from paddle_tpu.tensor import Tensor, unwrap
+def _paged_attend(attn, x, kp, vp, rows, pos, active, layer):
+    """The attention layer over a PagedKV source (serving/kv_cache.py):
+    pos [slots] is the decode step, [slots, C] a verified chunk."""
+    from paddle_tpu.serving.kv_cache import PagedKV
+    from paddle_tpu.tensor import Tensor
+
+    return attn(Tensor(jnp.asarray(x)),
+                PagedKV(*map(jnp.asarray, (kp, vp, rows, pos, active)), 16),
+                layer)
+
+
+def _run_decode_pages(attn, c, layer, **over):
+    from paddle_tpu.tensor import unwrap
 
     c = {**c, **over}
-    o, kk, vv = getattr(attn, method)(
-        Tensor(jnp.asarray(c["x"])), Tensor(jnp.asarray(c["kp"].copy())),
-        Tensor(jnp.asarray(c["vp"].copy())), Tensor(jnp.asarray(c["rows"])),
-        Tensor(jnp.asarray(c["pos"])), Tensor(jnp.asarray(c["active"])),
-        16, layer)
-    return [np.asarray(unwrap(t)) for t in (o, kk, vv)]
+    o, kv = _paged_attend(attn, c["x"], c["kp"].copy(), c["vp"].copy(),
+                          c["rows"], c["pos"], c["active"], layer)
+    return [np.asarray(unwrap(t)) for t in (o, kv.k_pages, kv.v_pages)]
 
 
 @pytest.mark.kernels
 def test_decode_pages_kernel_vs_dense_token_path(monkeypatch):
-    """GPTAttention.decode_pages with the kernel produces the same
+    """GPTAttention over a PagedKV source with the kernel produces the same
     context (to f32 tolerance) and the SAME page-pool contents as the
     dense-gather path, and the kernel call does not add steady-state
     recompiles (same jitted callable serves different table contents)."""
     from paddle_tpu.ops import fused
-    from paddle_tpu.tensor import Tensor, unwrap
+    from paddle_tpu.tensor import unwrap
 
     attn, c = _decode_pages_case()
     x, kp, vp, rows, pos, active = (c[k] for k in
@@ -644,10 +652,8 @@ def test_decode_pages_kernel_vs_dense_token_path(monkeypatch):
     assert k_pal.shape == kp.shape               # the whole pool comes back
 
     # compile tripwire: one jitted decode fn serves changed rows/pos
-    calls = jax.jit(lambda r, p: unwrap(attn.decode_pages(
-        Tensor(jnp.asarray(x)), Tensor(jnp.asarray(kp)),
-        Tensor(jnp.asarray(vp)), Tensor(r), Tensor(p),
-        Tensor(jnp.asarray(active)), 16, 1)[0]))
+    calls = jax.jit(lambda r, p: unwrap(
+        _paged_attend(attn, x, kp, vp, r, p, active, 1)[0]))
     calls(jnp.asarray(rows), jnp.asarray(pos))
     calls(jnp.asarray([[0, 5], [3, -1]], jnp.int32),
           jnp.asarray([14, 7], jnp.int32))
@@ -657,10 +663,10 @@ def test_decode_pages_kernel_vs_dense_token_path(monkeypatch):
 @pytest.mark.kernels
 @pytest.mark.parametrize("use_kernel", [False, True],
                          ids=["dense", "kernel"])
-@pytest.mark.parametrize("method", ["decode_pages", "verify_pages"])
+@pytest.mark.parametrize("chunk", [1, 2], ids=["decode", "verify"])
 @pytest.mark.parametrize("layer", [0, 1, 2])
 def test_pages_of_other_layers_come_back_untouched(monkeypatch, layer,
-                                                   method, use_kernel):
+                                                   chunk, use_kernel):
     """The step writes plane `layer` and nothing else: the other planes of
     both pools come back bitwise as they went in, plane `layer` differs
     from its input in exactly the rows the live lanes wrote, and an
@@ -670,10 +676,10 @@ def test_pages_of_other_layers_come_back_untouched(monkeypatch, layer,
     monkeypatch.setattr(fused, "_use_pallas", lambda: use_kernel)
     attn, c = _decode_pages_case()
     over = {"active": np.asarray([True, False])}
-    if method == "verify_pages":        # a chunk of 2 candidates a lane
+    if chunk == 2:                      # a chunk of 2 candidates a lane
         over["x"] = np.concatenate([c["x"], c["x"] * 0.5], axis=1)
         over["pos"] = np.stack([c["pos"], c["pos"] + 1], axis=1)
-    _, kk, vv = _run_decode_pages(attn, c, layer, method, **over)
+    _, kk, vv = _run_decode_pages(attn, c, layer, **over)
     ps = c["kp"].shape[2]
     for got, was in ((kk, c["kp"]), (vv, c["vp"])):
         others = [i for i in range(was.shape[0]) if i != layer]
