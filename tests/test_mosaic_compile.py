@@ -1,7 +1,8 @@
 """The Pallas kernels of the main path, compiled by Mosaic for a described
 TPU v5e at GPT-2 124M shapes (B=8, S=1024, 12 heads x 64, H=768, FFN=3072,
-V=50304; 16 slots x 16-token pages), flash attention at the benchmark
-cells' own sizes (the train cell's B=16; the chat cell's prefill buckets).
+V=50304; 16 slots x 16-token pages), flash attention and the loss at the
+benchmark cells' own sizes (the train cell's B=16; the chat cell's prefill
+buckets).
 
 Nothing runs: the chip is described, not attached (`on-chip-measurement`
 guide, section 2), so a pass says the chip's compiler accepts the kernel
@@ -64,7 +65,13 @@ LN_F32 = [((B, S, H), F32), ((H,), F32), ((H,), F32)]      # fit, autocast
 LN_BF16 = [((SLOTS, 1, H), BF16), ((H,), BF16), ((H,), BF16)]  # bf16 decode
 GELU_ARGS = [((B, S, FFN), BF16), ((FFN,), BF16)]
 XENT_BF16 = [((B * S, V), BF16), ((B * S,), I32)]
+# the loss as gpt2-124m.train calls it: 16 x 1024 rows a step
+XENT_N = 16 * S
+XENT_TRAIN = [((XENT_N, V), BF16), ((XENT_N,), I32)]
 XENT_F32 = [((2048, 50257), F32), ((2048,), I32)]   # BERT/HF vocab, padded
+# a vocabulary whose narrowest row block is over the kernel's VMEM budget:
+# it runs under a raised scoped limit (softmax_xent.pick_blocks)
+XENT_WIDE = [((2048, 131072), BF16), ((2048,), I32)]
 # the engine's stacked pool, all layers of it: the kernel reads one plane
 POOL_LAYERS, PAGED_LAYER = 12, 5
 POOL = ((POOL_LAYERS, SLOTS * PAGES_PER_SLOT + 1, PAGE, NH, HD), BF16)
@@ -115,8 +122,12 @@ CASES = {
     "bias_gelu_bwd": (_bwd(_gelu, 2), GELU_ARGS),
     "softmax_xent_bf16_fwd": (_xent, XENT_BF16),
     "softmax_xent_bf16_bwd": (_bwd(_xent, 1), XENT_BF16),
+    "softmax_xent_train_fwd": (_xent, XENT_TRAIN),
+    "softmax_xent_train_bwd": (_bwd(_xent, 1), XENT_TRAIN),
     "softmax_xent_f32_v50257_fwd": (_xent, XENT_F32),
     "softmax_xent_f32_v50257_bwd": (_bwd(_xent, 1), XENT_F32),
+    "softmax_xent_bf16_v131072_fwd": (_xent, XENT_WIDE),
+    "softmax_xent_bf16_v131072_bwd": (_bwd(_xent, 1), XENT_WIDE),
     "paged_decode": (_paged, PAGED_ARGS),
 }
 
@@ -174,18 +185,10 @@ def test_kernel_name_is_on_the_compiled_call(v5e, kernel):
                for c in calls), calls
 
 
-@pytest.mark.kernels
-def test_flash_roofline_patterns_find_the_compiled_calls(v5e):
-    """The benchmark's `flash_roofline` knows the three flash calls by the
-    text of their instructions as a trace's `XLA Ops` line prints it
-    (operand shapes and all), cut by `benchmarks.trace.short_name`.  Each
-    of its patterns, with the train cell's sizes filled in, has to match
-    exactly one of the calls compiled for that cell's shape, and between
-    them they match all three."""
-    import json
-    import pathlib
-    import re
-
+def _compiled_calls(v5e, name):
+    """The Pallas calls of a compiled case, each as a trace's `XLA Ops`
+    line prints its instruction (operand shapes and all) and
+    `benchmarks.trace.short_name` cuts it."""
     from benchmarks import trace
 
     try:
@@ -195,25 +198,57 @@ def test_flash_roofline_patterns_find_the_compiled_calls(v5e):
         options.include_layout_in_shapes = True
     except (ImportError, AttributeError) as e:
         pytest.skip(f"this jaxlib prints no operand shapes: {e}")
-    # the gradient's executable holds the forward call beside dQ and dK/dV
-    module, = _compiled(v5e, "flash_causal_bwd").runtime_executable(
-        ).hlo_modules()
-    calls = [trace.short_name(line.strip().removeprefix("ROOT "))
-             for line in module.to_string(options).splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 3, calls
-    metric = json.loads((pathlib.Path(trace.__file__).parent / "layer_metrics"
-                         / "flash_roofline.json").read_text())
-    sizes = {"BH": FLASH_B * NH, "S": S, "HD": HD}
+    module, = _compiled(v5e, name).runtime_executable().hlo_modules()
+    return [trace.short_name(line.strip().removeprefix("ROOT "))
+            for line in module.to_string(options).splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _assert_patterns_find(metric, sizes, calls):
+    """Each pattern of benchmarks/layer_metrics/<metric>.json, with the
+    cell's sizes filled in, matches exactly one of `calls`, and between
+    them they match every call."""
+    import json
+    import pathlib
+    import re
+
+    from benchmarks import trace
+
+    spec = json.loads((pathlib.Path(trace.__file__).parent / "layer_metrics"
+                       / f"{metric}.json").read_text())
     matched = set()
-    for part in metric["args"]["parts"]:
+    for part in spec["args"]["parts"]:
         pattern = part["pattern"]
         for key, value in sizes.items():
             pattern = pattern.replace("{" + key + "}", str(value))
         hits = [c for c in calls if re.search(pattern, c)]
         assert len(hits) == 1, (part["what"], calls)
         matched.add(hits[0])
-    assert len(matched) == 3, calls
+    assert len(matched) == len(calls), calls
+
+
+@pytest.mark.kernels
+def test_flash_roofline_patterns_find_the_compiled_calls(v5e):
+    """The benchmark's `flash_roofline` knows the three flash calls by the
+    text of their instructions.  Each of its patterns, with the train
+    cell's sizes filled in, has to match exactly one of the calls compiled
+    for that cell's shape, and between them they match all three."""
+    # the gradient's executable holds the forward call beside dQ and dK/dV
+    calls = _compiled_calls(v5e, "flash_causal_bwd")
+    assert len(calls) == 3, calls
+    _assert_patterns_find("flash_roofline",
+                          {"BH": FLASH_B * NH, "S": S, "HD": HD}, calls)
+
+
+@pytest.mark.kernels
+def test_xent_roofline_patterns_find_the_compiled_calls(v5e):
+    """`xent_roofline` knows the loss kernel's two calls by their operands
+    and results: the lane-replicated [N, 128] rows and the [N, V] logits.
+    At the train cell's N = 16384, V = 50304 each pattern matches exactly
+    one call of the compiled gradient, which holds both."""
+    calls = _compiled_calls(v5e, "softmax_xent_train_bwd")
+    assert len(calls) == 2, calls
+    _assert_patterns_find("xent_roofline", {"N": XENT_N, "V": V}, calls)
 
 
 @pytest.mark.kernels

@@ -690,32 +690,168 @@ def test_pages_of_other_layers_come_back_untouched(monkeypatch, layer,
         assert changed.tolist() == sorted(want), (changed, want)
 
 
+# (rows, vocabulary, ignored rows): every branch of the loss kernel's walk
+XENT_SHAPES = {
+    "one_chunk_v2": (16, 2, "first"),
+    "one_chunk_v128": (16, 128, "first"),
+    "whole_chunks": (256, 512, "first"),          # 4 chunks of 128 columns
+    "tail_chunk_128x7": (64, 896, "first"),       # one chunk of 512, 384 left
+    "tail_chunk_128x131": (16, 128 * 131, "first"),
+    "padded_vocab_1000": (32, 1000, "first"),
+    "padded_vocab_50257": (16, 50257, "first"),
+    "rows_off_the_sublanes": (37, 1000, "first"),
+    "several_row_blocks": (264, 256, "first"),    # 264 = 3 x 88 = 33 x 8
+    "all_ignored_blocks": (264, 256, "half"),
+    "every_row_ignored": (16, 384, "all"),
+}
+
+
+def _xent_ref(z, lab, ignore_index=-100):
+    lp = jax.nn.log_softmax(z.astype(jnp.float32), -1)
+    pick = jnp.take_along_axis(lp, lab[:, None].clip(0), 1)[:, 0]
+    return jnp.where(lab == ignore_index, 0.0, -pick)
+
+
 @pytest.mark.kernels
-def test_softmax_xent_fwd_bwd_parity():
-    """Fused loss kernel vs the XLA composite: unpadded AND padded
-    (vocab % 128 != 0, rows % 8 != 0), ignore_index rows, gradients."""
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(XENT_SHAPES))
+def test_softmax_xent_fwd_bwd_parity(shape, dtype):
+    """Fused loss kernel vs the XLA composite: loss and gradient over one
+    chunk, whole chunks, a tail chunk, a padded vocabulary (vocab % 128),
+    rows off the sublane multiple and off the block, ignore_index rows
+    (loss 0, gradient exactly 0) down to whole blocks of them.  bf16 is
+    held to the composite on the same logits in float32, rounded once."""
     from paddle_tpu.ops.pallas.softmax_xent import softmax_xent
 
+    n, v, ignored = XENT_SHAPES[shape]
     rs = np.random.RandomState(11)
-    for (n, v) in [(32, 512), (37, 1000)]:
-        z = jnp.asarray(rs.randn(n, v), jnp.float32)
-        lab = jnp.asarray(rs.randint(0, v, n), jnp.int32)
-        lab = lab.at[0].set(-100)
+    z = jnp.asarray(rs.randn(n, v), dtype)
+    lab = jnp.asarray(rs.randint(0, v, n), jnp.int32)
+    gone = {"first": slice(0, 1), "half": slice(0, n // 2),
+            "all": slice(0, n)}[ignored]
+    lab = lab.at[gone].set(-100)
+    # a rounding of the result to bf16 is half a unit in its 8th bit
+    tol = {"float32": dict(rtol=1e-5, atol=1e-5),
+           "bfloat16": dict(rtol=2 ** -7, atol=1e-5)}[dtype]
+    gtol = {"float32": dict(rtol=1e-4, atol=1e-5),
+            "bfloat16": dict(rtol=2 ** -7, atol=1e-5)}[dtype]
 
-        def ref(z, lab):
-            lp = jax.nn.log_softmax(z, -1)
-            pick = jnp.take_along_axis(lp, lab[:, None].clip(0), 1)[:, 0]
-            return jnp.where(lab == -100, 0.0, -pick)
+    out = softmax_xent(z, lab)
+    assert out.dtype == z.dtype and out.shape == (n,)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(_xent_ref(z, lab)), **tol)
+    g1 = jax.grad(lambda zz: softmax_xent(zz, lab).astype(
+        jnp.float32).sum())(z)
+    g2 = jax.grad(lambda zz: _xent_ref(zz, lab).sum())(
+        z.astype(jnp.float32))
+    assert g1.dtype == z.dtype
+    np.testing.assert_allclose(np.asarray(g1, np.float32), np.asarray(g2),
+                               **gtol)
+    # ignored rows: loss 0 and exactly zero gradient
+    assert float(jnp.abs(out[gone]).max()) == 0.0
+    assert float(jnp.abs(g1[gone]).max()) == 0.0
 
-        out = softmax_xent(z, lab)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref(z, lab)),
-                                   rtol=1e-5, atol=1e-5)
-        g1 = jax.grad(lambda zz: softmax_xent(zz, lab).sum())(z)
-        g2 = jax.grad(lambda zz: ref(zz, lab).sum())(z)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                                   rtol=1e-4, atol=1e-5)
-        # ignore rows get exactly zero gradient
-        assert float(jnp.abs(g1[0]).max()) == 0.0
+
+@pytest.mark.kernels
+def test_softmax_xent_ignore_index_that_is_a_column():
+    """An ignore_index inside the vocabulary ignores the rows that carry
+    it and nothing else."""
+    from paddle_tpu.ops.pallas.softmax_xent import softmax_xent
+
+    rs = np.random.RandomState(12)
+    z = jnp.asarray(rs.randn(24, 300), jnp.float32)
+    lab = jnp.asarray(rs.randint(1, 300, 24), jnp.int32).at[::3].set(0)
+    out = softmax_xent(z, lab, ignore_index=0)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_xent_ref(z, lab, 0)),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda zz: softmax_xent(zz, lab, ignore_index=0).sum())(z)
+    assert float(jnp.abs(g[::3]).max()) == 0.0
+    assert float(jnp.abs(g[1::3]).min()) > 0.0
+
+
+# (n, v, itemsize) as the wrapper pads them; the callers of the tree:
+# GPT-2's cell whole and over dp=4, BERT's MLM and NSP heads, ResNet's
+XENT_CALLS = [(16384, 50304, 2), (4096, 50304, 2), (16384, 50304, 4),
+              (4096, 30592, 4), (4096, 30592, 2), (4096, 128, 4),
+              (256, 1024, 4), (1008, 50304, 2), (40, 1024, 4), (16, 128, 2),
+              (8, 128, 4), (2048, 131072, 2)]
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("n,v,itemsize", XENT_CALLS)
+def test_softmax_xent_pick_blocks(n, v, itemsize, backward):
+    """The row block divides n, is whole native tiles of the type, and
+    fits the budget by the module's own arithmetic (or is the narrowest
+    there is, under a scoped limit raised to hold it); the chunk is whole
+    lane groups of the vocabulary."""
+    from paddle_tpu.ops.pallas import softmax_xent as sx
+
+    rows, chunk = sx.pick_blocks(n, v, itemsize, backward)
+    sub = {2: 16, 4: 8}[itemsize]
+    assert n % rows == 0 and rows % sub == 0
+    assert chunk % 128 == 0 and 128 <= chunk <= v
+    assert rows * 128 <= sx._TILE_ELEMS
+    need = sx._vmem_bytes(rows, v, itemsize, backward)
+    limit = sx._vmem_limit(rows, v, itemsize, backward)
+    if need <= sx._VMEM_BUDGET:
+        assert limit == sx._VMEM_SCOPED
+        # and no larger block would have done
+        widest = min(n, sx._TILE_ELEMS // 128)
+        assert not [r for r in range(rows + sub, widest + 1, sub)
+                    if n % r == 0 and sx._vmem_bytes(
+                        r, v, itemsize, backward) <= sx._VMEM_BUDGET]
+    else:
+        assert rows == sub and need < limit <= sx._VMEM_CEILING
+    # what is counted covers the resident blocks
+    assert need > (4 if backward else 2) * rows * v * itemsize
+
+
+@pytest.mark.kernels
+def test_softmax_xent_blocks_of_the_train_cell():
+    """GPT-2 124M's step, 16384 x 50304 bf16: 32 rows a grid step forward
+    and 16 backward, grids of 512 and 1,024 steps."""
+    from paddle_tpu.ops.pallas import softmax_xent as sx
+
+    n, v = 16 * 1024, 50304
+    fwd, _ = sx.pick_blocks(n, v, 2, backward=False)
+    bwd, _ = sx.pick_blocks(n, v, 2, backward=True)
+    assert (n // fwd, n // bwd) == (512, 1024)
+
+
+@pytest.mark.kernels
+def test_softmax_xent_vocabulary_too_wide_takes_the_composite(monkeypatch):
+    """A vocabulary whose narrowest row block outgrows VMEM: DoesNotTile
+    from the shapes, before anything is traced, for the backward's sake
+    too; ops/fused.py counts a fallback and gives the composite's loss."""
+    from paddle_tpu.ops import fused
+    from paddle_tpu.ops.pallas import softmax_xent as sx
+
+    wide = 128 * 2048
+    with pytest.raises(DoesNotTile):
+        sx.pick_blocks(16, wide, 2, backward=True)
+    with pytest.raises(DoesNotTile):
+        jax.eval_shape(sx.softmax_xent,
+                       jax.ShapeDtypeStruct((16, wide), jnp.bfloat16),
+                       jax.ShapeDtypeStruct((16,), jnp.int32))
+    # the same way out at a size a test can run: the ceiling pulled down
+    monkeypatch.setattr(sx, "_VMEM_CEILING", sx._VMEM_SCOPED)
+    monkeypatch.setattr(sx, "_VMEM_BUDGET", 64 * 1024)
+    rs = np.random.RandomState(13)
+    z = paddle.to_tensor(rs.randn(16, 4096).astype("f"))
+    lab = paddle.to_tensor(rs.randint(0, 4096, 16))
+    ref = fused.softmax_cross_entropy(z, lab)
+    monkeypatch.setattr(fused, "_use_pallas", lambda: True)
+    monkeypatch.setattr(fused, "_warned_sites", set())
+    counter = fused.fallback_counter()
+    key = ("softmax_xent", "shape")
+    base = counter.values.get(key, 0)
+    with pytest.warns(RuntimeWarning, match="softmax_xent"):
+        out = fused.softmax_cross_entropy(z, lab)
+    assert counter.values[key] == base + 1
+    np.testing.assert_array_equal(np.asarray(out.value),
+                                  np.asarray(ref.value))
 
 
 @pytest.mark.kernels
