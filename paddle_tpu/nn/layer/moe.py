@@ -10,6 +10,11 @@ einsums (MXU-friendly, no scatters); the stacked expert weights [E, ...]
 carry a PartitionSpec over `ep`, so under jit on an ep mesh XLA turns the
 dispatch einsum into the all-to-all the GPU frameworks hand-code.
 Over-capacity tokens are dropped (combine weight zero), matching GShard.
+
+``DroplessMoE`` below is the other routing: the k largest of a softmax
+router, no capacity, every assignment computed by a grouped product over
+rows sorted by expert (ops/fused.py ``moe_dropless``).  The two layers
+share no code yet (ROADMAP Design: two MoE layers).
 """
 from __future__ import annotations
 
@@ -19,10 +24,11 @@ import jax.numpy as jnp
 from ...distributed.meta_parallel import annotate
 from ..layer_base import Layer
 from .. import initializer as I
+from ...ops import fused
 from ...tensor import apply
 from .common import Linear
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "DroplessMoE"]
 
 EP_AXIS = "ep"
 
@@ -103,3 +109,64 @@ class MoELayer(Layer):
                          self.b2, _multi_out=True)
         self.l_aux = aux
         return out
+
+
+class DroplessMoE(Layer):
+    """Gated-SiLU experts behind a softmax router, top-k of E with no
+    capacity: every routed token is computed.
+
+        p = softmax(W_r x) in float32; the k largest; w_e = p_e / sum of
+        the k (``norm_topk_prob``); y = sum_e w_e W_down,e (silu(W_gate,e x)
+        * W_up,e x)
+
+    x [..., d_model] -> [..., d_model].  ``count`` (bool, x's leading
+    shape) marks the rows that matter (a serving step's live lanes) and
+    asks for their routing's statistics too: returns (y, assignments [E]
+    int32 of the counted rows, experts they touched as an int32 scalar).
+    A row it does not mark is padding: it goes through the first counted
+    row's experts, so it fetches no expert's weights of its own (a dead
+    lane's stale tokens would otherwise touch experts nobody asked for),
+    and its output means nothing."""
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k,
+                 norm_topk_prob=True, weight_attr=None):
+        super().__init__()
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.norm_topk_prob = norm_topk_prob
+        self.router = Linear(d_model, num_experts, weight_attr=weight_attr,
+                             bias_attr=False)
+        self.w_gate = self.create_parameter(
+            [num_experts, d_model, d_hidden], attr=weight_attr)
+        self.w_up = self.create_parameter(
+            [num_experts, d_model, d_hidden], attr=weight_attr)
+        self.w_down = self.create_parameter(
+            [num_experts, d_hidden, d_model], attr=weight_attr)
+
+    def forward(self, x, count=None):
+        E, K, norm = self.num_experts, self.top_k, self.norm_topk_prob
+        train = self.training
+
+        def f(xv, rw, wg, wu, wd, *cnt):
+            xt = xv.reshape(-1, xv.shape[-1])
+            p = jax.nn.softmax(jnp.dot(
+                xt, rw, preferred_element_type=jnp.float32), -1)
+            top, idx = jax.lax.top_k(p, K)
+            if norm:
+                top = top / top.sum(-1, keepdims=True)
+            if cnt:
+                live = cnt[0].reshape(-1, 1)
+                idx = jnp.where(live, idx, idx[jnp.argmax(live[:, 0])])
+            y = fused.moe_dropless(xt, idx, top, wg, wu, wd,
+                                   differentiable=train).reshape(xv.shape)
+            if not cnt:
+                return y
+            per = jnp.zeros((E,), jnp.int32).at[idx].add(
+                jnp.broadcast_to(live.astype(jnp.int32), idx.shape))
+            return y, per, (per > 0).sum(dtype=jnp.int32)
+
+        args = (x, self.router.weight, self.w_gate, self.w_up, self.w_down)
+        if count is None:
+            return apply(f, *args)
+        return apply(f, *args, count, _multi_out=True)

@@ -460,6 +460,84 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
 
 
 # ---------------------------------------------------------------------------
+# dropless mixture of experts: assignments sorted by expert, one grouped
+# product over the groups' rows (Pallas `paddle_moe_gmm`), combine
+# ---------------------------------------------------------------------------
+def moe_layout(expert_ids, num_experts: int, tm: int):
+    """Where each assignment's row goes when rows are grouped by expert
+    with every group starting at a multiple of ``tm`` (a counting sort:
+    no token is dropped, whatever the imbalance).
+
+    expert_ids [N, k] int32.  Returns (dest [N, k] row of each assignment
+    in the grouped buffer, src [Mp] the token each grouped row holds
+    (padding rows hold token 0), tile_expert [Mp // tm] the expert of each
+    row tile, n_used the tiles that hold rows, group_rows [E] each group's
+    rows with its padding); Mp is static."""
+    n, k = expert_ids.shape
+    a, e = n * k, num_experts
+    mp = -(-min(a + e * (tm - 1), a * tm) // tm) * tm
+    flat = expert_ids.reshape(a).astype(jnp.int32)
+    seen = jnp.cumsum(
+        (flat[:, None] == jnp.arange(e, dtype=jnp.int32)[None]).astype(
+            jnp.int32), axis=0)                               # [A, E]
+    rank = jnp.take_along_axis(seen, flat[:, None], 1)[:, 0] - 1
+    group_rows = (seen[-1] + tm - 1) // tm * tm
+    ends = jnp.cumsum(group_rows)
+    dest = (ends - group_rows)[flat] + rank
+    src = jnp.zeros((mp,), jnp.int32).at[dest].set(
+        jnp.arange(a, dtype=jnp.int32) // k)
+    n_used = ends[-1] // tm
+    tile = jnp.arange(mp // tm, dtype=jnp.int32)
+    te = jnp.minimum(jnp.searchsorted(ends, tile * tm, side="right"),
+                     e - 1).astype(jnp.int32)
+    # tiles past the rows keep the last used expert: nothing new to fetch
+    te = jnp.where(tile < n_used, te, te[jnp.maximum(n_used - 1, 0)])
+    return dest.reshape(n, k), src, te, n_used.astype(jnp.int32), group_rows
+
+
+def grouped_expert_ffn(x, w_gate, w_up, w_down, tile_expert, n_used,
+                       group_rows, tm: int, differentiable=False):
+    """W_down[e] (silu(W_gate[e] x) * W_up[e] x) over rows grouped by
+    expert (``moe_layout``): the Pallas kernel on a TPU, ``lax.ragged_dot``
+    as the counted fallback and wherever a gradient is wanted (the kernel
+    has no backward pass yet).  Raw jax arrays in and out, [Mp, H]."""
+    if _use_pallas() and not differentiable:
+        from .pallas import moe_gmm as mg
+
+        out = _kernel_or_none("moe_gmm", lambda: mg.moe_gmm(
+            x, w_gate, w_up, w_down, tile_expert, n_used, tm))
+        if out is not None:
+            return out
+    g = jax.lax.ragged_dot(x, w_gate, group_rows,
+                           preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(x, w_up, group_rows,
+                           preferred_element_type=jnp.float32)
+    a = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    return jax.lax.ragged_dot(
+        a, w_down, group_rows,
+        preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def moe_dropless(x, expert_ids, expert_weights, w_gate, w_up, w_down,
+                 differentiable=False):
+    """sum_k weight[n, k] * expert_{ids[n, k]}(x[n]) for x [N, H], the
+    gated-SiLU experts stacked as w_gate / w_up [E, H, F], w_down
+    [E, F, H]; expert_ids / expert_weights [N, k].  Every assignment is
+    computed (no capacity).  The weighted sum is taken in float32.  Raw
+    jax arrays; returns [N, H] in x's dtype."""
+    from .pallas.moe_gmm import pick_tile_rows
+
+    n, k = expert_ids.shape
+    tm = pick_tile_rows(n * k, w_gate.shape[0])
+    dest, src, te, n_used, group_rows = moe_layout(
+        expert_ids, w_gate.shape[0], tm)
+    y = grouped_expert_ffn(x[src], w_gate, w_up, w_down, te, n_used,
+                           group_rows, tm, differentiable)
+    return jnp.einsum("nk,nkh->nh", expert_weights.astype(jnp.float32),
+                      y[dest].astype(jnp.float32)).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
 # fused bias + GeLU (fused_gemm_epilogue intent): matmul stays with XLA's
 # MXU scheduling, the bias-add + exact-erf GeLU epilogue runs as one Pallas
 # pass (forward and backward) instead of separate elementwise HLOs

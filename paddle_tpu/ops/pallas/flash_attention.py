@@ -586,8 +586,15 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
             f"({block_q},{block_k})")
     if min(block_q, block_k) < 8:
         raise DoesNotTile("flash_attention: sequence too short")
-    if k.shape[2] != h:
-        raise DoesNotTile("flash_attention: GQA head mismatch")
+    if k.shape[2] != h or v.shape[2] != h:
+        # no silent composite for grouped heads: the kernel reads one KV
+        # head a query head, so a caller with fewer KV heads expands them
+        # first (models/sdar.py's prompt pass does); anything else is a
+        # caller's error, here and in the composite alike
+        raise ValueError(
+            f"flash_attention: {h} query heads over {k.shape[2]} key and "
+            f"{v.shape[2]} value heads; expand grouped KV heads to the "
+            "query heads before the call")
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:

@@ -37,6 +37,16 @@ shape discipline, in the Orca iteration-level-scheduling shape:
     compiled with NamedSharding in/out (out-shardings pinned to
     in-shardings, so donation holds under GSPMD).
 
+  * **generation by blocks** — a model whose ``cfg`` gives a
+    ``block_length`` B (models/sdar.py) generates by diffusion over
+    blocks: the engine builds ``block_step`` in place of ``decode_step``.
+    A lane holds a block of B positions that starts masked; each
+    iteration runs the block over the committed prefix with the block
+    visible both ways and unmasks its most confident positions; when no
+    mask is left one more pass writes the block's final K/V and the next
+    block starts.  A block's tokens reach the stream together, in the
+    iteration that resolves its last mask.
+
 Per-slot sampling (greedy / temperature / top-k, per-request seed)
 reproduces ``GPTForCausalLM.generate``'s exact PRNG chain — one
 ``split`` at admission, one per decode iteration — which is what makes
@@ -84,6 +94,9 @@ class GenerationHandle:
         self.prompt_len = prompt_len
         self.max_new_tokens = max_new_tokens
         self.tokens: list[int] = []       # appended by the decode thread
+        # a block engine's: the denoising step at which each token of
+        # `tokens` was unmasked (None where tokens come one a step)
+        self.steps: list[int] | None = None
         self._q: queue.Queue = queue.Queue()
         self._done = threading.Event()
         self._error: BaseException | None = None
@@ -170,7 +183,7 @@ class _GenRequest:
                  "deadline", "handle", "engine", "cancelled",
                  "t_last_token", "span", "own_span", "span_queue",
                  "span_decode", "prefilling", "prefill_cursor",
-                 "chunk_row", "j_hit", "pin_final")
+                 "chunk_row", "j_hit", "pin_final", "block_start")
 
     def __init__(self, engine, prompt, bucket, max_new_tokens, do_sample,
                  temperature, top_k, seed, eos, deadline, span=None,
@@ -197,6 +210,7 @@ class _GenRequest:
         self.chunk_row = None              # slot's page row so far (np)
         self.j_hit = 0                     # prefix-cache pages mapped
         self.pin_final = 0                 # pinned count once armed
+        self.block_start = 0               # block engines: the open block
         self.handle = GenerationHandle(len(prompt), max_new_tokens)
         self.handle._req = self
 
@@ -223,7 +237,13 @@ class GenerationEngine:
         ``slot_step(tokens, positions, kv, last=None)`` (models/gpt.py
         GPTForCausalLM; ``kv`` is a KV source of serving/kv_cache.py) and
         a ``cfg`` with num_layers / num_heads / hidden_size / vocab_size
-        / max_position_embeddings.  A ``draft_model`` answers the same.
+        / max_position_embeddings (and, where they differ from
+        hidden_size // num_heads and num_heads, head_dim and
+        num_kv_heads).  A ``draft_model`` answers the same.  A ``cfg``
+        with a ``block_length`` (and denoising_steps, mask_token_id,
+        remasking_strategy, confidence_threshold) declares generation by
+        blocks; with ``num_experts`` the model's ``slot_step`` takes
+        ``live=`` and returns its routing counts as a third value.
       max_slots: in-flight sequences per decode iteration
         (``FLAGS_genserve_max_slots``).
       max_seq_len: per-slot sequence cap S_max >= prompt + new tokens
@@ -327,6 +347,13 @@ class GenerationEngine:
                     "speculative decode under a mesh is not supported "
                     "yet — drop draft_model or mesh")
         self.draft_model = draft_model
+        # generation by blocks: declared by the model, never by a flag
+        self.block_length = int(getattr(cfg, "block_length", 0) or 0)
+        if self.block_length and (draft_model is not None
+                                  or mesh is not None):
+            raise ValueError(
+                "a block-generating model is served without a draft model "
+                "and without a mesh (neither path is written yet)")
         if spec_tokens is None:
             spec_tokens = int(_flags.flag("FLAGS_genserve_spec_tokens", 4))
         self.spec_tokens = int(spec_tokens) if draft_model is not None \
@@ -368,9 +395,14 @@ class GenerationEngine:
         self.geometry = CacheGeometry(
             num_layers=cfg.num_layers, max_slots=self.max_slots,
             max_seq_len=self.max_seq_len, num_heads=cfg.num_heads,
-            head_dim=cfg.hidden_size // cfg.num_heads,
+            head_dim=(getattr(cfg, "head_dim", 0)
+                      or cfg.hidden_size // cfg.num_heads),
             vocab_size=cfg.vocab_size, page_size=page_size,
-            num_pages=int(num_pages), dtype=kv_dtype, **draft_kw)
+            num_pages=int(num_pages), dtype=kv_dtype,
+            num_kv_heads=getattr(cfg, "num_kv_heads", 0),
+            block_length=self.block_length,
+            num_experts=(getattr(cfg, "num_experts", 0)
+                         if self.block_length else 0), **draft_kw)
         self.metrics = GenerationMetrics(
             max_slots=self.max_slots, num_pages=self.geometry.num_pages)
         self._prefix = (PrefixCache(page_size) if prefix_cache else None)
@@ -417,6 +449,8 @@ class GenerationEngine:
         self._decode_exec = None
         self.decode_temp_bytes = None   # set by start(), from the compiler
         self._spec_exec = None
+        self._block_exec = None
+        self._expert_counts = None      # block_step's last published copy
         self._release_exec = None
         self._reclaim_exec = None
         self._prefill_execs = {}
@@ -447,6 +481,8 @@ class GenerationEngine:
         # static prefix extent of the hit-path executables: the largest
         # full-page prefix any admitted prompt can share
         pfx_pages = min(pps, -(-self.prompt_buckets[-1] // ps))
+        B = geom.block_length
+        prefix_kw = {"block": B} if B else {}
 
         # sharding plan: None entries (no mesh) keep today's lowering
         mesh, layout = self._mesh, self._layout
@@ -514,7 +550,11 @@ class GenerationEngine:
             out, _ = functional_call(
                 model, params, (Tensor(ids), length), buffers=buffers,
                 mutable=False, method="slot_prefill")
-            return out                     # (k [L,Sp,nh,hd], v, logits [V])
+            if B:
+                # a block engine samples nothing at admission: the
+                # logits go, and the compiler drops the head with them
+                return out[0], out[1], jnp.zeros((1,), out[2].dtype)
+            return out                     # (k [L,Sp,nkv,hd], v, logits [V])
 
         if draft is None:
             prefill_step = target_prefill
@@ -529,18 +569,45 @@ class GenerationEngine:
                     method="slot_prefill")
                 return k, v, lg, dk, dv
 
+        def arm(state, slot, logits, length, seed, resume_pos, do_sample,
+                temp, top_k, stop_pos, eos, pinned, opening=(), active=True):
+            """Arm lane ``slot`` after its prompt is in the pool: sample
+            the first token from ``logits``; or, for a block engine, open
+            the first block: ``opening`` = (the prompt's last L mod B
+            tokens padded to B, how many they are), known from the start,
+            the rest masked.  Returns (state, first token)."""
+            key, sub = jax.random.split(resume_chain(seed, resume_pos))
+            if not B:
+                tok1 = sample_token(logits, sub, do_sample, temp, top_k)
+                return admit_slot(state, slot, tok1, length, key, do_sample,
+                                  temp, top_k, stop_pos, eos, pinned,
+                                  active), tok1
+            tail, n_known = opening
+            state = admit_slot(state, slot, 0, length // B * B, key,
+                               do_sample, temp, top_k, stop_pos, eos, pinned,
+                               active)
+            known = jnp.arange(B, dtype=jnp.int32) < n_known
+            return dict(
+                state,
+                blk=state["blk"].at[slot].set(
+                    jnp.where(known, tail, mask_id)),
+                blk_open=state["blk_open"].at[slot].set(~known),
+                blk_step=state["blk_step"].at[slot].set(-1),
+                step=state["step"].at[slot].set(0)), jnp.int32(0)
+
         def insert_step(state, slot, k_new, v_new, logits, length, seed,
                         resume_pos, do_sample, temp, top_k, stop_pos, eos,
-                        pinned, *draft_kv):
+                        pinned, *extra):
             # prefix-miss admission: every mapped page is freshly
-            # allocated and written (shared_n = 0)
+            # allocated and written (shared_n = 0).  `extra` is the
+            # draft's K/V (speculative) or the opening block (blocks)
+            draft_kv, opening = ((), extra) if B else (extra, ())
             no_shared = jnp.full((pps,), -1, jnp.int32)
             state, row = write_prompt(state, slot, k_new, v_new, length,
                                       no_shared, jnp.int32(0), *draft_kv)
-            key, sub = jax.random.split(resume_chain(seed, resume_pos))
-            tok1 = sample_token(logits, sub, do_sample, temp, top_k)
-            state = admit_slot(state, slot, tok1, length, key, do_sample,
-                               temp, top_k, stop_pos, eos, pinned)
+            state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
+                              do_sample, temp, top_k, stop_pos, eos, pinned,
+                              opening)
             return state, tok1, row
 
         def suffix_prefill(params, dparams, state, ids, shared_ids,
@@ -559,7 +626,8 @@ class GenerationEngine:
                     m, p,
                     (ids, positions,
                      PrefixKV.gather(k_pool, v_pool,
-                                     shared_ids[:pfx_pages], prefix_len),
+                                     shared_ids[:pfx_pages], prefix_len,
+                                     **prefix_kw),
                      last),
                     buffers=b, mutable=False, method="slot_step")
                 return kv.suffix_kv(), lg[0, 0]
@@ -574,7 +642,7 @@ class GenerationEngine:
 
         def _insert_prefix(params, dparams, state, slot, ids, shared_ids,
                            shared_n, length, seed, resume_pos, do_sample,
-                           temp, top_k, stop_pos, eos, pinned):
+                           temp, top_k, stop_pos, eos, pinned, *opening):
             # prefix-hit admission: the shared pages are never
             # recomputed; the suffix pages in at the (page-aligned)
             # boundary
@@ -583,10 +651,9 @@ class GenerationEngine:
                 length)
             state, row = write_prompt(state, slot, k_suf, v_suf, length,
                                       shared_ids, shared_n, *draft_kv)
-            key, sub = jax.random.split(resume_chain(seed, resume_pos))
-            tok1 = sample_token(logits, sub, do_sample, temp, top_k)
-            state = admit_slot(state, slot, tok1, length, key, do_sample,
-                               temp, top_k, stop_pos, eos, pinned)
+            state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
+                              do_sample, temp, top_k, stop_pos, eos, pinned,
+                              opening)
             return state, tok1, row
 
         if draft is None:
@@ -597,9 +664,10 @@ class GenerationEngine:
 
         def _chunk(params, dparams, state, slot, ids, shared_ids,
                    shared_n, length, seed, resume_pos, do_sample, temp,
-                   top_k, stop_pos, eos, pin_now, pin_final, arm):
+                   top_k, stop_pos, eos, pin_now, pin_final, arm_now,
+                   *opening):
             # one prefill chunk: scatter this slice's K/V behind the
-            # resumable cursor; ONLY the final chunk (arm=True) samples
+            # resumable cursor; ONLY the final chunk (arm_now) samples
             # a real first token and activates the lane.  Until then
             # ``pinned`` stays at the prefix-cache hit count (pin_now)
             # so a cancel/deadline sweep frees every privately written
@@ -611,13 +679,11 @@ class GenerationEngine:
                 length)
             state, row = write_prompt(state, slot, k_suf, v_suf, length,
                                       shared_ids, shared_n, *draft_kv)
-            key, sub = jax.random.split(resume_chain(seed, resume_pos))
-            tok1 = sample_token(logits, sub, do_sample, temp, top_k)
-            pinned = jnp.where(jnp.asarray(arm, bool), pin_final,
+            pinned = jnp.where(jnp.asarray(arm_now, bool), pin_final,
                                pin_now)
-            state = admit_slot(state, slot, tok1, length, key, do_sample,
-                               temp, top_k, stop_pos, eos, pinned,
-                               active=arm)
+            state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
+                              do_sample, temp, top_k, stop_pos, eos, pinned,
+                              opening, active=arm_now)
             return state, tok1, row
 
         if draft is None:
@@ -780,6 +846,133 @@ class GenerationEngine:
                              active=active & ~finished)
             return new_state, outs, emitted, finished
 
+        if B:
+            cfg = model.cfg
+            mask_id = int(cfg.mask_token_id)
+            steps = int(cfg.denoising_steps)
+            if not 1 <= steps <= B:
+                raise ValueError(
+                    f"{steps} denoising steps for a block of {B}")
+            # tokens a step unmasks under the static strategy: B / steps,
+            # the remainder going to the first steps
+            n_transfer = jnp.asarray(
+                [B // steps + (1 if t < B % steps else 0)
+                 for t in range(steps)], jnp.int32)
+            strategy = getattr(cfg, "remasking_strategy",
+                               "low_confidence_static")
+            if strategy not in ("low_confidence_static",
+                                "low_confidence_dynamic"):
+                raise ValueError(
+                    f"unknown remasking strategy {strategy!r}")
+            threshold = float(getattr(cfg, "confidence_threshold", 0.85))
+            counted = bool(geometry.num_experts)
+
+        def block_step(params, state):
+            """ONE iteration of generation by blocks.  A lane holds the
+            block [start, start + B) (``pos`` is its start).  Every live
+            lane's B tokens run at their positions over the paged pool,
+            each query seeing the committed prefix and the whole block;
+            the block's K/V are written EVERY pass at the block's own
+            positions, where nothing but the block's own queries can see
+            them before the final pass overwrites them (as ``spec_step``
+            argues for rejected proposals).  A lane with masks left takes
+            a token and its probability at every masked position and
+            unmasks by its strategy; a lane with none left has just
+            written its block's final K/V: it moves to the next block,
+            all masked, or retires on eos or at ``stop_pos``.
+
+            Returns (state, out [slots, 2B + 3] int32: the block's tokens
+            and the step at which each was unmasked, then three flags:
+            the lane resolved its last mask this pass, the pass was the
+            lane's final (committing) one, the lane retired; and the
+            routed-assignment counters, as buffers of their own)."""
+            lane = jnp.arange(geometry.max_slots)
+            start, active = state["pos"], state["active"]
+            ptab = state["ptab"]
+            # (1) map the page under the block (B divides the page size)
+            pidx = jnp.clip(start // ps, 0, pps - 1)
+            cur = ptab[lane, pidx]
+            need = active & (cur < 0)
+            pages, free_count = take_pages(state["free_stack"],
+                                           state["free_count"], need)
+            ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
+            # (2) the block's B tokens a lane, the block visible both ways
+            off = jnp.arange(B, dtype=jnp.int32)[None]
+            P = start[:, None] + off
+            limits = jnp.broadcast_to((start + B - 1)[:, None], P.shape)
+            out, _ = functional_call(
+                model, params,
+                (state["blk"], P,
+                 PagedKV(state["kp"], state["vp"], ptab, P, active,
+                         seq_cap, limits=limits)),
+                dict(live=active) if counted else {},
+                buffers=buffers, mutable=False, method="slot_step")
+            logits, kv = out[0].astype(jnp.float32), out[1]
+            # (3) denoise: the token and its probability at each position
+            x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            conf = 1.0 / jnp.exp(
+                logits - logits.max(-1, keepdims=True)).sum(-1)
+            blk, is_open, step = state["blk"], state["blk_open"], \
+                state["step"]
+            denoise = active & is_open.any(axis=1)
+            commit = active & ~is_open.any(axis=1)
+            n_t = n_transfer[jnp.clip(step, 0, steps - 1)][:, None]
+            # the n_t most confident masked positions, ties to the lower
+            # index; a known position is never chosen
+            order = jnp.argsort(jnp.where(is_open, -conf, 2.0), axis=1,
+                                stable=True)
+            rank = jnp.argsort(order, axis=1, stable=True)
+            pick = is_open & (rank < n_t)
+            if strategy == "low_confidence_dynamic":
+                high = is_open & (conf > threshold)
+                pick = jnp.where(high.sum(1, keepdims=True) >= n_t, high,
+                                 pick)
+            pick = pick & denoise[:, None]
+            blk = jnp.where(pick, x0, blk)
+            is_open = is_open & ~pick
+            blk_step = jnp.where(pick, step[:, None], state["blk_step"])
+            resolved = denoise & ~is_open.any(axis=1)
+            step = jnp.where(denoise, step + 1, step)
+            # (4) commit: the next block, or retirement on an eos among
+            # the block's generated tokens inside the budget, or at it
+            stop_pos = state["stop_pos"]
+            eos_in = ((blk_step >= 0) & (P < stop_pos[:, None])
+                      & (blk == state["eos"][:, None])).any(axis=1)
+            finished = commit & (eos_in | (start + B >= stop_pos))
+            nxt = commit & ~finished
+            report = jnp.concatenate(
+                [blk, blk_step,
+                 jnp.stack([resolved, commit, finished], 1).astype(
+                     jnp.int32)], axis=1)
+            blk = jnp.where(nxt[:, None], mask_id, blk)
+            is_open = is_open | nxt[:, None]
+            blk_step = jnp.where(nxt[:, None], -1, blk_step)
+            step = jnp.where(nxt, 0, step)
+            start = jnp.where(nxt, start + B, start)
+            # (5) retire in-graph, as the plain decode step does
+            col = jnp.arange(pps, dtype=jnp.int32)[None, :]
+            freeable = finished[:, None] & (ptab >= 0) \
+                & (col >= state["pinned"][:, None])
+            free_stack, free_count = push_pages(
+                state["free_stack"], free_count,
+                jnp.where(freeable, ptab, -1).reshape(-1))
+            ptab = jnp.where(finished[:, None], -1, ptab)
+            new_state = dict(state, kp=kv.k_pages, vp=kv.v_pages, ptab=ptab,
+                             free_stack=free_stack, free_count=free_count,
+                             pos=start, blk=blk, blk_open=is_open,
+                             blk_step=blk_step, step=step,
+                             active=active & ~finished)
+            counts = ()
+            if counted:
+                per, touched = out[2]
+                new_state["moe_counts"] = state["moe_counts"] + per
+                new_state["moe_touched"] = state["moe_touched"] + touched
+                # copies of their own: read from other threads while the
+                # loop donates the state
+                counts = (new_state["moe_counts"] + 0,
+                          new_state["moe_touched"] + 0)
+            return new_state, report, counts
+
         def release_step(state, mask):
             return release_slots(state, mask)
 
@@ -829,6 +1022,9 @@ class GenerationEngine:
                 self._spec_exec = inference.aot_compile(
                     spec_step, (pspec, dpspec, sspec),
                     donate_argnums=(2,))
+            elif B:
+                self._block_exec = inference.aot_compile(
+                    block_step, (pspec, sspec), donate_argnums=(1,))
             else:
                 self._decode_exec = inference.aot_compile(
                     decode_step, (pspec, sspec), donate_argnums=(1,),
@@ -856,10 +1052,12 @@ class GenerationEngine:
                 kv = sds(pre[0].shape, pre[0].dtype, kv_sh)
                 lg = sds(pre[2].shape, pre[2].dtype)
                 dkv_in = tuple(sds(a.shape, a.dtype) for a in pre[3:])
+                # a block engine's admissions carry the opening block
+                opening = (sds((B,), np.int32), i32) if B else ()
                 self._insert_execs[sp] = inference.aot_compile(
                     insert_step,
                     (sspec, i32, kv, kv, lg, i32, i32, i32, b1, f32, i32,
-                     i32, i32, i32) + dkv_in,
+                     i32, i32, i32) + dkv_in + opening,
                     donate_argnums=(0,), out_shardings=outs(rep, rep))
                 self.compile_count += 2
                 tail = (i32, ids, pvec, i32, i32, i32, i32, b1, f32, i32,
@@ -867,7 +1065,7 @@ class GenerationEngine:
                 if self._prefix is not None:
                     self._insert_prefix_execs[sp] = inference.aot_compile(
                         insert_prefix_step,
-                        (pspec,) + dpre + (sspec,) + tail,
+                        (pspec,) + dpre + (sspec,) + tail + opening,
                         donate_argnums=(1 + len(dpre),),
                         out_shardings=outs(rep, rep))
                     self.compile_count += 1
@@ -875,7 +1073,7 @@ class GenerationEngine:
                     self._chunk_execs[sp] = inference.aot_compile(
                         chunk_step,
                         (pspec,) + dpre + (sspec,) + tail[:-1]
-                        + (i32, i32, b1),
+                        + (i32, i32, b1) + opening,
                         donate_argnums=(1 + len(dpre),),
                         out_shardings=outs(rep, rep))
                     self.compile_count += 1
@@ -883,7 +1081,8 @@ class GenerationEngine:
         # the decode step rewrites the donated pools in place: what it
         # holds beside them stays far under one layer's plane of one pool
         # (a copied plane or pool would show here before any chip run)
-        mem = (self._spec_exec or self._decode_exec).memory_analysis()
+        mem = (self._spec_exec or self._block_exec
+               or self._decode_exec).memory_analysis()
         self.decode_temp_bytes = (int(mem.temp_size_in_bytes)
                                   if mem is not None else None)
         logger.info(
@@ -920,8 +1119,7 @@ class GenerationEngine:
         p50 — in steady state one decode iteration IS the inter-token
         gap.  Reads only the compiled executable's HLO; never touches
         the live (donated) decode state."""
-        exe = self._spec_exec if self._spec_exec is not None \
-            else self._decode_exec
+        exe = self._spec_exec or self._block_exec or self._decode_exec
         if exe is None:
             raise RuntimeError("op_report() before start()")
         ca = exe.cost_analysis()
@@ -992,6 +1190,10 @@ class GenerationEngine:
         resume_pos = int(resume_pos)
         if resume_pos < 0:
             raise ValueError("resume_pos must be >= 0")
+        if self.block_length and (do_sample or resume_pos):
+            raise ValueError(
+                "generation by blocks is greedy and does not resume "
+                "mid-stream yet (do_sample and resume_pos are not served)")
         eos = self.geometry.vocab_size if eos_token_id is None \
             else int(eos_token_id)
         deadline = (time.monotonic() + deadline_ms / 1e3
@@ -1012,6 +1214,8 @@ class GenerationEngine:
                           bool(do_sample), float(temperature), top_k,
                           int(seed), eos, deadline, span=span,
                           own_span=own_span, resume_pos=resume_pos)
+        if self.block_length:
+            req.handle.steps = []
         if span is not None:
             # attached BEFORE enqueue: the decode thread may admit the
             # request (and close this child) before put_nowait returns
@@ -1043,7 +1247,7 @@ class GenerationEngine:
         try:
             # Every statement of an iteration lies in one top-level
             # phase of self.timers (wait, pull, sweep, admit, chunk,
-            # decode | spec_decode, fetch, distribute), so the phases'
+            # decode | spec_decode | block_step, fetch, distribute), so the phases'
             # totals sum to the loop's wall time; README "Reading a
             # trace" lists them with their children.
             scope = self.timers.scope
@@ -1072,6 +1276,10 @@ class GenerationEngine:
                             outs, emitted, fin = self.step_spec()
                             with scope("distribute"):
                                 self._distribute_spec(outs, emitted, fin)
+                        elif self._block_exec is not None:
+                            report = self.step_block()
+                            with scope("distribute"):
+                                self._distribute_block(report)
                         else:
                             toks, fin = self.step()
                             with scope("distribute"):
@@ -1213,6 +1421,7 @@ class GenerationEngine:
         stop = np.int32(L + req.max_new_tokens)
         dpre = ((self._draft_params,)
                 if self.draft_model is not None else ())
+        opening = self._opening(req.prompt)
         scope = self.timers.scope
         with scope("prefill"):
             if j_hit > 0:
@@ -1229,7 +1438,7 @@ class GenerationEngine:
                     np.int32(req.seed), np.int32(req.resume_pos),
                     np.bool_(req.do_sample),
                     np.float32(req.temperature), np.int32(req.top_k),
-                    stop, np.int32(req.eos), np.int32(pinned))
+                    stop, np.int32(req.eos), np.int32(pinned), *opening)
             else:
                 ids = np.zeros((1, req.bucket), np.int32)
                 ids[0, :L] = req.prompt
@@ -1242,7 +1451,7 @@ class GenerationEngine:
                     np.int32(req.resume_pos),
                     np.bool_(req.do_sample), np.float32(req.temperature),
                     np.int32(req.top_k), stop, np.int32(req.eos),
-                    np.int32(pinned), *out[3:])
+                    np.int32(pinned), *out[3:], *opening)
         self._state = state
         with scope("admit/fetch"), host_fetch():
             # blocks until the device has run the prefill and insert
@@ -1263,9 +1472,28 @@ class GenerationEngine:
                 sp_prefill.end(status="ok")
             self._push_first(req, slot, t1)
 
+    def _opening(self, prompt):
+        """A block engine's extra admission arguments: the prompt's last
+        L mod B tokens padded to a block, and how many they are (they
+        open the first generated block as known tokens)."""
+        B = self.block_length
+        if not B:
+            return ()
+        n = len(prompt) % B
+        tail = np.zeros((B,), np.int32)
+        tail[:n] = prompt[len(prompt) - n:]
+        return tail, np.int32(n)
+
     def _push_first(self, req: _GenRequest, slot: int, t1: int):
         """The first token goes to the handle (TTFT observed); the lane
-        retires at once on eos / max_new_tokens == 1."""
+        retires at once on eos / max_new_tokens == 1.  A block engine has
+        no token yet: its first come from ``block_step``."""
+        if self.block_length:
+            req.block_start = len(req.prompt) // self.block_length \
+                * self.block_length
+            if req.span is not None:
+                req.span_decode = req.span.child("gen.decode", slot=slot)
+            return
         now = time.monotonic()
         req.t_last_token = now
         req.handle._push(t1)
@@ -1357,7 +1585,7 @@ class GenerationEngine:
                 np.int32(req.top_k),
                 np.int32(L + req.max_new_tokens), np.int32(req.eos),
                 np.int32(req.j_hit), np.int32(req.pin_final),
-                np.bool_(arm))
+                np.bool_(arm), *self._opening(req.prompt))
         self._state = state
         with scope("chunk/fetch"), host_fetch():
             t1 = int(np.array(tok1, copy=True))
@@ -1469,6 +1697,87 @@ class GenerationEngine:
             emitted_np = np.array(emitted, copy=True)
             fin_np = np.array(fin, copy=True)
         return outs_np, emitted_np, fin_np
+
+    def step_block(self):
+        """ONE iteration of generation by blocks (``block_step``): every
+        armed lane runs its block once.  Returns the step's report
+        [slots, 2B + 3] (tokens, their steps, three flags), fetched; the
+        routed-assignment counters stay on the device."""
+        with self.timers.scope("block_step"):
+            self._iter += 1
+            chaos.on_step(self._iter)
+            state, report, counts = self._block_exec(self._params,
+                                                     self._state)
+        self._state = state
+        if counts:
+            self._expert_counts = counts
+        with self.timers.scope("fetch"), host_fetch():
+            return np.array(report, copy=True)
+
+    def expert_counts(self):
+        """What the device has counted of the live lanes' routed
+        assignments since start: {"assignments": [layers, experts],
+        "touched": [layers] (experts with at least one assignment, summed
+        over steps)}, zeros before the first block step.  Reads a copy
+        that ``block_step`` published, never the donated state, so any
+        thread may call it."""
+        geom = self.geometry
+        counts = self._expert_counts
+        if counts is None:
+            return {"assignments": np.zeros(
+                        (geom.num_layers, geom.num_experts), np.int64),
+                    "touched": np.zeros((geom.num_layers,), np.int64)}
+        with host_fetch():
+            return {"assignments": np.array(counts[0], np.int64),
+                    "touched": np.array(counts[1], np.int64)}
+
+    def _distribute_block(self, report):
+        """A block's tokens go to the stream together, in the iteration
+        that resolved its last mask, cut at eos and at max_new_tokens.
+        The gap histogram records what a client sees: one gap a block and
+        zeros inside it."""
+        B = self.block_length
+        now = time.monotonic()
+        denoised = committed = emitted = 0
+        for slot, req in list(self._sched.occupied.items()):
+            if req.prefilling:
+                continue
+            resolved, commit, fin = (bool(f) for f in report[slot, 2 * B:])
+            committed += commit
+            denoised += not commit
+            if resolved:
+                L = len(req.prompt)
+                lo = max(req.block_start, L) - req.block_start
+                hi = min(req.block_start + B,
+                         L + req.max_new_tokens) - req.block_start
+                for i in range(lo, hi):
+                    tok = int(report[slot, i])
+                    if req.t_last_token is None:
+                        self.metrics.observe_ttft(now - req.handle.t_submit)
+                        if req.span is not None:
+                            req.span.event("first_token", slot=slot,
+                                           iter=self._iter)
+                    else:
+                        self.metrics.observe_inter_token(
+                            now - req.t_last_token)
+                    req.t_last_token = now
+                    req.handle.steps.append(int(report[slot, B + i]))
+                    req.handle._push(tok)
+                    emitted += 1
+                    if req.span_decode is not None:
+                        req.span_decode.event(
+                            "token", i=len(req.handle.tokens),
+                            iter=self._iter)
+                    if tok == req.eos:
+                        break
+                req.block_start += B
+            if fin:
+                self._host_retire(slot)
+                self.metrics.count("retired")
+                req.end_spans("ok")
+                req.handle._finish()
+        self.metrics.observe_tokens(emitted)
+        self.metrics.observe_block_step(denoised, committed, emitted)
 
     def _distribute_spec(self, outs_np, emitted_np, fin_np):
         now = time.monotonic()

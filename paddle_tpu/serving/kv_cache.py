@@ -2,7 +2,8 @@
 
 The generation engine's whole mutable decode state is ONE pytree of
 fixed-shape jax arrays: a page pool ``[layers, num_pages, page_size,
-nh, hd]`` (the fused_multi_transformer CacheKV layout broken into
+nkv, hd]`` of the model's KV heads (the fused_multi_transformer CacheKV
+layout broken into
 fixed-size pages, vLLM-style), an int32 per-slot page table
 ``[max_slots, pages_per_slot]`` (-1 = unmapped), a free-list register
 (``free_stack`` + scalar ``free_count``), and the per-slot lane
@@ -70,7 +71,7 @@ class CacheGeometry:
     num_layers: int
     max_slots: int
     max_seq_len: int       # S_max: prompt + generated tokens per slot
-    num_heads: int
+    num_heads: int         # QUERY heads; the pool holds num_kv_heads
     head_dim: int
     vocab_size: int
     page_size: int = 16
@@ -82,10 +83,32 @@ class CacheGeometry:
     draft_layers: int = 0
     draft_num_heads: int = 0
     draft_head_dim: int = 0
+    # grouped heads: the KV heads the pool holds, each read by
+    # num_heads / num_kv_heads query heads; 0 = num_heads (one each)
+    num_kv_heads: int = 0
+    # generation by blocks (a model whose cfg gives block_length): the
+    # lanes then carry a block's registers; 0 = one token a lane a step
+    block_length: int = 0
+    # routed experts a layer (0 = none): the state then counts the
+    # assignments of the live lanes' rows, layer by expert
+    num_experts: int = 0
 
     def __post_init__(self):
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {self.page_size}")
+        if self.num_kv_heads == 0:
+            object.__setattr__(self, "num_kv_heads", self.num_heads)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.num_heads} query heads do not divide over "
+                f"{self.num_kv_heads} KV heads")
+        if self.block_length and (self.page_size % self.block_length
+                                  or self.max_seq_len % self.block_length):
+            raise ValueError(
+                f"page_size {self.page_size} and max_seq_len "
+                f"{self.max_seq_len} must be multiples of the block length "
+                f"{self.block_length}: a block lies in one page, and a "
+                "shared prefix page is a whole number of blocks")
         if self.num_pages == 0:
             object.__setattr__(self, "num_pages",
                                self.max_slots * self.pages_per_slot)
@@ -100,7 +123,7 @@ class CacheGeometry:
     @property
     def pool_shape(self):
         return (self.num_layers, self.num_pages, self.page_size,
-                self.num_heads, self.head_dim)
+                self.num_kv_heads, self.head_dim)
 
     @property
     def draft_pool_shape(self):
@@ -113,7 +136,7 @@ class CacheGeometry:
         num_pages * page_bytes()."""
         import numpy as np
 
-        per_tok = (self.num_layers * self.num_heads * self.head_dim
+        per_tok = (self.num_layers * self.num_kv_heads * self.head_dim
                    + self.draft_layers * self.draft_num_heads
                    * self.draft_head_dim)
         return (2 * self.page_size * per_tok
@@ -140,6 +163,17 @@ def make_state(geom: CacheGeometry):
     sampling registers ``do_sample``/``temp``/``top_k``/``eos``/
     ``stop_pos`` (stop_pos = prompt_len + max_new_tokens; a lane retires
     when its next write position would reach it, or on eos).
+
+    A block-generating geometry (``block_length`` B) adds the block's
+    registers: ``pos`` is then the block's start, ``blk`` [slots, B] its
+    tokens, ``blk_open`` [slots, B] which of them are still masked (a
+    position is masked because this register says so, not because its
+    token is the mask id), ``blk_step`` [slots, B] the denoising step at
+    which each was unmasked (-1 = not yet, or known from the prompt),
+    ``step`` the denoising steps the block has had.  With
+    ``num_experts`` the state counts routed assignments of live lanes'
+    rows: ``moe_counts`` [layers, experts] and ``moe_touched`` [layers]
+    (experts with at least one, summed over steps).
     """
     S = geom.max_slots
     key_shape = jax.random.PRNGKey(0).shape  # (2,) for threefry
@@ -160,6 +194,15 @@ def make_state(geom: CacheGeometry):
         "eos": jnp.full((S,), geom.vocab_size, jnp.int32),  # V = never
         "stop_pos": jnp.zeros((S,), jnp.int32),
     }
+    if geom.block_length:
+        state["blk"] = jnp.zeros((S, geom.block_length), jnp.int32)
+        state["blk_open"] = jnp.zeros((S, geom.block_length), bool)
+        state["blk_step"] = jnp.full((S, geom.block_length), -1, jnp.int32)
+        state["step"] = jnp.zeros((S,), jnp.int32)
+    if geom.num_experts:
+        state["moe_counts"] = jnp.zeros(
+            (geom.num_layers, geom.num_experts), jnp.int32)
+        state["moe_touched"] = jnp.zeros((geom.num_layers,), jnp.int32)
     if geom.draft_layers:
         # draft-model KV pool, same page ids as kp/vp: one page-table
         # row addresses both models' cache for a lane
@@ -185,11 +228,29 @@ def state_specs(state, shardings=None):
 
 # -- KV sources: what a model's attention layers attend over ----------------
 # One protocol, ``attend(layer, q, k, v, head_axis=None) -> (ctx, source')``:
-# q/k/v [B, C, nh, hd] are the new tokens' projections (raw jax arrays),
-# ``layer`` a static int, ``head_axis`` the mesh axis the model pins its
-# heads to (or None); ctx [B, C, nh, hd] is the attention output and
+# q [B, C, nh, hd] and k/v [B, C, nkv, hd] are the new tokens' projections
+# (raw jax arrays; nkv divides nh, query head h reads KV head h // (nh /
+# nkv)), ``layer`` a static int, ``head_axis`` the mesh axis the model pins
+# its heads to (or None); ctx [B, C, nh, hd] is the attention output and
 # source' the source with the new rows taken in.  Pytrees, so a source
 # passes through ``jit`` and ``functional_call`` like the arrays it holds.
+
+def _attend(q, keys, values, valid):
+    """``fused.masked_attention`` for nh query heads over nkv KV heads: the
+    nh / nkv query heads of a KV head are folded into the query axis, so
+    the keys and values are read once, as cached, and never expanded."""
+    B, Q, nh, hd = q.shape
+    nkv = keys.shape[2]
+    if nkv == nh:
+        return fused.masked_attention(q, keys, values, valid)
+    g = nh // nkv
+    qg = q.reshape(B, Q, nkv, g, hd).transpose(0, 1, 3, 2, 4) \
+        .reshape(B, Q * g, nkv, hd)
+    vg = valid if valid.shape[1] == 1 else jnp.repeat(valid, g, axis=1)
+    ctx = fused.masked_attention(qg, keys, values, vg)
+    return ctx.reshape(B, Q, g, nkv, hd).transpose(0, 1, 3, 2, 4) \
+        .reshape(B, Q, nh, hd)
+
 
 @jax.tree_util.register_dataclass
 @dataclass
@@ -199,7 +260,7 @@ class PagedKV:
     chunk of C candidates a lane at consecutive positions, ``positions``
     [slots, C]).
 
-    k_pages/v_pages: [layers, num_pages, page_size, nh, hd], the WHOLE
+    k_pages/v_pages: [layers, num_pages, page_size, nkv, hd], the WHOLE
     pools: ``attend`` writes and reads plane ``layer`` and returns the
     whole pools, so a donated pool is rewritten in place and no plane is
     ever sliced out or stacked back.  rows: [slots, pages_per_slot] int32
@@ -216,6 +277,11 @@ class PagedKV:
     committed history plus candidates 0..i: the reduction extent the
     one-token step would have seen, so accepted tokens stay bitwise-equal
     to the sequential path.
+
+    ``limits`` [slots, C], when given, is the last key position each
+    query may see in place of its own: a block-generating step gives every
+    query of a block the block's end, so the block is visible both ways
+    over the committed prefix.
     """
     k_pages: Any
     v_pages: Any
@@ -223,16 +289,17 @@ class PagedKV:
     positions: Any
     active: Any
     seq_cap: int = field(metadata=dict(static=True))
+    limits: Any = None
 
     def attend(self, layer, q, k, v, head_axis=None):
         kp, vp, rows, pos = self.k_pages, self.v_pages, self.rows, \
             self.positions
         B, _, nh, hd = q.shape
-        num_pages, ps = kp.shape[1], kp.shape[2]
+        num_pages, ps, nkv = kp.shape[1], kp.shape[2], kp.shape[3]
         lane, active = jnp.arange(B), self.active
         one = pos.ndim == 1
         if one:
-            k, v = k[:, 0], v[:, 0]                  # [slots, nh, hd]
+            k, v = k[:, 0], v[:, 0]                  # [slots, nkv, hd]
         else:
             lane, active = lane[:, None], active[:, None]
         # token (b, i) writes its K/V at (layer, rows[b, pos // ps],
@@ -251,16 +318,19 @@ class PagedKV:
         # paddle_pallas_fallbacks_total).  The dense gather below is the
         # reference, the fallback, and the chunk's path (verification is
         # one step per K drafted tokens, off the per-token hot loop).
+        # The kernel reads one KV head a query head; grouped heads take
+        # the gather by design (no kernel for them yet), uncounted.
         ctx = fused.paged_decode_attention(
             q, kp, vp, rows, pos, self.seq_cap, layer,
-            tp_axis=head_axis) if one else None
+            tp_axis=head_axis) if one and nkv == nh else None
         if ctx is None:
             gidx = jnp.clip(rows, 0, num_pages - 1)
-            kg = kp[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
-            vg = vp[layer, gidx].reshape(B, rows.shape[1] * ps, nh, hd)
+            kg = kp[layer, gidx].reshape(B, rows.shape[1] * ps, nkv, hd)
+            vg = vp[layer, gidx].reshape(B, rows.shape[1] * ps, nkv, hd)
+            last = pos if self.limits is None else self.limits
             valid = jnp.arange(self.seq_cap)[None, None, :] \
-                <= pos.reshape(B, -1)[:, :, None]
-            ctx = fused.masked_attention(
+                <= last.reshape(B, -1)[:, :, None]
+            ctx = _attend(
                 q, kg[:, :self.seq_cap], vg[:, :self.seq_cap], valid)
         return unwrap(ctx), replace(self, k_pages=kp, v_pages=vp)
 
@@ -273,10 +343,13 @@ class PrefixKV:
     suffix tokens (absolute positions ``prefix_len + i``), keys are
     [prefix ++ suffix] with the prefix entries valid below ``prefix_len``
     and the suffix causal, so the shared pages are never recomputed.
+    ``block`` (static) > 1 makes the suffix's mask the block mask: suffix
+    token i sees suffix token j where j // block <= i // block (the prefix
+    ends at a page boundary, a whole number of blocks).
 
-    prefix_k/prefix_v: [layers, C, nh, hd] gathered from the pool (C
+    prefix_k/prefix_v: [layers, C, nkv, hd] gathered from the pool (C
     static, entries >= prefix_len garbage the mask hides); prefix_len:
-    traced scalar; suffix: the (k, v) [Ss, nh, hd] each layer attended
+    traced scalar; suffix: the (k, v) [Ss, nkv, hd] each layer attended
     so far, which ``write_prompt`` pages in at the (page-aligned) prefix
     boundary.  Token- (not bitwise-) equivalent to a full prefill: the
     math matches up to float reassociation of the explicit softmax
@@ -286,16 +359,17 @@ class PrefixKV:
     prefix_v: Any
     prefix_len: Any
     suffix: tuple = ()
+    block: int = field(default=1, metadata=dict(static=True))
 
     @classmethod
-    def gather(cls, k_pages, v_pages, page_ids, prefix_len):
+    def gather(cls, k_pages, v_pages, page_ids, prefix_len, block=1):
         """The prefix held by pool pages ``page_ids`` [n] (-1 entries
         gather an arbitrary page past ``prefix_len``)."""
-        L, num_pages, ps, nh, hd = k_pages.shape
+        L, num_pages, ps, nkv, hd = k_pages.shape
         gidx = jnp.clip(page_ids, 0, num_pages - 1)
-        return cls(k_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nh, hd),
-                   v_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nh, hd),
-                   jnp.asarray(prefix_len, jnp.int32))
+        return cls(k_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nkv, hd),
+                   v_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nkv, hd),
+                   jnp.asarray(prefix_len, jnp.int32), block=block)
 
     def attend(self, layer, q, k, v, head_axis=None):
         S = q.shape[1]
@@ -303,14 +377,16 @@ class PrefixKV:
         C = pk.shape[1]
         i = jnp.arange(S)[:, None]
         j = jnp.arange(C + S)[None, :]
+        if self.block > 1:       # a query sees to the end of its block
+            i = i // self.block * self.block + self.block - 1
         ok = (j < self.prefix_len) | ((j >= C) & (j - C <= i))
-        ctx = fused.masked_attention(
+        ctx = _attend(
             q, jnp.concatenate([pk.astype(k.dtype), k], axis=1),
             jnp.concatenate([pv.astype(v.dtype), v], axis=1), ok[None])
         return ctx, replace(self, suffix=self.suffix + ((k[0], v[0]),))
 
     def suffix_kv(self):
-        """(k, v) [layers, Ss, nh, hd] of the suffix, for ``write_prompt``."""
+        """(k, v) [layers, Ss, nkv, hd] of the suffix, for ``write_prompt``."""
         return (jnp.stack([k for k, _ in self.suffix]),
                 jnp.stack([v for _, v in self.suffix]))
 
@@ -347,7 +423,7 @@ def write_prompt(state, slot, k_new, v_new, length, shared_ids, shared_n,
                  dk_new=None, dv_new=None):
     """Map + fill one admitted request's cache pages.
 
-    ``k_new``/``v_new`` ``[layers, Sb, nh, hd]`` hold prefill K/V for
+    ``k_new``/``v_new`` ``[layers, Sb, nkv, hd]`` hold prefill K/V for
     absolute positions ``[shared_n * page_size, shared_n * page_size +
     Sb)`` (a full-prompt bucket on a prefix miss, the suffix bucket on a
     prefix hit — full-page-only sharing keeps the boundary aligned).

@@ -196,6 +196,13 @@ class GenerationMetrics:
       paddle_genserve_prefix_cache_hit_ratio hits / (hits + misses)
       paddle_genserve_spec_accept_ratio      accepted / proposed drafts
       paddle_genserve_prefill_chunks_total   chunked-prefill slices run
+      paddle_genserve_block_steps_total      iterations of a block engine
+      paddle_genserve_block_lane_steps_total{kind}
+                                             live lanes over those steps:
+                                             denoised (took a denoising
+                                             step) or committed (the final
+                                             pass that writes a block's K/V)
+      paddle_genserve_block_tokens_total     tokens the blocks emitted
       paddle_genserve_compile_count          executables built at warmup
       paddle_genserve_loop_seconds_total{phase}
                                              the decode thread's seconds
@@ -274,6 +281,17 @@ class GenerationMetrics:
         self._spec_proposed = reg.counter(
             "paddle_genserve_spec_proposed_total",
             "draft proposals offered to target verification")
+        self._block_steps = reg.counter(
+            "paddle_genserve_block_steps_total",
+            "iterations of generation by blocks (block_step runs)")
+        self._block_lane_steps = reg.counter(
+            "paddle_genserve_block_lane_steps_total",
+            "live lanes over the block steps, by what the step was to the "
+            "lane", label="kind", preset=("denoised", "committed"),
+            fixed=True)
+        self._block_tokens = reg.counter(
+            "paddle_genserve_block_tokens_total",
+            "tokens emitted by resolved blocks")
         self._loop_seconds = reg.counter(
             "paddle_genserve_loop_seconds_total",
             "decode-thread seconds by phase of its loop (top-level "
@@ -338,6 +356,15 @@ class GenerationMetrics:
         self._spec_accepted.inc(accepted)
         self._spec_proposed.inc(proposed)
 
+    def observe_block_step(self, denoised: int, committed: int,
+                           emitted: int):
+        """One iteration of a block engine: its live lanes by kind, and
+        the tokens its resolved blocks emitted."""
+        self._block_steps.inc()
+        self._block_lane_steps.inc("denoised", denoised)
+        self._block_lane_steps.inc("committed", committed)
+        self._block_tokens.inc(emitted)
+
     def set_compile_count(self, n: int):
         with self._lock:
             self.compile_count = int(n)
@@ -384,6 +411,12 @@ class GenerationMetrics:
                 "spec_accept_ratio": round(self._spec_ratio_locked(), 4),
                 "spec_proposed": self._spec_proposed.value,
                 "prefill_chunks": self._chunks.value,
+                "block_steps": self._block_steps.value,
+                "block_lane_steps_denoised":
+                    self._block_lane_steps.values["denoised"],
+                "block_lane_steps_committed":
+                    self._block_lane_steps.values["committed"],
+                "block_tokens_emitted": self._block_tokens.value,
                 "compile_count": self.compile_count,
                 **{k: v for k, v in sorted(self.counters.items())},
             }

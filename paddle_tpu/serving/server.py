@@ -21,8 +21,11 @@ Endpoints:
                   stream=true  → Server-Sent Events over chunked
                   transfer, one `data: {"token": t}` event per decoded
                   token as the decode loop produces it, then a final
-                  `data: {"done": true, ...}` event.  Same 400/429/503/
-                  504 admission split as /predict.
+                  `data: {"done": true, ...}` event (a block-generating
+                  model's tokens come a block at a time, and its last
+                  event carries `steps`, the denoising step at which
+                  each token was unmasked).  Same 400/429/503/504
+                  admission split as /predict.
   GET  /healthz   200 {"status": "ok", ...} | 503 {"status": "draining",
                   ...} — plus framework/jax versions, device kind/count,
                   uptime_s and pid (fleet version-skew detection)
@@ -290,11 +293,16 @@ class _Handler(BaseHTTPRequestHandler):
                 span.set_attr("tokens", n)
                 if handle.ttft_ms is not None:
                     span.set_attr("ttft_ms", round(handle.ttft_ms, 3))
-                event({"done": True, "tokens": n,
-                       "ttft_ms": round(handle.ttft_ms, 3)
-                       if handle.ttft_ms is not None else None,
-                       "latency_ms": round((time.monotonic() - t0) * 1e3,
-                                           3)})
+                done = {"done": True, "tokens": n,
+                        "ttft_ms": round(handle.ttft_ms, 3)
+                        if handle.ttft_ms is not None else None,
+                        "latency_ms": round((time.monotonic() - t0) * 1e3,
+                                            3)}
+                if handle.steps is not None:
+                    # generation by blocks: the denoising step at which
+                    # each token was unmasked
+                    done["steps"] = handle.steps[:n]
+                event(done)
             except TimeoutError as e:  # covers DeadlineExceededError
                 handle.cancel()
                 event({"done": True, "tokens": n, "error": str(e)})

@@ -150,6 +150,11 @@ def served(gpt):
     return eng, life["wall"]
 
 
+def _module(exe):
+    """The HLO module name of a compiled executable (`jit_<function>`)."""
+    return exe.as_text().split("HloModule ", 1)[1].split(",")[0]
+
+
 class TestDecodeLoop:
     def test_documented_phases_exist(self, served):
         eng, _ = served
@@ -192,13 +197,10 @@ class TestDecodeLoop:
         metrics anchor on these module names, letter for letter."""
         eng, _ = served
 
-        def module(exe):
-            return exe.as_text().split("HloModule ", 1)[1].split(",")[0]
-
-        assert module(eng._decode_exec) == "jit_decode_step"
-        assert {module(e) for e in eng._prefill_execs.values()} \
+        assert _module(eng._decode_exec) == "jit_decode_step"
+        assert {_module(e) for e in eng._prefill_execs.values()} \
             == {"jit_target_prefill"}
-        assert {module(e) for e in eng._insert_prefix_execs.values()} \
+        assert {_module(e) for e in eng._insert_prefix_execs.values()} \
             == {"jit_insert_prefix_step"}
 
     def test_token_events_carry_the_iteration(self, gpt, tracer_on):
@@ -220,6 +222,120 @@ class TestDecodeLoop:
         prefill = [s for s in tracer_on.spans()
                    if s["name"] == "gen.prefill"][1]
         assert prefill["attrs"]["iter"] == it0
+
+
+    def test_a_gpt_engine_builds_the_executables_it_always_built(
+            self, served):
+        """The guard on chat's `setup_s`: a model that does not declare
+        generation by blocks gets no executable more, none less and none
+        under another name."""
+        eng, _ = served
+
+        assert eng._block_exec is None and eng._spec_exec is None
+        assert eng.block_length == 0 and eng._expert_counts is None
+        built = [eng._decode_exec, eng._release_exec, eng._reclaim_exec]
+        for per_bucket in (eng._prefill_execs, eng._insert_execs,
+                           eng._insert_prefix_execs, eng._chunk_execs):
+            built += list(per_bucket.values())
+        assert sorted(_module(e) for e in built) == sorted(
+            ["jit_decode_step", "jit_release_step", "jit_reclaim_step"]
+            + ["jit_target_prefill", "jit_insert_step",
+               "jit_insert_prefix_step"] * 2 + ["jit_chunk_step"])
+        assert eng.compile_count == len(built) == 10
+        assert set(eng._state) == {
+            "kp", "vp", "ptab", "free_stack", "free_count", "pinned", "tok",
+            "pos", "active", "rng", "do_sample", "temp", "top_k", "eos",
+            "stop_pos"}
+
+
+BLOCK_TOP = (DECODE_TOP - {"decode"}) | {"block_step"}
+
+
+@pytest.fixture(scope="module")
+def served_blocks():
+    """A tiny block-generating engine that served a plain request, a prefix
+    hit and a chunked prompt, drained; with the wall time of its loop."""
+    from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
+
+    paddle.seed(0)
+    m = SDARForCausalLM(SDARConfig(
+        vocab_size=211, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=16, moe_intermediate_size=32, num_experts=8,
+        num_experts_per_tok=2, max_position_embeddings=64,
+        mask_token_id=210))
+    m.eval()
+    eng = GenerationEngine(m, max_slots=3, max_seq_len=40,
+                           prompt_buckets="8,16", page_size=4,
+                           prefix_cache=True, prefill_chunk=8)
+    run, life = eng._run, {}
+
+    def timed_run():
+        t0 = time.perf_counter()
+        try:
+            run()
+        finally:
+            life["wall"] = time.perf_counter() - t0
+
+    eng._run = timed_run
+    eng.start()
+    prompt = list(range(50, 58))
+    assert len(eng.generate([5, 9, 2], 6, timeout=60)) == 6     # plain
+    assert len(eng.generate(prompt, 6, timeout=60)) == 6        # miss
+    assert len(eng.generate(prompt, 6, timeout=60)) == 6        # hit
+    assert eng.metrics.snapshot()["prefix_cache_hits"] == 1
+    assert len(eng.generate(list(range(60, 74)), 4, timeout=60)) == 4
+    assert eng.metrics.snapshot()["prefill_chunks"] >= 2        # chunked
+    assert eng.drain(timeout=60)
+    return eng, life["wall"]
+
+
+class TestBlockLoop:
+    def test_block_step_takes_the_decode_phases_place(self, served_blocks):
+        eng, _ = served_blocks
+        assert set(top_level(eng.timers)) == BLOCK_TOP | {"chunk"}
+        assert "decode" not in eng.timers.totals
+        assert {n for n, p in eng.timers.parents.items()
+                if p == "admit"} == ADMIT_CHILDREN
+
+    def test_top_level_phases_cover_the_loops_wall_time(self, served_blocks):
+        eng, wall = served_blocks
+        covered = sum(top_level(eng.timers).values())
+        assert 0.95 * wall <= covered <= 1.001 * wall
+        assert_children_within_parents(eng.timers)
+        c = eng.timers.counts
+        assert c["block_step"] == c["fetch"] == c["distribute"] == eng._iter
+        assert eng.metrics.snapshot()["block_steps"] == eng._iter
+
+    def test_executable_and_scope_names_the_benchmark_matches(
+            self, served_blocks, monkeypatch):
+        """`block_step_device_ms`, `block_host_idle_ms`, `moe_step_share`
+        anchor on `jit_block_step`, `block_iter_p95_ms` on the scope."""
+        eng, _ = served_blocks
+
+        assert _module(eng._block_exec) == "jit_block_step"
+        assert eng._decode_exec is None
+        assert {_module(e) for e in eng._prefill_execs.values()} \
+            == {"jit_target_prefill"}
+        assert {_module(e) for e in eng._insert_prefix_execs.values()} \
+            == {"jit_insert_prefix_step"}
+        import jax
+
+        seen = []
+
+        class Spy:
+            def __init__(self, name):
+                seen.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+        with eng.timers.scope("block_step"):
+            pass
+        assert seen == ["paddle.genserve/block_step"]
 
 
 class TestFitLoop:

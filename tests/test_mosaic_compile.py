@@ -20,8 +20,8 @@ import pytest
 
 import paddle_tpu  # noqa: F401 - the package decides the process's x64 mode
 from paddle_tpu.ops.pallas import (bias_gelu as bg, flash_attention as fa,
-                                   layer_norm as ln, paged_attention as pa,
-                                   softmax_xent as sx)
+                                   layer_norm as ln, moe_gmm as mg,
+                                   paged_attention as pa, softmax_xent as sx)
 
 B, S, NH, HD, H, FFN, V = 8, 1024, 12, 64, 768, 3072, 50304
 SLOTS, PAGE = 16, 16
@@ -103,6 +103,26 @@ def _paged(q, kp, vp, rows, pos):
                                      interpret=False)
 
 
+# the grouped expert FFN as sdar-30b-a3b.blockgen calls it: 128 experts of
+# 2048 x 768 (gate, up) and 768 x 2048 (down); a block step's 512
+# assignments (16 lanes x 4 positions x 8 experts) and a 768-token
+# prefill's 6144, each in the padded grouped layout of ops/fused.moe_layout
+MOE_E, MOE_H, MOE_F = 128, 2048, 768
+
+
+def _gmm_case(assignments):
+    tm = mg.pick_tile_rows(assignments, MOE_E)
+    rows = -(-min(assignments + MOE_E * (tm - 1), assignments * tm)
+             // tm) * tm
+
+    def f(x, wg, wu, wd, te, n_used):
+        return mg.moe_gmm(x, wg, wu, wd, te, n_used, tm, interpret=False)
+
+    return f, [((rows, MOE_H), BF16), ((MOE_E, MOE_H, MOE_F), BF16),
+               ((MOE_E, MOE_H, MOE_F), BF16), ((MOE_E, MOE_F, MOE_H), BF16),
+               ((rows // tm,), I32), ((), I32)]
+
+
 def _bwd(f, n):
     return jax.grad(_sum32(f), argnums=tuple(range(n)))
 
@@ -129,6 +149,8 @@ CASES = {
     "softmax_xent_bf16_v131072_fwd": (_xent, XENT_WIDE),
     "softmax_xent_bf16_v131072_bwd": (_bwd(_xent, 1), XENT_WIDE),
     "paged_decode": (_paged, PAGED_ARGS),
+    "moe_gmm_block_step_512": _gmm_case(512),
+    "moe_gmm_prefill_6144": _gmm_case(6144),
 }
 
 
@@ -169,6 +191,7 @@ KERNEL_NAMES = {
     "paddle_bias_gelu_fwd": "bias_gelu_fwd",
     "paddle_bias_gelu_bwd": "bias_gelu_bwd",
     "paddle_paged_decode_fwd": "paged_decode",
+    "paddle_moe_gmm": "moe_gmm_block_step_512",
 }
 
 
