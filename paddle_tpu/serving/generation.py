@@ -84,6 +84,10 @@ __all__ = ["GenerationEngine", "GenerationHandle"]
 _WAKE = object()   # queue sentinel: wakes an idle-blocked decode loop
 _END = object()    # handle sentinel: no more tokens
 
+# a step in flight: its iteration number, the (slot, request) pairs armed
+# at its launch, and the device arrays of its report, not yet fetched
+_Step = collections.namedtuple("_Step", "iter lanes out")
+
 
 class GenerationHandle:
     """Per-request streaming face: tokens arrive as the decode loop
@@ -439,6 +443,7 @@ class GenerationEngine:
         self._idle = threading.Event()
         self._idle.set()
         self._iter = 0
+        self._flight = None             # the _Step launched, not collected
         # the decode loop's phases (see _run): annotations under
         # paddle.genserve/ in a profiler trace, totals in /metrics
         self.timers = StepTimers("paddle.genserve")
@@ -1244,6 +1249,21 @@ class GenerationEngine:
             pass
 
     def _run(self):
+        """The decode thread.  An iteration keeps ONE step in flight:
+        ``pull``, ``sweep``, ``admit``, ``chunk``; then step k+1 is
+        launched for the lanes armed now (``_launch``: dispatch only);
+        and only then is step k, launched an iteration earlier, collected
+        (``_collect``: ``fetch`` blocks until the device has run it, with
+        step k+1 already queued behind it, and ``distribute`` hands its
+        tokens to the lanes that were armed at ITS launch).  The compiled
+        step reads nothing the host learns from the fetch (token,
+        position, stop and eos are registers of the donated state; a
+        lane that ends is deactivated and its pages freed in the graph),
+        so the chip goes from one step to the next without the host.  A
+        step's tokens reach the client one ``fetch`` after the step
+        ends, as they always did.  With no lane armed nothing is
+        launched, and what is in flight is collected before the loop
+        blocks in ``wait`` or returns."""
         try:
             # Every statement of an iteration lies in one top-level
             # phase of self.timers (wait, pull, sweep, admit, chunk,
@@ -1271,20 +1291,13 @@ class GenerationEngine:
                     if self._sched.prefilling():
                         with scope("chunk"):
                             self._advance_chunk()
+                    ahead = None
                     if len(self._sched.occupied) > self._sched.prefilling():
-                        if self._spec_exec is not None:
-                            outs, emitted, fin = self.step_spec()
-                            with scope("distribute"):
-                                self._distribute_spec(outs, emitted, fin)
-                        elif self._block_exec is not None:
-                            report = self.step_block()
-                            with scope("distribute"):
-                                self._distribute_block(report)
-                        else:
-                            toks, fin = self.step()
-                            with scope("distribute"):
-                                self._distribute(toks, fin)
+                        ahead = self._launch()
+                    self._collect()
+                    self._flight = ahead
                     continue
+                self._collect()
                 with scope("pull"):
                     if self._queue.empty() and not self._backlog:
                         self._idle.set()
@@ -1304,6 +1317,12 @@ class GenerationEngine:
             except Exception:  # noqa: BLE001 - never mask the crash
                 pass
             self._stopped = True
+            try:
+                # a step launched before the failure ran: its tokens
+                # are its lanes', as they were when nothing ran ahead
+                self._collect()
+            except Exception:  # noqa: BLE001 - it fails with the rest
+                logger.exception("the step in flight failed too")
             self._fail_everything(EngineStoppedError(
                 "generation decode loop crashed"))
             self._idle.set()
@@ -1313,6 +1332,7 @@ class GenerationEngine:
         """Move queued requests to the backlog; block only when idle."""
         scope = self.timers.scope
         if (not self._sched.occupied and not self._backlog
+                and self._flight is None
                 and not (self._draining or self._stopped)):
             # no lane waits for a token: time here is nobody's latency
             with scope("wait"):
@@ -1503,10 +1523,7 @@ class GenerationEngine:
         self.metrics.observe_tokens(1)
         if req.max_new_tokens == 1 or t1 == req.eos:
             self._release([slot])
-            self._host_retire(slot)
-            self.metrics.count("retired")
-            req.end_spans("ok")
-            req.handle._finish()
+            self._retire_ended(slot, req)
         elif req.span is not None:
             req.span_decode = req.span.child("gen.decode", slot=slot)
 
@@ -1664,55 +1681,65 @@ class GenerationEngine:
                 None if reason == "cancelled" else DeadlineExceededError(
                     "request deadline passed mid-decode"))
 
-    def step(self):
-        """ONE decode iteration: every in-flight lane advances a token.
-        The state pytree is donated to the compiled executable (the KV
-        page pool is rewritten on device, never fetched); only the
-        sampled token ids and finished mask cross to host, under
-        host_fetch()."""
-        with self.timers.scope("decode"):
+    def _launch(self):
+        """Dispatch ONE step (``decode_step`` | ``spec_step`` |
+        ``block_step``) for the lanes armed now and fetch nothing: every
+        armed lane advances a token (1..spec_tokens+1 when speculating; a
+        block engine runs each lane's block once).  The state pytree is
+        donated to the compiled executable (the KV page pool is rewritten
+        on device, never fetched); what ``_collect`` fetches an iteration
+        later is the step's small report.  Returns the ``_Step`` to
+        collect: the iteration number, the (slot, request) pairs armed at
+        this launch, and the report's device arrays."""
+        spec, block = self._spec_exec, self._block_exec
+        with self.timers.scope("spec_decode" if spec is not None else
+                               "block_step" if block is not None else
+                               "decode"):
+            lanes = [(s, r) for s, r in self._sched.occupied.items()
+                     if not r.prefilling]
             self._iter += 1
             chaos.on_step(self._iter)   # fault-injection seam (utils/chaos)
-            state, toks, fin = self._decode_exec(self._params, self._state)
-        self._state = state
-        with self.timers.scope("fetch"), host_fetch():
-            # blocks until the device has run the step
-            toks_np = np.array(toks, copy=True)
-            fin_np = np.array(fin, copy=True)
-        return toks_np, fin_np
+            before = self._flight
+            self.metrics.count_step(
+                ahead=before is not None and not before.out[0].is_ready())
+            if spec is not None:
+                state, *out = spec(self._params, self._draft_params,
+                                   self._state)
+            else:
+                state, *out = (block or self._decode_exec)(self._params,
+                                                           self._state)
+            self._state = state
+            if block is not None:
+                # the routed-assignment counters stay on the device
+                counts = out.pop()
+                if counts:
+                    self._expert_counts = counts
+        return _Step(self._iter, lanes, out)
 
-    def step_spec(self):
-        """ONE speculative iteration (draft chain + batched target
-        verify, compiled as a single executable): every armed lane
-        advances 1..spec_tokens+1 tokens.  Returns (outs [slots, K+1],
-        emitted [slots, K+1] prefix mask, finished [slots])."""
-        with self.timers.scope("spec_decode"):
-            self._iter += 1
-            chaos.on_step(self._iter)
-            state, outs, emitted, fin = self._spec_exec(
-                self._params, self._draft_params, self._state)
-        self._state = state
+    def _collect(self):
+        """Fetch and hand out the step in flight, if there is one: only
+        its report crosses to host, under host_fetch().  A lane that
+        ended, was cancelled or swept since the launch takes nothing, and
+        a request admitted into its slot since is not among the step's
+        lanes: neither reads a token that is not its own."""
+        step, self._flight = self._flight, None
+        if step is None:
+            return
         with self.timers.scope("fetch"), host_fetch():
-            outs_np = np.array(outs, copy=True)
-            emitted_np = np.array(emitted, copy=True)
-            fin_np = np.array(fin, copy=True)
-        return outs_np, emitted_np, fin_np
-
-    def step_block(self):
-        """ONE iteration of generation by blocks (``block_step``): every
-        armed lane runs its block once.  Returns the step's report
-        [slots, 2B + 3] (tokens, their steps, three flags), fetched; the
-        routed-assignment counters stay on the device."""
-        with self.timers.scope("block_step"):
-            self._iter += 1
-            chaos.on_step(self._iter)
-            state, report, counts = self._block_exec(self._params,
-                                                     self._state)
-        self._state = state
-        if counts:
-            self._expert_counts = counts
-        with self.timers.scope("fetch"), host_fetch():
-            return np.array(report, copy=True)
+            # blocks until the device has run the step; the one launched
+            # after it is queued behind it already
+            out = [np.array(a, copy=True) for a in step.out]
+        with self.timers.scope("distribute"):
+            lanes = [(s, r) for s, r in step.lanes
+                     if self._sched.occupied.get(s) is r]
+            if not lanes:
+                self.metrics.count_empty_step()
+            if self._spec_exec is not None:
+                self._distribute_spec(step.iter, lanes, *out)
+            elif self._block_exec is not None:
+                self._distribute_block(step.iter, lanes, *out)
+            else:
+                self._distribute(step.iter, lanes, *out)
 
     def expert_counts(self):
         """What the device has counted of the live lanes' routed
@@ -1731,17 +1758,24 @@ class GenerationEngine:
             return {"assignments": np.array(counts[0], np.int64),
                     "touched": np.array(counts[1], np.int64)}
 
-    def _distribute_block(self, report):
-        """A block's tokens go to the stream together, in the iteration
-        that resolved its last mask, cut at eos and at max_new_tokens.
-        The gap histogram records what a client sees: one gap a block and
-        zeros inside it."""
+    def _retire_ended(self, slot: int, req: _GenRequest):
+        """A lane that ended by eos or at its budget: the device freed
+        its private pages in the graph (or ``_release`` did); this drops
+        the host's bookkeeping and ends the stream."""
+        self._host_retire(slot)
+        self.metrics.count("retired")
+        req.end_spans("ok")
+        req.handle._finish()
+
+    def _distribute_block(self, it, lanes, report):
+        """A block's tokens go to the stream together, in the collect of
+        the step that resolved its last mask, cut at eos and at
+        max_new_tokens.  The gap histogram records what a client sees:
+        one gap a block and zeros inside it."""
         B = self.block_length
         now = time.monotonic()
         denoised = committed = emitted = 0
-        for slot, req in list(self._sched.occupied.items()):
-            if req.prefilling:
-                continue
+        for slot, req in lanes:
             resolved, commit, fin = (bool(f) for f in report[slot, 2 * B:])
             committed += commit
             denoised += not commit
@@ -1756,7 +1790,7 @@ class GenerationEngine:
                         self.metrics.observe_ttft(now - req.handle.t_submit)
                         if req.span is not None:
                             req.span.event("first_token", slot=slot,
-                                           iter=self._iter)
+                                           iter=it)
                     else:
                         self.metrics.observe_inter_token(
                             now - req.t_last_token)
@@ -1766,25 +1800,19 @@ class GenerationEngine:
                     emitted += 1
                     if req.span_decode is not None:
                         req.span_decode.event(
-                            "token", i=len(req.handle.tokens),
-                            iter=self._iter)
+                            "token", i=len(req.handle.tokens), iter=it)
                     if tok == req.eos:
                         break
                 req.block_start += B
             if fin:
-                self._host_retire(slot)
-                self.metrics.count("retired")
-                req.end_spans("ok")
-                req.handle._finish()
+                self._retire_ended(slot, req)
         self.metrics.observe_tokens(emitted)
         self.metrics.observe_block_step(denoised, committed, emitted)
 
-    def _distribute_spec(self, outs_np, emitted_np, fin_np):
+    def _distribute_spec(self, it, lanes, outs_np, emitted_np, fin_np):
         now = time.monotonic()
         emitted_total = accepted = proposed = 0
-        for slot, req in list(self._sched.occupied.items()):
-            if req.prefilling:
-                continue
+        for slot, req in lanes:
             n = int(emitted_np[slot].sum())
             if n <= 0:
                 continue
@@ -1803,40 +1831,28 @@ class GenerationEngine:
                 req.handle._push(int(outs_np[slot, i]))
                 if req.span_decode is not None:
                     req.span_decode.event("token",
-                                          i=len(req.handle.tokens),
-                                          iter=self._iter)
+                                          i=len(req.handle.tokens), iter=it)
             req.t_last_token = now
             if bool(fin_np[slot]):
-                self._host_retire(slot)
-                self.metrics.count("retired")
-                req.end_spans("ok")
-                req.handle._finish()
+                self._retire_ended(slot, req)
         self.metrics.observe_tokens(emitted_total)
         if proposed:
             self.metrics.observe_spec(accepted, proposed)
 
-    def _distribute(self, toks_np, fin_np):
+    def _distribute(self, it, lanes, toks_np, fin_np):
         now = time.monotonic()
-        occupied = [(s, r) for s, r in self._sched.occupied.items()
-                    if not r.prefilling]
-        self.metrics.observe_tokens(len(occupied))
-        for slot, req in occupied:
-            tok = int(toks_np[slot])
+        self.metrics.observe_tokens(len(lanes))
+        for slot, req in lanes:
             if req.t_last_token is not None:
                 self.metrics.observe_inter_token(now - req.t_last_token)
             req.t_last_token = now
-            req.handle._push(tok)
+            req.handle._push(int(toks_np[slot]))
             if req.span_decode is not None:
-                # host ints only — toks/fin were fetched in step()
+                # host ints only, of the step that made the token
                 req.span_decode.event("token", i=len(req.handle.tokens),
-                                      iter=self._iter)
+                                      iter=it)
             if bool(fin_np[slot]):
-                # the decode step already pushed the lane's private
-                # pages back in-graph; this drops the host bookkeeping
-                self._host_retire(slot)
-                self.metrics.count("retired")
-                req.end_spans("ok")
-                req.handle._finish()
+                self._retire_ended(slot, req)
 
     def _fail_everything(self, exc):
         for dq in (self._backlog,):

@@ -204,6 +204,13 @@ class GenerationMetrics:
                                              pass that writes a block's K/V)
       paddle_genserve_block_tokens_total     tokens the blocks emitted
       paddle_genserve_compile_count          executables built at warmup
+      paddle_genserve_steps_total            steps launched
+      paddle_genserve_steps_launched_ahead_total
+                                             of them, launched while the
+                                             step before was still on the
+                                             device (the loop keeps one
+                                             step in flight)
+      paddle_genserve_empty_steps_total      steps nobody took a result of
       paddle_genserve_loop_seconds_total{phase}
                                              the decode thread's seconds
                                              by phase of its loop (the
@@ -292,6 +299,20 @@ class GenerationMetrics:
         self._block_tokens = reg.counter(
             "paddle_genserve_block_tokens_total",
             "tokens emitted by resolved blocks")
+        self._steps = reg.counter(
+            "paddle_genserve_steps_total",
+            "decode steps launched (decode_step, spec_step or block_step "
+            "runs)")
+        self._steps_ahead = reg.counter(
+            "paddle_genserve_steps_launched_ahead_total",
+            "steps launched while the step before them had not finished "
+            "on the device: over steps_total, the share of launches the "
+            "chip did not wait for")
+        self._empty_steps = reg.counter(
+            "paddle_genserve_empty_steps_total",
+            "steps nobody took a result of: every lane armed at the launch "
+            "had ended in the step before it (the step ran with no lane "
+            "armed) or was swept before the collect")
         self._loop_seconds = reg.counter(
             "paddle_genserve_loop_seconds_total",
             "decode-thread seconds by phase of its loop (top-level "
@@ -356,6 +377,16 @@ class GenerationMetrics:
         self._spec_accepted.inc(accepted)
         self._spec_proposed.inc(proposed)
 
+    def count_step(self, ahead: bool):
+        """One step launched; `ahead` when the step before it was still
+        running on the device."""
+        self._steps.inc()
+        if ahead:
+            self._steps_ahead.inc()
+
+    def count_empty_step(self):
+        self._empty_steps.inc()
+
     def observe_block_step(self, denoised: int, committed: int,
                            emitted: int):
         """One iteration of a block engine: its live lanes by kind, and
@@ -417,6 +448,9 @@ class GenerationMetrics:
                 "block_lane_steps_committed":
                     self._block_lane_steps.values["committed"],
                 "block_tokens_emitted": self._block_tokens.value,
+                "steps": self._steps.value,
+                "steps_launched_ahead": self._steps_ahead.value,
+                "empty_steps": self._empty_steps.value,
                 "compile_count": self.compile_count,
                 **{k: v for k, v in sorted(self.counters.items())},
             }

@@ -1,6 +1,7 @@
 """Test config: force a deterministic 8-device CPU mesh (SURVEY.md §4 —
 multi-process NCCL tests are replaced by virtual-device mesh tests)."""
 import os
+import time
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
@@ -14,6 +15,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
@@ -102,3 +104,41 @@ def _seed():
 
     paddle.seed(42)
     yield
+
+
+class _Late:
+    """A step's output that is done on the "device" `delay` seconds after
+    its launch: `is_ready()` says so without blocking, `np.array` waits."""
+
+    def __init__(self, value, ready_at):
+        self.value, self.ready_at = value, ready_at
+
+    def is_ready(self):
+        return time.monotonic() >= self.ready_at
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(max(0.0, self.ready_at - time.monotonic()))
+        return np.asarray(self.value, dtype=dtype)
+
+
+@pytest.fixture()
+def slow_steps():
+    """slow_steps(engine, delay=0.02): every step of a started
+    `GenerationEngine` takes `delay` seconds on the "device" while its
+    launch returns at once, as on a chip, so that a step IS in flight when
+    the decode loop launches the next."""
+    def patch(eng, delay=0.02):
+        attr = next(a for a in ("_spec_exec", "_block_exec", "_decode_exec")
+                    if getattr(eng, a) is not None)
+        fast, done = getattr(eng, attr), [0.0]
+
+        def launch(*args):
+            state, *out = fast(*args)
+            # one step at a time, in order, as the device runs them
+            ready_at = done[0] = max(done[0], time.monotonic()) + delay
+            return (state, *[_Late(a, ready_at) if hasattr(a, "shape") else a
+                             for a in out])
+
+        setattr(eng, attr, launch)
+
+    return patch

@@ -593,3 +593,155 @@ class TestHTTP:
         text = ServingClient(server.url).metrics()
         assert "paddle_genserve_decode_tokens_per_sec" in text
         assert "paddle_genserve_compile_count" in text
+
+
+# -- one step in flight ------------------------------------------------------
+def staggered_mix(n=16):
+    """`n` requests of unequal lengths, greedy and sampled by turns."""
+    prompts = (PROMPT_A, PROMPT_B, PROMPT_C, [7, 7, 7, 11, 2, 4])
+    return [(prompts[i % 4], 3 + (5 * i) % 17,
+             dict(seed=i, **SAMPLE_KW) if i % 2 else {}) for i in range(n)]
+
+
+class TestStepInFlight:
+    """The loop launches step k+1 before it fetches and hands out step k
+    (`_launch`, `_collect`): the streams stay each request's own."""
+
+    @pytest.mark.parametrize("device_ms", [0, 6])
+    def test_sixteen_staggered_requests_equal_the_solo_reference(
+            self, model, slow_steps, device_ms):
+        """Greedy and sampled, unequal lengths, five times the slots;
+        with steps that outlast the host's part of an iteration too."""
+        eng = GenerationEngine(model, max_slots=3, max_seq_len=40,
+                               prompt_buckets="8,16").start()
+        try:
+            if device_ms:
+                slow_steps(eng, device_ms / 1e3)
+            jobs = []
+            for prompt, n, kw in staggered_mix():
+                jobs.append((eng.submit(prompt, n, **kw), prompt, n, kw))
+                time.sleep(0.004)      # lands mid-iteration of the others
+            for h, prompt, n, kw in jobs:
+                assert h.result(120) == solo(model, prompt, n, **kw)
+            assert eng.drain(timeout=60) and eng._flight is None
+            snap = eng.metrics.snapshot()
+            assert snap["retired"] == 16 and snap["steps"] == eng._iter
+        finally:
+            eng.stop()
+
+    @pytest.mark.parametrize("ending", ["max_new_tokens", "eos"])
+    def test_an_ended_lanes_slot_takes_nothing_of_the_step_after(
+            self, model, slow_steps, ending):
+        """One slot, steps of 20 ms: request A ends in step k while step
+        k+1 (launched before the host knew) is in flight; B is admitted
+        into A's slot before k+1 is collected.  Neither reads k+1."""
+        ref_a = solo(model, PROMPT_A, 12)
+        kw = {"eos_token_id": ref_a[5]} if ending == "eos" else {}
+        want_a = ref_a[:ref_a.index(ref_a[5]) + 1] if kw else ref_a[:6]
+        eng = GenerationEngine(model, max_slots=1, max_seq_len=40,
+                               prompt_buckets="8").start()
+        try:
+            slow_steps(eng)
+            a = eng.submit(PROMPT_A, 12 if kw else 6, **kw)
+            b = eng.submit(PROMPT_B, 9, seed=7, **SAMPLE_KW)
+            assert a.result(60) == want_a
+            assert b.result(60) == solo(model, PROMPT_B, 9, seed=7,
+                                        **SAMPLE_KW)
+            assert eng.drain(timeout=60)
+            snap = eng.metrics.snapshot()
+            # A's and B's last steps were each followed by a step that
+            # ran with no lane armed, and nobody took a token of either
+            assert snap["empty_steps"] == 2 and snap["retired"] == 2
+            assert eng._flight is None
+        finally:
+            eng.stop()
+
+    def test_cancel_and_deadline_with_a_step_in_flight(self, model,
+                                                       slow_steps):
+        eng = GenerationEngine(model, max_slots=2, max_seq_len=40,
+                               prompt_buckets="8,16").start()
+        try:
+            slow_steps(eng)
+            gone = eng.submit(PROMPT_C, 25)
+            late = eng.submit(PROMPT_A, 30, deadline_ms=150)
+            for _ in range(3):                  # steps are going out
+                assert gone.next_token(timeout=60) is not None
+            gone.cancel()
+            with pytest.raises(DeadlineExceededError):
+                late.result(60)
+            t0 = time.monotonic()
+            while not gone.done and time.monotonic() - t0 < 30:
+                time.sleep(0.005)
+            assert gone.done and gone.error is None
+            assert 0 < len(gone.tokens) < 25 and 0 < len(late.tokens) < 30
+            # what they had is a prefix of their own streams, and both
+            # slots serve the next requests exactly
+            assert gone.tokens == solo(model, PROMPT_C, 25)[:len(gone.tokens)]
+            assert late.tokens == solo(model, PROMPT_A, 30)[:len(late.tokens)]
+            hs = [eng.submit(PROMPT_B, 8, seed=3, **SAMPLE_KW)
+                  for _ in range(2)]
+            ref = solo(model, PROMPT_B, 8, seed=3, **SAMPLE_KW)
+            assert all(h.result(60) == ref for h in hs)
+            # steps went out ahead all the while (not those behind an
+            # admission, whose fetch waits for the device)
+            snap = eng.metrics.snapshot()
+            assert snap["steps_launched_ahead"] / snap["steps"] > 0.3
+            assert snap["cancelled"] == 1 and snap["deadline_expired"] == 1
+        finally:
+            eng.stop()
+
+    @pytest.mark.filterwarnings(
+        "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+    @pytest.mark.parametrize("how", ["drain", "stop", "raise"])
+    def test_no_handle_is_left_unfinished(self, model, slow_steps, how):
+        from paddle_tpu.utils import chaos
+
+        eng = GenerationEngine(model, max_slots=2, max_seq_len=40,
+                               prompt_buckets="8").start()
+        slow_steps(eng)
+        hs = [eng.submit(PROMPT_A, 20), eng.submit(PROMPT_B, 14),
+              eng.submit(PROMPT_A, 9)]          # the third waits for a slot
+        assert hs[0].next_token(timeout=60) is not None
+        if how == "drain":
+            assert eng.drain(timeout=120)
+            assert [len(h.result(1)) for h in hs] == [20, 14, 9]
+        elif how == "stop":
+            eng.stop()
+        else:
+            with chaos.inject(crash_at_step=eng._iter + 3):
+                for h in hs:
+                    with pytest.raises(EngineStoppedError):
+                        h.result(60)
+            eng._thread.join(30)
+            # the step launched before the failing one was collected: its
+            # tokens reached their lanes before everything failed
+            assert eng.timers.counts["fetch"] \
+                == eng.timers.counts["distribute"] == eng._iter - 1
+        assert all(h.done for h in hs)
+        assert eng._flight is None
+        ref = solo(model, PROMPT_A, 20)
+        assert hs[0].tokens == ref[:len(hs[0].tokens)]
+        eng.stop()
+
+    def test_launched_ahead_and_empty_steps_are_counted(self, model,
+                                                        slow_steps):
+        eng = GenerationEngine(model, max_slots=2, max_seq_len=40,
+                               prompt_buckets="8").start()
+        try:
+            slow_steps(eng)
+            assert len(eng.generate(PROMPT_A, 30, timeout=60)) == 30
+            assert eng.drain(timeout=60)
+            snap = eng.metrics.snapshot()
+            assert snap["steps"] == eng._iter == 30
+            # every step but the first found the one before it running
+            assert snap["steps_launched_ahead"] / snap["steps"] > 0.9
+            # the lane's last token came from step 29; step 30 had been
+            # launched by then and ran with no lane armed
+            assert snap["empty_steps"] == 1
+            text = eng.metrics.prometheus_text()
+            assert "paddle_genserve_steps_launched_ahead_total " \
+                f"{snap['steps_launched_ahead']}" in text
+            assert "paddle_genserve_empty_steps_total 1" in text
+            assert "paddle_genserve_steps_total 30" in text
+        finally:
+            eng.stop()

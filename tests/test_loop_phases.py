@@ -223,6 +223,74 @@ class TestDecodeLoop:
                    if s["name"] == "gen.prefill"][1]
         assert prefill["attrs"]["iter"] == it0
 
+    def test_the_next_launch_opens_before_this_steps_fetch(
+            self, gpt, slow_steps, monkeypatch):
+        """One step in flight: `decode` k+1 opens before `fetch` k, which
+        opens before `distribute` k; as many of each as steps."""
+        import jax
+
+        order = []
+
+        class Spy:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                order.append(self.name.split("/", 1)[-1])
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        eng = GenerationEngine(gpt, max_slots=2, max_seq_len=40,
+                               prompt_buckets="8,16").start()
+        try:
+            slow_steps(eng)
+            monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+            assert len(eng.generate([5, 9, 2], 9, timeout=60)) == 9
+            assert eng.drain(timeout=60)
+        finally:
+            monkeypatch.undo()
+            eng.stop()
+        at = {n: [i for i, x in enumerate(order) if x == n]
+              for n in ("decode", "fetch", "distribute")}
+        n = eng._iter
+        assert n == 9        # eight tokens by steps, one step ran empty
+        assert [len(v) for v in at.values()] == [n, n, n]
+        for k in range(n):
+            assert at["decode"][k] < at["fetch"][k] < at["distribute"][k]
+            if k + 1 < n:
+                assert at["decode"][k + 1] < at["fetch"][k]
+
+    def test_token_events_carry_the_step_that_made_the_token(
+            self, gpt, slow_steps, tracer_on):
+        """`iter` is the producing step's number, not the number the loop
+        has reached when the token is handed out (one further): A's tokens
+        come from steps 1-3, step 4 runs empty, B is admitted at 4 into
+        A's slot and its tokens come from steps 5 and 6."""
+        eng = GenerationEngine(gpt, max_slots=1, max_seq_len=40,
+                               prompt_buckets="8,16").start()
+        try:
+            slow_steps(eng)
+            a = eng.submit([5, 9, 2], 4)
+            b = eng.submit([7, 7, 3], 3)
+            assert len(a.result(60)) == 4 and len(b.result(60)) == 3
+            assert eng.drain(timeout=60)
+        finally:
+            eng.stop()
+        spans = tracer_on.spans()
+        iters = sorted([e["iter"] for e in s["events"]
+                        if e["name"] == "token"]
+                       for s in spans if s["name"] == "gen.decode")
+        assert iters == [[1, 2, 3], [5, 6]]
+        assert sorted(s["attrs"]["iter"] for s in spans
+                      if s["name"] == "gen.prefill") == [0, 4]
+        first = sorted(e["iter"] for s in spans
+                       if s["name"] == "genserve.request"
+                       for e in s["events"] if e["name"] == "first_token")
+        assert first == [0, 4]
+        assert eng._iter == 7 and eng.metrics.snapshot()["empty_steps"] == 2
+
 
     def test_a_gpt_engine_builds_the_executables_it_always_built(
             self, served):
@@ -305,6 +373,42 @@ class TestBlockLoop:
         c = eng.timers.counts
         assert c["block_step"] == c["fetch"] == c["distribute"] == eng._iter
         assert eng.metrics.snapshot()["block_steps"] == eng._iter
+
+    def test_block_token_events_carry_the_step_that_resolved_the_block(
+            self, slow_steps, tracer_on):
+        """A block's tokens and `first_token` carry the number of the step
+        that resolved its last mask, handed out an iteration later."""
+        from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
+
+        paddle.seed(0)
+        m = SDARForCausalLM(SDARConfig(
+            vocab_size=211, hidden_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, head_dim=16, moe_intermediate_size=32,
+            num_experts=8, num_experts_per_tok=2, max_position_embeddings=64,
+            mask_token_id=210))
+        m.eval()
+        eng = GenerationEngine(m, max_slots=1, max_seq_len=40,
+                               prompt_buckets="8,16", page_size=4).start()
+        try:
+            slow_steps(eng)
+            B, T = eng.block_length, m.cfg.denoising_steps
+            h = eng.submit(list(range(50, 58)), 2 * B)      # two blocks
+            assert len(h.result(120)) == 2 * B
+            assert eng.drain(timeout=60)
+        finally:
+            eng.stop()
+        spans = tracer_on.spans()
+        decode = next(s for s in spans if s["name"] == "gen.decode")
+        iters = [e["iter"] for e in decode["events"] if e["name"] == "token"]
+        # block b resolves in its T-th step; the (T+1)-th commits it
+        assert iters == [T] * B + [2 * T + 1] * B
+        root = next(s for s in spans if s["name"] == "genserve.request")
+        assert [e["iter"] for e in root["events"]
+                if e["name"] == "first_token"] == [T]
+        c = eng.timers.counts
+        assert c["block_step"] == c["fetch"] == c["distribute"] \
+            == eng._iter == 2 * (T + 1) + 1
+        assert eng.metrics.snapshot()["empty_steps"] == 1
 
     def test_executable_and_scope_names_the_benchmark_matches(
             self, served_blocks, monkeypatch):

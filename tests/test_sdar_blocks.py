@@ -313,6 +313,61 @@ def test_two_lanes_admitted_in_different_iterations(tiny, engine):
         assert h.result(120) == want_t and h.steps == want_s
 
 
+# -- one step in flight: block_step k+1 is launched before k is handed out ----
+@pytest.mark.parametrize("device_ms", [0, 6])
+def test_sixteen_staggered_requests_equal_the_published_procedure(
+        tiny, slow_steps, device_ms):
+    """Unequal prompts and lengths, four times the slots, admitted while
+    others are mid-block; with steps that outlast the host's part too."""
+    import time
+
+    eng = engine_for(tiny[1])
+    try:
+        if device_ms:
+            slow_steps(eng, device_ms / 1e3)
+        jobs = []
+        for i in range(16):
+            p, n = prompt_of(3 + (7 * i) % 14, seed=40 + i), 4 + (5 * i) % 23
+            jobs.append((eng.submit(p, n), p, n))
+            time.sleep(0.004)
+        for h, p, n in jobs:
+            want_t, want_s, _ = ref.block_diffusion_generate(
+                tiny[0], p.tolist(), CFG, n)
+            assert h.result(120) == want_t and h.steps == want_s
+        assert eng.drain(timeout=60) and eng._flight is None
+        snap = eng.metrics.snapshot()
+        assert snap["retired"] == 16
+        assert snap["block_steps"] == snap["steps"] == eng._iter
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("ending", ["max_new_tokens", "eos"])
+def test_an_ended_lanes_slot_takes_nothing_of_the_block_step_after(
+        tiny, slow_steps, ending):
+    """One slot: A's last block is committed in step k with step k+1 out
+    already; B takes A's slot before k+1 is collected.  Neither reads it."""
+    pa, pb = prompt_of(9, seed=5), prompt_of(6, seed=6)
+    free, _, _ = ref.block_diffusion_generate(tiny[0], pa.tolist(), CFG, 24)
+    kw = {"eos_token_id": free[6]} if ending == "eos" else {}
+    n_a = 24 if kw else 10
+    want_a = ref.block_diffusion_generate(tiny[0], pa.tolist(), CFG, n_a,
+                                          eos=kw.get("eos_token_id"))
+    want_b = ref.block_diffusion_generate(tiny[0], pb.tolist(), CFG, 7)
+    eng = engine_for(tiny[1], max_slots=1)
+    try:
+        slow_steps(eng)
+        a, b = eng.submit(pa, n_a, **kw), eng.submit(pb, 7)
+        assert a.result(120) == want_a[0] and a.steps == want_a[1]
+        assert b.result(120) == want_b[0] and b.steps == want_b[1]
+        assert eng.drain(timeout=60)
+        snap = eng.metrics.snapshot()
+        assert snap["empty_steps"] == 2 and snap["retired"] == 2
+        assert snap["steps_launched_ahead"] / snap["steps"] > 0.8
+    finally:
+        eng.stop()
+
+
 def test_dynamic_strategy_follows_the_reference():
     cfg = dict(CFG, remasking_strategy="low_confidence_dynamic",
                confidence_threshold=0.012)
@@ -363,8 +418,11 @@ def test_counters_and_gaps_say_what_a_client_sees(tiny):
     eng = engine_for(tiny[1], max_slots=1)
     try:
         eng.generate(prompt_of(8), 16, timeout=120)     # four whole blocks
+        assert eng.drain(timeout=60)
         snap = eng.metrics.snapshot()
-        assert snap["block_steps"] == 20                # T + 1 a block
+        # T + 1 a block, and the step launched before the host knew the
+        # last block had been committed, which ran with no lane armed
+        assert snap["block_steps"] == 21 and snap["empty_steps"] == 1
         assert snap["block_lane_steps_denoised"] == 16
         assert snap["block_lane_steps_committed"] == 4
         assert snap["block_tokens_emitted"] == 16
@@ -375,7 +433,7 @@ def test_counters_and_gaps_say_what_a_client_sees(tiny):
         assert counts["assignments"].sum() == 20 * 4 * 3 * 2
         assert (counts["touched"] <= 20 * 8).all()
         text = eng.metrics.prometheus_text()
-        assert "paddle_genserve_block_steps_total 20" in text
+        assert "paddle_genserve_block_steps_total 21" in text
         assert 'paddle_genserve_block_lane_steps_total{kind="committed"} 4' \
             in text
     finally:

@@ -155,6 +155,55 @@ class TestSpecParity:
         finally:
             eng.stop()
 
+    @pytest.mark.parametrize("device_ms", [0, 6])
+    def test_sixteen_staggered_requests_equal_solo_with_a_step_in_flight(
+            self, model, draft, slow_steps, device_ms):
+        """spec_step k+1 is launched before step k's 1..K+1 tokens a lane
+        are handed out: greedy and seeded sampling lanes of unequal
+        lengths, five times the slots, each the stream it has alone."""
+        eng = GenerationEngine(model, max_slots=3, max_seq_len=40,
+                               prompt_buckets="8,16", draft_model=draft,
+                               spec_tokens=3).start()
+        try:
+            if device_ms:
+                slow_steps(eng, device_ms / 1e3)
+            jobs = []
+            for i in range(16):
+                p, n = PROMPTS[i % 4], 3 + (5 * i) % 17
+                kw = dict(seed=i, **SAMPLE_KW) if i % 2 else {}
+                jobs.append((eng.submit(p, n, **kw), p, n, kw))
+                time.sleep(0.004)
+            for h, p, n, kw in jobs:
+                assert h.result(120) == solo(model, p, n, **kw)
+            assert eng.drain(timeout=60) and eng._flight is None
+            snap = eng.metrics.snapshot()
+            assert snap["retired"] == 16 and snap["steps"] == eng._iter
+            c = eng.timers.counts
+            assert c["spec_decode"] == c["fetch"] == c["distribute"] \
+                == eng._iter
+        finally:
+            eng.stop()
+
+    def test_an_ended_lanes_slot_takes_nothing_of_the_spec_step_after(
+            self, model, draft, slow_steps):
+        """One slot, steps of 20 ms: A ends inside a speculative run while
+        the next step is out; B takes the slot before that is collected."""
+        eng = GenerationEngine(model, max_slots=1, max_seq_len=40,
+                               prompt_buckets="8,16", draft_model=draft,
+                               spec_tokens=3).start()
+        try:
+            slow_steps(eng)
+            a = eng.submit(PROMPTS[0], 7)
+            b = eng.submit(PROMPTS[1], 9, seed=7, **SAMPLE_KW)
+            assert a.result(60) == solo(model, PROMPTS[0], 7)
+            assert b.result(60) == solo(model, PROMPTS[1], 9, seed=7,
+                                        **SAMPLE_KW)
+            assert eng.drain(timeout=60)
+            snap = eng.metrics.snapshot()
+            assert snap["empty_steps"] == 2 and snap["retired"] == 2
+        finally:
+            eng.stop()
+
     def test_accept_ratio_counter(self, eng_spec):
         """The acceptance counters move and the PTA007-clean gauge is
         exposed on /metrics."""
