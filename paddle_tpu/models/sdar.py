@@ -91,14 +91,19 @@ class RMSNorm(Layer):
         return apply(f, x, self.weight)
 
 
-def rope(x, positions, theta):
+def rope(x, positions, theta, inv_freq=None, scale=None):
     """Rotary positions over the whole head, rotate-half: x [B, S, n, hd]
-    at `positions` [B|1, S]."""
+    at `positions` [B|1, S].  The frequencies are theta's, or `inv_freq`
+    [hd / 2] where a layer has a law of its own, whose cos and sin are
+    then multiplied by `scale`."""
     hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
     return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
@@ -133,28 +138,39 @@ class SDARAttention(Layer):
         pos = unwrap(positions)
         q = self.q_norm(Tensor(unwrap(self.q(x)).reshape(B, S, nq, hd)))
         k = self.k_norm(Tensor(unwrap(self.k(x)).reshape(B, S, nkv, hd)))
-        q = rope(unwrap(q), pos, cfg.rope_theta)
-        k = rope(unwrap(k), pos, cfg.rope_theta)
+        q = self.rotary(unwrap(q), pos)
+        k = self.rotary(unwrap(k), pos)
         v = unwrap(self.v(x)).reshape(B, S, nkv, hd)
         if kv is not None:
             ctx, kv = kv.attend(layer, q, k, v)
         else:
-            blk = jnp.arange(S) // cfg.block_length
-            mask = (blk[None, :] <= blk[:, None])[None, None]
-            g = nq // nkv      # the flash kernel wants one KV head a head
-            ctx = unwrap(fused.scaled_dot_product_attention(
-                Tensor(q), Tensor(jnp.repeat(k, g, axis=2)),
-                Tensor(jnp.repeat(v, g, axis=2)), attn_mask=Tensor(mask),
-                training=False))
+            ctx = self.among(q, k, v)
         out = self.out(Tensor(unwrap(ctx).reshape(B, S, nq * hd)))
         return (out, kv) if kv is not None else (out, k, v)
 
+    def rotary(self, x, positions):
+        """The layer's rotary law on q or k [B, S, n, hd]."""
+        return rope(x, positions, self.cfg.rope_theta)
+
+    def among(self, q, k, v):
+        """The prompt pass: attention among the tokens themselves, here
+        under the block mask."""
+        cfg = self.cfg
+        blk = jnp.arange(q.shape[1]) // cfg.block_length
+        mask = (blk[None, :] <= blk[:, None])[None, None]
+        g = cfg.num_heads // cfg.num_kv_heads
+        # the flash kernel wants one KV head a head
+        return unwrap(fused.scaled_dot_product_attention(
+            Tensor(q), Tensor(jnp.repeat(k, g, axis=2)),
+            Tensor(jnp.repeat(v, g, axis=2)), attn_mask=Tensor(mask),
+            training=False))
+
 
 class SDARBlock(Layer):
-    def __init__(self, cfg: SDARConfig):
+    def __init__(self, cfg: SDARConfig, attn=None):
         super().__init__()
         self.ln_1 = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
-        self.attn = SDARAttention(cfg)
+        self.attn = attn if attn is not None else SDARAttention(cfg)
         self.ln_2 = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         self.moe = DroplessMoE(cfg.hidden_size, cfg.moe_intermediate_size,
                                cfg.num_experts, cfg.num_experts_per_tok,
@@ -178,10 +194,16 @@ class SDARModel(Layer):
         self.cfg = cfg
         self.embed = Embedding(cfg.vocab_size, cfg.hidden_size,
                                weight_attr=_init(cfg))
-        self.h = [SDARBlock(cfg) for _ in range(cfg.num_layers)]
+        self.h = [self.block(cfg, i) for i in range(cfg.num_layers)]
         for i, blk in enumerate(self.h):
             self.add_sublayer(f"h_{i}", blk)
         self.norm_f = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+
+    @staticmethod
+    def block(cfg, i):
+        """Layer i of the decoder (a model of this block with layers of
+        more than one kind gives each its own attention)."""
+        return SDARBlock(cfg)
 
     def forward(self, input_ids, positions=None, kv=None, count=None):
         """Hidden states [B, S, H].  With a KV source every layer attends
@@ -218,10 +240,12 @@ def _row(hidden, i):
 
 
 class SDARForCausalLM(Layer):
+    backbone = SDARModel
+
     def __init__(self, cfg: SDARConfig):
         super().__init__()
         self.cfg = cfg
-        self.sdar = SDARModel(cfg)
+        self.sdar = self.backbone(cfg)
         self.lm_head = Linear(cfg.hidden_size, cfg.vocab_size,
                               weight_attr=_init(cfg), bias_attr=False)
 

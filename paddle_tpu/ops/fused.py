@@ -419,16 +419,77 @@ def masked_attention(q, keys, values, valid):
     return jnp.einsum("bnqs,bsnd->bqnd", probs, values)
 
 
+def banded_attention(q, keys, values, offset=0, window=0, floor=0,
+                     block=512):
+    """Attention of a run of queries over a run of keys under a band:
+    query i sees key c iff ``floor <= c <= i + offset`` and, with a
+    ``window``, ``c > i + offset - window``.  Causal attention among a
+    prompt's tokens is ``offset=0``; a suffix over [prefix ++ suffix] is
+    ``offset=len(prefix)`` with ``floor`` hiding the rows before the
+    prefix's first token.
+
+    q [B, Q, nh, hd]; keys/values [B, S, nkv, hd], nkv dividing nh (query
+    head h reads KV head h // (nh / nkv); the keys are never expanded).
+    ``offset``, ``window`` and ``block`` static, ``floor`` may be traced.
+    Queries go ``block`` at a time, so the scores held at once are
+    [nh, block, S] float32 and never [nh, Q, S]; under a window a block
+    reads only the ``block + window`` keys that some query of it can see.
+    Softmax in float32 (a prompt of thousands of keys sums thousands of
+    terms).  Raw jax arrays in and out; returns [B, Q, nh, hd].
+    """
+    B, Q, nh, hd = q.shape
+    S, nkv = keys.shape[1], keys.shape[2]
+    g = nh // nkv
+    scale = 1.0 / float(hd) ** 0.5
+
+    def part(qb, i0):
+        n = qb.shape[1]
+        kb, vb, start = keys, values, 0
+        if window and S > n + window:
+            # the first key query i0 sees is i0 + offset - window + 1
+            start = jnp.clip(i0 + offset - window + 1, 0, S - n - window)
+            kb = jax.lax.dynamic_slice_in_dim(keys, start, n + window, 1)
+            vb = jax.lax.dynamic_slice_in_dim(values, start, n + window, 1)
+        c = start + jnp.arange(kb.shape[1])[None]
+        i = i0 + offset + jnp.arange(n)[:, None]
+        ok = (c <= i) & (c >= floor)
+        if window:
+            ok = ok & (c > i - window)
+        scores = jnp.einsum("bqngd,bsnd->bngqs",
+                            qb.reshape(B, n, nkv, g, hd), kb,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(ok, scores, jnp.finfo(jnp.float32).min)
+        probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+        probs = probs / probs.sum(axis=-1, keepdims=True)
+        out = jnp.einsum("bngqs,bsnd->bqngd", probs.astype(vb.dtype), vb)
+        return out.reshape(B, n, nh, hd)
+
+    if Q <= block or Q % block:
+        return part(q, 0)
+    nb = Q // block
+    out = jax.lax.map(
+        lambda x: part(*x),
+        (q.reshape(B, nb, block, nh, hd).swapaxes(0, 1),
+         jnp.arange(nb) * block))
+    return out.swapaxes(0, 1).reshape(B, Q, nh, hd)
+
+
 # ---------------------------------------------------------------------------
 # paged decode attention (fused_multi_transformer's masked decode analog):
 # ragged Pallas kernel walking each lane's page-table row over the KV pool
 # ---------------------------------------------------------------------------
 def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
-                           tp_axis=None):
+                           tp_axis=None, window=0):
     """Pallas paged decode attention over plane `layer` of the whole KV
     pool, or None when the kernels are off or the kernel says it does not
     tile this geometry (the caller keeps its dense-gather reference path
     and the refusal shows up in the fallback counter).
+
+    A pool of as many heads as the query has, and no window, is
+    `paddle_paged_decode_fwd`'s; a pool of fewer (KV heads, each read by a
+    group of query heads) or a layer that sees a static `window` of keys
+    is `paddle_paged_gqa_decode_fwd`'s (no mesh yet: under one it is
+    refused, counted, and the caller gathers).
 
     q [slots, 1, nh, hd] (the step's query, post-scatter); k_pages/v_pages
     [layers, num_pages, page_size, nh, hd] (the stacked pools, never a
@@ -444,9 +505,18 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
 
     mesh, _, _ = _mesh_axes()
 
+    grouped = bool(window) or k_pages.shape[3] != q.shape[2]
+
     def pf(qv, kp, vp, rw, ps_):
         q1 = qv[:, 0]
-        if mesh is not None:
+        if grouped:
+            if mesh is not None:
+                raise pa.DoesNotTile(
+                    "paged_gqa_decode_attention is not composed with a "
+                    "mesh yet")
+            out = pa.paged_gqa_decode_attention(q1, kp, vp, rw, ps_,
+                                                seq_cap, layer, window)
+        elif mesh is not None:
             out = pa.sharded_paged_decode_attention(
                 q1, kp, vp, rw, ps_, seq_cap, layer, mesh,
                 tp_axis if tp_axis in mesh.axis_names else None)

@@ -72,7 +72,7 @@ from .engine import (DeadlineExceededError, EngineStoppedError,
                      QueueFullError)
 from .kv_cache import (CacheGeometry, PagedKV, PrefixKV, admit_slot,
                        make_state, push_pages, reclaim_pages, release_slots,
-                       state_specs, take_pages, write_prompt)
+                       slide_window, state_specs, take_pages, write_prompt)
 from .metrics import GenerationMetrics
 from .prefix_cache import PrefixCache
 from .scheduler import SlotScheduler
@@ -187,7 +187,8 @@ class _GenRequest:
                  "deadline", "handle", "engine", "cancelled",
                  "t_last_token", "span", "own_span", "span_queue",
                  "span_decode", "prefilling", "prefill_cursor",
-                 "chunk_row", "j_hit", "pin_final", "block_start")
+                 "chunk_row", "chunk_wrow", "j_hit", "pin_final",
+                 "block_start")
 
     def __init__(self, engine, prompt, bucket, max_new_tokens, do_sample,
                  temperature, top_k, seed, eos, deadline, span=None,
@@ -212,6 +213,7 @@ class _GenRequest:
         self.prefilling = False            # chunked prefill in flight
         self.prefill_cursor = 0            # tokens already prefilled
         self.chunk_row = None              # slot's page row so far (np)
+        self.chunk_wrow = None             # its window pool's (or None)
         self.j_hit = 0                     # prefix-cache pages mapped
         self.pin_final = 0                 # pinned count once armed
         self.block_start = 0               # block engines: the open block
@@ -265,7 +267,13 @@ class GenerationEngine:
         0 sizes it dense-equivalently (max_slots * pages_per_slot) —
         smaller pools oversubscribe slots against actual footprint and
         the scheduler queues admissions that cannot reserve their
-        worst case.
+        worst case.  A model whose ``cfg`` gives ``layer_windows`` (one
+        entry a layer, the window of keys it sees in tokens, 0 = all)
+        gets a second pool for those layers, in which a lane holds only
+        the pages that meet its window; its capacity follows from this
+        one (``CacheGeometry.window_pages``).  Nothing selects the
+        cache's kind: a model without windows gets the one pool it
+        always got.
       prefix_cache: share identical tokenized prompt prefixes as
         refcounted read-only pages (``FLAGS_genserve_prefix_cache``);
         hits skip prefill for the shared pages.
@@ -396,6 +404,12 @@ class GenerationEngine:
         kv_dtype = next((str(p.dtype) for p in model.parameters()
                          if jnp.issubdtype(p.dtype, jnp.floating)),
                         "float32")
+        # layers that see a window: declared by the model, never by a flag
+        windows = tuple(getattr(cfg, "layer_windows", ()) or ())
+        if any(windows) and mesh is not None:
+            raise ValueError(
+                "a model with window layers is served without a mesh (the "
+                "window pool's sharding is not written yet)")
         self.geometry = CacheGeometry(
             num_layers=cfg.num_layers, max_slots=self.max_slots,
             max_seq_len=self.max_seq_len, num_heads=cfg.num_heads,
@@ -405,16 +419,25 @@ class GenerationEngine:
             num_pages=int(num_pages), dtype=kv_dtype,
             num_kv_heads=getattr(cfg, "num_kv_heads", 0),
             block_length=self.block_length,
-            num_experts=(getattr(cfg, "num_experts", 0)
-                         if self.block_length else 0), **draft_kw)
+            num_experts=getattr(cfg, "num_experts", 0),
+            windows=windows, **draft_kw)
+        geom = self.geometry
         self.metrics = GenerationMetrics(
-            max_slots=self.max_slots, num_pages=self.geometry.num_pages)
-        self._prefix = (PrefixCache(page_size) if prefix_cache else None)
+            max_slots=self.max_slots, num_pages=geom.num_pages,
+            window_pool=bool(geom.windows))
+        # window pages go by the ids past the full pool's in the prefix
+        # cache, which counts the two kinds apart; room for the entries of
+        # four whole prompts at least (a prompt registers one a page)
+        self._prefix = (PrefixCache(
+            page_size, capacity=max(1024, 4 * geom.pages_per_slot),
+            split=geom.num_pages if geom.windows else None)
+            if prefix_cache else None)
         self._slot_pins: dict[int, list] = {}   # slot -> pinned page ids
         self._queue: queue.Queue = queue.Queue(self.queue_depth)
         self._backlog: collections.deque = collections.deque()
-        self._sched = SlotScheduler(self.max_slots,
-                                    num_pages=self.geometry.num_pages)
+        self._sched = SlotScheduler(
+            self.max_slots, num_pages=geom.num_pages,
+            window_pages=geom.window_pages if geom.windows else None)
         if mesh is not None and not hasattr(mesh, "axis_names"):
             # {"tp": 2}-style dict: build a mesh over exactly the
             # devices the shape needs (the process may expose more)
@@ -488,6 +511,21 @@ class GenerationEngine:
         pfx_pages = min(pps, -(-self.prompt_buckets[-1] // ps))
         B = geom.block_length
         prefix_kw = {"block": B} if B else {}
+        # a window engine: the layers' windows, and each pool's layers
+        W = geom.windows
+        counted = bool(geom.num_experts)
+
+        def window_args(extra, w_pin):
+            """A window engine's trailing admission arguments `extra` (the
+            window pool's shared ids, the first column its row keeps) as
+            (ids for the prefix gather, `write_prompt`'s keywords, what is
+            left of `extra`); its own pages start at `w_pin`."""
+            if not W:
+                return None, {}, extra
+            wshared, w_from = extra
+            return wshared, {"window": (
+                (geom.full_layers, geom.window_layers), wshared, w_from,
+                w_pin)}, ()
 
         # sharding plan: None entries (no mesh) keep today's lowering
         mesh, layout = self._mesh, self._layout
@@ -606,17 +644,23 @@ class GenerationEngine:
             # prefix-miss admission: every mapped page is freshly
             # allocated and written (shared_n = 0).  `extra` is the
             # draft's K/V (speculative) or the opening block (blocks)
-            draft_kv, opening = ((), extra) if B else (extra, ())
             no_shared = jnp.full((pps,), -1, jnp.int32)
+            if W:       # extra: the first column the window row keeps
+                _, win, extra = window_args((no_shared,) + extra,
+                                            jnp.int32(0))
+            else:
+                win = {}
+            draft_kv, opening = ((), extra) if B else (extra, ())
             state, row = write_prompt(state, slot, k_new, v_new, length,
-                                      no_shared, jnp.int32(0), *draft_kv)
+                                      no_shared, jnp.int32(0), *draft_kv,
+                                      **win)
             state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
                               do_sample, temp, top_k, stop_pos, eos, pinned,
                               opening)
             return state, tok1, row
 
         def suffix_prefill(params, dparams, state, ids, shared_ids,
-                           shared_n, length):
+                           shared_n, length, wshared_ids=None):
             # prefill ONLY the suffix, attending over the prefix already
             # resident in the pool(s) — shared by the prefix-hit admission
             # path and every prefill chunk.  The suffix tokens sit at
@@ -627,13 +671,13 @@ class GenerationEngine:
             last = jnp.asarray(length, jnp.int32) - prefix_len - 1
 
             def suffix(m, p, b, k_pool, v_pool):
+                prefix = PrefixKV.gather_windowed(
+                    state, shared_ids, wshared_ids, shared_n, pfx_pages,
+                    W) if W else PrefixKV.gather(
+                        k_pool, v_pool, shared_ids[:pfx_pages], prefix_len,
+                        **prefix_kw)
                 (lg, kv), _ = functional_call(
-                    m, p,
-                    (ids, positions,
-                     PrefixKV.gather(k_pool, v_pool,
-                                     shared_ids[:pfx_pages], prefix_len,
-                                     **prefix_kw),
-                     last),
+                    m, p, (ids, positions, prefix, last),
                     buffers=b, mutable=False, method="slot_step")
                 return kv.suffix_kv(), lg[0, 0]
 
@@ -651,11 +695,14 @@ class GenerationEngine:
             # prefix-hit admission: the shared pages are never
             # recomputed; the suffix pages in at the (page-aligned)
             # boundary
+            # a window engine's own pages start where the shared ones end
+            wshared, win, opening = window_args(opening, shared_n)
             k_suf, v_suf, logits, draft_kv = suffix_prefill(
                 params, dparams, state, ids, shared_ids, shared_n,
-                length)
+                length, wshared)
             state, row = write_prompt(state, slot, k_suf, v_suf, length,
-                                      shared_ids, shared_n, *draft_kv)
+                                      shared_ids, shared_n, *draft_kv,
+                                      **win)
             state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
                               do_sample, temp, top_k, stop_pos, eos, pinned,
                               opening)
@@ -679,11 +726,15 @@ class GenerationEngine:
             # chunk page — the stale-pinned leak this executable exists
             # to prevent; the final chunk raises it to pin_final to
             # protect the pages about to be registered as shared.
+            # a window engine: what an earlier chunk wrote (index >=
+            # pin_now) and the window has passed goes back
+            wshared, win, opening = window_args(opening, pin_now)
             k_suf, v_suf, logits, draft_kv = suffix_prefill(
                 params, dparams, state, ids, shared_ids, shared_n,
-                length)
+                length, wshared)
             state, row = write_prompt(state, slot, k_suf, v_suf, length,
-                                      shared_ids, shared_n, *draft_kv)
+                                      shared_ids, shared_n, *draft_kv,
+                                      **win)
             pinned = jnp.where(jnp.asarray(arm_now, bool), pin_final,
                                pin_now)
             state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
@@ -710,13 +761,26 @@ class GenerationEngine:
             pages, free_count = take_pages(state["free_stack"],
                                            state["free_count"], need)
             ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
+            win = {}
+            if W:       # the window pool's tail page, off its own stack
+                wtab = state["wtab"]
+                wcur = wtab[lane, pidx]
+                wneed = active & (wcur < 0)
+                wpages, wfree_count = take_pages(
+                    state["wfree_stack"], state["wfree_count"], wneed)
+                wtab = wtab.at[lane, pidx].set(
+                    jnp.where(wneed, wpages, wcur))
+                win = dict(wk_pages=state["wkp"], wv_pages=state["wvp"],
+                           wrows=wtab, windows=W)
             # (2) one paged-attention token per lane
-            (logits, kv), _ = functional_call(
+            out, _ = functional_call(
                 model, params,
                 (state["tok"][:, None], pos[:, None],
                  PagedKV(state["kp"], state["vp"], ptab, pos, active,
-                         seq_cap)),
+                         seq_cap, **win)),
+                dict(live=active) if counted else {},
                 buffers=buffers, mutable=False, method="slot_step")
+            logits, kv = out[0], out[1]
             logits, kp, vp = logits[:, 0], kv.k_pages, kv.v_pages
             pair = jax.vmap(jax.random.split)(state["rng"])
             new_keys, subs = pair[:, 0], pair[:, 1]
@@ -741,7 +805,43 @@ class GenerationEngine:
                              free_stack=free_stack, free_count=free_count,
                              tok=toks, pos=new_pos, rng=new_keys,
                              active=active & ~finished)
-            return new_state, toks, finished
+            report = (toks, finished)
+            if W:
+                # (4) the window pool: what lies wholly behind the next
+                # query's window leaves the row (the lane's own pages go
+                # back on the stack), then retirement as above.  The
+                # step's report gains the pools' registers: pages off
+                # each free stack, table entries the live lanes hold
+                # after the step, pages let go behind the window so far
+                live = active & ~finished
+                wtab, wfree_stack, wfree_count, gone = slide_window(
+                    state, wtab, wfree_count, new_pos, live, geom.window)
+                wfree_stack, wfree_count = push_pages(
+                    wfree_stack, wfree_count, jnp.where(
+                        finished[:, None] & (wtab >= 0)
+                        & (col >= state["pinned"][:, None]),
+                        wtab, -1).reshape(-1))
+                new_state.update(
+                    wkp=kv.wk_pages, wvp=kv.wv_pages,
+                    wtab=jnp.where(finished[:, None], -1, wtab),
+                    wfree_stack=wfree_stack, wfree_count=wfree_count,
+                    w_released=state["w_released"] + gone)
+                report += (jnp.stack([
+                    geom.num_pages - new_state["free_count"],
+                    geom.window_pages - new_state["wfree_count"],
+                    (live[:, None] & (new_state["ptab"] >= 0)).sum(
+                        dtype=jnp.int32),
+                    (live[:, None] & (new_state["wtab"] >= 0)).sum(
+                        dtype=jnp.int32),
+                    new_state["w_released"]]),)
+            if counted:
+                per, touched = out[2]
+                new_state["moe_counts"] = state["moe_counts"] + per
+                new_state["moe_touched"] = state["moe_touched"] + touched
+                # copies of their own, as block_step's
+                report += ((new_state["moe_counts"] + 0,
+                            new_state["moe_touched"] + 0),)
+            return (new_state,) + report
 
         def spec_step(params, dparams, state):
             """ONE speculative iteration: the draft model chains K
@@ -870,7 +970,6 @@ class GenerationEngine:
                 raise ValueError(
                     f"unknown remasking strategy {strategy!r}")
             threshold = float(getattr(cfg, "confidence_threshold", 0.85))
-            counted = bool(geometry.num_experts)
 
         def block_step(params, state):
             """ONE iteration of generation by blocks.  A lane holds the
@@ -981,8 +1080,8 @@ class GenerationEngine:
         def release_step(state, mask):
             return release_slots(state, mask)
 
-        def reclaim_step(state, pages):
-            return reclaim_pages(state, pages)
+        def reclaim_step(state, pages, *wpages):
+            return reclaim_pages(state, pages, *wpages)
 
         self._state = make_state(geom)
         if mesh is not None:
@@ -1041,8 +1140,8 @@ class GenerationEngine:
             self.compile_count += 1
             if self._prefix is not None:
                 self._reclaim_exec = inference.aot_compile(
-                    reclaim_step, (sspec, pvec), donate_argnums=(0,),
-                    out_shardings=out_state)
+                    reclaim_step, (sspec, pvec) + ((pvec,) if W else ()),
+                    donate_argnums=(0,), out_shardings=out_state)
                 self.compile_count += 1
             dpre = (dpspec,) if draft is not None else ()
             for sp in self.prompt_buckets:
@@ -1057,12 +1156,15 @@ class GenerationEngine:
                 kv = sds(pre[0].shape, pre[0].dtype, kv_sh)
                 lg = sds(pre[2].shape, pre[2].dtype)
                 dkv_in = tuple(sds(a.shape, a.dtype) for a in pre[3:])
-                # a block engine's admissions carry the opening block
-                opening = (sds((B,), np.int32), i32) if B else ()
+                # a block engine's admissions carry the opening block, a
+                # window engine's the window pool's shared ids and the
+                # first column its row keeps
+                opening = (sds((B,), np.int32), i32) if B else \
+                    (pvec, i32) if W else ()
                 self._insert_execs[sp] = inference.aot_compile(
                     insert_step,
                     (sspec, i32, kv, kv, lg, i32, i32, i32, b1, f32, i32,
-                     i32, i32, i32) + dkv_in + opening,
+                     i32, i32, i32) + dkv_in + opening[1 if W else 0:],
                     donate_argnums=(0,), out_shardings=outs(rep, rep))
                 self.compile_count += 2
                 tail = (i32, ids, pvec, i32, i32, i32, i32, b1, f32, i32,
@@ -1188,6 +1290,13 @@ class GenerationEngine:
                 f"request needs {worst_pages} KV pages worst-case; the "
                 f"pool holds {self.geometry.num_pages} (raise num_pages "
                 f"or page_size)")
+        if self.geometry.windows and self.geometry.window_pages \
+                < self._window_need(L, max_new_tokens, 0):
+            self.metrics.count("rejected_pages_exhausted")
+            raise ValueError(
+                "the window pool is too small for this request even when "
+                f"empty ({self.geometry.window_pages} pages; raise "
+                "num_pages)")
         top_k = int(top_k)
         if top_k > self.max_top_k:
             raise ValueError(f"top_k {top_k} exceeds max_top_k "
@@ -1385,6 +1494,9 @@ class GenerationEngine:
                              if self._prefix is not None else (0, ()))
             need = self.geometry.pages_for(
                 len(req.prompt) + req.max_new_tokens) - j_hit
+            if self.geometry.windows:
+                need = (need, self._window_need(
+                    len(req.prompt), req.max_new_tokens, j_hit))
             if not self._sched.can_admit(need):
                 # page-pressure escape hatch BEFORE queuing: when a
                 # free lane exists and idle prefix-cache residents are
@@ -1392,12 +1504,11 @@ class GenerationEngine:
                 # head's reservation fits — otherwise a stream of
                 # distinct prompts parks one-reader prefixes over the
                 # whole pool and the backlog never drains
+                short = self._sched.short_of(need)
                 if (self._prefix is not None and len(self._prefix)
-                        and need > self._sched.pages_available):
-                    short = need - self._sched.pages_available
+                        and short > 0):
                     self._reclaim(self._prefix.evict_idle(short))
-                    self._sched.set_shared_resident(
-                        self._prefix.resident_pages)
+                    self._sync_resident()
                 if not self._sched.can_admit(need):
                     # the pool cannot reserve the worst case even
                     # after eviction — FIFO head-of-line wait until a
@@ -1431,9 +1542,10 @@ class GenerationEngine:
         if req.span_queue is not None:
             req.span_queue.end(status="ok")
             req.span_queue = None
-        j_reg = (self._prefix.shareable_pages(L)
-                 if self._prefix is not None else 0)
+        j_reg = self._shareable(L, j_hit)
         pinned = max(j_hit, j_reg)
+        hit_ids = shared        # the lookup's, of both pools where two
+        shared, win = self._window_args(j_hit, shared, L)
         sp_prefill = (req.span.child("gen.prefill", bucket=req.bucket,
                                      prompt_len=L, slot=slot,
                                      prefix_pages=j_hit, iter=self._iter)
@@ -1458,7 +1570,8 @@ class GenerationEngine:
                     np.int32(req.seed), np.int32(req.resume_pos),
                     np.bool_(req.do_sample),
                     np.float32(req.temperature), np.int32(req.top_k),
-                    stop, np.int32(req.eos), np.int32(pinned), *opening)
+                    stop, np.int32(req.eos), np.int32(pinned), *opening,
+                    *win)
             else:
                 ids = np.zeros((1, req.bucket), np.int32)
                 ids[0, :L] = req.prompt
@@ -1471,7 +1584,7 @@ class GenerationEngine:
                     np.int32(req.resume_pos),
                     np.bool_(req.do_sample), np.float32(req.temperature),
                     np.int32(req.top_k), stop, np.int32(req.eos),
-                    np.int32(pinned), *out[3:], *opening)
+                    np.int32(pinned), *out[3:], *opening, *win[1:])
         self._state = state
         with scope("admit/fetch"), host_fetch():
             # blocks until the device has run the prefill and insert
@@ -1480,17 +1593,30 @@ class GenerationEngine:
         if self._prefix is not None:
             with scope("admit/register"):
                 self.metrics.count_prefix(hit=j_hit > 0)
-                pin_pages = [int(p) for p in row_np[:pinned]]
+                # behind a hit a window engine registers nothing, and its
+                # window row no longer maps all it shares: pin the hit's
+                pin_pages = list(hit_ids) if pinned == j_hit else \
+                    self._prefix.ids(row_np, pinned)
                 self._prefix.pin(pin_pages)
                 self._slot_pins[slot] = pin_pages
                 self._reclaim(self._prefix.register(req.prompt, row_np,
                                                     j_hit, j_reg))
-                self._sched.set_shared_resident(
-                    self._prefix.resident_pages)
+                self._sync_resident()
         with scope("admit/push"):
             if sp_prefill is not None:
                 sp_prefill.end(status="ok")
             self._push_first(req, slot, t1)
+
+    def _shareable(self, L: int, j_hit: int) -> int:
+        """Pages of an L-token prompt the admission registers as shared.
+        A window engine registers a prompt only where it keeps its window
+        layers' K/V whole, which is on a miss: behind a hit its own pages
+        slide, and nothing deeper than the hit is registered."""
+        if self._prefix is None:
+            return 0
+        if self.geometry.windows and j_hit:
+            return j_hit
+        return self._prefix.shareable_pages(L)
 
     def _opening(self, prompt):
         """A block engine's extra admission arguments: the prompt's last
@@ -1539,8 +1665,7 @@ class GenerationEngine:
         if req.span_queue is not None:
             req.span_queue.end(status="ok")
             req.span_queue = None
-        j_reg = (self._prefix.shareable_pages(L)
-                 if self._prefix is not None else 0)
+        j_reg = self._shareable(L, j_hit)
         req.j_hit = j_hit
         req.pin_final = max(j_hit, j_reg)
         req.prefilling = True
@@ -1549,17 +1674,18 @@ class GenerationEngine:
         if j_hit > 0:
             row[:j_hit] = shared[:j_hit]
         req.chunk_row = row
+        win = self._window_args(j_hit, shared, 0)[1]
+        req.chunk_wrow = win[0] if win else None
         if self._prefix is not None:
             with self.timers.scope("admit/register"):
                 self.metrics.count_prefix(hit=j_hit > 0)
                 # pin the cache-shared head NOW: it must stay resident
                 # for every later chunk's prefix gather (LRU cannot
                 # evict it)
-                pin_pages = [int(p) for p in row[:j_hit]]
+                pin_pages = [int(p) for p in shared]
                 self._prefix.pin(pin_pages)
                 self._slot_pins[slot] = pin_pages
-                self._sched.set_shared_resident(
-                    self._prefix.resident_pages)
+                self._sync_resident()
         if req.span is not None:
             req.span_decode = req.span.child(
                 "gen.prefill", bucket=req.bucket, prompt_len=L,
@@ -1589,6 +1715,11 @@ class GenerationEngine:
         ids = np.zeros((1, sb), np.int32)
         ids[0, :len(chunk)] = chunk
         shared_vec = np.array(req.chunk_row, np.int32)
+        win = ()
+        if geom.windows:
+            keep_all = self._prefix is not None and req.j_hit == 0
+            win = (np.array(req.chunk_wrow, np.int32),
+                   np.int32(0 if keep_all else geom.first_col(end)))
         dpre = ((self._draft_params,)
                 if self.draft_model is not None else ())
         scope = self.timers.scope
@@ -1602,12 +1733,15 @@ class GenerationEngine:
                 np.int32(req.top_k),
                 np.int32(L + req.max_new_tokens), np.int32(req.eos),
                 np.int32(req.j_hit), np.int32(req.pin_final),
-                np.bool_(arm), *self._opening(req.prompt))
+                np.bool_(arm), *self._opening(req.prompt), *win)
         self._state = state
         with scope("chunk/fetch"), host_fetch():
             t1 = int(np.array(tok1, copy=True))
             row_np = np.array(row, copy=True)
-        req.chunk_row = row_np
+        if geom.windows:
+            req.chunk_row, req.chunk_wrow = row_np
+        else:
+            req.chunk_row = row_np
         req.prefill_cursor = end
         self.metrics.count_chunk()
         if req.span_decode is not None:
@@ -1622,15 +1756,15 @@ class GenerationEngine:
         req.prefilling = False
         j_hit = req.j_hit
         if self._prefix is not None:
-            j_reg = self._prefix.shareable_pages(len(req.prompt))
-            pin_pages = [int(p) for p in row_np[:req.pin_final]]
+            j_reg = self._shareable(len(req.prompt), j_hit)
             # the cache-hit head was pinned at admission; pin the
             # freshly registered tail
-            self._prefix.pin(pin_pages[j_hit:])
-            self._slot_pins[slot] = pin_pages
+            tail = self._prefix.ids(row_np, req.pin_final, j_hit)
+            self._prefix.pin(tail)
+            self._slot_pins[slot] = self._slot_pins.get(slot, []) + tail
             self._reclaim(self._prefix.register(req.prompt, row_np,
                                                 j_hit, j_reg))
-            self._sched.set_shared_resident(self._prefix.resident_pages)
+            self._sync_resident()
         if req.span_decode is not None:
             req.span_decode.end(status="ok")
             req.span_decode = None
@@ -1652,8 +1786,46 @@ class GenerationEngine:
         if pages and self._prefix is not None:
             self._reclaim(self._prefix.unpin(pages))
         if self._prefix is not None:
-            self._sched.set_shared_resident(self._prefix.resident_pages)
+            self._sync_resident()
         return req
+
+    def _sync_resident(self):
+        """Tell the scheduler what the prefix cache holds resident (of
+        each pool, for a window engine)."""
+        if self.geometry.windows:
+            self._sched.set_shared_resident(
+                self._prefix.resident_pages - self._prefix.resident_high,
+                self._prefix.resident_high)
+        else:
+            self._sched.set_shared_resident(self._prefix.resident_pages)
+
+    def _window_need(self, L: int, max_new: int, j_hit: int) -> int:
+        """The most pages of the window pool a request can come to hold
+        of its own: what its window meets, and on a miss under a prefix
+        cache the prompt's full pages as well, which are then kept whole
+        for later requests to share."""
+        geom = self.geometry
+        own = min(geom.pages_for(L + max_new) - j_hit, geom.window_cols)
+        if self._prefix is not None and j_hit == 0:
+            own += self._prefix.shareable_pages(L)
+        return own
+
+    def _window_args(self, j_hit: int, shared, end: int):
+        """A window engine's extra arguments of an admission (or a chunk
+        that ends at token ``end``): the shared pages split by pool, and
+        the first column the window pool's row keeps: 0 where the prompt
+        is being kept whole to be shared (a miss under a prefix cache),
+        else the first page the next query's window meets.  Returns
+        (full pool's shared ids, (window pool's ids [pps], first column))
+        or (shared, ()) for an engine without windows."""
+        geom = self.geometry
+        if not geom.windows:
+            return shared, ()
+        wvec = np.full((geom.pages_per_slot,), -1, np.int32)
+        wvec[:j_hit] = [p - geom.num_pages for p in shared[j_hit:]]
+        keep_all = self._prefix is not None and j_hit == 0
+        return shared[:j_hit], (
+            wvec, np.int32(0 if keep_all else geom.first_col(end)))
 
     def _reclaim(self, pages):
         """Return evicted/orphaned prefix-cache pages to the device free
@@ -1661,11 +1833,25 @@ class GenerationEngine:
         if not pages:
             return
         pps = self.geometry.pages_per_slot
-        for i in range(0, len(pages), pps):
-            vec = np.full((pps,), -1, np.int32)
-            chunk = pages[i:i + pps]
-            vec[:len(chunk)] = chunk
-            self._state = self._reclaim_exec(self._state, vec)
+
+        def vecs(ids):
+            for i in range(0, len(ids), pps):
+                vec = np.full((pps,), -1, np.int32)
+                vec[:len(ids[i:i + pps])] = ids[i:i + pps]
+                yield vec
+
+        if not self.geometry.windows:
+            for vec in vecs(pages):
+                self._state = self._reclaim_exec(self._state, vec)
+            return
+        import itertools
+
+        n = self.geometry.num_pages
+        none = np.full((pps,), -1, np.int32)
+        for vec, wvec in itertools.zip_longest(
+                vecs([p for p in pages if p < n]),
+                vecs([p - n for p in pages if p >= n]), fillvalue=none):
+            self._state = self._reclaim_exec(self._state, vec, wvec)
 
     def _preempt_swept(self):
         swept = self._sched.sweep()
@@ -1709,11 +1895,11 @@ class GenerationEngine:
                 state, *out = (block or self._decode_exec)(self._params,
                                                            self._state)
             self._state = state
-            if block is not None:
+            if self.geometry.num_experts:
                 # the routed-assignment counters stay on the device
-                counts = out.pop()
-                if counts:
-                    self._expert_counts = counts
+                self._expert_counts = out.pop()
+            elif block is not None:
+                out.pop()
         return _Step(self._iter, lanes, out)
 
     def _collect(self):
@@ -1839,8 +2025,10 @@ class GenerationEngine:
         if proposed:
             self.metrics.observe_spec(accepted, proposed)
 
-    def _distribute(self, it, lanes, toks_np, fin_np):
+    def _distribute(self, it, lanes, toks_np, fin_np, pools_np=None):
         now = time.monotonic()
+        if pools_np is not None:
+            self.metrics.observe_pools(*(int(x) for x in pools_np))
         self.metrics.observe_tokens(len(lanes))
         for slot, req in lanes:
             if req.t_last_token is not None:

@@ -55,7 +55,8 @@ from ..tensor import unwrap
 
 __all__ = ["CacheGeometry", "PagedKV", "PrefixKV", "make_state",
            "state_specs", "take_pages", "push_pages", "write_prompt",
-           "admit_slot", "release_slots", "reclaim_pages"]
+           "admit_slot", "release_slots", "slide_window",
+           "reclaim_pages"]
 
 
 @dataclass(frozen=True)
@@ -92,10 +93,30 @@ class CacheGeometry:
     # routed experts a layer (0 = none): the state then counts the
     # assignments of the live lanes' rows, layer by expert
     num_experts: int = 0
+    # layers that see a window of keys: one entry a layer, the window in
+    # tokens (0 = the layer sees everything); () = every layer sees
+    # everything, and the state is the one pool and one table it always
+    # was.  With windows the window layers get planes, a page table and a
+    # free list of their own (``window_pages`` pages, derived below): a
+    # lane maps there only the pages that meet its window
+    windows: tuple = ()
 
     def __post_init__(self):
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {self.page_size}")
+        object.__setattr__(self, "windows",
+                           tuple(int(w) for w in self.windows))
+        if not any(self.windows):
+            object.__setattr__(self, "windows", ())
+        elif (len(self.windows) != self.num_layers
+              or len(set(self.windows) - {0}) != 1
+              or self.window % self.page_size
+              or self.draft_layers or self.block_length):
+            raise ValueError(
+                f"windows {self.windows}: one entry a layer ("
+                f"{self.num_layers}), one window size, a multiple of the "
+                f"page size {self.page_size}, and neither a draft model "
+                "nor generation by blocks (none of those paths is written)")
         if self.num_kv_heads == 0:
             object.__setattr__(self, "num_kv_heads", self.num_heads)
         if self.num_heads % self.num_kv_heads:
@@ -121,8 +142,54 @@ class CacheGeometry:
         return -(-self.max_seq_len // self.page_size)
 
     @property
+    def window(self) -> int:
+        """The window layers' window in tokens (0 = no such layer)."""
+        return max(self.windows, default=0)
+
+    @property
+    def full_layers(self) -> tuple:
+        """The model's layers whose K/V the full pool holds, in order."""
+        return tuple(i for i in range(self.num_layers)
+                     if not (self.windows and self.windows[i]))
+
+    @property
+    def window_layers(self) -> tuple:
+        return tuple(i for i, w in enumerate(self.windows) if w)
+
+    @property
+    def window_cols(self) -> int:
+        """Private pages a lane can hold in the window pool at once: the
+        pages a window can meet, and the tail page a step maps before it
+        lets the last one go."""
+        return self.window // self.page_size + 2
+
+    @property
+    def window_pages(self) -> int:
+        """The window pool's capacity (0 without windows): every lane's
+        own pages at their most, and a quarter of the full pool's count
+        for the prompts a prefix cache keeps whole in both pools, never
+        more than the full pool holds (each shared page there has one
+        twin here).  The quarter is the pool's one sizing rule: where it
+        is short the prefix cache drops its oldest prompts and admissions
+        queue, as they do for the full pool; nothing fails."""
+        if not self.windows:
+            return 0
+        return min(self.num_pages,
+                   self.max_slots * self.window_cols + self.num_pages // 4)
+
+    def first_col(self, pos: int) -> int:
+        """The first table column whose page meets the window of a query
+        at ``pos`` (host arithmetic; traced code has ``_first_col``)."""
+        return max(int(pos) - self.window + 1, 0) // self.page_size
+
+    @property
     def pool_shape(self):
-        return (self.num_layers, self.num_pages, self.page_size,
+        return (len(self.full_layers), self.num_pages, self.page_size,
+                self.num_kv_heads, self.head_dim)
+
+    @property
+    def window_pool_shape(self):
+        return (len(self.window_layers), self.window_pages, self.page_size,
                 self.num_kv_heads, self.head_dim)
 
     @property
@@ -136,14 +203,23 @@ class CacheGeometry:
         num_pages * page_bytes()."""
         import numpy as np
 
-        per_tok = (self.num_layers * self.num_kv_heads * self.head_dim
+        per_tok = (len(self.full_layers) * self.num_kv_heads * self.head_dim
                    + self.draft_layers * self.draft_num_heads
                    * self.draft_head_dim)
         return (2 * self.page_size * per_tok
                 * np.dtype(self.dtype).itemsize)
 
+    def window_page_bytes(self) -> int:
+        """Bytes one page of the window pool costs (k+v, window layers)."""
+        import numpy as np
+
+        return (2 * self.page_size * len(self.window_layers)
+                * self.num_kv_heads * self.head_dim
+                * np.dtype(self.dtype).itemsize)
+
     def kv_bytes(self) -> int:
-        return self.num_pages * self.page_bytes()
+        return (self.num_pages * self.page_bytes()
+                + self.window_pages * self.window_page_bytes())
 
     def pages_for(self, n_tokens: int) -> int:
         """Pages an ``n_tokens``-long sequence occupies."""
@@ -173,7 +249,10 @@ def make_state(geom: CacheGeometry):
     ``step`` the denoising steps the block has had.  With
     ``num_experts`` the state counts routed assignments of live lanes'
     rows: ``moe_counts`` [layers, experts] and ``moe_touched`` [layers]
-    (experts with at least one, summed over steps).
+    (experts with at least one, summed over steps).  With ``windows`` the
+    window layers' pool: ``wkp``/``wvp``, ``wtab``, ``wfree_stack``/
+    ``wfree_count`` (``kp``/``vp`` then hold the other layers alone) and
+    ``w_released``, the pages let go behind the window since start.
     """
     S = geom.max_slots
     key_shape = jax.random.PRNGKey(0).shape  # (2,) for threefry
@@ -203,6 +282,18 @@ def make_state(geom: CacheGeometry):
         state["moe_counts"] = jnp.zeros(
             (geom.num_layers, geom.num_experts), jnp.int32)
         state["moe_touched"] = jnp.zeros((geom.num_layers,), jnp.int32)
+    if geom.windows:
+        # the window layers' planes, table and free list, and the pages
+        # the steps have let go behind the window since start
+        state["wkp"] = jnp.zeros(geom.window_pool_shape,
+                                 jnp.dtype(geom.dtype))
+        state["wvp"] = jnp.zeros(geom.window_pool_shape,
+                                 jnp.dtype(geom.dtype))
+        state["wtab"] = jnp.full((S, geom.pages_per_slot), -1, jnp.int32)
+        state["wfree_stack"] = jnp.arange(geom.window_pages,
+                                          dtype=jnp.int32)
+        state["wfree_count"] = jnp.int32(geom.window_pages)
+        state["w_released"] = jnp.int32(0)
     if geom.draft_layers:
         # draft-model KV pool, same page ids as kp/vp: one page-table
         # row addresses both models' cache for a lane
@@ -234,6 +325,22 @@ def state_specs(state, shardings=None):
 # its heads to (or None); ctx [B, C, nh, hd] is the attention output and
 # source' the source with the new rows taken in.  Pytrees, so a source
 # passes through ``jit`` and ``functional_call`` like the arrays it holds.
+
+def _first_col(pos, window, page_size):
+    """The first table column whose page meets ``[pos - window + 1, pos]``
+    (traced ``pos``)."""
+    return jnp.maximum(pos - window + 1, 0) // page_size
+
+
+def _plane(windows, layer):
+    """(window, plane) of model layer ``layer``: the window it sees (0 =
+    everything) and its plane among its pool's layers (its own index
+    where no layer has a window)."""
+    if not windows:
+        return 0, layer
+    w = windows[layer]
+    return w, sum(1 for x in windows[:layer] if bool(x) == bool(w))
+
 
 def _attend(q, keys, values, valid):
     """``fused.masked_attention`` for nh query heads over nkv KV heads: the
@@ -290,10 +397,27 @@ class PagedKV:
     active: Any
     seq_cap: int = field(metadata=dict(static=True))
     limits: Any = None
+    # layers that see a window (CacheGeometry.windows; () = none): their
+    # K/V live in wk_pages/wv_pages [window layers, window_pages, ...],
+    # mapped by wrows, and k_pages/v_pages hold the other layers alone
+    wk_pages: Any = None
+    wv_pages: Any = None
+    wrows: Any = None
+    windows: tuple = field(default=(), metadata=dict(static=True))
 
     def attend(self, layer, q, k, v, head_axis=None):
-        kp, vp, rows, pos = self.k_pages, self.v_pages, self.rows, \
-            self.positions
+        w, layer = _plane(self.windows, layer)
+        if w:
+            ctx, kp, vp = self._attend(layer, q, k, v, head_axis,
+                                       self.wk_pages, self.wv_pages,
+                                       self.wrows, w)
+            return ctx, replace(self, wk_pages=kp, wv_pages=vp)
+        ctx, kp, vp = self._attend(layer, q, k, v, head_axis, self.k_pages,
+                                   self.v_pages, self.rows, 0)
+        return ctx, replace(self, k_pages=kp, v_pages=vp)
+
+    def _attend(self, layer, q, k, v, head_axis, kp, vp, rows, window):
+        pos = self.positions
         B, _, nh, hd = q.shape
         num_pages, ps, nkv = kp.shape[1], kp.shape[2], kp.shape[3]
         lane, active = jnp.arange(B), self.active
@@ -318,12 +442,33 @@ class PagedKV:
         # paddle_pallas_fallbacks_total).  The dense gather below is the
         # reference, the fallback, and the chunk's path (verification is
         # one step per K drafted tokens, off the per-token hot loop).
-        # The kernel reads one KV head a query head; grouped heads take
-        # the gather by design (no kernel for them yet), uncounted.
+        # A pool of KV heads, each read by a group of query heads, and a
+        # layer with a window have a kernel of their own behind the same
+        # call; a chunk of grouped queries (a block step) takes the gather
+        # by design, uncounted.
         ctx = fused.paged_decode_attention(
             q, kp, vp, rows, pos, self.seq_cap, layer,
-            tp_axis=head_axis) if one and nkv == nh else None
-        if ctx is None:
+            tp_axis=head_axis, window=window) if one else None
+        if ctx is None and window:
+            # the gather is as wide as the pages a window can meet (and a
+            # chunk can span), not the table
+            qpos = pos.reshape(B, -1)
+            ncol = min(rows.shape[1],
+                       (window - 2) // ps + 2 + (qpos.shape[1] - 1) // ps + 1)
+            col = _first_col(qpos.min(axis=1), window, ps)[:, None] \
+                + jnp.arange(ncol)[None]
+            ids = jnp.where(col < rows.shape[1], jnp.take_along_axis(
+                rows, jnp.clip(col, 0, rows.shape[1] - 1), axis=1), -1)
+            gidx = jnp.clip(ids, 0, num_pages - 1)
+            kg = kp[layer, gidx].reshape(B, ncol * ps, nkv, hd)
+            vg = vp[layer, gidx].reshape(B, ncol * ps, nkv, hd)
+            kpos = (col[:, :, None] * ps + jnp.arange(ps)).reshape(B, 1, -1)
+            last = qpos if self.limits is None else self.limits
+            valid = (kpos <= last[:, :, None]) \
+                & (kpos > qpos[:, :, None] - window) \
+                & jnp.repeat(ids >= 0, ps, axis=1)[:, None]
+            ctx = _attend(q, kg, vg, valid)
+        elif ctx is None:
             gidx = jnp.clip(rows, 0, num_pages - 1)
             kg = kp[layer, gidx].reshape(B, rows.shape[1] * ps, nkv, hd)
             vg = vp[layer, gidx].reshape(B, rows.shape[1] * ps, nkv, hd)
@@ -332,7 +477,7 @@ class PagedKV:
                 <= last.reshape(B, -1)[:, :, None]
             ctx = _attend(
                 q, kg[:, :self.seq_cap], vg[:, :self.seq_cap], valid)
-        return unwrap(ctx), replace(self, k_pages=kp, v_pages=vp)
+        return unwrap(ctx), kp, vp
 
 
 @jax.tree_util.register_dataclass
@@ -360,6 +505,15 @@ class PrefixKV:
     prefix_len: Any
     suffix: tuple = ()
     block: int = field(default=1, metadata=dict(static=True))
+    # layers that see a window (CacheGeometry.windows; () = none).  The
+    # prefix is then held RIGHT-aligned, its last token in the last row:
+    # prefix_k/prefix_v the other layers' [full layers, C, nkv, hd], and
+    # wprefix_k/wprefix_v the window layers' last window of it [window
+    # layers, window, nkv, hd], so [prefix ++ suffix] is one run of
+    # consecutive positions and every mask is a band of it
+    wprefix_k: Any = None
+    wprefix_v: Any = None
+    windows: tuple = field(default=(), metadata=dict(static=True))
 
     @classmethod
     def gather(cls, k_pages, v_pages, page_ids, prefix_len, block=1):
@@ -371,7 +525,44 @@ class PrefixKV:
                    v_pages[:, gidx].reshape(L, gidx.shape[0] * ps, nkv, hd),
                    jnp.asarray(prefix_len, jnp.int32), block=block)
 
+    @classmethod
+    def gather_windowed(cls, state, page_ids, wpage_ids, n_pages, cols,
+                        windows):
+        """The first ``n_pages`` pages of a prompt, from both pools of a
+        window engine's ``state``: table columns ``[n_pages - cols,
+        n_pages)`` of ``page_ids`` for the layers that see everything, and
+        the last window's worth of ``wpage_ids`` for the others (columns
+        before 0 and unmapped ones gather an arbitrary page the masks
+        hide).  ``cols`` static, ``n_pages`` traced."""
+        ps = state["kp"].shape[2]
+        window = max(windows)
+
+        def right(kp, vp, ids, n):
+            col = n_pages - n + jnp.arange(n)
+            gidx = jnp.clip(ids[jnp.clip(col, 0, ids.shape[0] - 1)], 0,
+                            kp.shape[1] - 1)
+            shape = (kp.shape[0], n * ps) + kp.shape[3:]
+            return kp[:, gidx].reshape(shape), vp[:, gidx].reshape(shape)
+
+        pk, pv = right(state["kp"], state["vp"], page_ids, cols)
+        wk, wv = right(state["wkp"], state["wvp"], wpage_ids, window // ps)
+        return cls(pk, pv, jnp.asarray(n_pages * ps, jnp.int32),
+                   wprefix_k=wk, wprefix_v=wv, windows=tuple(windows))
+
     def attend(self, layer, q, k, v, head_axis=None):
+        if self.windows:
+            w, plane = _plane(self.windows, layer)
+            pk, pv = (self.wprefix_k, self.wprefix_v) if w else \
+                (self.prefix_k, self.prefix_v)
+            C = pk.shape[1]
+            # key c of [prefix ++ suffix] is at position prefix_len - C + c
+            # and suffix token i at prefix_len + i: i sees c <= i + C, at
+            # most a window back, and nothing before position 0
+            ctx = fused.banded_attention(
+                q, jnp.concatenate([pk[plane][None].astype(k.dtype), k], 1),
+                jnp.concatenate([pv[plane][None].astype(v.dtype), v], 1),
+                offset=C, window=w, floor=C - self.prefix_len)
+            return ctx, replace(self, suffix=self.suffix + ((k[0], v[0]),))
         S = q.shape[1]
         pk, pv = self.prefix_k[layer][None], self.prefix_v[layer][None]
         C = pk.shape[1]
@@ -420,7 +611,7 @@ def push_pages(free_stack, free_count, pages):
 # -- traced transitions ------------------------------------------------------
 
 def write_prompt(state, slot, k_new, v_new, length, shared_ids, shared_n,
-                 dk_new=None, dv_new=None):
+                 dk_new=None, dv_new=None, window=None, keep_from=None):
     """Map + fill one admitted request's cache pages.
 
     ``k_new``/``v_new`` ``[layers, Sb, nkv, hd]`` hold prefill K/V for
@@ -439,7 +630,43 @@ def write_prompt(state, slot, k_new, v_new, length, shared_ids, shared_n,
     ``dk_new``/``dv_new`` (speculative engines only): the DRAFT model's
     prefill K/V for the same positions, scattered into ``dkp``/``dvp``
     at the same page ids — the shared table row keeps both pools'
-    extents in lockstep."""
+    extents in lockstep.
+
+    ``window`` (a window engine only): ``(layers, wshared_ids, w_from,
+    w_pin)``.  ``layers`` is the static pair (CacheGeometry.full_layers,
+    .window_layers): ``k_new``/``v_new`` hold every layer of the model and
+    each pool takes its own.  The window pool's row keeps table indices
+    ``[w_from, ceil(length / page_size))`` only: below ``shared_n`` from
+    ``wshared_ids``, the rest popped off the window pool's free stack and
+    written.  What ``wshared_ids`` maps below ``w_from`` is dropped from
+    the row, and pushed back where it is the lane's own (index >=
+    ``w_pin``: pages an earlier chunk of the same prompt wrote, now
+    behind the window).  Returns ``(state, rows [2, pages_per_slot])``,
+    the full pool's row and the window pool's."""
+    if window is not None:
+        layers, wshared_ids, w_from, w_pin = window
+        sub = dict(state, kp=state["wkp"], vp=state["wvp"],
+                   ptab=state["wtab"], free_stack=state["wfree_stack"],
+                   free_count=state["wfree_count"])
+        full_idx, win_idx = (jnp.asarray(i, jnp.int32) for i in layers)
+        j = jnp.arange(state["wtab"].shape[1], dtype=jnp.int32)
+        gone = (j < shared_n) & (j < w_from) & (j >= w_pin) \
+            & (wshared_ids >= 0)
+        sub, wrow = write_prompt(
+            sub, slot, k_new[win_idx], v_new[win_idx], length,
+            jnp.where(j >= w_from, wshared_ids, -1), shared_n,
+            keep_from=jnp.asarray(w_from, jnp.int32))
+        wfree_stack, wfree_count = push_pages(
+            sub["free_stack"], sub["free_count"],
+            jnp.where(gone, wshared_ids, -1))
+        state, row = write_prompt(state, slot, k_new[full_idx],
+                                  v_new[full_idx], length, shared_ids,
+                                  shared_n)
+        state = dict(state, wkp=sub["kp"], wvp=sub["vp"], wtab=sub["ptab"],
+                     wfree_stack=wfree_stack, wfree_count=wfree_count,
+                     w_released=state["w_released"]
+                     + gone.sum(dtype=jnp.int32))
+        return state, jnp.stack([row, wrow])
     kp, vp = state["kp"], state["vp"]
     L, num_pages, ps = kp.shape[0], kp.shape[1], kp.shape[2]
     pps = state["ptab"].shape[1]
@@ -452,6 +679,8 @@ def write_prompt(state, slot, k_new, v_new, length, shared_ids, shared_n,
     n_total = (length + ps - 1) // ps       # traced: pages the prompt needs
     j = jnp.arange(pps, dtype=jnp.int32)
     priv = (j >= shared_n) & (j < n_total)
+    if keep_from is not None:   # the window pool's row starts there
+        priv = priv & (j >= keep_from)
     pages, free_count = take_pages(state["free_stack"],
                                    state["free_count"], priv)
     row = jnp.where(j < shared_n, shared_ids, pages)
@@ -463,6 +692,7 @@ def write_prompt(state, slot, k_new, v_new, length, shared_ids, shared_n,
     pj = shared_n + t
     tgt = jnp.where(pj < n_total,
                     row[jnp.clip(pj, 0, pps - 1)], num_pages)
+    tgt = jnp.where(tgt < 0, num_pages, tgt)    # a column the row dropped
 
     def to_pages(x, n_layers):
         pad = jnp.zeros((n_layers, n_pb * ps) + x.shape[2:], kp.dtype)
@@ -517,21 +747,50 @@ def release_slots(state, mask):
     register) back onto the free stack; shared prefix pages stay
     resident for the prefix cache, returned later via
     ``reclaim_pages`` when their host refcount drops to zero."""
-    ptab = state["ptab"]
-    col = jnp.arange(ptab.shape[1], dtype=jnp.int32)[None, :]
-    freeable = mask[:, None] & (ptab >= 0) & (col >= state["pinned"][:, None])
-    free_stack, free_count = push_pages(
-        state["free_stack"], state["free_count"],
-        jnp.where(freeable, ptab, -1).reshape(-1))
-    ptab = jnp.where(mask[:, None], -1, ptab)
-    return dict(state, ptab=ptab, free_stack=free_stack,
-                free_count=free_count, active=state["active"] & ~mask)
+    out = dict(state, active=state["active"] & ~mask)
+    pools = [("ptab", "free_stack", "free_count")]
+    if "wtab" in state:
+        pools.append(("wtab", "wfree_stack", "wfree_count"))
+    for tab, stack, cnt in pools:
+        ptab = state[tab]
+        col = jnp.arange(ptab.shape[1], dtype=jnp.int32)[None, :]
+        freeable = mask[:, None] & (ptab >= 0) \
+            & (col >= state["pinned"][:, None])
+        out[stack], out[cnt] = push_pages(
+            state[stack], state[cnt],
+            jnp.where(freeable, ptab, -1).reshape(-1))
+        out[tab] = jnp.where(mask[:, None], -1, ptab)
+    return out
 
 
-def reclaim_pages(state, pages):
+def slide_window(state, wtab, wfree_count, pos, active, window):
+    """Let go of what lies wholly behind the window of the lanes' next
+    query (at ``pos``): table columns whose last token is at or before
+    ``pos - window`` leave the row, and the lane's own among them (index
+    >= ``pinned``) go back on the window pool's free stack.  Returns
+    (wtab, wfree_stack, wfree_count, pages pushed)."""
+    ps = state["wkp"].shape[2]
+    col = jnp.arange(wtab.shape[1], dtype=jnp.int32)[None, :]
+    behind = active[:, None] & (wtab >= 0) \
+        & ((col + 1) * ps <= (pos - window + 1)[:, None])
+    own = behind & (col >= state["pinned"][:, None])
+    wfree_stack, wfree_count = push_pages(
+        state["wfree_stack"], wfree_count,
+        jnp.where(own, wtab, -1).reshape(-1))
+    return jnp.where(behind, -1, wtab), wfree_stack, wfree_count, \
+        own.sum(dtype=jnp.int32)
+
+
+def reclaim_pages(state, pages, wpages=None):
     """Return evicted prefix-cache pages (int32, -1-padded) to the free
     stack — the host calls this once a shared page's refcount hits zero
-    (entry evicted AND no slot still reading it)."""
+    (entry evicted AND no slot still reading it).  ``wpages``: the window
+    pool's, for a state that has one."""
     free_stack, free_count = push_pages(
         state["free_stack"], state["free_count"], pages)
-    return dict(state, free_stack=free_stack, free_count=free_count)
+    state = dict(state, free_stack=free_stack, free_count=free_count)
+    if wpages is not None:
+        wfree_stack, wfree_count = push_pages(
+            state["wfree_stack"], state["wfree_count"], wpages)
+        state = dict(state, wfree_stack=wfree_stack, wfree_count=wfree_count)
+    return state

@@ -218,12 +218,28 @@ class GenerationMetrics:
                                              phases sum to its wall time,
                                              `a/b` ran under `a`)
       paddle_genserve_loop_iterations_total  decode-loop iterations
+    and, for an engine whose model has window layers (two page pools,
+    serving/kv_cache.py), from the registers each decode step reports:
+      paddle_genserve_kv_pages_in_use{pool}  pages off the free stack of
+                                             the "full" and the "window"
+                                             pool
+      paddle_genserve_kv_pages_mapped{pool}  page-table entries the live
+                                             lanes hold (shared prefix
+                                             pages among them)
+      paddle_genserve_kv_window_pages_released_total
+                                             window-pool pages let go
+                                             behind a lane's window
+      paddle_genserve_kv_mapped_page_steps_total{pool}
+                                             kv_pages_mapped summed over
+                                             the steps: the mean a step
+                                             is a ratio of two counters
     """
 
     WINDOW_S = 60.0
     RESERVOIR = 4096
 
-    def __init__(self, max_slots: int = 1, num_pages: int = 1):
+    def __init__(self, max_slots: int = 1, num_pages: int = 1,
+                 window_pool: bool = False):
         self.registry = MetricsRegistry()
         self._lock = self.registry._lock
         self.started_at = time.monotonic()
@@ -321,6 +337,27 @@ class GenerationMetrics:
         self._loop_iterations = reg.counter(
             "paddle_genserve_loop_iterations_total",
             "iterations of the decode loop")
+        self._pools = None
+        if window_pool:
+            self._pools = {"in_use": {"full": 0, "window": 0},
+                           "mapped": {"full": 0, "window": 0}}
+            reg.gauge("paddle_genserve_kv_pages_in_use",
+                      "pages off each pool's free stack after the last "
+                      "decode step", fn=lambda: self._pools["in_use"],
+                      label="pool")
+            reg.gauge("paddle_genserve_kv_pages_mapped",
+                      "page-table entries the live lanes hold in each "
+                      "pool after the last decode step (shared prefix "
+                      "pages among them)",
+                      fn=lambda: self._pools["mapped"], label="pool")
+            self._window_released = reg.counter(
+                "paddle_genserve_kv_window_pages_released_total",
+                "window-pool pages the decode steps and prefill chunks "
+                "let go behind a lane's window")
+            self._mapped_steps = reg.counter(
+                "paddle_genserve_kv_mapped_page_steps_total",
+                "kv_pages_mapped summed over the decode steps",
+                label="pool", preset=("full", "window"), fixed=True)
         # the last RESERVOIR samples of the trailing WINDOW_S seconds
         self._ttft = Reservoir(self.RESERVOIR, self._lock, self.WINDOW_S)
         self._gaps = Reservoir(self.RESERVOIR, self._lock, self.WINDOW_S)
@@ -396,6 +433,15 @@ class GenerationMetrics:
         self._block_lane_steps.inc("committed", committed)
         self._block_tokens.inc(emitted)
 
+    def observe_pools(self, in_use, w_in_use, mapped, w_mapped, released):
+        """One decode step of a window engine: its report's registers."""
+        with self._lock:
+            self._pools = {"in_use": {"full": in_use, "window": w_in_use},
+                           "mapped": {"full": mapped, "window": w_mapped}}
+        self._window_released.inc(released - self._window_released.value)
+        self._mapped_steps.inc("full", mapped)
+        self._mapped_steps.inc("window", w_mapped)
+
     def set_compile_count(self, n: int):
         with self._lock:
             self.compile_count = int(n)
@@ -453,6 +499,13 @@ class GenerationMetrics:
                 "empty_steps": self._empty_steps.value,
                 "compile_count": self.compile_count,
                 **{k: v for k, v in sorted(self.counters.items())},
+                **({} if self._pools is None else {
+                    "kv_pages_in_use": dict(self._pools["in_use"]),
+                    "kv_pages_mapped": dict(self._pools["mapped"]),
+                    "kv_window_pages_released":
+                        self._window_released.value,
+                    "kv_mapped_page_steps":
+                        dict(self._mapped_steps.values)}),
             }
 
     def prometheus_text(self) -> str:

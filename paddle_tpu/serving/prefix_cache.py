@@ -32,7 +32,8 @@ __all__ = ["PrefixCache"]
 class PrefixCache:
     """Refcounted read-only shared KV pages keyed by prompt prefix."""
 
-    def __init__(self, page_size: int, capacity: int = 1024):
+    def __init__(self, page_size: int, capacity: int = 1024,
+                 split: int | None = None):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if capacity < 1:
@@ -41,6 +42,11 @@ class PrefixCache:
         self.capacity = int(capacity)
         self._entries = collections.OrderedDict()  # key -> tuple(page ids)
         self._rc: dict[int, int] = {}              # page id -> refcount
+        # an engine with a second pool (window layers, kv_cache.py): its
+        # page w goes by the id split + w, an entry of j pages holds j ids
+        # of each pool, and rows are [2, pages_per_slot]
+        self.split = None if split is None else int(split)
+        self.resident_high = 0                     # resident ids >= split
 
     # -- introspection -----------------------------------------------------
     def __len__(self) -> int:
@@ -60,10 +66,19 @@ class PrefixCache:
     def _key(self, prompt, n_pages: int) -> bytes:
         return prompt[:n_pages * self.page_size].tobytes()
 
+    def ids(self, row, n: int, start: int = 0) -> list:
+        """The ids of table columns ``[start, n)`` of a fetched row (of
+        both pools' rows, where there are two)."""
+        if self.split is None:
+            return [int(p) for p in row[start:n]]
+        return [int(p) for p in row[0][start:n]] \
+            + [self.split + int(p) for p in row[1][start:n]]
+
     def lookup(self, prompt):
         """Longest cached page-aligned prefix of ``prompt`` (np.int32
         1-D).  Returns (n_shared_pages, page_ids tuple) — (0, ()) on a
-        miss.  LRU-touches the hit entry; the caller pins the returned
+        miss (with a second pool the tuple holds the n ids of the first
+        pool, then the n of the second).  LRU-touches the hit entry; the caller pins the returned
         pages before any device work.  Idempotent and side-effect-free
         on a miss: the engine probes the backlog head every loop
         iteration while waiting for pages, so hit/miss METRICS are
@@ -86,10 +101,9 @@ class PrefixCache:
             key = self._key(prompt, j)
             if key in self._entries:
                 continue
-            pages = tuple(int(p) for p in row[:j])
+            pages = tuple(self.ids(row, j))
             self._entries[key] = pages
-            for p in pages:
-                self._rc[p] = self._rc.get(p, 0) + 1
+            self.pin(pages)
             while len(self._entries) > self.capacity:
                 _, old = self._entries.popitem(last=False)
                 reclaim.extend(self._unref(old))
@@ -118,9 +132,13 @@ class PrefixCache:
     def pin(self, pages):
         """A slot started reading ``pages`` (its shared prefix + any
         pages it just registered): hold them resident until unpin."""
+        rc, split = self._rc, self.split
         for p in pages:
             p = int(p)
-            self._rc[p] = self._rc.get(p, 0) + 1
+            n = rc.get(p, 0)
+            rc[p] = n + 1
+            if n == 0 and split is not None and p >= split:
+                self.resident_high += 1
 
     def unpin(self, pages):
         """The slot retired: drop its holds.  Returns pages whose
@@ -134,7 +152,9 @@ class PrefixCache:
             p = int(p)
             n = self._rc.get(p, 0) - 1
             if n <= 0:
-                self._rc.pop(p, None)
+                if self._rc.pop(p, None) is not None \
+                        and self.split is not None and p >= self.split:
+                    self.resident_high -= 1
                 freed.append(p)
             else:
                 self._rc[p] = n

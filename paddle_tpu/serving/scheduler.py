@@ -29,7 +29,8 @@ class SlotScheduler:
     boundaries, ``retire`` on EOS/length, ``sweep`` for mid-decode
     preemption."""
 
-    def __init__(self, max_slots: int, num_pages: int | None = None):
+    def __init__(self, max_slots: int, num_pages: int | None = None,
+                 window_pages: int | None = None):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
         self.max_slots = int(max_slots)
@@ -40,6 +41,12 @@ class SlotScheduler:
         self.num_pages = None if num_pages is None else int(num_pages)
         self._reserved: dict[int, int] = {}       # slot -> pages reserved
         self._shared_resident = 0                 # prefix-cache pages
+        # a second pool (the window layers', kv_cache.py) under the same
+        # discipline: page demands are then pairs (full pool, window pool)
+        self.window_pages = None if window_pages is None \
+            else int(window_pages)
+        self._window_reserved: dict[int, int] = {}
+        self._window_resident = 0
 
     @property
     def free_slots(self) -> int:
@@ -67,24 +74,42 @@ class SlotScheduler:
             return 1 << 30
         return self.num_pages - self.pages_reserved - self._shared_resident
 
-    def set_shared_resident(self, n_pages: int):
+    @property
+    def window_available(self) -> int:
+        """``pages_available`` of the window pool."""
+        return self.window_pages - sum(self._window_reserved.values()) \
+            - self._window_resident
+
+    def set_shared_resident(self, n_pages: int, n_window: int = 0):
         """Pages currently held by the prefix cache (refcount > 0) —
         the engine refreshes this after register/unpin/evict."""
         self._shared_resident = int(n_pages)
+        self._window_resident = int(n_window)
 
-    def can_admit(self, n_pages: int) -> bool:
+    def short_of(self, n_pages) -> int:
+        """Pages a demand exceeds what the pool(s) can promise by (0 when
+        it fits): what eviction has to free."""
+        if self.window_pages is None:
+            return max(0, n_pages - self.pages_available)
+        return max(0, n_pages[0] - self.pages_available) \
+            + max(0, n_pages[1] - self.window_available)
+
+    def can_admit(self, n_pages) -> bool:
         """True when a free slot exists AND the pool can reserve the
         request's worst-case ``n_pages`` — an exhausted pool queues the
         request even with lanes free (admit-and-crash is the failure
-        mode this check exists to prevent)."""
-        return bool(self._free) and n_pages <= self.pages_available
+        mode this check exists to prevent).  With a window pool
+        ``n_pages`` is the pair of demands, and both have to fit."""
+        return bool(self._free) and self.short_of(n_pages) == 0
 
-    def admit(self, request, n_pages: int = 0) -> int:
+    def admit(self, request, n_pages=0) -> int:
         """Claim a free slot for ``request`` and reserve its worst-case
         page demand; raises when full (the engine checks ``can_admit``
         first — a raise is a logic bug)."""
         slot = self._free.pop()
         self._occupants[slot] = request
+        if self.window_pages is not None:
+            n_pages, self._window_reserved[slot] = n_pages
         self._reserved[slot] = int(n_pages)
         return slot
 
@@ -94,6 +119,7 @@ class SlotScheduler:
         req = self._occupants.pop(slot)
         self._free.append(slot)
         self._reserved.pop(slot, None)
+        self._window_reserved.pop(slot, None)
         return req
 
     def prefilling(self) -> int:

@@ -133,16 +133,18 @@ class Counter:
 class Gauge:
     """Instantaneous value; either `set()` explicitly or computed at
     scrape time via `fn` (called with the registry lock held — keep it
-    lock-free or reentrant)."""
+    lock-free or reentrant).  With `label=` (one label key) the value is
+    a dict {label value: number} and each renders as `name{key="value"}`."""
 
     kind = "gauge"
 
-    def __init__(self, name: str, help_: str, lock, fn=None):
+    def __init__(self, name: str, help_: str, lock, fn=None, label=None):
         self.name = name
         self.help = help_
         self._lock = lock
         self.fn = fn
-        self.value = 0
+        self.label = label
+        self.value = {} if label else 0
 
     def set(self, v):
         with self._lock:
@@ -158,9 +160,12 @@ class Gauge:
 
     def render(self) -> list[str]:
         v = self.fn() if self.fn is not None else self.value
-        return [f"# HELP {self.name} {self.help}",
-                f"# TYPE {self.name} gauge",
-                f"{self.name} {_fmt(v)}"]
+        head = [f"# HELP {self.name} {self.help}",
+                f"# TYPE {self.name} gauge"]
+        if self.label:
+            return head + [f'{self.name}{{{self.label}="{k}"}} {_fmt(x)}'
+                           for k, x in v.items()]
+        return head + [f"{self.name} {_fmt(v)}"]
 
 
 class Histogram:
@@ -290,11 +295,12 @@ class MetricsRegistry:
                 self._metrics[name] = m
             return m
 
-    def gauge(self, name: str, help_: str = "", fn=None) -> Gauge:
+    def gauge(self, name: str, help_: str = "", fn=None,
+              label=None) -> Gauge:
         with self._lock:
             m = self._existing(name, "gauge")
             if m is None:
-                m = Gauge(name, help_, self._lock, fn=fn)
+                m = Gauge(name, help_, self._lock, fn=fn, label=label)
                 self._metrics[name] = m
             elif fn is not None:
                 m.fn = fn

@@ -442,6 +442,87 @@ class TestBlockLoop:
         assert seen == ["paddle.genserve/block_step"]
 
 
+    def test_a_block_engine_builds_the_executables_it_always_built(
+            self, served_blocks):
+        """The guard on blockgen's `setup_s`: a model without window layers
+        gets the one pool, the one table and the executables it got before
+        any model had them; its routed assignments are counted as they
+        were."""
+        eng, _ = served_blocks
+
+        built = [eng._block_exec, eng._release_exec, eng._reclaim_exec]
+        for per_bucket in (eng._prefill_execs, eng._insert_execs,
+                           eng._insert_prefix_execs, eng._chunk_execs):
+            built += list(per_bucket.values())
+        assert sorted(_module(e) for e in built) == sorted(
+            ["jit_block_step", "jit_release_step", "jit_reclaim_step"]
+            + ["jit_target_prefill", "jit_insert_step",
+               "jit_insert_prefix_step"] * 2 + ["jit_chunk_step"])
+        assert eng.compile_count == len(built) == 10
+        assert eng.geometry.windows == () and eng.geometry.window_pages == 0
+        assert set(eng._state) == {
+            "kp", "vp", "ptab", "free_stack", "free_count", "pinned", "tok",
+            "pos", "active", "rng", "do_sample", "temp", "top_k", "eos",
+            "stop_pos", "blk", "blk_open", "blk_step", "step", "moe_counts",
+            "moe_touched"}
+        assert "kv_pages_in_use" not in eng.metrics.snapshot()
+        assert "paddle_genserve_kv_pages" not in \
+            eng.metrics.prometheus_text()
+
+
+class TestWindowLoop:
+    """A model with window layers: the decode step keeps its name and its
+    scope, the state gains the window pool's leaves and nothing else."""
+
+    @pytest.fixture(scope="class")
+    def served_windows(self):
+        from paddle_tpu.models.mellum import MellumConfig, MellumForCausalLM
+
+        paddle.seed(0)
+        m = MellumForCausalLM(MellumConfig(
+            vocab_size=211, hidden_size=64, num_layers=4, num_heads=4,
+            num_kv_heads=2, head_dim=16, moe_intermediate_size=32,
+            num_experts=8, num_experts_per_tok=2, max_position_embeddings=64,
+            sliding_window=8))
+        m.eval()
+        eng = GenerationEngine(m, max_slots=3, max_seq_len=40,
+                               prompt_buckets="8,16", page_size=4,
+                               prefix_cache=True, prefill_chunk=8)
+        eng.start()
+        prompt = list(range(50, 62))
+        assert len(eng.generate(prompt, 6, timeout=60)) == 6        # miss
+        assert len(eng.generate(prompt, 6, timeout=60)) == 6        # hit
+        assert len(eng.generate(list(range(60, 74)), 4, timeout=60)) == 4
+        assert eng.drain(timeout=60)
+        return eng
+
+    def test_executables_keep_the_contracts_names(self, served_windows):
+        eng = served_windows
+        assert _module(eng._decode_exec) == "jit_decode_step"
+        assert eng._block_exec is None and eng._spec_exec is None
+        assert {_module(e) for e in eng._prefill_execs.values()} \
+            == {"jit_target_prefill"}
+        assert {_module(e) for e in eng._insert_prefix_execs.values()} \
+            == {"jit_insert_prefix_step"}
+        assert eng.compile_count == 10
+        assert set(eng._state) == {
+            "kp", "vp", "ptab", "free_stack", "free_count", "pinned", "tok",
+            "pos", "active", "rng", "do_sample", "temp", "top_k", "eos",
+            "stop_pos", "moe_counts", "moe_touched", "wkp", "wvp", "wtab",
+            "wfree_stack", "wfree_count", "w_released"}
+        # three window layers and one full layer, each in its own pool
+        assert eng._state["kp"].shape[0] == 1
+        assert eng._state["wkp"].shape[0] == 3
+
+    def test_the_loop_keeps_its_phases(self, served_windows):
+        eng = served_windows
+        assert set(eng.timers.totals) - {"wait"} >= DECODE_TOP - {"wait"}
+        snap = eng.metrics.snapshot()
+        assert snap["prefix_cache_hits"] == 1 and snap["prefill_chunks"] >= 2
+        assert snap["kv_pages_mapped"] == {"full": 0, "window": 0}
+        assert eng.expert_counts()["assignments"].sum() > 0
+
+
 class TestFitLoop:
     def _fit(self):
         """A tiny warm `fit` of two epochs; its loop's wall time is read

@@ -103,6 +103,26 @@ def _paged(q, kp, vp, rows, pos):
                                      interpret=False)
 
 
+# the grouped call as a decode step of `configs/mellum2-12b-a2.5b.json`
+# makes it at 16 slots x 8192 (PERF.md section 4, item 4):
+# 16 lanes of 32 query heads over 4 KV heads of 128, 8192 positions in
+# pages of 64; plane 1 of the 2 full layers' pool (2048 pages), and plane 4
+# of the 6 window layers' pool (800 pages) under a window of 1024
+GQA_SEQ, GQA_NH, GQA_NKV, GQA_HD, GQA_WINDOW = 8192, 32, 4, 128, 1024
+GQA_PAGE = 64
+
+
+def _gqa_case(planes, pages, layer, window):
+    pool = ((planes, pages, GQA_PAGE, GQA_NKV, GQA_HD), BF16)
+
+    def f(q, kp, vp, rows, pos):
+        return pa.paged_gqa_decode_attention(
+            q, kp, vp, rows, pos, GQA_SEQ, layer, window, interpret=False)
+
+    return f, [((SLOTS, GQA_NH, GQA_HD), BF16), pool, pool,
+               ((SLOTS, GQA_SEQ // GQA_PAGE), I32), ((SLOTS,), I32)]
+
+
 # the grouped expert FFN as sdar-30b-a3b.blockgen calls it: 128 experts of
 # 2048 x 768 (gate, up) and 768 x 2048 (down); a block step's 512
 # assignments (16 lanes x 4 positions x 8 experts) and a 768-token
@@ -149,6 +169,8 @@ CASES = {
     "softmax_xent_bf16_v131072_fwd": (_xent, XENT_WIDE),
     "softmax_xent_bf16_v131072_bwd": (_bwd(_xent, 1), XENT_WIDE),
     "paged_decode": (_paged, PAGED_ARGS),
+    "paged_gqa_decode_full": _gqa_case(2, 2048, 1, 0),
+    "paged_gqa_decode_window": _gqa_case(6, 800, 4, GQA_WINDOW),
     "moe_gmm_block_step_512": _gmm_case(512),
     "moe_gmm_prefill_6144": _gmm_case(6144),
 }
@@ -191,6 +213,7 @@ KERNEL_NAMES = {
     "paddle_bias_gelu_fwd": "bias_gelu_fwd",
     "paddle_bias_gelu_bwd": "bias_gelu_bwd",
     "paddle_paged_decode_fwd": "paged_decode",
+    "paddle_paged_gqa_decode_fwd": "paged_gqa_decode_window",
     "paddle_moe_gmm": "moe_gmm_block_step_512",
 }
 
@@ -272,6 +295,22 @@ def test_xent_roofline_patterns_find_the_compiled_calls(v5e):
     calls = _compiled_calls(v5e, "softmax_xent_train_bwd")
     assert len(calls) == 2, calls
     _assert_patterns_find("xent_roofline", {"N": XENT_N, "V": V}, calls)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("case", ["paged_gqa_decode_full",
+                                  "paged_gqa_decode_window"])
+def test_paged_gqa_roofline_pattern_finds_the_compiled_call(v5e, case):
+    """`paged_gqa_decode_roofline` (and `attn_decode_share`) know the
+    grouped paged call by its name and its result [slots, KV heads, group,
+    head]: at the cell's sizes the pattern matches the one call of each
+    compiled case, the full layers' and the window layers'."""
+    calls = _compiled_calls(v5e, case)
+    assert len(calls) == 1, calls
+    _assert_patterns_find(
+        "paged_gqa_decode_roofline",
+        {"SLOTS": SLOTS, "NKV": GQA_NKV, "G": GQA_NH // GQA_NKV,
+         "HD": GQA_HD}, calls)
 
 
 @pytest.mark.kernels

@@ -548,6 +548,92 @@ def test_paged_decode_attention_unmapped_tail(layer):
                                rtol=1e-5, atol=1e-5)
 
 
+def _dense_gqa_ref(q, kp, vp, rows, pos, layer, window):
+    """Plane `layer` gathered densely for nh query heads over nkv KV heads
+    (query head h reads KV head h // g), a lane seeing the keys at
+    (pos - window, pos] (all up to pos without a window)."""
+    slots, nh, hd = q.shape
+    num_pages, ps, nkv = kp.shape[1], kp.shape[2], kp.shape[3]
+    g = nh // nkv
+    gidx = jnp.clip(rows, 0, num_pages - 1)
+    kg = jnp.repeat(kp[layer, gidx].reshape(slots, -1, nkv, hd), g, axis=2)
+    vg = jnp.repeat(vp[layer, gidx].reshape(slots, -1, nkv, hd), g, axis=2)
+    s = jnp.einsum("bnd,bsnd->bns", q, kg) / np.sqrt(hd)
+    tok = jnp.arange(kg.shape[1])[None, :]
+    valid = (tok <= pos[:, None]) & jnp.repeat(rows >= 0, ps, axis=1)
+    if window:
+        valid = valid & (tok > pos[:, None] - window)
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    return jnp.einsum("bns,bsnd->bnd", jax.nn.softmax(s, -1), vg)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("window", [0, 16, 24], ids=lambda w: f"window{w}")
+@pytest.mark.parametrize("g", [8, 1], ids=lambda g: f"g{g}")
+def test_paged_gqa_decode_attention_parity(g, window):
+    """The grouped kernel against the dense gather: g query heads a KV head
+    (8, and 1 where it does the ungrouped kernel's work), with and without
+    a window (one that is whole pages and one that is not), ragged rows:
+    a lane mid-page, one at a page boundary, one shorter than the window,
+    one whose pages behind the window are unmapped as the engine leaves
+    them, and unmapped tails."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_gqa_decode_attention
+
+    rs = np.random.RandomState(80 + g + window)
+    slots, ps, nkv, hd, seq_cap, layer = 5, 8, 2, 16, 48, 1
+    nh = nkv * g
+    q = jnp.asarray(rs.randn(slots, nh, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(3, 20, ps, nkv, hd), jnp.float32)
+    vp = jnp.asarray(rs.randn(3, 20, ps, nkv, hd), jnp.float32)
+    rows = np.array([[2, 5, 11, -1, -1, -1],     # mid-page
+                     [7, 1, 3, 9, 12, 19],       # full table
+                     [4, -1, -1, -1, -1, -1],    # shorter than any window
+                     [6, 8, -1, -1, -1, -1],     # pos at a page boundary
+                     [13, 14, 15, 16, 17, -1]], np.int32)
+    pos = np.array([19, 47, 3, 15, 38], np.int32)
+    if window:
+        # the engine unmaps what lies wholly behind the window
+        behind = (np.arange(6)[None] + 1) * ps <= (pos - window + 1)[:, None]
+        rows = np.where(behind, -1, rows)
+    rows, pos = jnp.asarray(rows), jnp.asarray(pos)
+    ref = _dense_gqa_ref(q, kp, vp, rows, pos, layer, window)
+    out = paged_gqa_decode_attention(q, kp, vp, rows, pos, seq_cap, layer,
+                                     window)
+    # float32 both sides; the kernel's running softmax sums in another order
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    out_j = jax.jit(lambda *a: paged_gqa_decode_attention(
+        *a, seq_cap, layer, window))(q, kp, vp, rows, pos)
+    np.testing.assert_allclose(np.asarray(out_j), np.asarray(out),
+                               rtol=0, atol=0)
+
+
+@pytest.mark.kernels
+def test_paged_gqa_decode_reads_nothing_behind_the_window():
+    """Poison (1e4) in every key and value at or before pos - window, in
+    mapped pages too: the kernel's answer does not move."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_gqa_decode_attention
+
+    rs = np.random.RandomState(3)
+    slots, ps, nkv, g, hd, window = 2, 8, 2, 4, 16, 16
+    q = jnp.asarray(rs.randn(slots, nkv * g, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(1, 8, ps, nkv, hd), jnp.float32)
+    vp = jnp.asarray(rs.randn(1, 8, ps, nkv, hd), jnp.float32)
+    rows = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
+    pos = jnp.asarray([29, 20], jnp.int32)
+    clean = paged_gqa_decode_attention(q, kp, vp, rows, pos, 32, 0, window)
+    tok = jnp.arange(4 * ps).reshape(4, ps)
+    for lane in range(slots):
+        bad = (tok <= pos[lane] - window)[:, :, None, None]
+        ids = rows[lane]
+        kp = kp.at[0, ids].set(jnp.where(bad, 1e4, kp[0, ids]))
+        vp = vp.at[0, ids].set(jnp.where(bad, 1e4, vp[0, ids]))
+    out = paged_gqa_decode_attention(q, kp, vp, rows, pos, 32, 0, window)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
 @pytest.mark.kernels
 def test_paged_decode_attention_refusals():
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
