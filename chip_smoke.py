@@ -39,8 +39,11 @@ PHASES = ("device", "kernels", "train", "serve", "train_mesh")
 # code inside a few seconds on the CPU.
 REAL = dict(
     model={}, batch=8, seq=1024, steps=10, lr=6e-4,
+    # the paged kernel at GPT-2's heads (pages no DMA can cut out of the
+    # pool: the grid walks them) and at heads whose pages the kernel copies
+    # itself in a loop of its own (16 of 128, the chat cell's)
     kernels=dict(B=8, S=1024, NH=12, HD=64, H=768, FFN=3072, V=50304,
-                 slots=16, page=16),
+                 slots=16, page=16, walk_NH=16, walk_HD=128),
     serve=dict(slots=16, max_seq_len=1024, page=16, buckets="128,256,512",
                new_tokens=64, prompt_len=128, long_len=384, prefix_len=256))
 TINY = dict(
@@ -48,7 +51,7 @@ TINY = dict(
                max_position_embeddings=128),
     batch=4, seq=64, steps=4, lr=3e-3,
     kernels=dict(B=1, S=64, NH=2, HD=32, H=128, FFN=256, V=384,
-                 slots=2, page=8),
+                 slots=2, page=8, walk_NH=2, walk_HD=128),
     serve=dict(slots=4, max_seq_len=128, page=8, buckets="16,64",
                new_tokens=8, prompt_len=16, long_len=48, prefix_len=32))
 
@@ -194,9 +197,10 @@ def phase_kernels(args, jax, sizes):
 
     def ref_paged(q, kp, vp, rows, pos):
         q, kp, vp = up(q, kp[PLANE], vp[PLANE])
-        kg = kp[jnp.clip(rows, 0)].reshape(slots, pps * page, NH, HD)
-        vg = vp[jnp.clip(rows, 0)].reshape(slots, pps * page, NH, HD)
-        s = jnp.einsum("bnd,bsnd->bns", q, kg) / math.sqrt(HD)
+        nh, hd = q.shape[1:]
+        kg = kp[jnp.clip(rows, 0)].reshape(slots, pps * page, nh, hd)
+        vg = vp[jnp.clip(rows, 0)].reshape(slots, pps * page, nh, hd)
+        s = jnp.einsum("bnd,bsnd->bns", q, kg) / math.sqrt(hd)
         s = jnp.where((jnp.arange(pps * page)[None] <= pos[:, None])[:, None],
                       s, -1e30)
         return jnp.einsum("bns,bsnd->bnd", jax.nn.softmax(s, -1), vg)
@@ -213,10 +217,12 @@ def phase_kernels(args, jax, sizes):
         used = 1 + rs.randint(pps)
         rows[lane, :used] = perm[lane * pps:lane * pps + used]
         pos[lane] = used * page - 1 - rs.randint(page)
-    paged_args = (rand((slots, NH, HD), bf16),
-                  rand((3, n_pages, page, NH, HD), bf16),
-                  rand((3, n_pages, page, NH, HD), bf16),
-                  jnp.asarray(rows), jnp.asarray(pos))
+    def paged_args(nh, hd):
+        return [rand((slots, nh, hd), bf16),
+                rand((3, n_pages, page, nh, hd), bf16),
+                rand((3, n_pages, page, nh, hd), bf16),
+                jnp.asarray(rows), jnp.asarray(pos)]
+
     labels = jnp.asarray(rs.randint(0, V, (B * S,)), jnp.int32)
 
     # name -> (kernel, reference, args, differentiated args, tolerance as a
@@ -242,7 +248,10 @@ def phase_kernels(args, jax, sizes):
             [rand((B * S, V), bf16), labels], (0,), 2e-2),
         "paged_decode": (
             lambda *a: paged_decode_attention(*a, S, PLANE), ref_paged,
-            list(paged_args), (), 2e-2),
+            paged_args(NH, HD), (), 2e-2),
+        "paged_decode_walk": (
+            lambda *a: paged_decode_attention(*a, S, PLANE), ref_paged,
+            paged_args(k["walk_NH"], k["walk_HD"]), (), 2e-2),
     }
 
     def timed(f, xs):

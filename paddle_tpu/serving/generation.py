@@ -1888,6 +1888,18 @@ class GenerationEngine:
             before = self._flight
             self.metrics.count_step(
                 ahead=before is not None and not before.out[0].is_ready())
+            if spec is None and block is None:
+                # where each lane attends from, of host integers: its
+                # prompt, the tokens it has been handed, and one more if
+                # the step in flight is its own; a lane whose budget is
+                # spent has ended on the device
+                flying = () if before is None else \
+                    {id(r) for _, r in before.lanes}
+                made = [(r, len(r.handle.tokens) + (id(r) in flying))
+                        for _, r in lanes]
+                self.metrics.observe_page_walk(*self.geometry.page_walk(
+                    [len(r.prompt) + n - 1 for r, n in made
+                     if n < r.max_new_tokens]))
             if spec is not None:
                 state, *out = spec(self._params, self._draft_params,
                                    self._state)

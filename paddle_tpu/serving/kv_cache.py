@@ -163,6 +163,25 @@ class CacheGeometry:
         lets the last one go."""
         return self.window // self.page_size + 2
 
+    def page_walk(self, positions) -> tuple[int, int]:
+        """What one decode step's paged attention has to walk, summed
+        over the layers: (the page slots of every lane's every table
+        column, which a grid over the table visits whatever is mapped;
+        the pages that the extents of lanes attending from ``positions``
+        cover: a full layer's from column 0, a window layer's from the
+        first column that meets the window, to the position's)."""
+        ps, cols = self.page_size, self.pages_per_slot
+        n_win = len(self.window_layers)
+        n_full = self.num_layers - n_win
+        win_cols = min(cols, (self.window - 2) // ps + 2) if n_win else 0
+        slots = self.max_slots * (n_full * cols + n_win * win_cols)
+        live = 0
+        for pos in positions:
+            last = min(pos // ps, cols - 1)
+            first = max(pos - self.window + 1, 0) // ps
+            live += n_full * (last + 1) + n_win * max(last - first + 1, 0)
+        return slots, live
+
     @property
     def window_pages(self) -> int:
         """The window pool's capacity (0 without windows): every lane's

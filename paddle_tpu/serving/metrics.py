@@ -211,6 +211,14 @@ class GenerationMetrics:
                                              device (the loop keeps one
                                              step in flight)
       paddle_genserve_empty_steps_total      steps nobody took a result of
+      paddle_genserve_paged_page_slots_total page slots of the decode
+                                             steps' page tables: steps x
+                                             slots x columns, a layer
+      paddle_genserve_paged_pages_live_total of them, pages the live
+                                             lanes' extents cover (their
+                                             ratio is the share of a walk
+                                             over the whole table that is
+                                             work)
       paddle_genserve_loop_seconds_total{phase}
                                              the decode thread's seconds
                                              by phase of its loop (the
@@ -329,6 +337,16 @@ class GenerationMetrics:
             "steps nobody took a result of: every lane armed at the launch "
             "had ended in the step before it (the step ran with no lane "
             "armed) or was swept before the collect")
+        self._page_slots = reg.counter(
+            "paddle_genserve_paged_page_slots_total",
+            "page-table slots of the one-token decode steps launched: "
+            "steps x slots x table columns, summed over the layers (what "
+            "a paged-attention grid over the whole table visits)")
+        self._pages_live = reg.counter(
+            "paddle_genserve_paged_pages_live_total",
+            "of paged_page_slots_total, the pages the live lanes' extents "
+            "cover (what paged attention has to read), from the lanes' "
+            "lengths as the host holds them at the launch")
         self._loop_seconds = reg.counter(
             "paddle_genserve_loop_seconds_total",
             "decode-thread seconds by phase of its loop (top-level "
@@ -421,6 +439,13 @@ class GenerationMetrics:
         if ahead:
             self._steps_ahead.inc()
 
+    def observe_page_walk(self, slots: int, live: int):
+        """One one-token decode step launched: the page slots of its page
+        tables and the pages its live lanes' extents cover
+        (``CacheGeometry.page_walk``)."""
+        self._page_slots.inc(slots)
+        self._pages_live.inc(live)
+
     def count_empty_step(self):
         self._empty_steps.inc()
 
@@ -497,6 +522,8 @@ class GenerationMetrics:
                 "steps": self._steps.value,
                 "steps_launched_ahead": self._steps_ahead.value,
                 "empty_steps": self._empty_steps.value,
+                "paged_page_slots": self._page_slots.value,
+                "paged_pages_live": self._pages_live.value,
                 "compile_count": self.compile_count,
                 **{k: v for k, v in sorted(self.counters.items())},
                 **({} if self._pools is None else {

@@ -77,7 +77,7 @@ def _kernels(ln):
     assert ln["interpret"] is True      # no chip: never "compiled"
     assert set(ln["kernels"]) == {
         "flash_causal", "flash_masked", "layer_norm", "bias_gelu",
-        "softmax_xent", "paged_decode"}
+        "softmax_xent", "paged_decode", "paged_decode_walk"}
     assert all(k["fwd_err"] <= k["tol"] for k in ln["kernels"].values())
 
 

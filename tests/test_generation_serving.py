@@ -745,3 +745,35 @@ class TestStepInFlight:
             assert "paddle_genserve_steps_total 30" in text
         finally:
             eng.stop()
+
+    @pytest.mark.parametrize("page_size", [4, 8])
+    def test_page_walk_counters_follow_the_lanes_lengths(self, model,
+                                                         page_size):
+        """`paged_pages_live / paged_page_slots` is the share of the page
+        tables that the live lanes' extents cover, from the lanes'
+        lengths alone: a lane with a prompt of L tokens attends from L + n
+        - 1 in its n-th decode step, over the pages up to that one."""
+        eng = GenerationEngine(model, max_slots=3, max_seq_len=40,
+                               prompt_buckets="8",
+                               page_size=page_size).start()
+        try:
+            asked = [(PROMPT_A, 20), (PROMPT_B, 9), (PROMPT_A, 2)]
+            hs = [eng.submit(p, m) for p, m in asked]
+            assert [len(h.result(60)) for h in hs] == [m for _, m in asked]
+            assert eng.drain(timeout=60)
+            snap = eng.metrics.snapshot()
+            layers, cols = 2, 40 // page_size
+            # a request's first token is its prefill's: m - 1 decode steps
+            live = sum((len(p) + n - 1) // page_size + 1
+                       for p, m in asked for n in range(1, m))
+            assert snap["paged_pages_live"] == layers * live
+            assert snap["paged_page_slots"] \
+                == snap["steps"] * layers * 3 * cols
+            assert 0 < snap["paged_pages_live"] < snap["paged_page_slots"]
+            text = eng.metrics.prometheus_text()
+            assert "paddle_genserve_paged_page_slots_total " \
+                f"{snap['paged_page_slots']}" in text
+            assert "paddle_genserve_paged_pages_live_total " \
+                f"{snap['paged_pages_live']}" in text
+        finally:
+            eng.stop()

@@ -392,6 +392,68 @@ def test_geometry_without_windows_is_what_it_was():
                       head_dim=8, vocab_size=11, windows=(24, 0))
 
 
+@pytest.mark.parametrize("pos, full, window", [
+    (0, 1, 1),        # one key: one page of each kind
+    (15, 2, 2),       # shorter than the window: both from column 0
+    (16, 3, 3),       # key 0 has left the window, column 0 holds 1..7
+    (23, 3, 2),       # keys 8..23: column 0 is behind the window
+    (40, 6, 3),       # keys 25..40 meet columns 3, 4, 5
+    (63, 8, 2),       # the table's last key: 48..63 are columns 6, 7
+    (200, 8, 0),      # past the table: bounded by its width, and the
+                      # window's first column lies beyond it
+])
+def test_page_walk_counts_a_window_layers_pages_from_the_window(pos, full,
+                                                                window):
+    """`CacheGeometry.page_walk` (the engine's paged_page_slots /
+    paged_pages_live counters): a full layer's extent runs from column 0,
+    a window layer's from the first column that meets the window; the
+    slots are a lane's whole table a full layer and a window's columns a
+    window layer."""
+    geom = CacheGeometry(num_layers=4, max_slots=3, max_seq_len=64,
+                         num_heads=2, head_dim=8, vocab_size=11, page_size=8,
+                         windows=(16, 16, 16, 0))
+    slots, live = geom.page_walk([pos])
+    assert slots == 3 * (1 * 8 + 3 * 3)     # (16 - 2) // 8 + 2 columns
+    assert live == 1 * full + 3 * window
+    assert geom.page_walk([pos, pos]) == (slots, 2 * live)
+    assert geom.page_walk([]) == (slots, 0)
+
+
+@pytest.mark.parametrize("window", [1, 8, 16, 20])
+def test_slide_window_keeps_the_first_column_the_paged_walk_reads(window):
+    """The paged kernel's walk reads a lane as released (zeros) when the
+    first column its window meets is unmapped, whatever lies after it,
+    where the dense gather would attend the later pages: `slide_window`
+    must never let go of that column.  At every position of the table,
+    lanes active and not, a pinned prefix and none: the column of
+    `_first_col(pos)` stays mapped, every column before it is let go, and
+    an inactive lane's row is left alone."""
+    from paddle_tpu.ops.pallas.paged_attention import _first_col
+    from paddle_tpu.serving.kv_cache import slide_window
+
+    ps, cols = 8, 8
+    pos = jnp.arange(ps * cols, dtype=jnp.int32)
+    lanes = pos.shape[0]
+    wtab = jnp.arange(lanes * cols, dtype=jnp.int32).reshape(lanes, cols)
+    state = {"wkp": jnp.zeros((1, lanes * cols, ps, 1, 1)),
+             "pinned": (pos % 3).astype(jnp.int32),
+             "wfree_stack": jnp.full((lanes * cols,), -1, jnp.int32)}
+    active = (pos % 5) != 4
+    out, _, _, pushed = slide_window(state, wtab, jnp.int32(0), pos, active,
+                                     window)
+    out, first = np.asarray(out), np.asarray(_first_col(pos, window, ps))
+    np.testing.assert_array_equal(
+        first, np.maximum(np.asarray(pos) - window + 1, 0) // ps)
+    col = np.arange(cols)[None, :]
+    live = np.asarray(active)[:, None]
+    assert (out[np.arange(lanes), first] >= 0).all()
+    assert (out[live & (col < first[:, None])] == -1).all()
+    assert (out[~live[:, 0]] == np.asarray(wtab)[~live[:, 0]]).all()
+    assert int(pushed) == int((live & (col < first[:, None])
+                               & (col >= np.asarray(state["pinned"])[:, None])
+                               ).sum())
+
+
 def test_expert_counts_and_pool_gauges_are_published(tiny, engine):
     eng = engine
     before = eng.expert_counts()["assignments"].sum()

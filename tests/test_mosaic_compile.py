@@ -1,8 +1,9 @@
 """The Pallas kernels of the main path, compiled by Mosaic for a described
 TPU v5e at GPT-2 124M shapes (B=8, S=1024, 12 heads x 64, H=768, FFN=3072,
-V=50304; 16 slots x 16-token pages), flash attention and the loss at the
-benchmark cells' own sizes (the train cell's B=16; the chat cell's prefill
-buckets).
+V=50304), flash attention and the loss at the benchmark cells' own sizes
+(the train cell's B=16; the chat cell's prefill buckets), and the paged
+decode calls at the chat and repochat cells' (16 slots; pages of 16 keys
+and 16 heads of 128; pages of 64 keys and 4 KV heads of 128).
 
 Nothing runs: the chip is described, not attached (`on-chip-measurement`
 guide, section 2), so a pass says the chip's compiler accepts the kernel
@@ -72,11 +73,18 @@ XENT_F32 = [((2048, 50257), F32), ((2048,), I32)]   # BERT/HF vocab, padded
 # a vocabulary whose narrowest row block is over the kernel's VMEM budget:
 # it runs under a raised scoped limit (softmax_xent.pick_blocks)
 XENT_WIDE = [((2048, 131072), BF16), ((2048,), I32)]
-# the engine's stacked pool, all layers of it: the kernel reads one plane
-POOL_LAYERS, PAGED_LAYER = 12, 5
-POOL = ((POOL_LAYERS, SLOTS * PAGES_PER_SLOT + 1, PAGE, NH, HD), BF16)
-PAGED_ARGS = [((SLOTS, NH, HD), BF16), POOL, POOL,
+# the engine's stacked pool, all layers of it: the kernel reads one plane.
+# The paged call as cerebras-gpt-1.3b.chat's decode step makes it: 16 slots
+# x 1024 in pages of 16, 16 heads of 128, 24 planes; and as GPT-2 124M's
+# makes it (12 planes, 12 heads of 64: pages that are no whole tiles of the
+# pool, which the grid walks: paged_attention._grid_kernel)
+POOL_LAYERS, PAGED_LAYER = 24, 5
+POOL = ((POOL_LAYERS, SLOTS * PAGES_PER_SLOT, PAGE, CHAT_NH, CHAT_HD), BF16)
+PAGED_ARGS = [((SLOTS, CHAT_NH, CHAT_HD), BF16), POOL, POOL,
               ((SLOTS, PAGES_PER_SLOT), I32), ((SLOTS,), I32)]
+GPT2_POOL = ((12, SLOTS * PAGES_PER_SLOT + 1, PAGE, NH, HD), BF16)
+PAGED_GPT2_ARGS = [((SLOTS, NH, HD), BF16), GPT2_POOL, GPT2_POOL,
+                   ((SLOTS, PAGES_PER_SLOT), I32), ((SLOTS,), I32)]
 
 
 def _flash(causal):
@@ -169,6 +177,7 @@ CASES = {
     "softmax_xent_bf16_v131072_fwd": (_xent, XENT_WIDE),
     "softmax_xent_bf16_v131072_bwd": (_bwd(_xent, 1), XENT_WIDE),
     "paged_decode": (_paged, PAGED_ARGS),
+    "paged_decode_gpt2": (_paged, PAGED_GPT2_ARGS),
     "paged_gqa_decode_full": _gqa_case(2, 2048, 1, 0),
     "paged_gqa_decode_window": _gqa_case(6, 800, 4, GQA_WINDOW),
     "moe_gmm_block_step_512": _gmm_case(512),
@@ -311,6 +320,63 @@ def test_paged_gqa_roofline_pattern_finds_the_compiled_call(v5e, case):
         "paged_gqa_decode_roofline",
         {"SLOTS": SLOTS, "NKV": GQA_NKV, "G": GQA_NH // GQA_NKV,
          "HD": GQA_HD}, calls)
+
+
+# what `paged_decode_roofline` (benchmarks/layer_metrics) knows the chat
+# cell's call by on a trace: its result and its first three operands
+@pytest.mark.kernels
+def test_paged_roofline_pattern_finds_the_compiled_call(v5e):
+    calls = _compiled_calls(v5e, "paged_decode")
+    assert len(calls) == 1, calls
+    _assert_patterns_find(
+        "paged_decode_roofline",
+        {"SLOTS": SLOTS, "NH": CHAT_NH, "HD": CHAT_HD}, calls)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("case, kernel, lane", [
+    ("paged_decode", "paddle_paged_decode_fwd", (CHAT_NH, CHAT_HD)),
+    ("paged_decode_gpt2", "paddle_paged_decode_fwd", (NH, HD)),
+    ("paged_gqa_decode_full", "paddle_paged_gqa_decode_fwd",
+     (GQA_NKV, GQA_NH // GQA_NKV, GQA_HD)),
+    ("paged_gqa_decode_window", "paddle_paged_gqa_decode_fwd",
+     (GQA_NKV, GQA_NH // GQA_NKV, GQA_HD)),
+])
+def test_paged_calls_keep_their_operands_and_result(v5e, case, kernel, lane):
+    """The custom call's operands in order (the page table, the positions,
+    the lanes' queries, then the two WHOLE pools and nothing else) and its
+    result, as the benchmark's readers and `tools/trace_ops.py` find
+    them: the walk's buffers and semaphores are scratch, not operands."""
+    import re
+
+    (call,) = _compiled_calls(v5e, case)
+    pool = CASES[case][1][1][0]
+    if not pa._page_is_tiles(jax.ShapeDtypeStruct(pool, BF16)):
+        # the grid's walk takes the planes end to end
+        pool = (pool[0] * pool[1],) + pool[2:]
+    pool = ",".join(map(str, pool))
+    cols = CASES[case][1][3][0][1]
+    q = ",".join(map(str, (SLOTS,) + lane))
+    assert re.match(
+        rf"%{kernel}\S* = bf16\[{q}\]\S* custom-call\("
+        rf"s32\[{SLOTS},{cols}\]\S* %\S+, s32\[{SLOTS}\]\S* %\S+, "
+        rf"bf16\[{q}\]\S* %\S+, bf16\[{pool}\]\S* %\S+, "
+        rf"bf16\[{pool}\]\S* %\S+\)", call), call
+
+
+@pytest.mark.kernels
+def test_paged_call_walks_pages_no_copy_can_cut_out_by_the_grid(v5e):
+    """GPT-2's pages, [16, 12, 64]: a slice of the pool that is no whole
+    tile is refused by Mosaic where a DMA makes it ("must be aligned to
+    tiling"), so from the shapes the call takes the grid's walk there (a
+    page a grid step, through a BlockSpec), which compiles as it did, and
+    the loop inside the kernel where a page is whole tiles."""
+    assert pa._block_pages(jax.ShapeDtypeStruct(*GPT2_POOL), 64, False) == 0
+    assert pa._block_pages(jax.ShapeDtypeStruct(*POOL), 64, False) == 8
+    (call,) = _compiled_calls(v5e, "paged_decode_gpt2")
+    planes, pages, *page = GPT2_POOL[0]
+    pool = ",".join(map(str, [planes * pages] + page))
+    assert call.count(f"bf16[{pool}]") == 2, call
 
 
 @pytest.mark.kernels

@@ -634,6 +634,234 @@ def test_paged_gqa_decode_reads_nothing_behind_the_window():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
 
 
+# -- the walk inside the kernel ----------------------------------------------
+# Both calls walk a lane's pages in blocks of `_block_pages` pages (16 of 8
+# keys here: 128 keys a block, or the table's width where that is less)
+# that the kernel copies itself; `_walk_case` builds a pool whose planes
+# differ, a table and positions, and asks both calls and the dense gather.
+def _walk_case(rows, pos, ps=8, nkv=2, g=1, hd=16, num_pages=None, window=0,
+               layer=1, seed=0, cols=None):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_gqa_decode_attention)
+
+    rows, pos = np.asarray(rows, np.int32), np.asarray(pos, np.int32)
+    rs = np.random.RandomState(seed)
+    num_pages = num_pages or int(rows.max()) + 2
+    q = jnp.asarray(rs.randn(rows.shape[0], nkv * g, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(3, num_pages, ps, nkv, hd), jnp.float32)
+    vp = jnp.asarray(rs.randn(3, num_pages, ps, nkv, hd), jnp.float32)
+    seq_cap = (cols or rows.shape[1]) * ps
+    ref = _dense_gqa_ref(q, kp, vp, jnp.asarray(rows), jnp.asarray(pos),
+                         layer, window)
+    # a lane that sees no key (released, or never armed) reads zero
+    dead = (pos < 0) | (np.take_along_axis(
+        rows, np.clip((np.maximum(pos - window + 1, 0) // ps if window
+                       else 0 * pos)[:, None], 0, rows.shape[1] - 1),
+        axis=1)[:, 0] < 0)
+    ref = jnp.where(jnp.asarray(dead)[:, None, None], 0.0, ref)
+    outs = [paged_gqa_decode_attention(q, kp, vp, rows, pos, seq_cap, layer,
+                                       window)]
+    if g == 1 and not window:
+        outs.append(paged_decode_attention(q, kp, vp, rows, pos, seq_cap,
+                                           layer))
+    return [np.asarray(o) for o in outs], np.asarray(ref)
+
+
+@pytest.fixture(params=["walk", "grid"])
+def page_walk(request, monkeypatch):
+    """Both of the file's walks, interpreted: the loop inside the kernel,
+    and the grid's (`_block_pages` says 0), which off the CPU serves the
+    pools whose page no DMA can cut out (GPT-2's 12 heads of 64)."""
+    if request.param == "grid":
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        monkeypatch.setattr(pa, "_block_pages", lambda *a, **k: 0)
+    return request.param
+
+
+def _table(lengths, cols, ps=8, start=1):
+    """A page table whose lane i maps the pages that hold `lengths[i]`
+    keys (ids counted up from `start`), -1 beyond."""
+    rows, nxt = np.full((len(lengths), cols), -1, np.int32), start
+    for i, n in enumerate(lengths):
+        need = -(-n // ps)
+        rows[i, :need] = np.arange(nxt, nxt + need)
+        nxt += need
+    return rows
+
+
+# a block is 16 pages of 8 keys: positions on a block's first key (128),
+# its last key (127, 255), one past a page boundary (136), and a page's
+# first and last key inside a block
+@pytest.mark.kernels
+@pytest.mark.parametrize("pos", [0, 7, 8, 127, 128, 136, 255, 256, 300])
+def test_paged_walk_position_on_a_block_and_page_edge(pos):
+    outs, ref = _walk_case(_table([pos + 1], 40), [pos], seed=pos)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("g", [1, 4], ids=lambda g: f"g{g}")
+def test_paged_walk_one_page_and_the_full_width_in_one_call(g, page_walk):
+    """A lane one page deep beside a lane that fills the table (three
+    blocks and a tail), a table width (37) that is no multiple of the
+    block (16), and lanes between them."""
+    cols = 37
+    lengths = [3, cols * 8, 8, 129, 17 * 8]
+    outs, ref = _walk_case(_table(lengths, cols), [n - 1 for n in lengths],
+                           g=g, seed=11)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("g", [1, 4], ids=lambda g: f"g{g}")
+def test_paged_walk_released_lanes_read_zero_and_move_nothing(g, page_walk):
+    """Released lanes (`release_slots`: the row all -1, the position
+    stale) and a lane never armed (position -1) beside live ones: zeros,
+    and the live lanes' rows bit-equal to a call without them."""
+    live_rows = _table([200, 9, 140], 32)
+    dead = np.full((1, 32), -1, np.int32)
+    rows = np.concatenate([dead, live_rows[:1], dead, live_rows[1:], dead])
+    pos = np.array([150, 199, 3, 8, 139, -1], np.int32)
+    outs, ref = _walk_case(rows, pos, g=g, seed=5)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+        assert not out[[0, 2, 5]].any()
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_gqa_decode_attention
+    rs = np.random.RandomState(5)
+    q = jnp.asarray(rs.randn(6, 2 * g, 16), jnp.float32)
+    kp = jnp.asarray(rs.randn(3, 60, 8, 2, 16), jnp.float32)
+    vp = jnp.asarray(rs.randn(3, 60, 8, 2, 16), jnp.float32)
+    both = paged_gqa_decode_attention(q, kp, vp, rows, pos, 256, 1)
+    live = np.array([1, 3, 4])
+    only = paged_gqa_decode_attention(q[live], kp, vp, rows[live], pos[live],
+                                      256, 1)
+    np.testing.assert_array_equal(np.asarray(both)[live], np.asarray(only))
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("hole", ["middle", "tail", "block_start"])
+def test_paged_walk_skips_an_unmapped_page_in_the_walked_range(hole,
+                                                               page_walk):
+    """A -1 between mapped pages, at the head of a block, and an unmapped
+    tail before the position: the page is not fetched (its id is no page
+    of the pool) and its keys weigh nothing."""
+    rows = _table([300], 40)
+    if hole == "middle":
+        rows[0, 5] = rows[0, 20] = -1
+    elif hole == "block_start":
+        rows[0, 16] = rows[0, 32] = -1
+    else:
+        rows[0, 30:] = -1
+    outs, ref = _walk_case(rows, [299], seed=9)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("window", [16, 40, 136])
+def test_paged_walk_behind_a_window(window, page_walk):
+    """Window layers: the walk starts at the first column that meets the
+    window; the pages before it are unmapped as the engine leaves them,
+    and one lane keeps them mapped (a shared prefix)."""
+    lengths = [300, 300, 50, 7]
+    rows = _table(lengths, 40)
+    pos = np.array([n - 1 for n in lengths], np.int32)
+    behind = (np.arange(40)[None] + 1) * 8 <= (pos - window + 1)[:, None]
+    behind[1] = False
+    outs, ref = _walk_case(np.where(behind, -1, rows), pos, g=4,
+                           window=window, seed=window)
+    np.testing.assert_allclose(outs[0], ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+def test_paged_walk_lanes_share_their_prefix_pages():
+    """Two lanes map the same prefix pages (the prefix cache) and their
+    own tails; a third maps the prefix alone."""
+    rows = np.full((3, 24), -1, np.int32)
+    rows[:, :18] = np.arange(1, 19)
+    rows[0, 18:21] = [19, 20, 21]
+    rows[1, 18:20] = [22, 23]
+    outs, ref = _walk_case(rows, [165, 153, 143], seed=21)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("g", [1, 4], ids=lambda g: f"g{g}")
+def test_paged_walk_reads_the_pools_last_page_in_a_tail_block(g):
+    """The pool's last page id sits in a tail block whose other columns
+    are past the position: a copy whose source were clamped, or taken
+    from a column past the extent, would read another page and miss the
+    reference."""
+    cols, num_pages = 20, 21
+    rows = np.full((1, cols), -1, np.int32)
+    rows[0, :17] = np.arange(num_pages - 17, num_pages)[::-1]
+    rows[0, 16] = num_pages - 1
+    rows[0, 0] = 3
+    outs, ref = _walk_case(rows, [16 * 8 + 2], g=g, num_pages=num_pages,
+                           seed=4)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+def test_paged_walk_at_gpt2s_heads(page_walk):
+    """GPT-2's pages, [16, 12, 64]: interpreted, the loop inside the
+    kernel takes them; on the chip such a page is no whole tile of the
+    pool and the grid's walk serves (test_mosaic_compile compiles it)."""
+    lengths = [40, 300, 16]
+    outs, ref = _walk_case(_table(lengths, 20, ps=16),
+                           [n - 1 for n in lengths], ps=16, nkv=12, hd=64,
+                           seed=12)
+    for out in outs:
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("shape, cols, pages", [
+    ((16, 16, 128), 64, 8),      # chat: pages of 16 keys, 16 heads of 128
+    ((64, 4, 128), 128, 4),      # repochat's full layers: pages of 64 keys
+    ((64, 4, 128), 17, 4),       # ... and what a window of 1024 can meet
+    ((16, 16, 128), 5, 5),       # no longer than the walk
+    ((128, 4, 128), 64, 2),      # as many as the VMEM budget holds
+    ((256, 4, 128), 32, 1),      # a page of 256 keys is a block
+    ((8, 2, 128), 64, 16),       # small pages: 128 keys
+])
+def test_paged_walk_block_comes_from_the_calls_shapes(shape, cols, pages):
+    from paddle_tpu.ops.pallas.paged_attention import _block_pages
+
+    pool = jax.ShapeDtypeStruct((2, 8) + shape, jnp.bfloat16)
+    assert _block_pages(pool, cols, interpret=False) == pages
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("shape, dtype", [
+    ((16, 12, 64), jnp.bfloat16),     # GPT-2: heads of 64, 12 of them
+    ((16, 12, 128), jnp.float32),     # 12 heads: no whole tiles
+    ((16, 8, 64), jnp.bfloat16),      # heads of 64
+])
+def test_paged_pages_no_copy_can_cut_out_take_the_grid_walk(shape, dtype):
+    """Off the CPU a page that is no whole tiles of the pool is walked by
+    the grid (`_block_pages` 0); interpreted, the loop takes any page."""
+    from paddle_tpu.ops.pallas.paged_attention import _block_pages
+
+    pool = jax.ShapeDtypeStruct((2, 8) + shape, dtype)
+    assert _block_pages(pool, 64, interpret=False) == 0
+    assert _block_pages(pool, 64, interpret=True) >= 1
+
+
+@pytest.mark.kernels
+def test_paged_walk_refuses_pages_over_its_vmem_budget():
+    from paddle_tpu.ops.pallas.paged_attention import _block_pages
+
+    pool = jax.ShapeDtypeStruct((2, 8, 1024, 64, 128), jnp.bfloat16)
+    with pytest.raises(DoesNotTile, match="MiB of VMEM"):
+        _block_pages(pool, 64, interpret=False)
+
+
 @pytest.mark.kernels
 def test_paged_decode_attention_refusals():
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
