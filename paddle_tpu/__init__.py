@@ -12,7 +12,11 @@ Architecture (see SURVEY.md §7):
 """
 from __future__ import annotations
 
-from . import framework
+import time as _time
+
+_IMPORT_BEGAN = _time.perf_counter()    # start-up's scope `import` opens
+
+from . import framework  # noqa: E402
 
 # Persistent XLA compilation cache: compiled executables are reused across
 # PROCESSES, so the second run of the same model skips XLA compilation.
@@ -248,3 +252,8 @@ standard_normal = randn  # noqa: F405 — tensor/random.py alias
 # aliases defined above (fill_constant etc.) at import time
 from . import fluid  # noqa: E402,F401
 from . import dataset  # noqa: E402,F401 — ref python/paddle/dataset/
+
+# start-up's scope `import` closes: first to last line of this file
+from .utils import profiler as _profiler  # noqa: E402
+
+_profiler.startup().stamp("import", _IMPORT_BEGAN)

@@ -62,7 +62,7 @@ from ..framework.transfer import (fetch_floats, host_fetch, in_host_fetch,
                                   shard_batch)
 from ..nn.layer_base import functional_call
 from ..tensor import Tensor
-from ..utils.profiler import StepTimers
+from ..utils.profiler import StepTimers, startup
 
 __all__ = ["TrainEngine", "build_pure_train_step", "host_fetch",
            "in_host_fetch", "fetch_floats", "resolve_mesh", "mesh_meta"]
@@ -699,7 +699,14 @@ class TrainEngine:
         if self._cost_cache is not None \
                 and self._cost_cache_fn is self._step_fn:
             return dict(self._cost_cache)
-        compiled = self.lower_step(inputs, labels).compile()
+        # the step lowered and compiled a second time: start-up's row
+        # `cost_analysis`
+        boot = startup()
+        with boot.executable("cost_analysis"):
+            with boot.scope("cost_analysis/lower"):
+                lowered = self.lower_step(inputs, labels)
+            with boot.scope("cost_analysis/compile"):
+                compiled = lowered.compile()
         ca = compiled.cost_analysis()
         ca = ca[0] if isinstance(ca, (list, tuple)) else (ca or {})
         self._cost_cache = dict(ca) if ca else {}

@@ -14,6 +14,8 @@ works between batches).
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import os
 import time
@@ -29,6 +31,7 @@ from ..io import DataLoader, Dataset
 from ..metric import Metric
 from ..nn.layer_base import Layer, functional_call, state_pytrees
 from ..tensor import Tensor, unwrap
+from ..utils.profiler import StepTimers, startup
 from .engine import (TrainEngine, build_pure_train_step, fetch_floats,
                      host_fetch)
 
@@ -43,6 +46,37 @@ def _to_list(x):
 
 def _as_tensor(x):
     return x if isinstance(x, Tensor) else Tensor(np.asarray(x))
+
+
+def _phase_line(timers, totals0, counts0):
+    """`<phase>_ms=<mean> <phase>_max_ms=<longest>@<count>` for every
+    phase that ran since the snapshot (`totals0`, `counts0`); the
+    longest run is since `timers.maxima` was last cleared."""
+    parts = []
+    for name, total in timers.totals.items():
+        n = timers.counts[name] - counts0.get(name, 0)
+        if n <= 0:
+            continue
+        mean = (total - totals0.get(name, 0.0)) / n * 1e3
+        parts.append(f"{name}_ms={mean:.3f}")
+        if name in timers.maxima:
+            longest, at = timers.maxima[name]
+            parts.append(f"{name}_max_ms={longest * 1e3:.3f}@{at}")
+    return " ".join(parts)
+
+
+def _fit_is_startup(fit):
+    """Start-up's scope `fit` (`utils.profiler.startup()`) opens at
+    `Model.fit`'s entry; `fit` closes it when its first step has been
+    dispatched (`self._fit_startup.close()`), and it is closed here
+    should `fit` end or raise before that."""
+    @functools.wraps(fit)
+    def wrapped(self, *args, **kwargs):
+        with contextlib.ExitStack() as first:
+            first.enter_context(startup().scope("fit"))
+            self._fit_startup = first
+            return fit(self, *args, **kwargs)
+    return wrapped
 
 
 class Model:
@@ -435,6 +469,7 @@ class Model:
         return int(back["meta"]["it"])
 
     # -- loop-level API ----------------------------------------------------
+    @_fit_is_startup
     def fit(self, train_data=None, eval_data=None, batch_size=1, epochs=1,
             eval_freq=1, log_freq=10, save_dir=None, save_freq=1, verbose=2,
             drop_last=False, shuffle=True, num_workers=0, callbacks=None,
@@ -524,15 +559,18 @@ class Model:
         # batch at log_freq boundaries and epoch ends.  The engine
         # begins BEFORE any checkpoint restore so an elastic resume can
         # land the saved state directly on the resolved mesh.
-        from ..utils.profiler import StepTimers
-
         if self._engine is None:
             self._engine = TrainEngine(self)
         engine = self._engine
         _step_fn_before = engine._step_fn
-        engine.begin(mesh=mesh, sharding_rule=sharding_rule, layout=layout,
-                     recompute=recompute, accum_steps=accum_steps,
-                     grad_sync=pod.grad_sync if pod is not None else None)
+        boot = startup()
+        since = boot.mark()
+        with boot.scope("fit/begin"):
+            engine.begin(mesh=mesh, sharding_rule=sharding_rule,
+                         layout=layout, recompute=recompute,
+                         accum_steps=accum_steps,
+                         grad_sync=pod.grad_sync if pod is not None
+                         else None)
         if pod is not None:
             # pod chaos (RANK_KILL/RANK_SLOW/RANK_PARTITION) must fire on
             # the same step boundary whether or not fault tolerance is on
@@ -646,6 +684,7 @@ class Model:
 
         history = {"loss": []}
         it_count = 0
+        first_step = True
         # telemetry step-window bookkeeping: wall time + StepTimers
         # snapshots since the last emitted window
         _win_t0 = time.perf_counter()
@@ -673,6 +712,8 @@ class Model:
                     # back into the device-resident state
                     engine.refresh_from_layers()
                     losses = []
+                    _ep_totals = dict(timers.totals)
+                    _ep_counts = dict(timers.counts)
                 with timers.scope("data"):
                     data_iter = iter(loader)
                 step_i = -1
@@ -745,8 +786,19 @@ class Model:
                             # in-memory rollback point for a mid-step
                             # shrink
                             pod.before_step(engine, it_count)
+                    if first_step:
+                        # the fit's first step traces, lowers and
+                        # compiles (or loads) the step: start-up's row
+                        # `fit/build/step`, and the end of its `fit`
+                        self._fit_startup.enter_context(
+                            boot.executable("fit/build/step"))
                     with timers.scope("dispatch"):
                         outs = engine.step(inputs, labels)
+                    if first_step:
+                        first_step = False
+                        self._fit_startup.close()
+                        if logger.isEnabledFor(logging.INFO):
+                            logger.info("%s", boot.report("fit", since))
                     if pod is not None:
                         # sync point + shrink check: on a mid-step rank
                         # loss the runtime rolls back to its in-memory
@@ -876,6 +928,14 @@ class Model:
                     if _epoch_span is not None:
                         _epoch_span.end(status="ok")
                         _epoch_span = None
+                    # the epoch's phases: the mean of each and its
+                    # longest single run, which names a stalled step
+                    # that a mean hides; the maxima start anew
+                    if logger.isEnabledFor(logging.INFO):
+                        logger.info("fit epoch %d phases: %s", epoch,
+                                    _phase_line(timers, _ep_totals,
+                                                _ep_counts))
+                    timers.maxima.clear()
                 # SIGTERM during epoch-end eval/callbacks must still turn
                 # into a clean preempted exit (not a SIGKILL after the
                 # grace window); a final-epoch latch just finishes the run
@@ -1039,11 +1099,17 @@ class Model:
                    - win_totals.get(name, 0.0),
                    timers.counts.get(name, 0) - win_counts.get(name, 0))
             for name in timers.totals}
+        # a phase's longest run of this epoch, where it fell inside
+        # this window (else an earlier window's line has it)
+        longest = {name: seconds
+                   for name, (seconds, at) in timers.maxima.items()
+                   if at > win_counts.get(name, 0)}
         telem.window(step=it_count, epoch=epoch,
                      steps=it_count - win_it0, wall_s=now - win_t0,
                      batch_size=batch_size,
                      loss=(losses[-1] if losses else None),
-                     lr=self._optimizer.get_lr(), phase_deltas=deltas)
+                     lr=self._optimizer.get_lr(), phase_deltas=deltas,
+                     phase_maxima=longest)
         from ..monitor import flightrec as _flightrec
 
         _flightrec.record(

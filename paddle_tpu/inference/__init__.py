@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from ..framework.dtype import convert_dtype
 from ..tensor import Tensor
+from ..utils import profiler as _profiler
 
 logger = logging.getLogger("paddle_tpu.inference")
 
@@ -288,9 +289,17 @@ class Predictor:
                 def call(params, *xs):
                     return exported.call(*jax.tree.leaves(params), *xs)
 
-                fn = jax.jit(call).lower(self._params, *specs).compile()
+                jitted = jax.jit(call)
             else:
-                fn = self._jitted.lower(self._params, *specs).compile()
+                jitted = self._jitted
+            # start-up's row of this bucket, named by its inputs' shapes
+            boot = _profiler.startup()
+            with boot.executable(boot.under("build/predict." + "_".join(
+                    "x".join(map(str, shape)) for shape, _ in key))):
+                with boot.scope(boot.under("lower")):
+                    lowered = jitted.lower(self._params, *specs)
+                with boot.scope(boot.under("compile")):
+                    fn = lowered.compile()
             self.compile_count += 1
         except Exception as e:  # noqa: BLE001 - bucket cache is an optimization
             logger.debug("bucket compile failed for %s (%s: %s) — using "
@@ -390,6 +399,15 @@ def aot_compile(fn, arg_specs, *, donate_argnums=(), out_shardings=None):
     forwarded to jax.jit, so a donated state argument keeps its
     buffer-reuse contract in the compiled executable.
 
+    The two halves run under start-up's recorder
+    (``utils.profiler.startup()``): ``lower`` (tracing and MLIR) and
+    ``compile`` (XLA, or a load from the persistent cache) under the
+    innermost open start-up scope, which is the executable's row
+    ``build/<name>`` that the caller opened around this call.  The
+    lowering is called from this frame, not through a helper or a
+    lambda: that form cost a model's prompt passes half again their
+    lowering time on the chip machine (PERF.md section 6, PR 36).
+
     Calling the result with a mismatched shape/dtype raises instead of
     recompiling — steady-state serving performs zero XLA compiles, and a
     signature drift is a loud error rather than a silent compile storm.
@@ -404,9 +422,11 @@ def aot_compile(fn, arg_specs, *, donate_argnums=(), out_shardings=None):
     else:
         jitted = jax.jit(fn, donate_argnums=donate_argnums,
                          out_shardings=out_shardings)
-    return jitted.lower(*arg_specs).compile()
-
-
+    boot = _profiler.startup()
+    with boot.scope(boot.under("lower")):
+        lowered = jitted.lower(*arg_specs)
+    with boot.scope(boot.under("compile")):
+        return lowered.compile()
 
 
 def symbolic_input_specs(manifest_shapes, dtypes):

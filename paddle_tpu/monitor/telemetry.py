@@ -500,10 +500,14 @@ class TrainTelemetry:
     # -- window emission (training thread) ---------------------------------
     def window(self, *, step: int, epoch: int, steps: int, wall_s: float,
                batch_size: int, loss=None, lr=None, timers=None,
-               phase_deltas: dict = None, extra: dict = None) -> dict:
+               phase_deltas: dict = None, phase_maxima: dict = None,
+               extra: dict = None) -> dict:
         """Close one step window: update every gauge/histogram and emit
         one JSONL line.  `phase_deltas` is {phase: (d_total_s, d_count)}
-        from StepTimers since the previous window."""
+        from StepTimers since the previous window, `phase_maxima`
+        {phase: seconds} the longest single run of each phase that fell
+        inside the window (`phase_max_ms` beside `phase_ms`, the means:
+        a stalled step shows in the one and not in the other)."""
         steps = max(1, int(steps))
         wall_s = max(1e-9, float(wall_s))
         sps = steps * batch_size / wall_s
@@ -538,6 +542,9 @@ class TrainTelemetry:
                     h.observe(mean_ms)
         if phase_ms:
             rec["phase_ms"] = phase_ms
+        if phase_maxima:
+            rec["phase_max_ms"] = {name: round(seconds * 1e3, 4)
+                                   for name, seconds in phase_maxima.items()}
         if self._flops_per_step:
             rec["flops_per_step"] = self._flops_per_step
         if mem is not None:

@@ -55,6 +55,7 @@ engine output comparable token-for-token with the solo path.
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import queue
 import threading
@@ -67,7 +68,7 @@ from ..framework import flags as _flags
 from ..framework.transfer import host_fetch
 from ..monitor import tracing as _tracing
 from ..utils import chaos
-from ..utils.profiler import RecordEvent, StepTimers
+from ..utils.profiler import StepTimers, startup
 from .engine import (DeadlineExceededError, EngineStoppedError,
                      QueueFullError)
 from .kv_cache import (CacheGeometry, PagedKV, PrefixKV, admit_slot,
@@ -490,6 +491,12 @@ class GenerationEngine:
 
     # -- warmup: build + AOT-compile every executable ----------------------
     def start(self) -> "GenerationEngine":
+        """Everything between construction and the first request, under
+        start-up's scope `genserve` (`utils.profiler.startup()`):
+        `genserve/state` (parameters gathered, pools, tables and
+        registers onto the device, specs built), one
+        `genserve/build/<executable>` each, `genserve/publish` (memory
+        analysis, providers, the decode thread)."""
         if self._started:
             return self
         import jax
@@ -499,657 +506,668 @@ class GenerationEngine:
         from ..nn.layer_base import functional_call, state_pytrees
         from ..tensor import Tensor
 
-        self.model.eval()
-        params, buffers = state_pytrees(self.model)
-        geom = self.geometry
-        V = geom.vocab_size
-        k_max = min(self.max_top_k, V)
-        ps, pps = geom.page_size, geom.pages_per_slot
-        seq_cap = geom.max_seq_len
-        # static prefix extent of the hit-path executables: the largest
-        # full-page prefix any admitted prompt can share
-        pfx_pages = min(pps, -(-self.prompt_buckets[-1] // ps))
-        B = geom.block_length
-        prefix_kw = {"block": B} if B else {}
-        # a window engine: the layers' windows, and each pool's layers
-        W = geom.windows
-        counted = bool(geom.num_experts)
+        boot = startup()
+        since = boot.mark()
+        # the builds stay in this frame, each calling aot_compile itself:
+        # behind a helper of this method, with the lowering under a lambda,
+        # the prompt passes of an 8-layer window model took half again as
+        # long to lower on the chip machine (PERF.md section 6, PR 36)
+        with boot.scope("genserve"), contextlib.ExitStack() as phase:
+            phase.enter_context(boot.scope("genserve/state"))
+            self.model.eval()
+            params, buffers = state_pytrees(self.model)
+            geom = self.geometry
+            V = geom.vocab_size
+            k_max = min(self.max_top_k, V)
+            ps, pps = geom.page_size, geom.pages_per_slot
+            seq_cap = geom.max_seq_len
+            # static prefix extent of the hit-path executables: the largest
+            # full-page prefix any admitted prompt can share
+            pfx_pages = min(pps, -(-self.prompt_buckets[-1] // ps))
+            B = geom.block_length
+            prefix_kw = {"block": B} if B else {}
+            # a window engine: the layers' windows, and each pool's layers
+            W = geom.windows
+            counted = bool(geom.num_experts)
 
-        def window_args(extra, w_pin):
-            """A window engine's trailing admission arguments `extra` (the
-            window pool's shared ids, the first column its row keeps) as
-            (ids for the prefix gather, `write_prompt`'s keywords, what is
-            left of `extra`); its own pages start at `w_pin`."""
-            if not W:
-                return None, {}, extra
-            wshared, w_from = extra
-            return wshared, {"window": (
-                (geom.full_layers, geom.window_layers), wshared, w_from,
-                w_pin)}, ()
+            def window_args(extra, w_pin):
+                """A window engine's trailing admission arguments `extra` (the
+                window pool's shared ids, the first column its row keeps) as
+                (ids for the prefix gather, `write_prompt`'s keywords, what is
+                left of `extra`); its own pages start at `w_pin`."""
+                if not W:
+                    return None, {}, extra
+                wshared, w_from = extra
+                return wshared, {"window": (
+                    (geom.full_layers, geom.window_layers), wshared, w_from,
+                    w_pin)}, ()
 
-        # sharding plan: None entries (no mesh) keep today's lowering
-        mesh, layout = self._mesh, self._layout
-        if mesh is not None:
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
+            # sharding plan: None entries (no mesh) keep today's lowering
+            mesh, layout = self._mesh, self._layout
+            if mesh is not None:
+                from jax.sharding import NamedSharding
+                from jax.sharding import PartitionSpec as P
 
-            rep = NamedSharding(mesh, P())
-            pool_sh = NamedSharding(mesh, layout.prune(
-                layout.kv_page_spec(), geom.pool_shape, mesh))
-            kv_sh = NamedSharding(mesh, layout.prune(
-                P(None, None, layout.tp_axis, None),
-                (geom.num_layers, 1, geom.num_heads, geom.head_dim), mesh))
-            pspecs = layout.resolve(
-                {n: np.shape(a) for n, a in params.items()}, mesh,
-                warn=False)
-            params = {n: jax.device_put(a, NamedSharding(mesh, pspecs[n]))
-                      for n, a in params.items()}
-            buffers = {n: jax.device_put(a, rep)
-                       for n, a in buffers.items()}
-        else:
-            rep = pool_sh = kv_sh = None
-        self._params, self._buffers = params, buffers
-        draft = self.draft_model
-        K = self.spec_tokens
-        if draft is not None:
-            draft.eval()
-            dparams, dbuffers = state_pytrees(draft)
-            self._draft_params, self._draft_buffers = dparams, dbuffers
-        else:
-            dparams = dbuffers = None
-
-        def sample_token(lg, key, do_sample, temp, top_k):
-            """Per-lane sampling, chain-compatible with generate():
-            greedy = argmax of raw logits; sampling = temperature scale,
-            static-width top-k cutoff (dynamic k), categorical over the
-            [1, V] row exactly as the solo path draws it."""
-            greedy = jnp.argmax(lg).astype(jnp.int32)
-            lg2 = lg / jnp.maximum(temp, 1e-6)
-            vals = jax.lax.top_k(lg2, k_max)[0]
-            kth = vals[jnp.clip(top_k - 1, 0, k_max - 1)]
-            lg3 = jnp.where((top_k > 0) & (lg2 < kth),
-                            jnp.finfo(lg2.dtype).min, lg2)
-            samp = jax.random.categorical(
-                key, lg3[None, :])[0].astype(jnp.int32)
-            return jnp.where(do_sample, samp, greedy)
-
-        def resume_chain(seed, resume_pos):
-            """Mid-stream failover (router re-admission): fast-forward
-            the per-request PRNG chain past the ``resume_pos`` tokens a
-            dead replica already emitted.  The chain is k_0=PRNGKey(seed)
-            with (k_i, s_i)=split(k_{i-1}) and token i drawn from s_i, so
-            after the fast-forward the admission split below yields
-            exactly (k_{P+1}, s_{P+1}) — the first resumed sample is the
-            token the uninterrupted run would have drawn next, and the
-            chain state is identical thereafter.  resume_pos=0 is the
-            normal (non-resumed) admission, bitwise today's behavior."""
-            key = jax.random.PRNGKey(seed)
-            return jax.lax.fori_loop(
-                0, resume_pos, lambda _, k: jax.random.split(k)[0], key)
-
-        model, geometry = self.model, geom
-
-        def target_prefill(params, ids, length):
-            out, _ = functional_call(
-                model, params, (Tensor(ids), length), buffers=buffers,
-                mutable=False, method="slot_prefill")
-            if B:
-                # a block engine samples nothing at admission: the
-                # logits go, and the compiler drops the head with them
-                return out[0], out[1], jnp.zeros((1,), out[2].dtype)
-            return out                     # (k [L,Sp,nkv,hd], v, logits [V])
-
-        if draft is None:
-            prefill_step = target_prefill
-        else:
-            def prefill_step(params, dparams, ids, length):
-                # one executable fills BOTH pools: the draft's KV must
-                # cover the prompt so its proposal chain can attend it
-                k, v, lg = target_prefill(params, ids, length)
-                (dk, dv, _), _ = functional_call(
-                    draft, dparams, (Tensor(ids), length),
-                    buffers=dbuffers, mutable=False,
-                    method="slot_prefill")
-                return k, v, lg, dk, dv
-
-        def arm(state, slot, logits, length, seed, resume_pos, do_sample,
-                temp, top_k, stop_pos, eos, pinned, opening=(), active=True):
-            """Arm lane ``slot`` after its prompt is in the pool: sample
-            the first token from ``logits``; or, for a block engine, open
-            the first block: ``opening`` = (the prompt's last L mod B
-            tokens padded to B, how many they are), known from the start,
-            the rest masked.  Returns (state, first token)."""
-            key, sub = jax.random.split(resume_chain(seed, resume_pos))
-            if not B:
-                tok1 = sample_token(logits, sub, do_sample, temp, top_k)
-                return admit_slot(state, slot, tok1, length, key, do_sample,
-                                  temp, top_k, stop_pos, eos, pinned,
-                                  active), tok1
-            tail, n_known = opening
-            state = admit_slot(state, slot, 0, length // B * B, key,
-                               do_sample, temp, top_k, stop_pos, eos, pinned,
-                               active)
-            known = jnp.arange(B, dtype=jnp.int32) < n_known
-            return dict(
-                state,
-                blk=state["blk"].at[slot].set(
-                    jnp.where(known, tail, mask_id)),
-                blk_open=state["blk_open"].at[slot].set(~known),
-                blk_step=state["blk_step"].at[slot].set(-1),
-                step=state["step"].at[slot].set(0)), jnp.int32(0)
-
-        def insert_step(state, slot, k_new, v_new, logits, length, seed,
-                        resume_pos, do_sample, temp, top_k, stop_pos, eos,
-                        pinned, *extra):
-            # prefix-miss admission: every mapped page is freshly
-            # allocated and written (shared_n = 0).  `extra` is the
-            # draft's K/V (speculative) or the opening block (blocks)
-            no_shared = jnp.full((pps,), -1, jnp.int32)
-            if W:       # extra: the first column the window row keeps
-                _, win, extra = window_args((no_shared,) + extra,
-                                            jnp.int32(0))
+                rep = NamedSharding(mesh, P())
+                pool_sh = NamedSharding(mesh, layout.prune(
+                    layout.kv_page_spec(), geom.pool_shape, mesh))
+                kv_sh = NamedSharding(mesh, layout.prune(
+                    P(None, None, layout.tp_axis, None),
+                    (geom.num_layers, 1, geom.num_heads, geom.head_dim), mesh))
+                pspecs = layout.resolve(
+                    {n: np.shape(a) for n, a in params.items()}, mesh,
+                    warn=False)
+                params = {n: jax.device_put(a, NamedSharding(mesh, pspecs[n]))
+                          for n, a in params.items()}
+                buffers = {n: jax.device_put(a, rep)
+                           for n, a in buffers.items()}
             else:
-                win = {}
-            draft_kv, opening = ((), extra) if B else (extra, ())
-            state, row = write_prompt(state, slot, k_new, v_new, length,
-                                      no_shared, jnp.int32(0), *draft_kv,
-                                      **win)
-            state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
-                              do_sample, temp, top_k, stop_pos, eos, pinned,
-                              opening)
-            return state, tok1, row
+                rep = pool_sh = kv_sh = None
+            self._params, self._buffers = params, buffers
+            draft = self.draft_model
+            K = self.spec_tokens
+            if draft is not None:
+                draft.eval()
+                dparams, dbuffers = state_pytrees(draft)
+                self._draft_params, self._draft_buffers = dparams, dbuffers
+            else:
+                dparams = dbuffers = None
 
-        def suffix_prefill(params, dparams, state, ids, shared_ids,
-                           shared_n, length, wshared_ids=None):
-            # prefill ONLY the suffix, attending over the prefix already
-            # resident in the pool(s) — shared by the prefix-hit admission
-            # path and every prefill chunk.  The suffix tokens sit at
-            # prefix_len + i; only the last real one's logits are wanted.
-            prefix_len = shared_n * ps
-            positions = (prefix_len
-                         + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
-            last = jnp.asarray(length, jnp.int32) - prefix_len - 1
+            def sample_token(lg, key, do_sample, temp, top_k):
+                """Per-lane sampling, chain-compatible with generate():
+                greedy = argmax of raw logits; sampling = temperature scale,
+                static-width top-k cutoff (dynamic k), categorical over the
+                [1, V] row exactly as the solo path draws it."""
+                greedy = jnp.argmax(lg).astype(jnp.int32)
+                lg2 = lg / jnp.maximum(temp, 1e-6)
+                vals = jax.lax.top_k(lg2, k_max)[0]
+                kth = vals[jnp.clip(top_k - 1, 0, k_max - 1)]
+                lg3 = jnp.where((top_k > 0) & (lg2 < kth),
+                                jnp.finfo(lg2.dtype).min, lg2)
+                samp = jax.random.categorical(
+                    key, lg3[None, :])[0].astype(jnp.int32)
+                return jnp.where(do_sample, samp, greedy)
 
-            def suffix(m, p, b, k_pool, v_pool):
-                prefix = PrefixKV.gather_windowed(
-                    state, shared_ids, wshared_ids, shared_n, pfx_pages,
-                    W) if W else PrefixKV.gather(
-                        k_pool, v_pool, shared_ids[:pfx_pages], prefix_len,
-                        **prefix_kw)
-                (lg, kv), _ = functional_call(
-                    m, p, (ids, positions, prefix, last),
-                    buffers=b, mutable=False, method="slot_step")
-                return kv.suffix_kv(), lg[0, 0]
+            def resume_chain(seed, resume_pos):
+                """Mid-stream failover (router re-admission): fast-forward
+                the per-request PRNG chain past the ``resume_pos`` tokens a
+                dead replica already emitted.  The chain is k_0=PRNGKey(seed)
+                with (k_i, s_i)=split(k_{i-1}) and token i drawn from s_i, so
+                after the fast-forward the admission split below yields
+                exactly (k_{P+1}, s_{P+1}) — the first resumed sample is the
+                token the uninterrupted run would have drawn next, and the
+                chain state is identical thereafter.  resume_pos=0 is the
+                normal (non-resumed) admission, bitwise today's behavior."""
+                key = jax.random.PRNGKey(seed)
+                return jax.lax.fori_loop(
+                    0, resume_pos, lambda _, k: jax.random.split(k)[0], key)
 
-            (k_suf, v_suf), logits = suffix(model, params, buffers,
-                                            state["kp"], state["vp"])
+            model, geometry = self.model, geom
+
+            def target_prefill(params, ids, length):
+                out, _ = functional_call(
+                    model, params, (Tensor(ids), length), buffers=buffers,
+                    mutable=False, method="slot_prefill")
+                if B:
+                    # a block engine samples nothing at admission: the
+                    # logits go, and the compiler drops the head with them
+                    return out[0], out[1], jnp.zeros((1,), out[2].dtype)
+                return out                     # (k [L,Sp,nkv,hd], v, logits [V])
+
             if draft is None:
-                return k_suf, v_suf, logits, ()
-            draft_kv, _ = suffix(draft, dparams, dbuffers,
-                                 state["dkp"], state["dvp"])
-            return k_suf, v_suf, logits, draft_kv
-
-        def _insert_prefix(params, dparams, state, slot, ids, shared_ids,
-                           shared_n, length, seed, resume_pos, do_sample,
-                           temp, top_k, stop_pos, eos, pinned, *opening):
-            # prefix-hit admission: the shared pages are never
-            # recomputed; the suffix pages in at the (page-aligned)
-            # boundary
-            # a window engine's own pages start where the shared ones end
-            wshared, win, opening = window_args(opening, shared_n)
-            k_suf, v_suf, logits, draft_kv = suffix_prefill(
-                params, dparams, state, ids, shared_ids, shared_n,
-                length, wshared)
-            state, row = write_prompt(state, slot, k_suf, v_suf, length,
-                                      shared_ids, shared_n, *draft_kv,
-                                      **win)
-            state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
-                              do_sample, temp, top_k, stop_pos, eos, pinned,
-                              opening)
-            return state, tok1, row
-
-        if draft is None:
-            def insert_prefix_step(params, state, *a):
-                return _insert_prefix(params, None, state, *a)
-        else:
-            insert_prefix_step = _insert_prefix
-
-        def _chunk(params, dparams, state, slot, ids, shared_ids,
-                   shared_n, length, seed, resume_pos, do_sample, temp,
-                   top_k, stop_pos, eos, pin_now, pin_final, arm_now,
-                   *opening):
-            # one prefill chunk: scatter this slice's K/V behind the
-            # resumable cursor; ONLY the final chunk (arm_now) samples
-            # a real first token and activates the lane.  Until then
-            # ``pinned`` stays at the prefix-cache hit count (pin_now)
-            # so a cancel/deadline sweep frees every privately written
-            # chunk page — the stale-pinned leak this executable exists
-            # to prevent; the final chunk raises it to pin_final to
-            # protect the pages about to be registered as shared.
-            # a window engine: what an earlier chunk wrote (index >=
-            # pin_now) and the window has passed goes back
-            wshared, win, opening = window_args(opening, pin_now)
-            k_suf, v_suf, logits, draft_kv = suffix_prefill(
-                params, dparams, state, ids, shared_ids, shared_n,
-                length, wshared)
-            state, row = write_prompt(state, slot, k_suf, v_suf, length,
-                                      shared_ids, shared_n, *draft_kv,
-                                      **win)
-            pinned = jnp.where(jnp.asarray(arm_now, bool), pin_final,
-                               pin_now)
-            state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
-                              do_sample, temp, top_k, stop_pos, eos, pinned,
-                              opening, active=arm_now)
-            return state, tok1, row
-
-        if draft is None:
-            def chunk_step(params, state, *a):
-                return _chunk(params, None, state, *a)
-        else:
-            chunk_step = _chunk
-
-        def decode_step(params, state):
-            lane = jnp.arange(geometry.max_slots)
-            pos, active = state["pos"], state["active"]
-            ptab = state["ptab"]
-            # (1) pop a fresh tail page for lanes whose write position
-            # crossed into an unmapped page — in-graph allocation off
-            # the free-list register (host reserved the worst case)
-            pidx = jnp.clip(pos // ps, 0, pps - 1)
-            cur = ptab[lane, pidx]
-            need = active & (cur < 0)
-            pages, free_count = take_pages(state["free_stack"],
-                                           state["free_count"], need)
-            ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
-            win = {}
-            if W:       # the window pool's tail page, off its own stack
-                wtab = state["wtab"]
-                wcur = wtab[lane, pidx]
-                wneed = active & (wcur < 0)
-                wpages, wfree_count = take_pages(
-                    state["wfree_stack"], state["wfree_count"], wneed)
-                wtab = wtab.at[lane, pidx].set(
-                    jnp.where(wneed, wpages, wcur))
-                win = dict(wk_pages=state["wkp"], wv_pages=state["wvp"],
-                           wrows=wtab, windows=W)
-            # (2) one paged-attention token per lane
-            out, _ = functional_call(
-                model, params,
-                (state["tok"][:, None], pos[:, None],
-                 PagedKV(state["kp"], state["vp"], ptab, pos, active,
-                         seq_cap, **win)),
-                dict(live=active) if counted else {},
-                buffers=buffers, mutable=False, method="slot_step")
-            logits, kv = out[0], out[1]
-            logits, kp, vp = logits[:, 0], kv.k_pages, kv.v_pages
-            pair = jax.vmap(jax.random.split)(state["rng"])
-            new_keys, subs = pair[:, 0], pair[:, 1]
-            toks = jax.vmap(sample_token)(
-                logits, subs, state["do_sample"], state["temp"],
-                state["top_k"])
-            toks = jnp.where(active, toks, state["tok"])
-            new_pos = jnp.where(active, pos + 1, pos)
-            finished = active & ((toks == state["eos"])
-                                 | (new_pos + 1 >= state["stop_pos"]))
-            # (3) retire in-graph: finished lanes' PRIVATE pages (table
-            # index >= pinned) go back on the free stack; shared prefix
-            # pages stay resident for the prefix cache
-            col = jnp.arange(pps, dtype=jnp.int32)[None, :]
-            freeable = finished[:, None] & (ptab >= 0) \
-                & (col >= state["pinned"][:, None])
-            free_stack, free_count = push_pages(
-                state["free_stack"], free_count,
-                jnp.where(freeable, ptab, -1).reshape(-1))
-            ptab = jnp.where(finished[:, None], -1, ptab)
-            new_state = dict(state, kp=kp, vp=vp, ptab=ptab,
-                             free_stack=free_stack, free_count=free_count,
-                             tok=toks, pos=new_pos, rng=new_keys,
-                             active=active & ~finished)
-            report = (toks, finished)
-            if W:
-                # (4) the window pool: what lies wholly behind the next
-                # query's window leaves the row (the lane's own pages go
-                # back on the stack), then retirement as above.  The
-                # step's report gains the pools' registers: pages off
-                # each free stack, table entries the live lanes hold
-                # after the step, pages let go behind the window so far
-                live = active & ~finished
-                wtab, wfree_stack, wfree_count, gone = slide_window(
-                    state, wtab, wfree_count, new_pos, live, geom.window)
-                wfree_stack, wfree_count = push_pages(
-                    wfree_stack, wfree_count, jnp.where(
-                        finished[:, None] & (wtab >= 0)
-                        & (col >= state["pinned"][:, None]),
-                        wtab, -1).reshape(-1))
-                new_state.update(
-                    wkp=kv.wk_pages, wvp=kv.wv_pages,
-                    wtab=jnp.where(finished[:, None], -1, wtab),
-                    wfree_stack=wfree_stack, wfree_count=wfree_count,
-                    w_released=state["w_released"] + gone)
-                report += (jnp.stack([
-                    geom.num_pages - new_state["free_count"],
-                    geom.window_pages - new_state["wfree_count"],
-                    (live[:, None] & (new_state["ptab"] >= 0)).sum(
-                        dtype=jnp.int32),
-                    (live[:, None] & (new_state["wtab"] >= 0)).sum(
-                        dtype=jnp.int32),
-                    new_state["w_released"]]),)
-            if counted:
-                per, touched = out[2]
-                new_state["moe_counts"] = state["moe_counts"] + per
-                new_state["moe_touched"] = state["moe_touched"] + touched
-                # copies of their own, as block_step's
-                report += ((new_state["moe_counts"] + 0,
-                            new_state["moe_touched"] + 0),)
-            return (new_state,) + report
-
-        def spec_step(params, dparams, state):
-            """ONE speculative iteration: the draft model chains K
-            greedy proposals, the target scores the committed token +
-            all K proposals in one batched verify step, and each greedy
-            lane emits the longest agreeing run + the target's first
-            divergent token (1..K+1 tokens).  Sampling lanes ride the
-            same executable emitting exactly one token from the verify
-            chunk's position-0 logits with the unchanged per-lane PRNG
-            chain — bitwise the non-speculative distribution.
-
-            Rejected proposals need no rollback: their pages stay
-            mapped inside the lane's reservation and the next
-            iteration's chain/verify scatter overwrites the dead K/V at
-            those positions before any emitted query can attend it.
-            """
-            lane = jnp.arange(geometry.max_slots)
-            pos, active = state["pos"], state["active"]
-            stop_pos = state["stop_pos"]
-            greedy_lane = ~state["do_sample"]
-            ptab = state["ptab"]
-            # (1) map every page covering [pos, hi] in one take — the
-            # speculation window never writes past the slot's reserved
-            # extent (positions clamp at stop_pos - 1)
-            hi = jnp.minimum(pos + K, stop_pos - 1)
-            col = jnp.arange(pps, dtype=jnp.int32)[None, :]
-            need = active[:, None] & (ptab < 0) \
-                & (col >= (pos // ps)[:, None]) \
-                & (col <= (hi // ps)[:, None])
-            pages, free_count = take_pages(
-                state["free_stack"], state["free_count"],
-                need.reshape(-1))
-            ptab = jnp.where(need, pages.reshape(ptab.shape), ptab)
-            # (2) draft chain: K+1 sequential one-token steps.  Step i
-            # writes chain token c_i's draft K/V at pos+i and (i < K)
-            # proposes c_{i+1} = argmax; step K only closes the draft
-            # cache for a fully accepted run (its logits are discarded).
-            dkv = PagedKV(state["dkp"], state["dvp"], ptab, pos, active,
-                          seq_cap)
-            t = state["tok"]
-            chain = [t]
-            for i in range(K + 1):
-                p_i = jnp.minimum(pos + i, stop_pos - 1)
-                (dlg, dkv), _ = functional_call(
-                    draft, dparams,
-                    (t[:, None], p_i[:, None], replace(dkv, positions=p_i)),
-                    buffers=dbuffers, mutable=False, method="slot_step")
-                if i < K:
-                    t = jnp.argmax(dlg[:, 0], axis=-1).astype(jnp.int32)
-                    chain.append(t)
-            tokens = jnp.stack(chain, axis=1)        # [slots, K+1]
-            # (3) target verification: score all K+1 candidates at once
-            P = jnp.minimum(
-                pos[:, None] + jnp.arange(K + 1, dtype=jnp.int32)[None],
-                (stop_pos - 1)[:, None])
-            (logits, kv), _ = functional_call(
-                model, params,
-                (tokens, P,
-                 PagedKV(state["kp"], state["vp"], ptab, P, active,
-                         seq_cap)),
-                buffers=buffers, mutable=False, method="slot_step")
-            # (4) accept/emit: outs[:, i] is what the target generates
-            # after consuming c_0..c_i; position 0 goes through the
-            # full sampling path (== argmax for greedy lanes) so the
-            # PRNG chain advances exactly once per iteration
-            pair = jax.vmap(jax.random.split)(state["rng"])
-            new_keys, subs = pair[:, 0], pair[:, 1]
-            outs = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out0 = jax.vmap(sample_token)(
-                logits[:, 0], subs, state["do_sample"], state["temp"],
-                state["top_k"])
-            outs = outs.at[:, 0].set(out0)
-            # emitted_i: outs[:, i] is produced this iteration — needs
-            # the previous emission alive (not finished) and draft
-            # proposal c_i to match what the target just generated;
-            # fin_i mirrors the non-speculative stop arithmetic for the
-            # equivalent iteration at write position pos + i
-            em = active
-            emitted, fins = [], []
-            for i in range(K + 1):
-                if i > 0:
-                    em = em & ~fins[i - 1] & greedy_lane \
-                        & (tokens[:, i] == outs[:, i - 1])
-                fin = (outs[:, i] == state["eos"]) \
-                    | (pos + i + 2 >= stop_pos)
-                emitted.append(em)
-                fins.append(fin)
-            emitted = jnp.stack(emitted, axis=1)     # [slots, K+1]
-            fins = jnp.stack(fins, axis=1)
-            n_emit = emitted.sum(axis=1).astype(jnp.int32)
-            new_tok = outs[lane, jnp.maximum(n_emit - 1, 0)]
-            new_tok = jnp.where(active, new_tok, state["tok"])
-            new_pos = jnp.where(active, pos + n_emit, pos)
-            finished = active & (emitted & fins).any(axis=1)
-            # (5) retire in-graph, same as the plain decode step
-            freeable = finished[:, None] & (ptab >= 0) \
-                & (col >= state["pinned"][:, None])
-            free_stack, free_count = push_pages(
-                state["free_stack"], free_count,
-                jnp.where(freeable, ptab, -1).reshape(-1))
-            ptab = jnp.where(finished[:, None], -1, ptab)
-            new_state = dict(state, kp=kv.k_pages, vp=kv.v_pages,
-                             dkp=dkv.k_pages, dvp=dkv.v_pages,
-                             ptab=ptab, free_stack=free_stack,
-                             free_count=free_count, tok=new_tok,
-                             pos=new_pos, rng=new_keys,
-                             active=active & ~finished)
-            return new_state, outs, emitted, finished
-
-        if B:
-            cfg = model.cfg
-            mask_id = int(cfg.mask_token_id)
-            steps = int(cfg.denoising_steps)
-            if not 1 <= steps <= B:
-                raise ValueError(
-                    f"{steps} denoising steps for a block of {B}")
-            # tokens a step unmasks under the static strategy: B / steps,
-            # the remainder going to the first steps
-            n_transfer = jnp.asarray(
-                [B // steps + (1 if t < B % steps else 0)
-                 for t in range(steps)], jnp.int32)
-            strategy = getattr(cfg, "remasking_strategy",
-                               "low_confidence_static")
-            if strategy not in ("low_confidence_static",
-                                "low_confidence_dynamic"):
-                raise ValueError(
-                    f"unknown remasking strategy {strategy!r}")
-            threshold = float(getattr(cfg, "confidence_threshold", 0.85))
-
-        def block_step(params, state):
-            """ONE iteration of generation by blocks.  A lane holds the
-            block [start, start + B) (``pos`` is its start).  Every live
-            lane's B tokens run at their positions over the paged pool,
-            each query seeing the committed prefix and the whole block;
-            the block's K/V are written EVERY pass at the block's own
-            positions, where nothing but the block's own queries can see
-            them before the final pass overwrites them (as ``spec_step``
-            argues for rejected proposals).  A lane with masks left takes
-            a token and its probability at every masked position and
-            unmasks by its strategy; a lane with none left has just
-            written its block's final K/V: it moves to the next block,
-            all masked, or retires on eos or at ``stop_pos``.
-
-            Returns (state, out [slots, 2B + 3] int32: the block's tokens
-            and the step at which each was unmasked, then three flags:
-            the lane resolved its last mask this pass, the pass was the
-            lane's final (committing) one, the lane retired; and the
-            routed-assignment counters, as buffers of their own)."""
-            lane = jnp.arange(geometry.max_slots)
-            start, active = state["pos"], state["active"]
-            ptab = state["ptab"]
-            # (1) map the page under the block (B divides the page size)
-            pidx = jnp.clip(start // ps, 0, pps - 1)
-            cur = ptab[lane, pidx]
-            need = active & (cur < 0)
-            pages, free_count = take_pages(state["free_stack"],
-                                           state["free_count"], need)
-            ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
-            # (2) the block's B tokens a lane, the block visible both ways
-            off = jnp.arange(B, dtype=jnp.int32)[None]
-            P = start[:, None] + off
-            limits = jnp.broadcast_to((start + B - 1)[:, None], P.shape)
-            out, _ = functional_call(
-                model, params,
-                (state["blk"], P,
-                 PagedKV(state["kp"], state["vp"], ptab, P, active,
-                         seq_cap, limits=limits)),
-                dict(live=active) if counted else {},
-                buffers=buffers, mutable=False, method="slot_step")
-            logits, kv = out[0].astype(jnp.float32), out[1]
-            # (3) denoise: the token and its probability at each position
-            x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            conf = 1.0 / jnp.exp(
-                logits - logits.max(-1, keepdims=True)).sum(-1)
-            blk, is_open, step = state["blk"], state["blk_open"], \
-                state["step"]
-            denoise = active & is_open.any(axis=1)
-            commit = active & ~is_open.any(axis=1)
-            n_t = n_transfer[jnp.clip(step, 0, steps - 1)][:, None]
-            # the n_t most confident masked positions, ties to the lower
-            # index; a known position is never chosen
-            order = jnp.argsort(jnp.where(is_open, -conf, 2.0), axis=1,
-                                stable=True)
-            rank = jnp.argsort(order, axis=1, stable=True)
-            pick = is_open & (rank < n_t)
-            if strategy == "low_confidence_dynamic":
-                high = is_open & (conf > threshold)
-                pick = jnp.where(high.sum(1, keepdims=True) >= n_t, high,
-                                 pick)
-            pick = pick & denoise[:, None]
-            blk = jnp.where(pick, x0, blk)
-            is_open = is_open & ~pick
-            blk_step = jnp.where(pick, step[:, None], state["blk_step"])
-            resolved = denoise & ~is_open.any(axis=1)
-            step = jnp.where(denoise, step + 1, step)
-            # (4) commit: the next block, or retirement on an eos among
-            # the block's generated tokens inside the budget, or at it
-            stop_pos = state["stop_pos"]
-            eos_in = ((blk_step >= 0) & (P < stop_pos[:, None])
-                      & (blk == state["eos"][:, None])).any(axis=1)
-            finished = commit & (eos_in | (start + B >= stop_pos))
-            nxt = commit & ~finished
-            report = jnp.concatenate(
-                [blk, blk_step,
-                 jnp.stack([resolved, commit, finished], 1).astype(
-                     jnp.int32)], axis=1)
-            blk = jnp.where(nxt[:, None], mask_id, blk)
-            is_open = is_open | nxt[:, None]
-            blk_step = jnp.where(nxt[:, None], -1, blk_step)
-            step = jnp.where(nxt, 0, step)
-            start = jnp.where(nxt, start + B, start)
-            # (5) retire in-graph, as the plain decode step does
-            col = jnp.arange(pps, dtype=jnp.int32)[None, :]
-            freeable = finished[:, None] & (ptab >= 0) \
-                & (col >= state["pinned"][:, None])
-            free_stack, free_count = push_pages(
-                state["free_stack"], free_count,
-                jnp.where(freeable, ptab, -1).reshape(-1))
-            ptab = jnp.where(finished[:, None], -1, ptab)
-            new_state = dict(state, kp=kv.k_pages, vp=kv.v_pages, ptab=ptab,
-                             free_stack=free_stack, free_count=free_count,
-                             pos=start, blk=blk, blk_open=is_open,
-                             blk_step=blk_step, step=step,
-                             active=active & ~finished)
-            counts = ()
-            if counted:
-                per, touched = out[2]
-                new_state["moe_counts"] = state["moe_counts"] + per
-                new_state["moe_touched"] = state["moe_touched"] + touched
-                # copies of their own: read from other threads while the
-                # loop donates the state
-                counts = (new_state["moe_counts"] + 0,
-                          new_state["moe_touched"] + 0)
-            return new_state, report, counts
-
-        def release_step(state, mask):
-            return release_slots(state, mask)
-
-        def reclaim_step(state, pages, *wpages):
-            return reclaim_pages(state, pages, *wpages)
-
-        self._state = make_state(geom)
-        if mesh is not None:
-            state_sh = {k: (pool_sh if k in ("kp", "vp") else rep)
-                        for k in self._state}
-            self._state = {k: jax.device_put(a, state_sh[k])
-                           for k, a in self._state.items()}
-        else:
-            state_sh = None
-        sspec = state_specs(self._state, shardings=state_sh)
-        if mesh is not None:
-            pspec = {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                             sharding=a.sharding)
-                     for n, a in params.items()}
-
-            def sds(shape, dtype, sh=rep):
-                return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-        else:
-            pspec = inference.spec_tree(params)
-
-            def sds(shape, dtype, sh=None):
-                return jax.ShapeDtypeStruct(shape, dtype)
-        dpspec = (inference.spec_tree(dparams)
-                  if draft is not None else None)  # draft => no mesh
-        i32 = sds((), np.int32)
-        f32 = sds((), np.float32)
-        b1 = sds((), np.bool_)
-        pvec = sds((pps,), np.int32)
-        out_state = state_sh if mesh is not None else None
-
-        def outs(*tail):
-            # out-shardings pinned to in-shardings (donation contract);
-            # None (no mesh) keeps the default lowering
-            if mesh is None:
-                return None
-            return (out_state,) + tail
-
-        chunk_bucket = (self._bucket_for(self.prefill_chunk)
-                        if self.prefill_chunk else 0)
-        with RecordEvent("paddle.genserve/warmup"):
-            if K:
-                self._spec_exec = inference.aot_compile(
-                    spec_step, (pspec, dpspec, sspec),
-                    donate_argnums=(2,))
-            elif B:
-                self._block_exec = inference.aot_compile(
-                    block_step, (pspec, sspec), donate_argnums=(1,))
+                prefill_step = target_prefill
             else:
-                self._decode_exec = inference.aot_compile(
-                    decode_step, (pspec, sspec), donate_argnums=(1,),
-                    out_shardings=outs(rep, rep))
-            self.compile_count += 1
-            self._release_exec = inference.aot_compile(
-                release_step, (sspec, sds((self.max_slots,), np.bool_)),
-                donate_argnums=(0,), out_shardings=out_state)
-            self.compile_count += 1
-            if self._prefix is not None:
-                self._reclaim_exec = inference.aot_compile(
-                    reclaim_step, (sspec, pvec) + ((pvec,) if W else ()),
+                def prefill_step(params, dparams, ids, length):
+                    # one executable fills BOTH pools: the draft's KV must
+                    # cover the prompt so its proposal chain can attend it
+                    k, v, lg = target_prefill(params, ids, length)
+                    (dk, dv, _), _ = functional_call(
+                        draft, dparams, (Tensor(ids), length),
+                        buffers=dbuffers, mutable=False,
+                        method="slot_prefill")
+                    return k, v, lg, dk, dv
+
+            def arm(state, slot, logits, length, seed, resume_pos, do_sample,
+                    temp, top_k, stop_pos, eos, pinned, opening=(), active=True):
+                """Arm lane ``slot`` after its prompt is in the pool: sample
+                the first token from ``logits``; or, for a block engine, open
+                the first block: ``opening`` = (the prompt's last L mod B
+                tokens padded to B, how many they are), known from the start,
+                the rest masked.  Returns (state, first token)."""
+                key, sub = jax.random.split(resume_chain(seed, resume_pos))
+                if not B:
+                    tok1 = sample_token(logits, sub, do_sample, temp, top_k)
+                    return admit_slot(state, slot, tok1, length, key, do_sample,
+                                      temp, top_k, stop_pos, eos, pinned,
+                                      active), tok1
+                tail, n_known = opening
+                state = admit_slot(state, slot, 0, length // B * B, key,
+                                   do_sample, temp, top_k, stop_pos, eos, pinned,
+                                   active)
+                known = jnp.arange(B, dtype=jnp.int32) < n_known
+                return dict(
+                    state,
+                    blk=state["blk"].at[slot].set(
+                        jnp.where(known, tail, mask_id)),
+                    blk_open=state["blk_open"].at[slot].set(~known),
+                    blk_step=state["blk_step"].at[slot].set(-1),
+                    step=state["step"].at[slot].set(0)), jnp.int32(0)
+
+            def insert_step(state, slot, k_new, v_new, logits, length, seed,
+                            resume_pos, do_sample, temp, top_k, stop_pos, eos,
+                            pinned, *extra):
+                # prefix-miss admission: every mapped page is freshly
+                # allocated and written (shared_n = 0).  `extra` is the
+                # draft's K/V (speculative) or the opening block (blocks)
+                no_shared = jnp.full((pps,), -1, jnp.int32)
+                if W:       # extra: the first column the window row keeps
+                    _, win, extra = window_args((no_shared,) + extra,
+                                                jnp.int32(0))
+                else:
+                    win = {}
+                draft_kv, opening = ((), extra) if B else (extra, ())
+                state, row = write_prompt(state, slot, k_new, v_new, length,
+                                          no_shared, jnp.int32(0), *draft_kv,
+                                          **win)
+                state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
+                                  do_sample, temp, top_k, stop_pos, eos, pinned,
+                                  opening)
+                return state, tok1, row
+
+            def suffix_prefill(params, dparams, state, ids, shared_ids,
+                               shared_n, length, wshared_ids=None):
+                # prefill ONLY the suffix, attending over the prefix already
+                # resident in the pool(s) — shared by the prefix-hit admission
+                # path and every prefill chunk.  The suffix tokens sit at
+                # prefix_len + i; only the last real one's logits are wanted.
+                prefix_len = shared_n * ps
+                positions = (prefix_len
+                             + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
+                last = jnp.asarray(length, jnp.int32) - prefix_len - 1
+
+                def suffix(m, p, b, k_pool, v_pool):
+                    prefix = PrefixKV.gather_windowed(
+                        state, shared_ids, wshared_ids, shared_n, pfx_pages,
+                        W) if W else PrefixKV.gather(
+                            k_pool, v_pool, shared_ids[:pfx_pages], prefix_len,
+                            **prefix_kw)
+                    (lg, kv), _ = functional_call(
+                        m, p, (ids, positions, prefix, last),
+                        buffers=b, mutable=False, method="slot_step")
+                    return kv.suffix_kv(), lg[0, 0]
+
+                (k_suf, v_suf), logits = suffix(model, params, buffers,
+                                                state["kp"], state["vp"])
+                if draft is None:
+                    return k_suf, v_suf, logits, ()
+                draft_kv, _ = suffix(draft, dparams, dbuffers,
+                                     state["dkp"], state["dvp"])
+                return k_suf, v_suf, logits, draft_kv
+
+            def _insert_prefix(params, dparams, state, slot, ids, shared_ids,
+                               shared_n, length, seed, resume_pos, do_sample,
+                               temp, top_k, stop_pos, eos, pinned, *opening):
+                # prefix-hit admission: the shared pages are never
+                # recomputed; the suffix pages in at the (page-aligned)
+                # boundary
+                # a window engine's own pages start where the shared ones end
+                wshared, win, opening = window_args(opening, shared_n)
+                k_suf, v_suf, logits, draft_kv = suffix_prefill(
+                    params, dparams, state, ids, shared_ids, shared_n,
+                    length, wshared)
+                state, row = write_prompt(state, slot, k_suf, v_suf, length,
+                                          shared_ids, shared_n, *draft_kv,
+                                          **win)
+                state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
+                                  do_sample, temp, top_k, stop_pos, eos, pinned,
+                                  opening)
+                return state, tok1, row
+
+            if draft is None:
+                def insert_prefix_step(params, state, *a):
+                    return _insert_prefix(params, None, state, *a)
+            else:
+                insert_prefix_step = _insert_prefix
+
+            def _chunk(params, dparams, state, slot, ids, shared_ids,
+                       shared_n, length, seed, resume_pos, do_sample, temp,
+                       top_k, stop_pos, eos, pin_now, pin_final, arm_now,
+                       *opening):
+                # one prefill chunk: scatter this slice's K/V behind the
+                # resumable cursor; ONLY the final chunk (arm_now) samples
+                # a real first token and activates the lane.  Until then
+                # ``pinned`` stays at the prefix-cache hit count (pin_now)
+                # so a cancel/deadline sweep frees every privately written
+                # chunk page — the stale-pinned leak this executable exists
+                # to prevent; the final chunk raises it to pin_final to
+                # protect the pages about to be registered as shared.
+                # a window engine: what an earlier chunk wrote (index >=
+                # pin_now) and the window has passed goes back
+                wshared, win, opening = window_args(opening, pin_now)
+                k_suf, v_suf, logits, draft_kv = suffix_prefill(
+                    params, dparams, state, ids, shared_ids, shared_n,
+                    length, wshared)
+                state, row = write_prompt(state, slot, k_suf, v_suf, length,
+                                          shared_ids, shared_n, *draft_kv,
+                                          **win)
+                pinned = jnp.where(jnp.asarray(arm_now, bool), pin_final,
+                                   pin_now)
+                state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
+                                  do_sample, temp, top_k, stop_pos, eos, pinned,
+                                  opening, active=arm_now)
+                return state, tok1, row
+
+            if draft is None:
+                def chunk_step(params, state, *a):
+                    return _chunk(params, None, state, *a)
+            else:
+                chunk_step = _chunk
+
+            def decode_step(params, state):
+                lane = jnp.arange(geometry.max_slots)
+                pos, active = state["pos"], state["active"]
+                ptab = state["ptab"]
+                # (1) pop a fresh tail page for lanes whose write position
+                # crossed into an unmapped page — in-graph allocation off
+                # the free-list register (host reserved the worst case)
+                pidx = jnp.clip(pos // ps, 0, pps - 1)
+                cur = ptab[lane, pidx]
+                need = active & (cur < 0)
+                pages, free_count = take_pages(state["free_stack"],
+                                               state["free_count"], need)
+                ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
+                win = {}
+                if W:       # the window pool's tail page, off its own stack
+                    wtab = state["wtab"]
+                    wcur = wtab[lane, pidx]
+                    wneed = active & (wcur < 0)
+                    wpages, wfree_count = take_pages(
+                        state["wfree_stack"], state["wfree_count"], wneed)
+                    wtab = wtab.at[lane, pidx].set(
+                        jnp.where(wneed, wpages, wcur))
+                    win = dict(wk_pages=state["wkp"], wv_pages=state["wvp"],
+                               wrows=wtab, windows=W)
+                # (2) one paged-attention token per lane
+                out, _ = functional_call(
+                    model, params,
+                    (state["tok"][:, None], pos[:, None],
+                     PagedKV(state["kp"], state["vp"], ptab, pos, active,
+                             seq_cap, **win)),
+                    dict(live=active) if counted else {},
+                    buffers=buffers, mutable=False, method="slot_step")
+                logits, kv = out[0], out[1]
+                logits, kp, vp = logits[:, 0], kv.k_pages, kv.v_pages
+                pair = jax.vmap(jax.random.split)(state["rng"])
+                new_keys, subs = pair[:, 0], pair[:, 1]
+                toks = jax.vmap(sample_token)(
+                    logits, subs, state["do_sample"], state["temp"],
+                    state["top_k"])
+                toks = jnp.where(active, toks, state["tok"])
+                new_pos = jnp.where(active, pos + 1, pos)
+                finished = active & ((toks == state["eos"])
+                                     | (new_pos + 1 >= state["stop_pos"]))
+                # (3) retire in-graph: finished lanes' PRIVATE pages (table
+                # index >= pinned) go back on the free stack; shared prefix
+                # pages stay resident for the prefix cache
+                col = jnp.arange(pps, dtype=jnp.int32)[None, :]
+                freeable = finished[:, None] & (ptab >= 0) \
+                    & (col >= state["pinned"][:, None])
+                free_stack, free_count = push_pages(
+                    state["free_stack"], free_count,
+                    jnp.where(freeable, ptab, -1).reshape(-1))
+                ptab = jnp.where(finished[:, None], -1, ptab)
+                new_state = dict(state, kp=kp, vp=vp, ptab=ptab,
+                                 free_stack=free_stack, free_count=free_count,
+                                 tok=toks, pos=new_pos, rng=new_keys,
+                                 active=active & ~finished)
+                report = (toks, finished)
+                if W:
+                    # (4) the window pool: what lies wholly behind the next
+                    # query's window leaves the row (the lane's own pages go
+                    # back on the stack), then retirement as above.  The
+                    # step's report gains the pools' registers: pages off
+                    # each free stack, table entries the live lanes hold
+                    # after the step, pages let go behind the window so far
+                    live = active & ~finished
+                    wtab, wfree_stack, wfree_count, gone = slide_window(
+                        state, wtab, wfree_count, new_pos, live, geom.window)
+                    wfree_stack, wfree_count = push_pages(
+                        wfree_stack, wfree_count, jnp.where(
+                            finished[:, None] & (wtab >= 0)
+                            & (col >= state["pinned"][:, None]),
+                            wtab, -1).reshape(-1))
+                    new_state.update(
+                        wkp=kv.wk_pages, wvp=kv.wv_pages,
+                        wtab=jnp.where(finished[:, None], -1, wtab),
+                        wfree_stack=wfree_stack, wfree_count=wfree_count,
+                        w_released=state["w_released"] + gone)
+                    report += (jnp.stack([
+                        geom.num_pages - new_state["free_count"],
+                        geom.window_pages - new_state["wfree_count"],
+                        (live[:, None] & (new_state["ptab"] >= 0)).sum(
+                            dtype=jnp.int32),
+                        (live[:, None] & (new_state["wtab"] >= 0)).sum(
+                            dtype=jnp.int32),
+                        new_state["w_released"]]),)
+                if counted:
+                    per, touched = out[2]
+                    new_state["moe_counts"] = state["moe_counts"] + per
+                    new_state["moe_touched"] = state["moe_touched"] + touched
+                    # copies of their own, as block_step's
+                    report += ((new_state["moe_counts"] + 0,
+                                new_state["moe_touched"] + 0),)
+                return (new_state,) + report
+
+            def spec_step(params, dparams, state):
+                """ONE speculative iteration: the draft model chains K
+                greedy proposals, the target scores the committed token +
+                all K proposals in one batched verify step, and each greedy
+                lane emits the longest agreeing run + the target's first
+                divergent token (1..K+1 tokens).  Sampling lanes ride the
+                same executable emitting exactly one token from the verify
+                chunk's position-0 logits with the unchanged per-lane PRNG
+                chain — bitwise the non-speculative distribution.
+
+                Rejected proposals need no rollback: their pages stay
+                mapped inside the lane's reservation and the next
+                iteration's chain/verify scatter overwrites the dead K/V at
+                those positions before any emitted query can attend it.
+                """
+                lane = jnp.arange(geometry.max_slots)
+                pos, active = state["pos"], state["active"]
+                stop_pos = state["stop_pos"]
+                greedy_lane = ~state["do_sample"]
+                ptab = state["ptab"]
+                # (1) map every page covering [pos, hi] in one take — the
+                # speculation window never writes past the slot's reserved
+                # extent (positions clamp at stop_pos - 1)
+                hi = jnp.minimum(pos + K, stop_pos - 1)
+                col = jnp.arange(pps, dtype=jnp.int32)[None, :]
+                need = active[:, None] & (ptab < 0) \
+                    & (col >= (pos // ps)[:, None]) \
+                    & (col <= (hi // ps)[:, None])
+                pages, free_count = take_pages(
+                    state["free_stack"], state["free_count"],
+                    need.reshape(-1))
+                ptab = jnp.where(need, pages.reshape(ptab.shape), ptab)
+                # (2) draft chain: K+1 sequential one-token steps.  Step i
+                # writes chain token c_i's draft K/V at pos+i and (i < K)
+                # proposes c_{i+1} = argmax; step K only closes the draft
+                # cache for a fully accepted run (its logits are discarded).
+                dkv = PagedKV(state["dkp"], state["dvp"], ptab, pos, active,
+                              seq_cap)
+                t = state["tok"]
+                chain = [t]
+                for i in range(K + 1):
+                    p_i = jnp.minimum(pos + i, stop_pos - 1)
+                    (dlg, dkv), _ = functional_call(
+                        draft, dparams,
+                        (t[:, None], p_i[:, None], replace(dkv, positions=p_i)),
+                        buffers=dbuffers, mutable=False, method="slot_step")
+                    if i < K:
+                        t = jnp.argmax(dlg[:, 0], axis=-1).astype(jnp.int32)
+                        chain.append(t)
+                tokens = jnp.stack(chain, axis=1)        # [slots, K+1]
+                # (3) target verification: score all K+1 candidates at once
+                P = jnp.minimum(
+                    pos[:, None] + jnp.arange(K + 1, dtype=jnp.int32)[None],
+                    (stop_pos - 1)[:, None])
+                (logits, kv), _ = functional_call(
+                    model, params,
+                    (tokens, P,
+                     PagedKV(state["kp"], state["vp"], ptab, P, active,
+                             seq_cap)),
+                    buffers=buffers, mutable=False, method="slot_step")
+                # (4) accept/emit: outs[:, i] is what the target generates
+                # after consuming c_0..c_i; position 0 goes through the
+                # full sampling path (== argmax for greedy lanes) so the
+                # PRNG chain advances exactly once per iteration
+                pair = jax.vmap(jax.random.split)(state["rng"])
+                new_keys, subs = pair[:, 0], pair[:, 1]
+                outs = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out0 = jax.vmap(sample_token)(
+                    logits[:, 0], subs, state["do_sample"], state["temp"],
+                    state["top_k"])
+                outs = outs.at[:, 0].set(out0)
+                # emitted_i: outs[:, i] is produced this iteration — needs
+                # the previous emission alive (not finished) and draft
+                # proposal c_i to match what the target just generated;
+                # fin_i mirrors the non-speculative stop arithmetic for the
+                # equivalent iteration at write position pos + i
+                em = active
+                emitted, fins = [], []
+                for i in range(K + 1):
+                    if i > 0:
+                        em = em & ~fins[i - 1] & greedy_lane \
+                            & (tokens[:, i] == outs[:, i - 1])
+                    fin = (outs[:, i] == state["eos"]) \
+                        | (pos + i + 2 >= stop_pos)
+                    emitted.append(em)
+                    fins.append(fin)
+                emitted = jnp.stack(emitted, axis=1)     # [slots, K+1]
+                fins = jnp.stack(fins, axis=1)
+                n_emit = emitted.sum(axis=1).astype(jnp.int32)
+                new_tok = outs[lane, jnp.maximum(n_emit - 1, 0)]
+                new_tok = jnp.where(active, new_tok, state["tok"])
+                new_pos = jnp.where(active, pos + n_emit, pos)
+                finished = active & (emitted & fins).any(axis=1)
+                # (5) retire in-graph, same as the plain decode step
+                freeable = finished[:, None] & (ptab >= 0) \
+                    & (col >= state["pinned"][:, None])
+                free_stack, free_count = push_pages(
+                    state["free_stack"], free_count,
+                    jnp.where(freeable, ptab, -1).reshape(-1))
+                ptab = jnp.where(finished[:, None], -1, ptab)
+                new_state = dict(state, kp=kv.k_pages, vp=kv.v_pages,
+                                 dkp=dkv.k_pages, dvp=dkv.v_pages,
+                                 ptab=ptab, free_stack=free_stack,
+                                 free_count=free_count, tok=new_tok,
+                                 pos=new_pos, rng=new_keys,
+                                 active=active & ~finished)
+                return new_state, outs, emitted, finished
+
+            if B:
+                cfg = model.cfg
+                mask_id = int(cfg.mask_token_id)
+                steps = int(cfg.denoising_steps)
+                if not 1 <= steps <= B:
+                    raise ValueError(
+                        f"{steps} denoising steps for a block of {B}")
+                # tokens a step unmasks under the static strategy: B / steps,
+                # the remainder going to the first steps
+                n_transfer = jnp.asarray(
+                    [B // steps + (1 if t < B % steps else 0)
+                     for t in range(steps)], jnp.int32)
+                strategy = getattr(cfg, "remasking_strategy",
+                                   "low_confidence_static")
+                if strategy not in ("low_confidence_static",
+                                    "low_confidence_dynamic"):
+                    raise ValueError(
+                        f"unknown remasking strategy {strategy!r}")
+                threshold = float(getattr(cfg, "confidence_threshold", 0.85))
+
+            def block_step(params, state):
+                """ONE iteration of generation by blocks.  A lane holds the
+                block [start, start + B) (``pos`` is its start).  Every live
+                lane's B tokens run at their positions over the paged pool,
+                each query seeing the committed prefix and the whole block;
+                the block's K/V are written EVERY pass at the block's own
+                positions, where nothing but the block's own queries can see
+                them before the final pass overwrites them (as ``spec_step``
+                argues for rejected proposals).  A lane with masks left takes
+                a token and its probability at every masked position and
+                unmasks by its strategy; a lane with none left has just
+                written its block's final K/V: it moves to the next block,
+                all masked, or retires on eos or at ``stop_pos``.
+
+                Returns (state, out [slots, 2B + 3] int32: the block's tokens
+                and the step at which each was unmasked, then three flags:
+                the lane resolved its last mask this pass, the pass was the
+                lane's final (committing) one, the lane retired; and the
+                routed-assignment counters, as buffers of their own)."""
+                lane = jnp.arange(geometry.max_slots)
+                start, active = state["pos"], state["active"]
+                ptab = state["ptab"]
+                # (1) map the page under the block (B divides the page size)
+                pidx = jnp.clip(start // ps, 0, pps - 1)
+                cur = ptab[lane, pidx]
+                need = active & (cur < 0)
+                pages, free_count = take_pages(state["free_stack"],
+                                               state["free_count"], need)
+                ptab = ptab.at[lane, pidx].set(jnp.where(need, pages, cur))
+                # (2) the block's B tokens a lane, the block visible both ways
+                off = jnp.arange(B, dtype=jnp.int32)[None]
+                P = start[:, None] + off
+                limits = jnp.broadcast_to((start + B - 1)[:, None], P.shape)
+                out, _ = functional_call(
+                    model, params,
+                    (state["blk"], P,
+                     PagedKV(state["kp"], state["vp"], ptab, P, active,
+                             seq_cap, limits=limits)),
+                    dict(live=active) if counted else {},
+                    buffers=buffers, mutable=False, method="slot_step")
+                logits, kv = out[0].astype(jnp.float32), out[1]
+                # (3) denoise: the token and its probability at each position
+                x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                conf = 1.0 / jnp.exp(
+                    logits - logits.max(-1, keepdims=True)).sum(-1)
+                blk, is_open, step = state["blk"], state["blk_open"], \
+                    state["step"]
+                denoise = active & is_open.any(axis=1)
+                commit = active & ~is_open.any(axis=1)
+                n_t = n_transfer[jnp.clip(step, 0, steps - 1)][:, None]
+                # the n_t most confident masked positions, ties to the lower
+                # index; a known position is never chosen
+                order = jnp.argsort(jnp.where(is_open, -conf, 2.0), axis=1,
+                                    stable=True)
+                rank = jnp.argsort(order, axis=1, stable=True)
+                pick = is_open & (rank < n_t)
+                if strategy == "low_confidence_dynamic":
+                    high = is_open & (conf > threshold)
+                    pick = jnp.where(high.sum(1, keepdims=True) >= n_t, high,
+                                     pick)
+                pick = pick & denoise[:, None]
+                blk = jnp.where(pick, x0, blk)
+                is_open = is_open & ~pick
+                blk_step = jnp.where(pick, step[:, None], state["blk_step"])
+                resolved = denoise & ~is_open.any(axis=1)
+                step = jnp.where(denoise, step + 1, step)
+                # (4) commit: the next block, or retirement on an eos among
+                # the block's generated tokens inside the budget, or at it
+                stop_pos = state["stop_pos"]
+                eos_in = ((blk_step >= 0) & (P < stop_pos[:, None])
+                          & (blk == state["eos"][:, None])).any(axis=1)
+                finished = commit & (eos_in | (start + B >= stop_pos))
+                nxt = commit & ~finished
+                report = jnp.concatenate(
+                    [blk, blk_step,
+                     jnp.stack([resolved, commit, finished], 1).astype(
+                         jnp.int32)], axis=1)
+                blk = jnp.where(nxt[:, None], mask_id, blk)
+                is_open = is_open | nxt[:, None]
+                blk_step = jnp.where(nxt[:, None], -1, blk_step)
+                step = jnp.where(nxt, 0, step)
+                start = jnp.where(nxt, start + B, start)
+                # (5) retire in-graph, as the plain decode step does
+                col = jnp.arange(pps, dtype=jnp.int32)[None, :]
+                freeable = finished[:, None] & (ptab >= 0) \
+                    & (col >= state["pinned"][:, None])
+                free_stack, free_count = push_pages(
+                    state["free_stack"], free_count,
+                    jnp.where(freeable, ptab, -1).reshape(-1))
+                ptab = jnp.where(finished[:, None], -1, ptab)
+                new_state = dict(state, kp=kv.k_pages, vp=kv.v_pages, ptab=ptab,
+                                 free_stack=free_stack, free_count=free_count,
+                                 pos=start, blk=blk, blk_open=is_open,
+                                 blk_step=blk_step, step=step,
+                                 active=active & ~finished)
+                counts = ()
+                if counted:
+                    per, touched = out[2]
+                    new_state["moe_counts"] = state["moe_counts"] + per
+                    new_state["moe_touched"] = state["moe_touched"] + touched
+                    # copies of their own: read from other threads while the
+                    # loop donates the state
+                    counts = (new_state["moe_counts"] + 0,
+                              new_state["moe_touched"] + 0)
+                return new_state, report, counts
+
+            def release_step(state, mask):
+                return release_slots(state, mask)
+
+            def reclaim_step(state, pages, *wpages):
+                return reclaim_pages(state, pages, *wpages)
+
+            self._state = make_state(geom)
+            if mesh is not None:
+                state_sh = {k: (pool_sh if k in ("kp", "vp") else rep)
+                            for k in self._state}
+                self._state = {k: jax.device_put(a, state_sh[k])
+                               for k, a in self._state.items()}
+            else:
+                state_sh = None
+            sspec = state_specs(self._state, shardings=state_sh)
+            if mesh is not None:
+                pspec = {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=a.sharding)
+                         for n, a in params.items()}
+
+                def sds(shape, dtype, sh=rep):
+                    return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+            else:
+                pspec = inference.spec_tree(params)
+
+                def sds(shape, dtype, sh=None):
+                    return jax.ShapeDtypeStruct(shape, dtype)
+            dpspec = (inference.spec_tree(dparams)
+                      if draft is not None else None)  # draft => no mesh
+            i32 = sds((), np.int32)
+            f32 = sds((), np.float32)
+            b1 = sds((), np.bool_)
+            pvec = sds((pps,), np.int32)
+            out_state = state_sh if mesh is not None else None
+
+            def outs(*tail):
+                # out-shardings pinned to in-shardings (donation contract);
+                # None (no mesh) keeps the default lowering
+                if mesh is None:
+                    return None
+                return (out_state,) + tail
+
+            chunk_bucket = (self._bucket_for(self.prefill_chunk)
+                            if self.prefill_chunk else 0)
+            phase.close()               # `genserve/state` ends here
+            if K:
+                with boot.executable("genserve/build/spec_step"):
+                    self._spec_exec = inference.aot_compile(
+                        spec_step, (pspec, dpspec, sspec),
+                        donate_argnums=(2,))
+            elif B:
+                with boot.executable("genserve/build/block_step"):
+                    self._block_exec = inference.aot_compile(
+                        block_step, (pspec, sspec), donate_argnums=(1,))
+            else:
+                with boot.executable("genserve/build/decode_step"):
+                    self._decode_exec = inference.aot_compile(
+                        decode_step, (pspec, sspec), donate_argnums=(1,),
+                        out_shardings=outs(rep, rep))
+            with boot.executable("genserve/build/release_step"):
+                self._release_exec = inference.aot_compile(
+                    release_step, (sspec, sds((self.max_slots,), np.bool_)),
                     donate_argnums=(0,), out_shardings=out_state)
-                self.compile_count += 1
+            if self._prefix is not None:
+                with boot.executable("genserve/build/reclaim_step"):
+                    self._reclaim_exec = inference.aot_compile(
+                        reclaim_step, (sspec, pvec) + ((pvec,) if W else ()),
+                        donate_argnums=(0,), out_shardings=out_state)
             dpre = (dpspec,) if draft is not None else ()
             for sp in self.prompt_buckets:
                 ids = sds((1, sp), np.int32)
-                self._prefill_execs[sp] = inference.aot_compile(
-                    prefill_step, (pspec,) + dpre + (ids, i32),
-                    out_shardings=(kv_sh, kv_sh, rep)
-                    if mesh is not None else None)
+                with boot.executable(f"genserve/build/prefill.{sp}"):
+                    self._prefill_execs[sp] = inference.aot_compile(
+                        prefill_step, (pspec,) + dpre + (ids, i32),
+                        out_shardings=(kv_sh, kv_sh, rep)
+                        if mesh is not None else None)
                 # insert takes what prefill gives, in the model's own
                 # dtypes (bf16 weights hand over bf16 K/V and logits)
                 pre = self._prefill_execs[sp].out_info
@@ -1161,63 +1179,67 @@ class GenerationEngine:
                 # first column its row keeps
                 opening = (sds((B,), np.int32), i32) if B else \
                     (pvec, i32) if W else ()
-                self._insert_execs[sp] = inference.aot_compile(
-                    insert_step,
-                    (sspec, i32, kv, kv, lg, i32, i32, i32, b1, f32, i32,
-                     i32, i32, i32) + dkv_in + opening[1 if W else 0:],
-                    donate_argnums=(0,), out_shardings=outs(rep, rep))
-                self.compile_count += 2
+                with boot.executable(f"genserve/build/insert.{sp}"):
+                    self._insert_execs[sp] = inference.aot_compile(
+                        insert_step,
+                        (sspec, i32, kv, kv, lg, i32, i32, i32, b1, f32, i32,
+                         i32, i32, i32) + dkv_in + opening[1 if W else 0:],
+                        donate_argnums=(0,), out_shardings=outs(rep, rep))
                 tail = (i32, ids, pvec, i32, i32, i32, i32, b1, f32, i32,
                         i32, i32, i32)
                 if self._prefix is not None:
-                    self._insert_prefix_execs[sp] = inference.aot_compile(
-                        insert_prefix_step,
-                        (pspec,) + dpre + (sspec,) + tail + opening,
-                        donate_argnums=(1 + len(dpre),),
-                        out_shardings=outs(rep, rep))
-                    self.compile_count += 1
+                    with boot.executable(f"genserve/build/insert_prefix.{sp}"):
+                        self._insert_prefix_execs[sp] = inference.aot_compile(
+                            insert_prefix_step,
+                            (pspec,) + dpre + (sspec,) + tail + opening,
+                            donate_argnums=(1 + len(dpre),),
+                            out_shardings=outs(rep, rep))
                 if self.prefill_chunk and sp <= chunk_bucket:
-                    self._chunk_execs[sp] = inference.aot_compile(
-                        chunk_step,
-                        (pspec,) + dpre + (sspec,) + tail[:-1]
-                        + (i32, i32, b1) + opening,
-                        donate_argnums=(1 + len(dpre),),
-                        out_shardings=outs(rep, rep))
-                    self.compile_count += 1
+                    with boot.executable(f"genserve/build/chunk.{sp}"):
+                        self._chunk_execs[sp] = inference.aot_compile(
+                            chunk_step,
+                            (pspec,) + dpre + (sspec,) + tail[:-1]
+                            + (i32, i32, b1) + opening,
+                            donate_argnums=(1 + len(dpre),),
+                            out_shardings=outs(rep, rep))
+            phase.enter_context(boot.scope("genserve/publish"))
+            # the decode step rewrites the donated pools in place: what it
+            # holds beside them stays far under one layer's plane of one pool
+            # (a copied plane or pool would show here before any chip run)
+            mem = (self._spec_exec or self._block_exec
+                   or self._decode_exec).memory_analysis()
+            self.decode_temp_bytes = (int(mem.temp_size_in_bytes)
+                                      if mem is not None else None)
+            # publish introspection surfaces (monitor/perf.py): the decode
+            # op table over /debug/perf, and owner tags so the buffer
+            # census attributes the KV cache and weights ("latest engine
+            # wins" — one process, one serving engine in practice)
+            from ..monitor import perf as _perf
+
+            _perf.register_provider("decode", self.op_report)
+            _perf.register_owner("params", lambda: self._params)
+            _perf.register_owner("kv_pages", lambda: self._state)
+
+            self._started = True
+            self._thread = threading.Thread(target=self._run, daemon=True,
+                                            name="paddle-genserve-decode")
+            self._thread.start()
+        # one source for the count: the rows this start built
+        self.compile_count = sum(
+            r["built"] for r in boot.table(["genserve/build"], since))
         self.metrics.set_compile_count(self.compile_count)
-        # the decode step rewrites the donated pools in place: what it
-        # holds beside them stays far under one layer's plane of one pool
-        # (a copied plane or pool would show here before any chip run)
-        mem = (self._spec_exec or self._block_exec
-               or self._decode_exec).memory_analysis()
-        self.decode_temp_bytes = (int(mem.temp_size_in_bytes)
-                                  if mem is not None else None)
-        logger.info(
-            "generation warmup compiled %d executable(s): slots=%d "
-            "S_max=%d prompt buckets=%s pages=%dx%d cache=%.1f MB "
-            "decode temps=%s MB%s",
-            self.compile_count, self.max_slots, self.max_seq_len,
-            self.prompt_buckets, geom.num_pages, geom.page_size,
-            geom.kv_bytes() / 1048576,
-            "n/a" if self.decode_temp_bytes is None
-            else f"{self.decode_temp_bytes / 1048576:.1f}",
-            f" mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}"
-            if mesh is not None else "")
-
-        # publish introspection surfaces (monitor/perf.py): the decode
-        # op table over /debug/perf, and owner tags so the buffer
-        # census attributes the KV cache and weights ("latest engine
-        # wins" — one process, one serving engine in practice)
-        from ..monitor import perf as _perf
-
-        _perf.register_provider("decode", self.op_report)
-        _perf.register_owner("params", lambda: self._params)
-        _perf.register_owner("kv_pages", lambda: self._state)
-
-        self._started = True
-        self._thread = threading.Thread(target=self._run, daemon=True,
-                                        name="paddle-genserve-decode")
-        self._thread.start()
+        if logger.isEnabledFor(logging.INFO):
+            geom, mesh = self.geometry, self._mesh
+            logger.info(
+                "generation start-up: slots=%d S_max=%d prompt buckets=%s "
+                "pages=%dx%d cache=%.1f MB decode temps=%s MB%s\n%s",
+                self.max_slots, self.max_seq_len, self.prompt_buckets,
+                geom.num_pages, geom.page_size, geom.kv_bytes() / 1048576,
+                "n/a" if self.decode_temp_bytes is None
+                else f"{self.decode_temp_bytes / 1048576:.1f}",
+                f" mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}"
+                if mesh is not None else "",
+                boot.report("genserve", since))
         return self
 
     def op_report(self, *, measured_step_ms=None, trace_dir=None):

@@ -16,6 +16,9 @@ import logging
 import time
 
 import jax
+import jax.monitoring
+
+from .metrics import default_registry
 
 logger = logging.getLogger("paddle_tpu.profiler")
 
@@ -47,6 +50,9 @@ class RecordEvent:
         return False
 
 
+_NO_RUN = (0.0, 0)      # what `maxima` reads for a phase that never ran
+
+
 class StepTimers:
     """Phase timing for a host loop: `Model.fit` (prefix `paddle.fit`)
     and the generation engine's decode loop (`paddle.genserve`).
@@ -62,13 +68,19 @@ class StepTimers:
     loop that is wholly covered sum to its wall time.  One thread
     drives a recorder; another may read `totals` at any time.  Under
     the async train engine `dispatch` measures enqueue cost only;
-    device execution overlaps and is paid for inside `sync`."""
+    device execution overlaps and is paid for inside `sync`.
+
+    `maxima[name]` is the phase's longest single run and the count at
+    which it fell, since the recorder began or `maxima` was last
+    cleared (`Model.fit` clears it at each epoch's end): a mean hides
+    one stalled step, this names it."""
 
     def __init__(self, prefix: str = "paddle.fit"):
         self.prefix = prefix
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
         self.parents: dict[str, str | None] = {}
+        self.maxima: dict[str, tuple[float, int]] = {}
         self._open: list[str] = []
 
     def reset(self):
@@ -77,6 +89,7 @@ class StepTimers:
         self.totals.clear()
         self.counts.clear()
         self.parents.clear()
+        self.maxima.clear()
 
     @contextlib.contextmanager
     def scope(self, name: str):
@@ -91,9 +104,11 @@ class StepTimers:
                 yield
         finally:
             self._open.pop()
-            self.totals[name] = (self.totals.get(name, 0.0)
-                                 + time.perf_counter() - begin)
-            self.counts[name] = self.counts.get(name, 0) + 1
+            took = time.perf_counter() - begin
+            self.totals[name] = self.totals.get(name, 0.0) + took
+            count = self.counts[name] = self.counts.get(name, 0) + 1
+            if took > self.maxima.get(name, _NO_RUN)[0]:
+                self.maxima[name] = (took, count)
 
     def self_seconds(self, name: str) -> float:
         """A phase's total less the totals of the phases that ran
@@ -103,13 +118,193 @@ class StepTimers:
             if self.parents.get(child) == name)
 
     def summary(self) -> dict:
-        """{phase: {total_s, count, mean_ms}} for every recorded phase."""
-        return {
-            name: {"total_s": round(t, 6),
-                   "count": self.counts[name],
-                   "mean_ms": round(t / self.counts[name] * 1e3, 4)}
-            for name, t in self.totals.items()
-        }
+        """{phase: {total_s, count, mean_ms, max_ms, max_at}} for every
+        recorded phase; the last two are left out where `maxima` was
+        cleared since the phase last ran."""
+        out = {}
+        for name, t in self.totals.items():
+            out[name] = {"total_s": round(t, 6),
+                         "count": self.counts[name],
+                         "mean_ms": round(t / self.counts[name] * 1e3, 4)}
+            if name in self.maxima:
+                longest, at = self.maxima[name]
+                out[name].update(max_ms=round(longest * 1e3, 4), max_at=at)
+        return out
+
+
+class StartupTimers(StepTimers):
+    """Start-up's phases (prefix `paddle.start`): the package's `import`,
+    `genserve` (all of `GenerationEngine.start()`), `fit` (from
+    `Model.fit`'s entry to the return of its first step) and
+    `cost_analysis`, with one row for every executable built under them.
+
+    A row is opened by `executable(name)`: `wall_s` is its scope, and
+    jax's own monitoring events that fire while it is the innermost
+    open row are put down to it: `compile_s` and `executables` count
+    `backend_compile_duration` (an XLA compile or a load from the
+    persistent cache, both), `cache_hits`, `cache_misses` and
+    `cache_load_s` the cache's events (jax counts a miss when it writes
+    the entry, so an executable under the cache's thresholds is
+    neither).  What is left of a row, `wall_s - compile_s`, is host
+    Python: tracing and lowering.  An event that fires with no row open
+    goes to a row named after the innermost open scope, which built
+    nothing and has no `wall_s`; with no scope open either, to the
+    row `(outside)` (a model's eager initialisers, the harness's own
+    jits).  Start-up runs on one thread; `startup()` is the process's
+    one recorder."""
+
+    OUTSIDE = "(outside)"
+    _EVENTS = {     # jax's event -> (the row's seconds, the row's count)
+        "/jax/core/compile/backend_compile_duration":
+            ("compile_s", "executables"),
+        "/jax/compilation_cache/cache_retrieval_time_sec":
+            ("cache_load_s", None),
+        "/jax/compilation_cache/cache_hits": (None, "cache_hits"),
+        "/jax/compilation_cache/cache_misses": (None, "cache_misses"),
+    }
+
+    def __init__(self):
+        super().__init__("paddle.start")
+        self.rows: list[dict] = []
+        self._building: list[dict] = []     # the open rows, innermost last
+        self._elsewhere: dict[tuple, dict] = {}
+
+    def reset(self):
+        super().reset()
+        self.rows.clear()
+        self._elsewhere.clear()
+
+    def _row(self, name, built):
+        row = {"name": name, "built": built, "wall_s": None,
+               "compile_s": 0.0, "executables": 0, "cache_hits": 0,
+               "cache_misses": 0, "cache_load_s": 0.0}
+        self.rows.append(row)
+        return row
+
+    def on_jax_event(self, event, secs=0.0, **_):
+        """The one listener of jax's monitoring events, of both kinds
+        (with and without a duration); an event that is none of the
+        four costs one lookup."""
+        fields = self._EVENTS.get(event)
+        if fields is None:
+            return
+        try:
+            row = self._building[-1]
+        except IndexError:
+            try:
+                name = self._open[-1]
+            except IndexError:
+                name = self.OUTSIDE
+            key = (name, self.counts.get(name, 0))  # this run of the scope
+            row = self._elsewhere.get(key)
+            if row is None:
+                row = self._elsewhere[key] = self._row(name, built=False)
+        seconds, count = fields
+        if seconds:
+            row[seconds] += secs
+        if count:
+            row[count] += 1
+
+    def under(self, name: str) -> str:
+        """`name` as a child of the innermost open scope."""
+        return f"{self._open[-1]}/{name}" if self._open else name
+
+    def stamp(self, name: str, began: float):
+        """A top-level scope that began at `began` (a `perf_counter`
+        reading) and ends now: for a stretch that starts before this
+        module can be imported (the package's `import`)."""
+        took = time.perf_counter() - began
+        self.parents[name] = None
+        self.totals[name] = self.totals.get(name, 0.0) + took
+        self.counts[name] = self.counts.get(name, 0) + 1
+
+    @contextlib.contextmanager
+    def executable(self, name: str):
+        """The scope `name` with a row of its own."""
+        row = self._row(name, built=True)
+        self._building.append(row)
+        begin = time.perf_counter()
+        try:
+            with self.scope(name):
+                yield row
+        finally:
+            self._building.pop()
+            row["wall_s"] = time.perf_counter() - begin
+
+    def cache_counts(self) -> dict:
+        """Executables the persistent cache gave (`hit`) and took
+        (`miss`) since the process began."""
+        return {"hit": sum(r["cache_hits"] for r in self.rows),
+                "miss": sum(r["cache_misses"] for r in self.rows)}
+
+    def mark(self):
+        """A place in the record: `table` and `report` with
+        `since=mark` tell only what came after it (a process may start
+        more than one engine, or fit more than once)."""
+        return len(self.rows), dict(self.totals)
+
+    def table(self, under=None, since=None) -> list[dict]:
+        """The rows, slowest first: every row, or those at or below one
+        of the scopes `under`."""
+        rows = self.rows[since[0]:] if since else self.rows
+        if under is not None:
+            rows = [r for r in rows if any(
+                r["name"] == s or r["name"].startswith(s + "/")
+                for s in under)]
+        return sorted(rows, key=lambda r: -(r["wall_s"] or 0.0))
+
+    def report(self, scope: str, since=None) -> str:
+        """What `scope` took, by phase and by executable, as text."""
+        rows = self.table([scope], since)
+        built = [r for r in rows if r["built"]]
+        before = since[1] if since else {}
+        lines = ["%s took %.3f s: %d executable(s) built, tracing and "
+                 "lowering %.3f s, compile or cache load %.3f s (%d from "
+                 "the cache)" % (
+                     scope, self.totals.get(scope, 0.0)
+                     - before.get(scope, 0.0), len(built),
+                     sum(r["wall_s"] - r["compile_s"] for r in built),
+                     sum(r["compile_s"] for r in rows),
+                     sum(r["cache_hits"] for r in rows))]
+        names = {r["name"] for r in built}
+        for name, total in self.totals.items():
+            if self.parents[name] == scope and name not in names:
+                lines.append("  phase %-34s %8.3f s" % (
+                    name, total - before.get(name, 0.0)))
+        lines.append("  %-40s %8s %11s %9s %3s %4s" % (
+            "executable", "wall_s", "trace_lower", "compile_s", "n", "hit"))
+        for r in rows:
+            lines.append("  %-40s %8s %11s %9.3f %3d %4d" % (
+                r["name"], "%.3f" % r["wall_s"] if r["built"] else "-",
+                "%.3f" % (r["wall_s"] - r["compile_s"]) if r["built"]
+                else "-", r["compile_s"], r["executables"],
+                r["cache_hits"]))
+        return "\n".join(lines)
+
+
+_startup = StartupTimers()
+jax.monitoring.register_event_listener(_startup.on_jax_event)
+jax.monitoring.register_event_duration_secs_listener(_startup.on_jax_event)
+
+
+def startup() -> StartupTimers:
+    """The process's start-up recorder."""
+    return _startup
+
+
+# on every /metrics that serves the process-wide registry
+# (serving/server.py, monitor/server.py)
+default_registry().gauge(
+    "paddle_startup_seconds",
+    "seconds of start-up by phase (utils.profiler.startup(): import, "
+    "genserve, fit, cost_analysis; a/b ran under a; build/<executable> "
+    "is one executable's tracing, lowering and compile or cache load)",
+    fn=lambda: dict(_startup.totals), label="phase")
+default_registry().gauge(
+    "paddle_startup_executables",
+    "executables the persistent compile cache gave (hit) and took (miss) "
+    "since the process began, by jax's own cache events",
+    fn=_startup.cache_counts, label="cache")
 
 
 class _BoundedCapture:

@@ -202,7 +202,7 @@ class TestLifecycle:
             assert eng.decode_temp_bytes == int(
                 eng._decode_exec.memory_analysis().temp_size_in_bytes)
             line = next(r.getMessage() for r in caplog.records
-                        if "generation warmup compiled" in r.getMessage())
+                        if "generation start-up" in r.getMessage())
             assert (f"decode temps={eng.decode_temp_bytes / 1048576:.1f} MB"
                     in line), line
             assert "cache=" in line
