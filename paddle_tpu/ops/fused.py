@@ -487,15 +487,17 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
 
     A pool of as many heads as the query has, and no window, is
     `paddle_paged_decode_fwd`'s; a pool of fewer (KV heads, each read by a
-    group of query heads) or a layer that sees a static `window` of keys
-    is `paddle_paged_gqa_decode_fwd`'s (no mesh yet: under one it is
-    refused, counted, and the caller gathers).
+    group of query heads), a layer that sees a static `window` of keys, or
+    a lane with more than one query is `paddle_paged_gqa_decode_fwd`'s (no
+    mesh yet: under one it is refused, counted, and the caller gathers).
 
-    q [slots, 1, nh, hd] (the step's query, post-scatter); k_pages/v_pages
-    [layers, num_pages, page_size, nh, hd] (the stacked pools, never a
+    q [slots, C, nh, hd] (the step's queries, post-scatter); k_pages/v_pages
+    [layers, num_pages, page_size, nkv, hd] (the stacked pools, never a
     slice of them: a sliced operand is a copied plane); rows [slots,
     pages_per_slot] int32 (-1 = unmapped); pos [slots] int32 inclusive
-    extent; seq_cap and layer static.  Returns [slots, 1, nh, hd].
+    extent, ONE a lane: with C > 1 every query of a lane sees the keys up
+    to it (a block step's `limits`), and there is no window; seq_cap and
+    layer static.  Returns [slots, C, nh, hd].
     `tp_axis` names the mesh axis the pool's head dim is sharded over (the
     models' "mp" pin), if any.
     """
@@ -505,10 +507,11 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
 
     mesh, _, _ = _mesh_axes()
 
-    grouped = bool(window) or k_pages.shape[3] != q.shape[2]
+    one = q.shape[1] == 1
+    grouped = bool(window) or k_pages.shape[3] != q.shape[2] or not one
 
     def pf(qv, kp, vp, rw, ps_):
-        q1 = qv[:, 0]
+        q1 = qv[:, 0] if one else qv
         if grouped:
             if mesh is not None:
                 raise pa.DoesNotTile(
@@ -523,7 +526,7 @@ def paged_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap, layer,
         else:
             out = pa.paged_decode_attention(q1, kp, vp, rw, ps_, seq_cap,
                                             layer)
-        return out[:, None]
+        return out[:, None] if one else out
 
     return _kernel_or_none(
         "paged_attention", lambda: apply(pf, q, k_pages, v_pages, rows, pos))

@@ -5,8 +5,15 @@ fused_multi_transformer_op.cu's masked decode attention — that kernel reads
 a dense [B, S_max] cache; this one reads the paged KV pool of
 serving/kv_cache.py directly).
 
-One query token per lane attends over that lane's pages, walked through its
-int32 page-table row — the pool is never gathered into a dense
+The queries of a lane that share ONE last key attend over that lane's
+pages: one query token a lane (the one-token decode step, full and window
+layers), or the C queries of a block-generating step's block, which all
+see the lane's keys to the block's end (``paged_gqa_decode_attention`` with
+q ``[slots, C, nh, hd]``: the C x g queries of a KV head are the rows of
+one product, since PR 37).  A chunk with a last key a QUERY (speculative
+verification: causal inside the chunk) or on a window layer is not asked
+of this file: ``PagedKV`` gathers for it.  The pages are walked through the
+lane's int32 page-table row — the pool is never gathered into a dense
 ``[slots, S_max]`` view, and never sliced to one layer either: the operand
 is the WHOLE stacked pool ``[layers, num_pages, page_size, heads, hd]``,
 left where it lies in HBM (``memory_space=pl.ANY``), and the copies the
@@ -26,8 +33,9 @@ inside it (``_walk``) over the lane's OWN extent only, from its first
 column (0, or the first column that meets a window) to the column of its
 position, a trip count read from ``pos_ref`` / ``rows_ref``; a released
 lane (row -1) or one never armed walks nothing and writes zeros.  The walk
-goes by blocks of several pages (``_block_pages``: about 128 keys, from
-the call's shapes alone): a block's mapped pages are copied HBM->VMEM by
+goes by blocks of several pages (``_block_pages``: about 128 keys, 256
+under a block step's 32 rows a product, from the call's shapes alone): a
+block's mapped pages are copied HBM->VMEM by
 ``pltpu.make_async_copy``, one DMA a page, all started together into one
 of two buffers, and block b + 1's are started before block b is computed
 on.  A page that is unmapped (table entry -1) or entirely past the lane's
@@ -37,7 +45,8 @@ matching the dense reference's validity mask exactly, token by token.  The
 flash running-softmax (m/l/acc in VMEM scratch, float32) runs across the
 blocks.  Both calls of this file take that walk; their bodies differ
 (``_attend``: multiply and reduce on the VPU over ``[keys, nh, hd]``;
-``_gqa_attend``: one ``[g, hd] x [hd, keys]`` product a KV head).
+``_gqa_attend``: one ``[rows, hd] x [hd, keys]`` product a KV head, its
+rows the head's g query heads of every query of the lane).
 
 A second walk stands beside it, chosen by the pool's shape alone
 (``_page_is_tiles``): Mosaic lets a DMA cut only whole tiles out of an HBM
@@ -47,18 +56,20 @@ pools keep the walk the file had before PR 35 (``_grid_kernel``): grid
 through a ``BlockSpec`` of a whole page, every column visited whatever is
 mapped and the dead ones skipped by ``pl.when``; same bodies, same masks,
 same table that counts through the planes.  Its time does not follow the
-live lanes; the way off it is the pool's layout (ROADMAP Speed 11).
+live lanes; the way off it is the pool's layout (ROADMAP Speed 13).
 Interpreted on the CPU, every pool takes the loop.
 
-Used by serving/kv_cache.py ``PagedKV.attend`` (the one-token-a-lane case)
-through ops/fused.py when FLAGS_use_pallas_kernels is on; the dense-gather
-path there stays as the fallback and parity reference.  The kernel only
-READS the pool: the current token's K/V rows are scattered by XLA before
-the call (``k_pages.at[layer, page, off].set``), in place into the donated
-pool, and the call then reads that same buffer — no plane and no pool is
-copied around it (tests/test_mosaic_compile.py holds the compiled decode
-step to that, and the calls to their names, operands and results, by
-which the benchmark finds them on a trace).
+Used by serving/kv_cache.py ``PagedKV.attend`` (one last key a lane: the
+one-token step, and the block step's ``limits`` [slots]) through
+ops/fused.py when FLAGS_use_pallas_kernels is on; the dense-gather path
+there stays as the fallback, the parity reference and the path of the
+other chunks.  The kernel only READS the pool: the current token's (or
+block's) K/V rows are scattered by XLA before the call
+(``k_pages.at[layer, page, off].set``), in place into the donated pool,
+and the call then reads that same buffer — no plane and no pool is copied
+around it (tests/test_mosaic_compile.py holds the compiled decode step
+and block step to that, and the calls to their names, operands and
+results, by which the benchmark finds them on a trace).
 """
 from __future__ import annotations
 
@@ -85,9 +96,14 @@ _VMEM_BUDGET = 4 * 1024 * 1024
 # many pages (their copies fly together, and a loop turn's waits and
 # softmax update are shared among them), if the budget allows: on a v5e
 # 8 pages of 16 keys beat 1, 2 and 16 at chat's geometry, and 4 pages of
-# 64 beat 1, 2 and 8 at repochat's (PERF.md section 6, PR 35)
+# 64 beat 1, 2 and 8 at repochat's (PERF.md section 6, PR 35).  From
+# _WIDE_ROWS rows a product on (a block step's 4 queries x 8 heads a KV
+# head) a turn's update of the running softmax is four times the work, and
+# a block of twice the keys shares it: 16 pages of 16 beat 4, 8 and 32 by a
+# tenth at the blockgen cell's geometry (PERF.md section 6, PR 37)
 _BLOCK_KEYS = 128
 _BLOCK_PAGES = 4
+_WIDE_ROWS = 32
 
 
 def _page_is_tiles(k_pages) -> bool:
@@ -100,12 +116,14 @@ def _page_is_tiles(k_pages) -> bool:
     return hd % 128 == 0 and (heads % 8 == 0 or heads in (2, 4))
 
 
-def _block_pages(k_pages, cols: int, interpret: bool) -> int:
-    """Pages a block of the walk holds, from the pool's shape and the
-    columns a lane can walk: enough for _BLOCK_KEYS keys and at least
+def _block_pages(k_pages, cols: int, interpret: bool, rows: int = 1) -> int:
+    """Pages a block of the walk holds, from the pool's shape, the
+    columns a lane can walk and the rows of a KV head's products: enough
+    for _BLOCK_KEYS keys (twice that from _WIDE_ROWS rows on) and at least
     _BLOCK_PAGES, no more than the walk is long, and no more than fit
     _VMEM_BUDGET twice over for K and for V (8 pages of 16 x 16 x 128
-    bf16; 4 pages of 64 x 4 x 128).
+    bf16; 4 pages of 64 x 4 x 128; 16 pages of 16 x 4 x 128 under a block
+    step's 32 rows).
     0, the grid's page walk (``_grid_kernel``), where the chip is the
     target and no DMA can cut a page out of the pool (GPT-2's 12 heads of
     64).  DoesNotTile where two pages for K and for V are over the
@@ -123,7 +141,8 @@ def _block_pages(k_pages, cols: int, interpret: bool) -> int:
             f"paged decode attention: two pages of {k_pages.shape[2:]} "
             f"{k_pages.dtype} for K and for V are over the walk's "
             f"{_VMEM_BUDGET >> 20} MiB of VMEM")
-    return max(1, min(max(-(-_BLOCK_KEYS // ps), _BLOCK_PAGES), fit, cols))
+    keys = _BLOCK_KEYS * (2 if rows >= _WIDE_ROWS else 1)
+    return max(1, min(max(-(-keys // ps), _BLOCK_PAGES), fit, cols))
 
 
 def _walk(rows_ref, pos_ref, k_hbm, v_hbm, k_buf, v_buf, sems, ends_ref,
@@ -291,7 +310,8 @@ def _attend(q_ref, k_ref, v_ref, seen, acc_ref, m_ref, l_ref, sm_scale):
 def _gqa_attend(q_ref, k_ref, v_ref, seen, acc_ref, m_ref, l_ref, sm_scale):
     """``paddle_paged_gqa_decode_fwd``'s body: the keys ``[keys, nkv,
     hd]`` of a block (or a page) into the running softmax of a lane's
-    ``[nkv, g, hd]`` query."""
+    ``[nkv, rows, hd]`` queries: ``rows`` is the g query heads of a KV
+    head, of each of the lane's queries (one, or a block step's C)."""
     nkv, g = q_ref.shape[1], q_ref.shape[2]
     sees = seen((g, k_ref.shape[0]), 1)
     for h in range(nkv):
@@ -500,7 +520,8 @@ def paged_gqa_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
                                layer: int, window: int = 0, sm_scale=None,
                                interpret: bool | None = None):
     """``paged_decode_attention`` for a pool of KV heads, each read by
-    ``g = nh // nkv`` query heads, and a layer that sees a window.
+    ``g = nh // nkv`` query heads, a layer that sees a window, and a lane
+    that brings several queries to the same keys.
 
     q: [slots, nh, hd], query head h reading KV head h // g; k_pages /
     v_pages: [layers, num_pages, page_size, nkv, hd] (the WHOLE pool, after
@@ -510,12 +531,23 @@ def paged_gqa_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
     them: at most ``(window - 2) // page_size + 2`` columns.  A block's
     keys are read once for the g query heads of their KV head: those are
     the rows of one [g, hd] x [hd, keys] product.  Returns [slots, nh, hd]
-    in q's dtype.  A call of its own beside ``paged_decode_attention``
-    (same file, same walk): that one's operands, name and VPU body are
-    what the one-KV-head-a-query-head engines were measured with, and stay
-    as they are.
+    in q's dtype.
+
+    q: [slots, C, nh, hd] is C queries a lane that ALL see the lane's keys
+    up to ``pos`` (a block-generating step: ``pos`` is the block's last
+    position, whose K/V the caller has scattered too).  One mask a lane,
+    so the C x g queries of a KV head are the rows of the same product:
+    the query is laid [slots, nkv, C * g, hd] (row c * g + j of KV head h
+    is query c, head h * g + j) and the result laid back [slots, C, nh,
+    hd]; same walk, same body, another row count.  No window there (a
+    window is measured from each query's own position: a mask a row).
+
+    A call of its own beside ``paged_decode_attention`` (same file, same
+    walk): that one's operands, name and VPU body are what the
+    one-KV-head-a-query-head engines were measured with, and stay as they
+    are.
     """
-    slots, nh, hd = q.shape
+    slots, (nh, hd) = q.shape[0], q.shape[-2:]
     if k_pages.ndim != 5 or v_pages.shape != k_pages.shape:
         raise ValueError(
             "paged_gqa_decode_attention takes the whole pools [layers, "
@@ -526,6 +558,10 @@ def paged_gqa_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
         raise ValueError(
             f"paged_gqa_decode_attention: layer {layer} outside a pool of "
             f"{k_pages.shape[0]} layers")
+    if q.ndim == 4 and window:
+        raise ValueError(
+            "paged_gqa_decode_attention: several queries a lane share one "
+            "mask, which a window (from each query's own position) is not")
     ps, nkv = k_pages.shape[2], k_pages.shape[3]
     if k_pages.shape[4] != hd or nh % nkv:
         raise DoesNotTile(
@@ -544,15 +580,22 @@ def paged_gqa_decode_attention(q, k_pages, v_pages, rows, pos, seq_cap: int,
         sm_scale = 1.0 / (hd ** 0.5)
     if interpret is None:
         interpret = _interpret_default()
+    # the rows of a KV head's products: its g query heads, of every query
+    # of the lane (of its one query: the transposes move an axis of 1)
+    lanes = q.reshape(slots, -1, nkv, g, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(slots, nkv, -1, hd)
     # the columns a lane can walk: a window meets this many pages at most
     walked = min(table_cols, (window - 2) // ps + 2) if window else table_cols
     out = _call(
-        q.reshape(slots, nkv, g, hd), k_pages, v_pages, rows, pos, layer,
+        lanes, k_pages, v_pages, rows, pos, layer,
         attend=_gqa_attend, name="paddle_paged_gqa_decode_fwd",
-        stats=(nkv, g, 128), sm_scale=float(sm_scale), interpret=interpret,
-        page_size=ps, table_cols=table_cols, window=window, walked=walked,
-        block_pages=_block_pages(k_pages, walked, interpret))
-    return out.reshape(slots, nh, hd)
+        stats=lanes.shape[1:3] + (128,), sm_scale=float(sm_scale),
+        interpret=interpret, page_size=ps, table_cols=table_cols,
+        window=window, walked=walked,
+        block_pages=_block_pages(k_pages, walked, interpret,
+                                 rows=lanes.shape[2]))
+    return out.reshape(slots, nkv, -1, g, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(q.shape)
 
 
 def sharded_paged_decode_attention(q, k_pages, v_pages, rows, pos,

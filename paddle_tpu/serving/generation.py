@@ -189,7 +189,7 @@ class _GenRequest:
                  "t_last_token", "span", "own_span", "span_queue",
                  "span_decode", "prefilling", "prefill_cursor",
                  "chunk_row", "chunk_wrow", "j_hit", "pin_final",
-                 "block_start")
+                 "block_start", "block_resolved")
 
     def __init__(self, engine, prompt, bucket, max_new_tokens, do_sample,
                  temperature, top_k, seed, eos, deadline, span=None,
@@ -218,6 +218,9 @@ class _GenRequest:
         self.j_hit = 0                     # prefix-cache pages mapped
         self.pin_final = 0                 # pinned count once armed
         self.block_start = 0               # block engines: the open block
+        # its last collected pass closed the block before block_start: the
+        # pass after that one commits it
+        self.block_resolved = False
         self.handle = GenerationHandle(len(prompt), max_new_tokens)
         self.handle._req = self
 
@@ -1018,12 +1021,13 @@ class GenerationEngine:
                 # (2) the block's B tokens a lane, the block visible both ways
                 off = jnp.arange(B, dtype=jnp.int32)[None]
                 P = start[:, None] + off
-                limits = jnp.broadcast_to((start + B - 1)[:, None], P.shape)
+                # one last key a lane, the block's end: the queries of a
+                # lane share their keys, and PagedKV walks the lane's pages
                 out, _ = functional_call(
                     model, params,
                     (state["blk"], P,
                      PagedKV(state["kp"], state["vp"], ptab, P, active,
-                             seq_cap, limits=limits)),
+                             seq_cap, limits=start + B - 1)),
                     dict(live=active) if counted else {},
                     buffers=buffers, mutable=False, method="slot_step")
                 logits, kv = out[0].astype(jnp.float32), out[1]
@@ -1910,18 +1914,14 @@ class GenerationEngine:
             before = self._flight
             self.metrics.count_step(
                 ahead=before is not None and not before.out[0].is_ready())
-            if spec is None and block is None:
-                # where each lane attends from, of host integers: its
-                # prompt, the tokens it has been handed, and one more if
-                # the step in flight is its own; a lane whose budget is
-                # spent has ended on the device
+            if spec is None:
+                # where each lane attends from, of host integers, for the
+                # share of the page tables that the paged kernel walks
                 flying = () if before is None else \
                     {id(r) for _, r in before.lanes}
-                made = [(r, len(r.handle.tokens) + (id(r) in flying))
-                        for _, r in lanes]
                 self.metrics.observe_page_walk(*self.geometry.page_walk(
-                    [len(r.prompt) + n - 1 for r, n in made
-                     if n < r.max_new_tokens]))
+                    self._attends_from(r, id(r) in flying)
+                    for _, r in lanes))
             if spec is not None:
                 state, *out = spec(self._params, self._draft_params,
                                    self._state)
@@ -1935,6 +1935,24 @@ class GenerationEngine:
             elif block is not None:
                 out.pop()
         return _Step(self._iter, lanes, out)
+
+    def _attends_from(self, req: _GenRequest, flying: bool):
+        """The last position the lane's queries see in the step being
+        launched, from what the host has been handed (``flying``: the
+        step in flight is the lane's own too), or None for a lane whose
+        budget is spent: it has ended on the device.  One-token engine:
+        its prompt, the tokens it has been handed, and one more if
+        flying.  Block engine: the end of the block the device holds,
+        which is the one before ``block_start`` while the pass that
+        commits it is still to launch."""
+        L, B = len(req.prompt), self.block_length
+        if not B:
+            n = len(req.handle.tokens) + flying
+            return L + n - 1 if n < req.max_new_tokens else None
+        start = req.block_start
+        if req.block_resolved and not flying:
+            start -= B
+        return start + B - 1 if start < L + req.max_new_tokens else None
 
     def _collect(self):
         """Fetch and hand out the step in flight, if there is one: only
@@ -1999,6 +2017,7 @@ class GenerationEngine:
             resolved, commit, fin = (bool(f) for f in report[slot, 2 * B:])
             committed += commit
             denoised += not commit
+            req.block_resolved = resolved
             if resolved:
                 L = len(req.prompt)
                 lo = max(req.block_start, L) - req.block_start

@@ -164,12 +164,13 @@ class CacheGeometry:
         return self.window // self.page_size + 2
 
     def page_walk(self, positions) -> tuple[int, int]:
-        """What one decode step's paged attention has to walk, summed
-        over the layers: (the page slots of every lane's every table
-        column, which a grid over the table visits whatever is mapped;
-        the pages that the extents of lanes attending from ``positions``
-        cover: a full layer's from column 0, a window layer's from the
-        first column that meets the window, to the position's)."""
+        """What one decode (or block) step's paged attention has to walk,
+        summed over the layers: (the page slots of every lane's every
+        table column, which a grid over the table visits whatever is
+        mapped; the pages that the extents of lanes attending from
+        ``positions`` cover, None for a lane that has ended: a full
+        layer's from column 0, a window layer's from the first column
+        that meets the window, to the position's)."""
         ps, cols = self.page_size, self.pages_per_slot
         n_win = len(self.window_layers)
         n_full = self.num_layers - n_win
@@ -177,6 +178,8 @@ class CacheGeometry:
         slots = self.max_slots * (n_full * cols + n_win * win_cols)
         live = 0
         for pos in positions:
+            if pos is None:         # a lane that has ended on the device
+                continue
             last = min(pos // ps, cols - 1)
             first = max(pos - self.window + 1, 0) // ps
             live += n_full * (last + 1) + n_win * max(last - first + 1, 0)
@@ -404,10 +407,22 @@ class PagedKV:
     one-token step would have seen, so accepted tokens stay bitwise-equal
     to the sequential path.
 
-    ``limits`` [slots, C], when given, is the last key position each
-    query may see in place of its own: a block-generating step gives every
-    query of a block the block's end, so the block is visible both ways
-    over the committed prefix.
+    ``limits``, when given, is the last key position a query may see in
+    place of its own: a block-generating step gives every query of a block
+    the block's end, so the block is visible both ways over the committed
+    prefix.  [slots]: ONE a lane, what ``block_step`` hands; [slots, C]:
+    one a query.
+
+    Which steps read the pool through the paged kernels
+    (ops/pallas/paged_attention.py: a lane's mapped pages walked in place)
+    and which gather the table's pages into a dense view follows from
+    these operands alone.  The walk takes ONE last key a lane: a
+    one-token step (``positions`` [slots], full and window layers), and a
+    chunk whose ``limits`` is [slots] on a layer without a window (the
+    block step: the C queries of a lane are rows of the same products).
+    The gather takes a last key a query (``positions`` [slots, C] with no
+    ``limits``, speculative verification; ``limits`` [slots, C]), a chunk
+    on a window layer, and whatever the kernel refuses (counted).
     """
     k_pages: Any
     v_pages: Any
@@ -455,19 +470,31 @@ class PagedKV:
         off = pos % ps
         kp = kp.at[layer, page, off].set(k.astype(kp.dtype), mode="drop")
         vp = vp.at[layer, page, off].set(v.astype(vp.dtype), mode="drop")
-        # hot path, one token a lane: the Pallas ragged kernel walks each
-        # lane's page-table row and reads plane `layer` of the pool in
-        # place.  None => flag off / untileable geometry (counted in
+        # hot path: the Pallas ragged kernel walks each lane's page-table
+        # row and reads plane `layer` of the pool in place.  It takes one
+        # last key a lane: a one-token step's position, or the `limits`
+        # [slots] that a block step gives all of a lane's queries (no
+        # window there: a window is measured from each query's own
+        # position).  None => flag off / untileable geometry (counted in
         # paddle_pallas_fallbacks_total).  The dense gather below is the
-        # reference, the fallback, and the chunk's path (verification is
-        # one step per K drafted tokens, off the per-token hot loop).
-        # A pool of KV heads, each read by a group of query heads, and a
-        # layer with a window have a kernel of their own behind the same
-        # call; a chunk of grouped queries (a block step) takes the gather
-        # by design, uncounted.
-        ctx = fused.paged_decode_attention(
-            q, kp, vp, rows, pos, self.seq_cap, layer,
-            tp_axis=head_axis, window=window) if one else None
+        # reference, the fallback, and the path of a chunk with a last key
+        # a QUERY (speculative verification, causal inside the chunk: one
+        # step per K drafted tokens) or on a window layer, by design and
+        # uncounted.  A pool of KV heads, each read by a group of query
+        # heads, a layer with a window and a lane of several queries have
+        # a kernel of their own behind the same call.
+        limits = self.limits
+        ctx = None
+        if one:
+            ctx = fused.paged_decode_attention(
+                q, kp, vp, rows, pos, self.seq_cap, layer,
+                tp_axis=head_axis, window=window)
+        elif limits is not None and limits.ndim == 1 and not window:
+            ctx = fused.paged_decode_attention(
+                q, kp, vp, rows, limits, self.seq_cap, layer,
+                tp_axis=head_axis)
+        # the last key each query sees, [slots, C] or [slots, 1]
+        last = (pos if limits is None else limits).reshape(B, -1)
         if ctx is None and window:
             # the gather is as wide as the pages a window can meet (and a
             # chunk can span), not the table
@@ -482,7 +509,6 @@ class PagedKV:
             kg = kp[layer, gidx].reshape(B, ncol * ps, nkv, hd)
             vg = vp[layer, gidx].reshape(B, ncol * ps, nkv, hd)
             kpos = (col[:, :, None] * ps + jnp.arange(ps)).reshape(B, 1, -1)
-            last = qpos if self.limits is None else self.limits
             valid = (kpos <= last[:, :, None]) \
                 & (kpos > qpos[:, :, None] - window) \
                 & jnp.repeat(ids >= 0, ps, axis=1)[:, None]
@@ -491,9 +517,8 @@ class PagedKV:
             gidx = jnp.clip(rows, 0, num_pages - 1)
             kg = kp[layer, gidx].reshape(B, rows.shape[1] * ps, nkv, hd)
             vg = vp[layer, gidx].reshape(B, rows.shape[1] * ps, nkv, hd)
-            last = pos if self.limits is None else self.limits
             valid = jnp.arange(self.seq_cap)[None, None, :] \
-                <= last.reshape(B, -1)[:, :, None]
+                <= last[:, :, None]
             ctx = _attend(
                 q, kg[:, :self.seq_cap], vg[:, :self.seq_cap], valid)
         return unwrap(ctx), kp, vp

@@ -339,9 +339,10 @@ class GenerationMetrics:
             "armed) or was swept before the collect")
         self._page_slots = reg.counter(
             "paddle_genserve_paged_page_slots_total",
-            "page-table slots of the one-token decode steps launched: "
-            "steps x slots x table columns, summed over the layers (what "
-            "a paged-attention grid over the whole table visits)")
+            "page-table slots of the one-token decode steps and block "
+            "steps launched: steps x slots x table columns, summed over "
+            "the layers (what a gather of the whole table, or a "
+            "paged-attention grid over it, visits)")
         self._pages_live = reg.counter(
             "paddle_genserve_paged_pages_live_total",
             "of paged_page_slots_total, the pages the live lanes' extents "
@@ -440,9 +441,9 @@ class GenerationMetrics:
             self._steps_ahead.inc()
 
     def observe_page_walk(self, slots: int, live: int):
-        """One one-token decode step launched: the page slots of its page
-        tables and the pages its live lanes' extents cover
-        (``CacheGeometry.page_walk``)."""
+        """One one-token decode step or block step launched: the page
+        slots of its page tables and the pages its live lanes' extents
+        cover (``CacheGeometry.page_walk``)."""
         self._page_slots.inc(slots)
         self._pages_live.inc(live)
 

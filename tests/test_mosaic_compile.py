@@ -131,6 +131,26 @@ def _gqa_case(planes, pages, layer, window):
                ((SLOTS, GQA_SEQ // GQA_PAGE), I32), ((SLOTS,), I32)]
 
 
+# the grouped call as a block step of `configs/sdar-30b-a3b.json` makes it
+# at 16 slots x 1280 (PERF.md section 4, item 3): a block of 4 queries a
+# lane, 32 query heads over 4 KV heads of 128, all seeing the lane's keys
+# to the block's end: the 4 x 8 queries of a KV head are the rows of one
+# product; 1280 positions in pages of 16, plane 3 of the 6 layers' pool
+BLOCK_C, BLOCK_SEQ, BLOCK_LAYERS = 4, 1280, 6
+BLOCK_POOL = ((BLOCK_LAYERS, SLOTS * BLOCK_SEQ // PAGE, PAGE, GQA_NKV,
+               GQA_HD), BF16)
+
+
+def _gqa_block(q, kp, vp, rows, pos):
+    return pa.paged_gqa_decode_attention(q, kp, vp, rows, pos, BLOCK_SEQ, 3,
+                                         interpret=False)
+
+
+GQA_BLOCK_ARGS = [((SLOTS, BLOCK_C, GQA_NH, GQA_HD), BF16), BLOCK_POOL,
+                  BLOCK_POOL, ((SLOTS, BLOCK_SEQ // PAGE), I32),
+                  ((SLOTS,), I32)]
+
+
 # the grouped expert FFN as sdar-30b-a3b.blockgen calls it: 128 experts of
 # 2048 x 768 (gate, up) and 768 x 2048 (down); a block step's 512
 # assignments (16 lanes x 4 positions x 8 experts) and a 768-token
@@ -180,6 +200,7 @@ CASES = {
     "paged_decode_gpt2": (_paged, PAGED_GPT2_ARGS),
     "paged_gqa_decode_full": _gqa_case(2, 2048, 1, 0),
     "paged_gqa_decode_window": _gqa_case(6, 800, 4, GQA_WINDOW),
+    "paged_gqa_block_step": (_gqa_block, GQA_BLOCK_ARGS),
     "moe_gmm_block_step_512": _gmm_case(512),
     "moe_gmm_prefill_6144": _gmm_case(6144),
 }
@@ -341,6 +362,9 @@ def test_paged_roofline_pattern_finds_the_compiled_call(v5e):
      (GQA_NKV, GQA_NH // GQA_NKV, GQA_HD)),
     ("paged_gqa_decode_window", "paddle_paged_gqa_decode_fwd",
      (GQA_NKV, GQA_NH // GQA_NKV, GQA_HD)),
+    # a block step's: the same call with the block's queries among the rows
+    ("paged_gqa_block_step", "paddle_paged_gqa_decode_fwd",
+     (GQA_NKV, BLOCK_C * GQA_NH // GQA_NKV, GQA_HD)),
 ])
 def test_paged_calls_keep_their_operands_and_result(v5e, case, kernel, lane):
     """The custom call's operands in order (the page table, the positions,
@@ -373,6 +397,10 @@ def test_paged_call_walks_pages_no_copy_can_cut_out_by_the_grid(v5e):
     the loop inside the kernel where a page is whole tiles."""
     assert pa._block_pages(jax.ShapeDtypeStruct(*GPT2_POOL), 64, False) == 0
     assert pa._block_pages(jax.ShapeDtypeStruct(*POOL), 64, False) == 8
+    # a block step's 32 rows a product share a turn among twice the keys
+    assert pa._block_pages(jax.ShapeDtypeStruct(*BLOCK_POOL), 80, False) == 8
+    assert pa._block_pages(jax.ShapeDtypeStruct(*BLOCK_POOL), 80, False,
+                           rows=32) == 16
     (call,) = _compiled_calls(v5e, "paged_decode_gpt2")
     planes, pages, *page = GPT2_POOL[0]
     pool = ",".join(map(str, [planes * pages] + page))
@@ -556,3 +584,91 @@ def test_decode_step_keeps_the_donated_pools_in_place_on_cpu():
         nh=2, hd=16, pages=4 * slots * seq // page, dtype="float32",
         pallas=False)
     _assert_pools_stay_in_place(compiled, pool, 4)
+
+
+# -- the block step walks the pool where it lies ------------------------------
+# `block_step` (serving/generation.py) hands `PagedKV` one last key a lane,
+# so a block's queries read the lane's mapped pages through the paged call;
+# gathered, every layer copied all 1,280 page slots of the 16 lanes' tables,
+# K and V (`bf16[1280,16,4,128] fusion(bf16[6,1280,16,4,128], s32[1280])`,
+# a fifth of the blockgen cell's step: PERF.md section 6, PR 37).
+def _block_step_compiled(device):
+    """The engine's own `block_step`, as `GenerationEngine.start()` builds
+    it, compiled for `device`: a small SDAR model at the blockgen cell's
+    attention geometry (6 layers, 32 query heads over 4 KV heads of 128,
+    blocks of 4; 16 slots x 1280 in pages of 16, bf16).  Returns
+    (compiled, pool shape)."""
+    import paddle_tpu as paddle
+    from paddle_tpu import inference
+    from paddle_tpu.models.sdar import SDARConfig, SDARForCausalLM
+    from paddle_tpu.serving import GenerationEngine
+
+    net = SDARForCausalLM(SDARConfig(
+        vocab_size=512, hidden_size=256, num_layers=BLOCK_LAYERS,
+        num_heads=GQA_NH, num_kv_heads=GQA_NKV, head_dim=GQA_HD,
+        moe_intermediate_size=128, num_experts=8, num_experts_per_tok=2,
+        max_position_embeddings=BLOCK_SEQ, block_length=BLOCK_C,
+        denoising_steps=BLOCK_C, mask_token_id=511))
+    net.eval()
+    for p in net.parameters():
+        p._value = p._value.astype(BF16)
+    eng = GenerationEngine(net, max_slots=SLOTS, max_seq_len=BLOCK_SEQ,
+                           page_size=PAGE, prompt_buckets=[256])
+    sharding = jax.sharding.SingleDeviceSharding(device)
+    built = []
+
+    class Built(Exception):
+        pass
+
+    def aot(fn, arg_specs, *, donate_argnums=(), out_shardings=None):
+        # the first executable `start()` builds; the others are not wanted
+        assert fn.__name__ == "block_step", fn.__name__
+        specs = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding),
+            tuple(arg_specs))
+        built.append(jax.jit(fn, donate_argnums=donate_argnums)
+                     .lower(*specs).compile())
+        raise Built
+
+    # the program asks the process which backend it runs under; the test
+    # answers in its place while the step is traced
+    real_aot, real_backend = inference.aot_compile, jax.default_backend
+    inference.aot_compile, jax.default_backend = aot, lambda: "tpu"
+    try:
+        with pytest.raises(Built):
+            eng.start()
+    finally:
+        inference.aot_compile, jax.default_backend = real_aot, real_backend
+        eng.stop()
+    return built[0], BLOCK_POOL[0]
+
+
+@pytest.mark.kernels
+def test_block_step_walks_the_donated_pools_in_place_on_v5e(v5e):
+    """The compiled `block_step` at the blockgen cell's attention geometry
+    holds one paged call a layer, each given the page table, one last key
+    a lane, the lanes' queries laid [slots, KV heads, block x group, head]
+    and the two WHOLE pools; the only instructions that output a pool or
+    a plane of one are the in-place scatters of the block's K/V: no gather
+    of the table's pages, no copy of a pool around the calls."""
+    compiled, pool = _block_step_compiled(v5e.devices[0])
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_block_step")
+    dims = ",".join(map(str, pool))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "paddle_paged_gqa_decode_fwd" in line.split(" = ", 1)[0]]
+    assert len(calls) == BLOCK_LAYERS
+    rows = BLOCK_C * GQA_NH // GQA_NKV
+    q = f"bf16[{SLOTS},{GQA_NKV},{rows},{GQA_HD}]"
+    operands = (f"operand_layout_constraints={{s32[{SLOTS},"
+                f"{BLOCK_SEQ // PAGE}]{{1,0}}, s32[{SLOTS}]{{0}}, "
+                f"{q}{{3,2,1,0}}, "
+                f"bf16[{dims}]{{4,3,2,1,0}}, bf16[{dims}]{{4,3,2,1,0}}}}")
+    for call in calls:
+        assert operands in call, call[:160]
+        assert call.lstrip().startswith(
+            f"%paddle_paged_gqa_decode_fwd"), call[:80]
+        assert call.split(" = ", 1)[1].startswith(q), call[:160]
+    _assert_pools_stay_in_place(compiled, pool, 2)

@@ -838,6 +838,75 @@ def test_paged_walk_block_comes_from_the_calls_shapes(shape, cols, pages):
 
 
 @pytest.mark.kernels
+@pytest.mark.parametrize("rows, pages", [(8, 8), (16, 8), (32, 16),
+                                         (64, 16)])
+def test_paged_walk_block_doubles_its_keys_from_32_rows_a_product(rows,
+                                                                  pages):
+    """The blockgen cell's pool (pages of 16 keys x 4 KV heads of 128):
+    8 pages a block for a one-token step's 8 rows a product, 16 for a
+    block step's 32 (PERF.md section 6, PR 37), the VMEM budget's 16 at
+    most."""
+    from paddle_tpu.ops.pallas.paged_attention import _block_pages
+
+    pool = jax.ShapeDtypeStruct((6, 1280, 16, 4, 128), jnp.bfloat16)
+    assert _block_pages(pool, 80, interpret=False, rows=rows) == pages
+
+
+# -- several queries a lane, one last key ------------------------------------
+# A block-generating step brings C queries a lane that all see the lane's
+# keys up to `pos`: the grouped call takes them [slots, C, nh, hd] and lays
+# the C x g queries of a KV head as the rows of one product.
+@pytest.mark.kernels
+@pytest.mark.parametrize("g", [1, 4, 8], ids=lambda g: f"g{g}")
+def test_paged_gqa_takes_a_lanes_queries_as_rows_of_one_product(g,
+                                                                page_walk):
+    """Against the one-token call asked once a query (the same keys, the
+    same mask) and the dense gather: a lane of one page, one that fills
+    the table (blocks and a tail), one at a block's edge, a released lane;
+    at g = 8 the 32 rows a product walk blocks of twice the keys."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_gqa_decode_attention
+
+    C, ps, nkv, hd, cols, layer = 4, 8, 2, 16, 37, 1
+    lengths = [3, cols * ps, 128, 17 * ps + 4, 0]
+    rows = _table(lengths, cols)
+    pos = np.asarray([n - 1 for n in lengths], np.int32)
+    rs = np.random.RandomState(20 + g)
+    q = jnp.asarray(rs.randn(len(lengths), C, nkv * g, hd), jnp.float32)
+    kp = jnp.asarray(rs.randn(3, int(rows.max()) + 2, ps, nkv, hd),
+                     jnp.float32)
+    vp = jnp.asarray(rs.randn(*kp.shape), jnp.float32)
+    out = np.asarray(paged_gqa_decode_attention(
+        q, kp, vp, rows, pos, cols * ps, layer))
+    assert out.shape == q.shape
+    assert not out[-1].any()
+    for c in range(C):
+        one = paged_gqa_decode_attention(q[:, c], kp, vp, rows, pos,
+                                         cols * ps, layer)
+        np.testing.assert_allclose(out[:, c], np.asarray(one),
+                                   rtol=1e-5, atol=1e-5)
+        ref = _dense_gqa_ref(q[:, c], kp, vp, jnp.asarray(rows),
+                             jnp.asarray(pos), layer, 0)
+        np.testing.assert_allclose(out[:-1, c], np.asarray(ref)[:-1],
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.kernels
+def test_paged_gqa_refuses_a_window_over_a_lanes_queries():
+    """A window is measured from each query's own position: a mask a row,
+    which one last key a lane is not."""
+    from paddle_tpu.ops.pallas.paged_attention import \
+        paged_gqa_decode_attention
+
+    q = jnp.zeros((2, 4, 4, 16))
+    kp = jnp.zeros((1, 4, 8, 2, 16))
+    with pytest.raises(ValueError, match="window"):
+        paged_gqa_decode_attention(q, kp, kp, jnp.zeros((2, 2), jnp.int32),
+                                   jnp.zeros((2,), jnp.int32), 16, 0,
+                                   window=8)
+
+
+@pytest.mark.kernels
 @pytest.mark.parametrize("shape, dtype", [
     ((16, 12, 64), jnp.bfloat16),     # GPT-2: heads of 64, 12 of them
     ((16, 12, 128), jnp.float32),     # 12 heads: no whole tiles

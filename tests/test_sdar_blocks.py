@@ -201,6 +201,163 @@ def test_paged_source_reads_grouped_heads_to_the_blocks_end():
     assert not np.asarray(src.k_pages)[0, [0, 2]].any()
 
 
+# -- a block step's queries through the paged walk ---------------------------
+# `PagedKV` with ONE last key a lane (`limits` [slots], what `block_step`
+# hands) reads the lane's mapped pages through the paged kernel, the C x g
+# queries of a KV head as rows of one product; interpreted here.  A lane is
+# (the block's start, active, mapped, pages): `pages` are the lane's table
+# row, None leaving it unmapped.
+WALK_NQ, WALK_NKV, WALK_HD, WALK_PS, WALK_B, WALK_CAP = 8, 2, 16, 8, 4, 48
+WALK_CASES = {
+    # extents of one page, three pages and the table's whole width
+    "lanes_of_different_extents": [(4, True, [5, -1, -1, -1, -1, -1]),
+                                   (20, True, [9, 2, 7, -1, -1, -1]),
+                                   (44, True, [1, 3, 4, 6, 8, 10])],
+    # the block's end in the middle of its page, and on its last key
+    "an_extent_that_ends_mid_page": [(8, True, [11, 12, -1, -1, -1, -1]),
+                                     (12, True, [13, 14, -1, -1, -1, -1])],
+    # neither writes; the unmapped one walks nothing and reads zero
+    "an_inactive_lane_and_an_unmapped_row": [
+        (16, False, [5, 6, 7, -1, -1, -1]),
+        (8, True, [2, 3, -1, -1, -1, -1]),
+        (0, False, None)],
+    # no committed prefix at all: the block sees itself alone
+    "a_block_in_the_first_page": [(0, True, [4, -1, -1, -1, -1, -1]),
+                                  (4, True, [9, -1, -1, -1, -1, -1])],
+}
+
+
+def walk_source(lanes, seed, limits="lane"):
+    """A pool of random keys, the lanes' tables, and each lane's block:
+    (source, q, k, v) with `limits` one a lane, one a query (the same
+    values, broadcast) or None."""
+    rng = np.random.default_rng(seed)
+    n, C = len(lanes), WALK_B
+    pool = (1, 16, WALK_PS, WALK_NKV, WALK_HD)
+    kp, vp = (jnp.asarray(rng.normal(size=pool).astype(np.float32))
+              for _ in range(2))
+    q, k, v = (jnp.asarray(rng.normal(size=(n, C, h, WALK_HD)).astype(
+        np.float32)) for h in (WALK_NQ, WALK_NKV, WALK_NKV))
+    rows = jnp.asarray([r or [-1] * 6 for _, _, r in lanes], jnp.int32)
+    start = jnp.asarray([s for s, _, _ in lanes], jnp.int32)
+    P = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+    last = start + C - 1
+    lim = {"lane": last, "query": jnp.broadcast_to(last[:, None], P.shape),
+           None: None}[limits]
+    src = PagedKV(kp, vp, rows, P, jnp.asarray([a for _, a, _ in lanes]),
+                  WALK_CAP, limits=lim)
+    return src, q, k, v
+
+
+def lane_reference(kp, vp, q, start, row, mask):
+    """`ref_attention` of one lane's block of queries `q` at positions
+    `start...` over the keys its table `row` maps in planes `kp`/`vp`;
+    `mask(pos)` gives the [T, T] mask from the positions 0..T-1.  Returns
+    (context of the block's queries, the lane's keys)."""
+    T = start + WALK_B
+    pages = [p for p in row if p >= 0][:-(-T // WALK_PS)]
+    keys, vals = (a[pages].reshape(-1, WALK_NKV, WALK_HD)[:T]
+                  for a in (kp, vp))
+    qs = np.zeros((T, WALK_NQ, WALK_HD), np.float32)
+    qs[start:] = q
+    return ref_attention(qs, keys, vals, mask(np.arange(T)))[start:], keys
+
+
+def everything(pos):
+    return np.ones((len(pos), len(pos)), bool)
+
+
+@pytest.fixture
+def paged_calls(monkeypatch):
+    """The kernels on (interpreted), and every call of the paged kernel's
+    wrapper recorded by its query's shape."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    calls, real = [], pa.paged_gqa_decode_attention
+
+    def spy(q, *a, **kw):
+        calls.append(q.shape)
+        return real(q, *a, **kw)
+
+    monkeypatch.setattr(fused, "_use_pallas", lambda: True)
+    monkeypatch.setattr(pa, "paged_gqa_decode_attention", spy)
+    return calls
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_block_queries_walk_the_lanes_pages_and_equal_the_gather(
+        case, paged_calls, monkeypatch):
+    lanes = WALK_CASES[case]
+    src, q, k, v = walk_source(lanes, seed=len(case))
+    before = sum(fused.fallback_counter().values.values())
+    ctx, out = src.attend(0, q, k, v)
+    assert paged_calls == [(len(lanes), WALK_B, WALK_NQ, WALK_HD)]
+    assert sum(fused.fallback_counter().values.values()) == before
+    monkeypatch.setattr(fused, "_use_pallas", lambda: False)
+    dense, dense_out = src.attend(0, q, k, v)
+    # the same writes: the block's K/V at its own positions, active lanes'
+    np.testing.assert_array_equal(np.asarray(out.k_pages),
+                                  np.asarray(dense_out.k_pages))
+    np.testing.assert_array_equal(np.asarray(out.v_pages),
+                                  np.asarray(dense_out.v_pages))
+    kp, vp = np.asarray(out.k_pages)[0], np.asarray(out.v_pages)[0]
+    for i, (start, active, row) in enumerate(lanes):
+        got = np.asarray(ctx)[i]
+        if row is None:
+            assert not got.any()
+            continue
+        np.testing.assert_allclose(got, np.asarray(dense)[i], atol=2e-5)
+        # and the plain reference over the lane's own keys, every query
+        # seeing all of them to the block's end
+        want, keys = lane_reference(kp, vp, np.asarray(q)[i], start, row,
+                                    everything)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        if active:
+            np.testing.assert_array_equal(keys[start:], np.asarray(k)[i])
+    written = (np.asarray(out.k_pages) != np.asarray(src.k_pages)).any(
+        axis=(0, 2, 3, 4))
+    assert set(np.flatnonzero(written)) == {
+        row[start // WALK_PS] for start, active, row in lanes if active}
+
+
+@pytest.mark.parametrize("form", ["a_limit_a_query", "no_limits_causal",
+                                  "a_window_layer"])
+def test_other_chunks_keep_the_gather_and_count_no_fallback(form,
+                                                            paged_calls):
+    """What `spec_step` sends (a last key a QUERY: causal inside the
+    chunk), `limits` [slots, C], and a chunk on a window layer gather as
+    they did, by design: the kernel is not asked, and nothing is counted
+    as a fallback."""
+    lanes = WALK_CASES["lanes_of_different_extents"]
+    src, q, k, v = walk_source(
+        lanes, seed=9, limits={"a_limit_a_query": "query",
+                               "no_limits_causal": None,
+                               "a_window_layer": "lane"}[form])
+    window = 0
+    if form == "a_window_layer":
+        from dataclasses import replace
+
+        window = 16
+        src = replace(src, wk_pages=src.k_pages, wv_pages=src.v_pages,
+                      wrows=src.rows, windows=(window,))
+    before = sum(fused.fallback_counter().values.values())
+    ctx, out = src.attend(0, q, k, v)
+    assert paged_calls == []
+    assert sum(fused.fallback_counter().values.values()) == before
+    kp, vp = (np.asarray(a)[0] for a in (
+        (out.wk_pages, out.wv_pages) if window else
+        (out.k_pages, out.v_pages)))
+
+    def mask(pos):
+        m = everything(pos) if form != "no_limits_causal" \
+            else pos[None] <= pos[:, None]
+        return m & (pos[None] > pos[:, None] - window) if window else m
+
+    for i, (start, _, row) in enumerate(lanes):
+        want, _ = lane_reference(kp, vp, np.asarray(q)[i], start, row, mask)
+        np.testing.assert_allclose(np.asarray(ctx)[i], want, atol=2e-5)
+
+
 def test_prefix_source_masks_the_suffix_by_blocks():
     rng = np.random.default_rng(3)
     nq, nkv, hd, T = 4, 2, 16, 16                    # prefix 8, suffix 8
@@ -269,9 +426,24 @@ def engine_for(net, **kw):
     return GenerationEngine(net, **args).start()
 
 
-@pytest.fixture(scope="module")
-def engine(tiny):
-    eng = engine_for(tiny[1])
+def kernel_engine_for(net, **kw):
+    """`engine_for` with the Pallas kernels on (interpreted here) while
+    `start()` traces and builds every executable; none is traced later."""
+    real = fused._use_pallas
+    fused._use_pallas = lambda: True
+    try:
+        return engine_for(net, **kw)
+    finally:
+        fused._use_pallas = real
+
+
+@pytest.fixture(scope="module", params=["composite", "kernels"])
+def engine(tiny, request):
+    """The engine every way it is built: XLA's composites (what the CPU
+    takes), and the kernels, where a block step's attention is the paged
+    walk and its experts the grouped product."""
+    eng = (kernel_engine_for if request.param == "kernels"
+           else engine_for)(tiny[1])
     yield eng
     eng.stop()
 
@@ -436,6 +608,47 @@ def test_counters_and_gaps_say_what_a_client_sees(tiny):
         assert "paddle_genserve_block_steps_total 21" in text
         assert 'paddle_genserve_block_lane_steps_total{kind="committed"} 4' \
             in text
+    finally:
+        eng.stop()
+
+
+def test_block_steps_feed_the_page_walk_counters_and_nothing_falls_back(
+        tiny):
+    """`paged_pages_live / paged_page_slots` is the share of the page
+    tables that a block step's paged kernel walks, from the host's own
+    bookkeeping: a lane attends to its block's end in every pass of the
+    block (one a mask and the committing one), over the pages up to that
+    one.  With the kernels on, a served request counts no fallback."""
+    B, ps, layers, slots, cap = 4, 8, 2, 2, 96
+    before = sum(fused.fallback_counter().values.values())
+    eng = kernel_engine_for(tiny[1], max_slots=slots)
+    try:
+        asked = [(prompt_of(11, seed=1), 14), (prompt_of(8, seed=2), 32),
+                 (prompt_of(17, seed=3), 5)]
+        hs = [eng.submit(p, n) for p, n in asked]
+        for h, (p, n) in zip(hs, asked):
+            want_t, want_s, _ = ref.block_diffusion_generate(
+                tiny[0], p.tolist(), CFG, n)
+            assert h.result(120) == want_t and h.steps == want_s
+        assert eng.drain(timeout=60)
+        snap = eng.metrics.snapshot()
+        live = 0
+        for p, n in asked:
+            L = len(p)
+            for start in range(L // B * B, L + n, B):
+                # the prompt's tail is known: a pass a mask left, and one
+                passes = B - max(L - start, 0) + 1
+                live += passes * ((start + B - 1) // ps + 1)
+        assert snap["paged_pages_live"] == layers * live
+        assert snap["paged_page_slots"] \
+            == snap["steps"] * layers * slots * (cap // ps)
+        assert 0 < snap["paged_pages_live"] < snap["paged_page_slots"]
+        text = eng.metrics.prometheus_text()
+        assert "paddle_genserve_paged_page_slots_total " \
+            f"{snap['paged_page_slots']}" in text
+        assert "paddle_genserve_paged_pages_live_total " \
+            f"{snap['paged_pages_live']}" in text
+        assert sum(fused.fallback_counter().values.values()) == before
     finally:
         eng.stop()
 
