@@ -20,9 +20,11 @@ reference gets the same effect from fluid's inplace op buffers), and the
 fit loop dispatches steps without ever blocking: loss scalars stay in
 flight inside `_LossRing` and are fetched in one batched `device_get`
 only at `log_freq` boundaries, epoch ends, and checkpoints.  Write-back
-into Layer `_value`s happens only at epoch boundaries / checkpoints /
-`fit()` exit, so dygraph-style inspection between epochs (and the
-single-call `Model.train_batch` contract) still works.
+into Layer `_value`s happens at `fit()` exit and at the epoch ends
+where something will read the Layer tree (an evaluate, a checkpoint, a
+user callback: `Model.fit` knows them), as ONE jitted copy of the whole
+tree; a copy that nothing reads is not kept on the device.  The
+single-call `Model.train_batch` contract is untouched.
 
 Every DELIBERATE device→host fetch goes through `host_fetch()`, which
 opens an explicit `jax.transfer_guard_device_to_host("allow")` scope —
@@ -48,8 +50,6 @@ reductions (partial sums + all-reduce), so dp=1 vs dp=8 agree to
 float32 ULP, not bit-for-bit (tests/test_spmd_fit.py pins both).
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -147,12 +147,18 @@ class _LossRing:
         return out
 
 
+@jax.jit
 def _copy_tree(tree):
-    # device-side copies (async, once per fit/epoch — NOT per step): the
-    # engine donates its state buffers, so anything the Layer tree keeps
-    # referencing must be a distinct buffer or the next dispatch would
-    # invalidate it under the user's feet
-    return jax.tree_util.tree_map(lambda a: jnp.array(a, copy=True), tree)
+    # a device-side copy of the whole tree in ONE dispatch (async, at
+    # most once an epoch — NOT per step): the engine donates its state
+    # buffers, so anything the Layer tree keeps referencing must be a
+    # distinct buffer or the next dispatch would invalidate it under the
+    # user's feet.  `jnp.copy` lowers to a copy XLA may not elide, and
+    # the inputs are not donated, so no output aliases an input
+    # (tests/test_train_engine.py reads the buffers' addresses).  One
+    # executable a tree structure: begin()'s state, and write_back()'s
+    # tree with and without the optimizer's slots.
+    return jax.tree_util.tree_map(jnp.copy, tree)
 
 
 def _tree_deleted(tree):
@@ -203,11 +209,11 @@ class TrainEngine:
     """Owns the device-resident state for one Model across fit() runs.
 
     Lifecycle: `begin()` snapshots Layer state → N x `step()` (donated,
-    sync-free) → `write_back()` at epoch/checkpoint boundaries →
-    `finish()` at fit exit.  The compiled step function is cached on the
-    instance, and the instance is cached on the Model, so repeated fit()
-    calls (and the persistent XLA compilation cache across processes —
-    FLAGS_jit_cache_dir) skip recompilation.
+    sync-free) → `write_back()` at the boundaries where the Layer tree
+    has a reader → `finish()` at fit exit.  The compiled step function
+    is cached on the instance, and the instance is cached on the Model,
+    so repeated fit() calls (and the persistent XLA compilation cache
+    across processes — FLAGS_jit_cache_dir) skip recompilation.
     """
 
     def __init__(self, model):
@@ -756,10 +762,14 @@ class TrainEngine:
     # -- state egress ------------------------------------------------------
     def write_back(self, copy=True, sync_opt=True):
         """Re-bind the device-resident state into the Layer tree (and the
-        optimizer's opt-state slot).  With copy=True (mid-run epoch
-        boundaries) the Layer tree receives device-side COPIES so the
-        engine can keep donating its own buffers; copy=False hands over
-        the buffers themselves (fit exit — no further donation).
+        optimizer's opt-state slot).  With copy=True (mid-run: an epoch
+        boundary that has a reader, a user callback's batch) the Layer
+        tree receives device-side COPIES, made by one dispatch over the
+        whole tree (`_copy_tree`), so the engine can keep donating its
+        own buffers; copy=False hands over the buffers themselves (fit
+        exit — no further donation).  `Model.fit` calls it at an epoch's
+        end only when something will read the tree there; between such
+        boundaries the tree holds the values of the last sync.
 
         User writes since the last sync (e.g. a weight-clip after the
         LAST batch of an epoch) are folded into the state first, so a
@@ -767,47 +777,39 @@ class TrainEngine:
 
         sync_opt=False skips the opt-state copy/rebind (the dominant
         bytes for Adam-family slots): the per-batch write-back of the
-        custom-callback path uses it, since callbacks observe
-        params/buffers — `model._opt_state` stays at its last
-        epoch/checkpoint value until the next full sync, and fault-
-        tolerance checkpoints read the live engine state directly.
+        custom-callback path and an epoch's end whose only reader is an
+        evaluate use it, since those observe params/buffers —
+        `model._opt_state` stays at its last full sync until the next
+        one, and fault-tolerance checkpoints read the live engine state
+        directly.
 
         Mesh mode always DE-SHARDS: the Layer tree receives single-
         device arrays (one replica pulled off the mesh — a gather for
         mp-split params), so evaluate/train_batch/save and user
         callbacks after or between sharded epochs never see a
-        multi-device committed array.  The cross-sharding device_put is
-        a fresh buffer by construction, so donation stays safe even
-        with copy=False."""
+        multi-device committed array.  device_put onto the mesh's first
+        device ALIASES the replica already living there (no copy) — and
+        the engine donates that buffer on the next dispatch — so the
+        de-sharded tree is always copied, even with copy=False."""
         st = self.state
         if st is None:
             return
         self.refresh_from_layers()
-        trainable, buffers = st["trainable"], st["buffers"]
+        tree = {"trainable": st["trainable"], "buffers": st["buffers"]}
+        if sync_opt:
+            tree["opt"] = st["opt"]
         if self.mesh is not None:
-            dev0 = self.mesh.devices.flat[0]
-
-            def de_shard(a):
-                # device_put onto dev0 ALIASES the replica already living
-                # there (no copy) — and the engine donates that buffer on
-                # the next dispatch, which would mutate the Layer tree's
-                # array in place.  Force a real copy after the de-shard.
-                return jnp.array(jax.device_put(a, dev0), copy=True)
-
-            unshard = partial(jax.tree_util.tree_map, de_shard)
-            trainable, buffers = unshard((trainable, buffers))
+            tree = _copy_tree(
+                jax.device_put(tree, self.mesh.devices.flat[0]))
         elif copy:
-            trainable, buffers = _copy_tree((trainable, buffers))
-        for k, v in trainable.items():
+            tree = _copy_tree(tree)
+        for k, v in tree["trainable"].items():
             self._param_refs[k]._value = v
-        for k, v in buffers.items():
+        for k, v in tree["buffers"].items():
             self._buffer_refs[k]._value = v
         m = self.model
         if sync_opt:
-            if self.mesh is not None:
-                m._opt_state = unshard(st["opt"])
-            else:
-                m._opt_state = _copy_tree(st["opt"]) if copy else st["opt"]
+            m._opt_state = tree["opt"]
         m._optimizer._step_count = self._host_step
         self._record_synced_ids()
 
@@ -896,8 +898,13 @@ class TrainEngine:
 
         If a dispatch failed AFTER donating the state (XLA runtime
         error, OOM), the engine holds deleted buffers — rebinding those
-        would clobber the valid epoch-boundary copies the Layer tree
-        still has, so a poisoned state is dropped instead."""
+        would clobber the valid arrays the Layer tree still has, so a
+        poisoned state is dropped instead.  The tree then keeps its last
+        sync: the start of the fit, or the last epoch's end that had a
+        reader (an evaluate, a checkpoint, a user callback), which may
+        be older than the last epoch.  A run that must not lose an epoch
+        to a failed dispatch checkpoints the live state with
+        `fit(fault_tolerant=True)` / `resume=`."""
         if self.state is None:
             return
         if not _tree_deleted(self.state):

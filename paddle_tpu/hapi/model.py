@@ -214,7 +214,7 @@ class Model:
         """Checkpointable training state: trainable params + buffers +
         optimizer slots + loop counters, as one pytree of arrays.  When
         the device-resident engine is live its state is authoritative
-        (the Layer tree is only synced at epoch boundaries) and must be
+        (the Layer tree is only synced where it has a reader) and must be
         MATERIALIZED to host — the engine donates those buffers on the
         next dispatch, which would race an async save.  This host copy
         IS the async checkpointer's double buffer: it happens on the
@@ -477,7 +477,24 @@ class Model:
             resume=None, checkpoint_interval=None, mesh=None,
             sharding_rule=None, layout=None, recompute=None, accum_steps=1,
             pod=None):
-        """[fault tolerance — opt-in] `resume=<dir>` (or `resume=True`
+        """[what the Layer tree holds during a fit] The train state
+        lives once, in the engine (hapi/engine.py), and the network's
+        parameters are brought up to date at an epoch's end only when
+        something reads them there: an evaluate (`eval_data`,
+        `eval_freq`), a `ModelCheckpoint` that saves that epoch
+        (`save_dir`, `save_freq`), or any callback that is not a
+        `ProgBarLogger`, an `LRScheduler` or a `ModelCheckpoint` (with
+        such a callback also after every batch, as before).  Between
+        such boundaries `network.parameters()` holds the values of the
+        last sync (the start of `fit`, or the last epoch that had a
+        reader): valid arrays, never a donated buffer.  When `fit`
+        returns or unwinds, the tree and `model._opt_state` are the
+        engine's last state; only after a dispatch that failed having
+        donated the state does the tree keep its last sync, which may
+        be older than the last epoch (`fault_tolerant=` checkpoints the
+        live state for runs that must not lose one).
+
+        [fault tolerance — opt-in] `resume=<dir>` (or `resume=True`
         with `save_dir`) auto-resumes from the newest checkpoint in that
         directory and checkpoints every `checkpoint_interval` iterations
         (default: each epoch end).  `fault_tolerant=True` additionally
@@ -551,6 +568,19 @@ class Model:
         # when a log step fires or a user callback might consume it
         user_cbs = any(not isinstance(c, (_PBCb, _LRCb, _CkptCb))
                        for c in cbks)
+        ckpt_cbs = [c for c in cbks if isinstance(c, _CkptCb)]
+
+        def tree_readers(epoch):
+            """Who reads the Layer tree at this epoch's end, as `(any,
+            one that reads the optimizer's slots too)`: a user callback
+            (anything but a logger, an LR scheduler or a checkpointer),
+            a `ModelCheckpoint` that saves this epoch, an evaluate
+            (parameters and buffers only)."""
+            saves = any(c.save_dir and (epoch + 1) % c.save_freq == 0
+                        for c in ckpt_cbs)
+            evals = (eval_data is not None
+                     and (epoch + 1) % eval_freq == 0)
+            return user_cbs or saves or evals, user_cbs or saves
         # Device-resident engine (hapi/engine.py): ONE state snapshot per
         # fit, donated buffers, no per-step host sync.  When user
         # callbacks or metrics need fresh per-batch values the loop
@@ -893,11 +923,18 @@ class Model:
                                 batch_size, losses, inputs, labels,
                                 _win_t0, _win_it0, _win_totals,
                                 _win_counts)
-                # epoch-boundary write-back: the Layer tree gets device
-                # COPIES so checkpoints/eval/user inspection see current
-                # values while the engine keeps donating its own buffers
+                # epoch boundary: the Layer tree is brought up to date
+                # only for a reader that is due here (device COPIES, one
+                # dispatch, while the engine keeps donating its own
+                # buffers); with none the tree keeps its last sync and
+                # nothing is put on the device that nobody reads
                 with timers.scope("write_back"):
-                    engine.write_back(copy=True)
+                    reader, of_opt = tree_readers(epoch)
+                    if reader:
+                        with timers.scope("write_back/copy"):
+                            engine.write_back(copy=True, sync_opt=of_opt)
+                    else:
+                        engine.refresh_from_layers()
                 if ft_mgr is not None and not checkpoint_interval \
                         and it_count > start_it:
                     with timers.scope("ckpt"):
@@ -932,9 +969,14 @@ class Model:
                     # longest single run, which names a stalled step
                     # that a mean hides; the maxima start anew
                     if logger.isEnabledFor(logging.INFO):
-                        logger.info("fit epoch %d phases: %s", epoch,
-                                    _phase_line(timers, _ep_totals,
-                                                _ep_counts))
+                        # and how often the Layer tree had a reader:
+                        # write_back scopes that copied over all so far
+                        logger.info(
+                            "fit epoch %d phases: %s tree_copies=%d/%d",
+                            epoch,
+                            _phase_line(timers, _ep_totals, _ep_counts),
+                            timers.counts.get("write_back/copy", 0),
+                            timers.counts.get("write_back", 0))
                     timers.maxima.clear()
                 # SIGTERM during epoch-end eval/callbacks must still turn
                 # into a clean preempted exit (not a SIGKILL after the

@@ -559,6 +559,33 @@ class TestPostFitContracts:
         assert any(not np.array_equal(seen[0][k], seen[2][k])
                    for k in seen[0])
 
+    @pytest.mark.parametrize("dp", [1, 8])
+    def test_no_reader_copies_nothing_and_ends_current(self, dp):
+        """With nobody to read the Layer tree between epochs nothing is
+        de-sharded there; when fit returns the tree is single-device
+        and holds the last step's weights and slots."""
+        plain, _ = _fit(mesh=None, epochs=3)
+        model, _ = _fit(mesh={"dp": dp}, epochs=3)
+        counts = model._last_fit_timers.counts
+        assert counts.get("write_back/copy", 0) == 0
+        assert counts["write_back"] == 4
+        for k, p in model.network.named_parameters():
+            assert len(p._value.sharding.device_set) == 1, k
+            assert not p._value.is_deleted(), k
+        for leaf in jax.tree_util.tree_leaves(model._opt_state):
+            assert len(leaf.sharding.device_set) == 1
+        want, got = _weights(plain), _weights(model)
+        for k in want:
+            if dp == 1:
+                np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+            else:
+                np.testing.assert_allclose(got[k], want[k], rtol=1e-5,
+                                           atol=1e-7, err_msg=k)
+        for a, b in zip(jax.tree_util.tree_leaves(model._opt_state),
+                        jax.tree_util.tree_leaves(plain._opt_state)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-7)
+
 
 # -- legacy DataParallel routing -------------------------------------------
 @needs8

@@ -413,3 +413,298 @@ class TestEngineUnit:
         assert not eng.active
         for k, v in _weights(model).items():  # weights survived intact
             np.testing.assert_array_equal(v, layer_vals[k])
+
+
+# -- the Layer tree during a fit: copied only for a reader -----------------
+#
+# 24 samples in batches of 8: three steps an epoch, three epochs.
+
+def _eager_steps(model, ds):
+    """One epoch of eager `train_batch` calls over the batches `fit`
+    would see, yielding after each."""
+    model.network.train()
+    for batch in DataLoader(ds, batch_size=8, shuffle=False):
+        inputs, labels = model._split_batch(list(batch))
+        model.train_batch(inputs, labels)
+        yield
+
+
+def _eager_states(steps):
+    """The oracle: `(weights, opt-state leaves)` after each of `steps`
+    eager steps (entry 0 is the start), which the engine reproduces bit
+    for bit."""
+    model, ds = _model_and_data()
+    out = [(_weights(model), None)]
+    while len(out) <= steps:
+        for _ in _eager_steps(model, ds):
+            out.append((_weights(model), [
+                np.asarray(a) for a in
+                jax.tree_util.tree_leaves(model._opt_state)]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def eager():
+    return _eager_states(9)
+
+
+def _assert_state(model, want):
+    weights, opt = want
+    got = _weights(model)
+    for k in weights:
+        np.testing.assert_array_equal(got[k], weights[k], err_msg=k)
+    leaves = jax.tree_util.tree_leaves(model._opt_state)
+    assert len(leaves) == len(opt)
+    for a, b in zip(leaves, opt):
+        np.testing.assert_array_equal(np.asarray(a), b)
+
+
+def _tree_is_valid(model):
+    return not any(p._value.is_deleted()
+                   for p in model.network.parameters())
+
+
+class _Watch(paddle.callbacks.ProgBarLogger):
+    """A logger by type, so no reader in `fit`'s eyes: it looks at the
+    tree after every batch and every epoch all the same."""
+
+    def __init__(self):
+        super().__init__(verbose=0)
+        self.valid, self.at_epoch_end = [], []
+
+    def on_train_batch_end(self, step, logs=None):
+        self.valid.append(_tree_is_valid(self.model))
+
+    def on_epoch_end(self, epoch, logs=None):
+        self.valid.append(_tree_is_valid(self.model))
+        self.at_epoch_end.append(_weights(self.model))
+
+
+class _Peek(paddle.callbacks.Callback):
+    def __init__(self):
+        super().__init__()
+        self.at_epoch_end = []
+
+    def on_epoch_end(self, epoch, logs=None):
+        assert _tree_is_valid(self.model)
+        self.at_epoch_end.append(_weights(self.model))
+
+
+class _FailsIn(TensorDataset):
+    """The dataset of `_model_and_data`, which raises at the read that
+    would start step `after + 1`."""
+
+    def __init__(self, ds, after):
+        super().__init__(ds.tensors)
+        self.reads, self.limit = 0, after * 8
+
+    def __getitem__(self, i):
+        self.reads += 1
+        if self.reads > self.limit:
+            raise RuntimeError("the disk is gone")
+        return super().__getitem__(i)
+
+
+READERS = {  # name -> copies at the ends of 3 epochs
+    "none": 0, "eval_data": 3, "save_dir_every_2": 1, "user_callback": 3,
+    "logger_subclass": 0}
+
+
+def _reader_kwargs(reader, ds, tmp_path):
+    watch = _Watch()
+    kw = {"callbacks": [watch]}
+    if reader == "none":
+        kw, watch = {}, None
+    elif reader == "eval_data":
+        kw["eval_data"] = ds
+    elif reader == "save_dir_every_2":
+        kw.update(save_dir=str(tmp_path), save_freq=2)
+    elif reader == "user_callback":
+        kw["callbacks"].append(_Peek())
+    return kw, watch
+
+
+class TestLayerTreeDuringFit:
+    @pytest.mark.parametrize("reader", READERS)
+    def test_copied_at_an_epochs_end_only_for_a_reader(self, reader, eager,
+                                                       tmp_path,
+                                                       monkeypatch):
+        model, ds = _model_and_data()
+        kw, watch = _reader_kwargs(reader, ds, tmp_path)
+        evaluated = []
+        evaluate = model.evaluate
+
+        def evaluate_and_note(*a, **k):
+            evaluated.append(_weights(model))
+            # an evaluate reads no slot of the optimizer: none was copied
+            assert getattr(model, "_opt_state", None) is None
+            return evaluate(*a, **k)
+
+        monkeypatch.setattr(model, "evaluate", evaluate_and_note)
+        start = _weights(model)
+        model.fit(ds, batch_size=8, epochs=3, shuffle=False, verbose=0,
+                  **kw)
+        counts = model._last_fit_timers.counts
+        assert counts.get("write_back/copy", 0) == READERS[reader]
+        if READERS[reader]:     # a child of the boundary's scope
+            assert model._last_fit_timers.parents["write_back/copy"] \
+                == "write_back"
+        _assert_state(model, eager[9])      # fit returned: all current
+        assert _tree_is_valid(model)
+        if watch is not None:
+            assert all(watch.valid) and len(watch.valid) == 12
+        # what each reader saw is the state of the step just ended
+        seen = None
+        if reader == "eval_data":
+            seen = evaluated
+        elif reader == "user_callback":
+            seen = kw["callbacks"][-1].at_epoch_end
+        if seen is not None:
+            assert len(seen) == 3
+            for epoch, got in enumerate(seen):
+                for k, v in eager[3 * (epoch + 1)][0].items():
+                    np.testing.assert_array_equal(got[k], v, err_msg=k)
+        if reader == "save_dir_every_2":
+            saved = Model(_model_and_data()[0].network)
+            saved.prepare(paddle.optimizer.Adam(
+                parameters=saved.network.parameters()))
+            saved.load(str(tmp_path / "1"))
+            _assert_state(saved, eager[6])
+        if reader == "logger_subclass":
+            # no reader: the tree kept the fit's start, valid arrays
+            for got in watch.at_epoch_end:
+                for k, v in start.items():
+                    np.testing.assert_array_equal(got[k], v, err_msg=k)
+        if reader != "user_callback":       # which syncs every batch too
+            assert counts["write_back"] == 4    # 3 epochs' ends + exit
+
+    @pytest.mark.parametrize("reader", READERS)
+    def test_current_after_a_fit_that_raises_in_epoch_2(self, reader, eager,
+                                                        tmp_path):
+        model, ds = _model_and_data()
+        kw, watch = _reader_kwargs(reader, ds, tmp_path)
+        with pytest.raises(RuntimeError, match="the disk is gone"):
+            model.fit(_FailsIn(ds, after=4), batch_size=8, epochs=3,
+                      shuffle=False, verbose=0, **kw)
+        assert not model._engine.active
+        _assert_state(model, eager[4])
+        assert _tree_is_valid(model)
+        if watch is not None:
+            assert all(watch.valid)
+        counts = model._last_fit_timers.counts
+        assert counts.get("write_back/copy", 0) == (
+            1 if reader in ("eval_data", "user_callback") else 0)
+
+
+class TestWholeTreeCopy:
+    def test_one_dispatch_and_no_output_aliases_an_input(self):
+        from jax import monitoring
+
+        from paddle_tpu.hapi.engine import _copy_tree
+
+        rs = np.random.RandomState(0)
+        shared = jax.numpy.asarray(rs.randn(7, 3).astype("float32"))
+        tree = {"trainable": {f"w{i}": jax.numpy.asarray(
+                    rs.randn(3 + i, 5).astype("float32")) for i in range(6)},
+                "opt": {"slow": shared, "sum": shared,
+                        "step": jax.numpy.zeros((), jax.numpy.int32)}}
+        built = []
+
+        def on_compile(event, duration, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                built.append(duration)
+
+        monitoring.register_event_duration_secs_listener(on_compile)
+        try:
+            out = _copy_tree(tree)
+            assert len(built) == 1      # the whole tree: one executable
+            again = _copy_tree(tree)
+            assert len(built) == 1      # and the same one a second time
+        finally:
+            monitoring.unregister_event_duration_listener(on_compile)
+        assert jax.tree_util.tree_structure(out) == \
+            jax.tree_util.tree_structure(tree)
+        leaves_in = jax.tree_util.tree_leaves(tree)
+        for got in (out, again):
+            leaves = jax.tree_util.tree_leaves(got)
+            for a, b in zip(leaves, leaves_in):
+                assert a.dtype == b.dtype
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            mine = {a.unsafe_buffer_pointer() for a in leaves}
+            # a leaf passed twice comes back as two buffers, which a
+            # donating step needs
+            assert len(mine) == len(leaves)
+            assert not mine & {b.unsafe_buffer_pointer()
+                               for b in leaves_in}
+
+    def test_begin_snapshots_with_it(self, monkeypatch):
+        """begin()'s snapshot and a boundary's copy are calls of the one
+        function: a fit with an evaluate every epoch makes one at the
+        start and one an epoch."""
+        from paddle_tpu.hapi import engine as eng_mod
+
+        calls = []
+        real = eng_mod._copy_tree
+        monkeypatch.setattr(eng_mod, "_copy_tree",
+                            lambda tree: calls.append(1) or real(tree))
+        model, ds = _model_and_data()
+        model.fit(ds, batch_size=8, epochs=2, shuffle=False, verbose=0,
+                  eval_data=ds)
+        assert len(calls) == 3
+
+
+# -- a metric is fed the step's outputs -------------------------------------
+
+def _batches(n, batch=5):
+    rs = np.random.RandomState(3)
+    return [([paddle.to_tensor(rs.randn(batch, 4).astype("float32"))],
+             [paddle.to_tensor(rs.randint(0, 2, (batch,)).astype("int64"))])
+            for _ in range(n)]
+
+
+def _prepared(metrics=None):
+    model, ds = _model_and_data()
+    model.prepare(model._optimizer, model._loss, metrics=metrics)
+    return model, ds
+
+
+class TestStepOutputs:
+    def test_losses_are_bitwise_equal_with_and_without_a_metric(self):
+        losses = []
+        for metrics in (None, [paddle.metric.Accuracy()]):
+            model, _ = _prepared(metrics)
+            eng = TrainEngine(model).begin()
+            for x, y in _batches(5):
+                eng.step(x, y)
+            losses.append(eng.drain())
+            eng.finish()
+        assert len(losses[0]) == 5
+        assert losses[0] == losses[1]
+
+    def test_metric_reads_what_the_eager_loop_reads(self):
+        eager_model, ds = _prepared([paddle.metric.Accuracy()])
+        for _ in _eager_steps(eager_model, ds):
+            pass
+        want = eager_model._metrics[0].accumulate()
+
+        model, ds = _prepared([paddle.metric.Accuracy()])
+        model.fit(ds, batch_size=8, epochs=1, shuffle=False, verbose=0)
+        assert model._metrics[0].accumulate() == want
+        assert 0.0 < want <= 1.0
+
+
+class TestFitLogsHowOftenItCopied:
+    @pytest.mark.parametrize("with_eval, want", [
+        (False, ["tree_copies=0/1", "tree_copies=0/2"]),
+        (True, ["tree_copies=1/1", "tree_copies=2/2"])])
+    def test_the_epoch_line_ends_in_copies_over_write_backs(
+            self, with_eval, want, caplog):
+        import logging
+
+        model, ds = _model_and_data()
+        with caplog.at_level(logging.INFO, logger="paddle_tpu.hapi"):
+            model.fit(ds, batch_size=8, epochs=2, shuffle=False, verbose=0,
+                      eval_data=ds if with_eval else None)
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("fit epoch")]
+        assert [line.split()[-1] for line in lines] == want
