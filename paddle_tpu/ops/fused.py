@@ -611,6 +611,176 @@ def moe_dropless(x, expert_ids, expert_weights, w_gate, w_up, w_down,
 
 
 # ---------------------------------------------------------------------------
+# selective state-space scan (Mamba-2, one group of B and C): the chunked
+# prompt pass (Pallas `paddle_ssd_chunk_scan`) and the one-token update of
+# the live lanes' state (Pallas `paddle_ssm_decode_update`)
+# ---------------------------------------------------------------------------
+def ssm_pack(heads: int, head_dim: int) -> int:
+    """Heads that share one row of a held state: the most that divide
+    `heads` and fill no more than 128 lanes (2 heads of 64)."""
+    r = max(1, min(heads, 128 // max(1, head_dim)))
+    while heads % r:
+        r -= 1
+    return r
+
+
+def ssm_pack_state(h, pack: int):
+    """A state as the scan computes it, [..., H, P, N], in the layout it is
+    HELD in, [..., H / pack, N, pack * P]: the state's N on the sublanes
+    and `pack` heads side by side on the lanes, so that the one-token update
+    multiplies every row of a head pair by rows it can broadcast (a head's
+    x and decay along the lanes, the lane's B and C down the sublanes) and
+    never needs a column."""
+    *lead, H, P, N = h.shape
+    n = len(lead)
+    h = h.reshape(*lead, H // pack, pack, P, N)
+    return jnp.transpose(h, (*range(n), n, n + 3, n + 1, n + 2)) \
+        .reshape(*lead, H // pack, N, pack * P)
+
+
+def ssm_unpack_state(s, pack: int):
+    """`ssm_pack_state`'s inverse: [..., H / pack, N, pack * P] -> [..., H,
+    P, N]."""
+    *lead, Hr, N, rP = s.shape
+    n = len(lead)
+    s = s.reshape(*lead, Hr, N, pack, rP // pack)
+    return jnp.transpose(s, (*range(n), n, n + 2, n + 3, n + 1)) \
+        .reshape(*lead, Hr * pack, rP // pack, N)
+
+
+def _ssd_chunks(x, dt, A, B, C, chunk):
+    """The operands of a chunked scan, padded to whole chunks with steps of
+    0 (which neither decay the state nor add to it) and laid [chunks, Q,
+    ...]; `cum` is the running sum of dt * A inside each chunk."""
+    T, H, P = x.shape
+    nc = -(-T // chunk)
+    pad = nc * chunk - T
+
+    def lay(v):
+        v = jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+        return v.reshape((nc, chunk) + v.shape[1:])
+
+    x, dt, B, C = lay(x), lay(dt.astype(jnp.float32)), lay(B), lay(C)
+    cum = jnp.cumsum(dt * A.astype(jnp.float32), axis=1)      # [nc, Q, H]
+    return x, dt, B, C, cum
+
+
+def _ssd_composite(x, dt, A, B, C, state0, chunk):
+    """The chunked scan in `jax.numpy` (the CPU's path, the counted
+    fallback, and what the kernel is tested against)."""
+    T, H, P = x.shape
+    f32 = jnp.float32
+    xc, dtc, Bc, Cc, cum = _ssd_chunks(x, dt, A, B, C, chunk)
+    Q = xc.shape[1]
+    # inside a chunk: y_q += sum_{s <= q} (C_q . B_s) exp(cum_q - cum_s)
+    # dt_s x_s
+    cb = jnp.einsum("cqn,csn->cqs", Cc, Bc, preferred_element_type=f32)
+    seen = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    gap = jnp.where(seen[None, :, :, None],
+                    cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
+    m = cb[..., None] * jnp.exp(gap) * dtc[:, None, :, :]     # [nc, Q, S, H]
+    y = jnp.einsum("cqsh,cshp->cqhp", m, xc.astype(f32))
+    # a chunk's own contribution to the state at its end
+    w_end = jnp.exp(cum[:, -1:, :] - cum) * dtc               # [nc, Q, H]
+    local = jnp.einsum("cqh,cqhp,cqn->chpn", w_end, xc.astype(f32),
+                       Bc.astype(f32))
+    decay = jnp.exp(cum[:, -1, :])                            # [nc, H]
+
+    def carry(s, inp):
+        loc, dec = inp
+        return dec[:, None, None] * s + loc, s
+
+    final, starts = jax.lax.scan(carry, state0.astype(f32), (local, decay))
+    y = y + jnp.einsum("cqn,chpn->cqhp", Cc.astype(f32), starts) \
+        * jnp.exp(cum)[..., None]
+    return y.reshape(-1, H, P)[:T], final, starts
+
+
+def ssd_chunk_scan(x, dt, A, B, C, state0, chunk: int):
+    """The selective scan of one sequence in chunks of `chunk` tokens:
+
+        H_t = exp(dt_t A) H_{t-1} + dt_t x_t B_t^T,   y_t = H_t C_t
+
+    x [T, H, P]; dt [T, H] >= 0 (after the softplus; a step of 0 leaves the
+    state as it is: padding); A [H] < 0; B, C [T, N] (one group); state0
+    [H, P, N].  Returns (y [T, H, P] float32, the state after the last
+    token [H, P, N] float32, the state at every chunk's start [chunks, H,
+    P, N] float32).  Inside a chunk the products run on the MXU, from chunk
+    to chunk the state is carried.  Raw jax arrays."""
+    if _use_pallas():
+        from .pallas import ssm
+
+        out = _kernel_or_none("ssd_chunk_scan", lambda: ssm.ssd_chunk_scan(
+            x, dt, A, B, C, state0, chunk))
+        if out is not None:
+            return out
+    return _ssd_composite(x, dt, A, B, C, state0, chunk)
+
+
+def ssd_state_at(x, dt, A, B, starts, at, chunk: int):
+    """The state after token `at` - 1 of a chunked scan (traced `at` in [0,
+    T]; `starts` as `ssd_chunk_scan` returns them): the start of the chunk
+    `at` lies in, carried over that chunk's tokens before `at`."""
+    f32 = jnp.float32
+    nc = starts.shape[0]
+    at = jnp.asarray(at, jnp.int32)
+    c = jnp.clip(at // chunk, 0, nc - 1)
+
+    def of_chunk(v):    # the chunk's rows of v, zeros past the sequence
+        v = jnp.pad(v, ((0, nc * chunk - v.shape[0]),)
+                    + ((0, 0),) * (v.ndim - 1))
+        return jax.lax.dynamic_slice_in_dim(v, c * chunk, chunk, 0)
+
+    d = jnp.where(jnp.arange(chunk)[:, None] < at - c * chunk,
+                  of_chunk(dt.astype(f32)), 0.0)
+    cum = jnp.cumsum(d * A.astype(f32), axis=0)               # [Q, H]
+    w_end = jnp.exp(cum[-1:] - cum) * d
+    return jnp.exp(cum[-1])[:, None, None] * starts[c] + jnp.einsum(
+        "qh,qhp,qn->hpn", w_end, of_chunk(x).astype(f32),
+        of_chunk(B).astype(f32))
+
+
+def ssm_decode_update(ssm, plane, lanes, n_live, x, dt, A, B, C):
+    """One token of every live lane through layer `plane` of the held
+    states, in place: H' = exp(dt A) H + dt x B^T, y = H' C.
+
+    ssm [layers, slots, H / r, N, r * P] float32 (`ssm_pack_state`'s layout;
+    the WHOLE array, so that a donated one is rewritten where it lies);
+    plane static; lanes [slots] int32 the live lanes first, n_live how many
+    they are (a dead lane's state stays as it is: the kernel neither reads
+    nor writes it, the composite writes it back as it read it); x [slots, H,
+    P]; dt [slots, H]; A [H]; B, C [slots, N].
+    Returns (y [slots, H, P] float32, whatever for a dead lane; ssm')."""
+    f32 = jnp.float32
+    slots, H, P = x.shape
+    Hr, N, rP = ssm.shape[2:]
+    dt = dt.astype(f32)
+
+    def rows(v):        # [slots, H, P] -> the held layout's [slots, Hr, rP]
+        return v.reshape(slots, Hr, rP)
+
+    decay = rows(jnp.broadcast_to(
+        jnp.exp(dt * A.astype(f32))[:, :, None], (slots, H, P)))
+    dtx = rows(dt[:, :, None] * x.astype(f32))
+    if _use_pallas():
+        from .pallas import ssm as _ssm
+
+        out = _kernel_or_none(
+            "ssm_decode_update", lambda: _ssm.ssm_decode_update(
+                ssm, plane, lanes, n_live, decay, dtx, B.astype(f32),
+                C.astype(f32)))
+        if out is not None:
+            return out[0].reshape(slots, H, P), out[1]
+    new = ssm[plane] * decay[:, :, None, :] \
+        + dtx[:, :, None, :] * B.astype(f32)[:, None, :, None]
+    y = (new * C.astype(f32)[:, None, :, None]).sum(2)
+    live = jnp.zeros((slots,), bool).at[lanes].set(
+        jnp.arange(slots) < n_live)
+    return y.reshape(slots, H, P), ssm.at[plane].set(
+        jnp.where(live[:, None, None, None], new, ssm[plane]))
+
+
+# ---------------------------------------------------------------------------
 # fused bias + GeLU (fused_gemm_epilogue intent): matmul stays with XLA's
 # MXU scheduling, the bias-add + exact-erf GeLU epilogue runs as one Pallas
 # pass (forward and backward) instead of separate elementwise HLOs

@@ -71,9 +71,11 @@ from ..utils import chaos
 from ..utils.profiler import StepTimers, startup
 from .engine import (DeadlineExceededError, EngineStoppedError,
                      QueueFullError)
-from .kv_cache import (CacheGeometry, PagedKV, PrefixKV, admit_slot,
-                       make_state, push_pages, reclaim_pages, release_slots,
-                       slide_window, state_specs, take_pages, write_prompt)
+from .kv_cache import (SCAN_FROM_SLOT, SCAN_FROM_ZERO, CacheGeometry,
+                       HybridKV, LaneStates, PagedKV, PrefixKV, PromptStates,
+                       admit_slot, initial_states, make_state, push_pages,
+                       put_states, reclaim_pages, release_slots, slide_window,
+                       state_specs, take_pages, write_prompt)
 from .metrics import GenerationMetrics
 from .prefix_cache import PrefixCache
 from .scheduler import SlotScheduler
@@ -189,7 +191,7 @@ class _GenRequest:
                  "t_last_token", "span", "own_span", "span_queue",
                  "span_decode", "prefilling", "prefill_cursor",
                  "chunk_row", "chunk_wrow", "j_hit", "pin_final",
-                 "block_start", "block_resolved")
+                 "block_start", "block_resolved", "restore", "snap_pages")
 
     def __init__(self, engine, prompt, bucket, max_new_tokens, do_sample,
                  temperature, top_k, seed, eos, deadline, span=None,
@@ -221,6 +223,11 @@ class _GenRequest:
         # its last collected pass closed the block before block_start: the
         # pass after that one commits it
         self.block_resolved = False
+        # an engine with state layers: the snapshot the admission restores
+        # (none: the scan starts from zero), and the depth in pages at
+        # which its prompt pass leaves one (0: none)
+        self.restore = SCAN_FROM_ZERO
+        self.snap_pages = 0
         self.handle = GenerationHandle(len(prompt), max_new_tokens)
         self.handle._req = self
 
@@ -253,7 +260,14 @@ class GenerationEngine:
         with a ``block_length`` (and denoising_steps, mask_token_id,
         remasking_strategy, confidence_threshold) declares generation by
         blocks; with ``num_experts`` the model's ``slot_step`` takes
-        ``live=`` and returns its routing counts as a third value.
+        ``live=`` and returns its routing counts as a third value.  A
+        ``cfg`` with ``state_layers`` (and state_shape, conv_shape:
+        models/granite_hybrid.py) declares layers that hold a recurrent
+        state and no page: every slot then has a state beside its pages,
+        a prefix hit restores one from a pool of snapshots, and the
+        model's ``slot_step`` takes a ``HybridKV`` and its
+        ``slot_prefill`` a third argument (the offset at which to leave
+        a snapshot) and returns the states as a fourth value.
       max_slots: in-flight sequences per decode iteration
         (``FLAGS_genserve_max_slots``).
       max_seq_len: per-slot sequence cap S_max >= prompt + new tokens
@@ -414,6 +428,18 @@ class GenerationEngine:
             raise ValueError(
                 "a model with window layers is served without a mesh (the "
                 "window pool's sharding is not written yet)")
+        # layers with a recurrent state: declared by the model too
+        state_kw = {}
+        if getattr(cfg, "state_layers", ()):
+            if mesh is not None:
+                raise ValueError(
+                    "a model with state layers is served without a mesh "
+                    "(the states' sharding is not written yet)")
+            state_kw = dict(state_layers=tuple(cfg.state_layers),
+                            state_shape=tuple(cfg.state_shape),
+                            conv_shape=tuple(cfg.conv_shape),
+                            state_chunk=int(cfg.state_chunk),
+                            state_pack=int(cfg.state_pack))
         self.geometry = CacheGeometry(
             num_layers=cfg.num_layers, max_slots=self.max_slots,
             max_seq_len=self.max_seq_len, num_heads=cfg.num_heads,
@@ -424,17 +450,19 @@ class GenerationEngine:
             num_kv_heads=getattr(cfg, "num_kv_heads", 0),
             block_length=self.block_length,
             num_experts=getattr(cfg, "num_experts", 0),
-            windows=windows, **draft_kw)
+            windows=windows, **draft_kw, **state_kw)
         geom = self.geometry
         self.metrics = GenerationMetrics(
             max_slots=self.max_slots, num_pages=geom.num_pages,
-            window_pool=bool(geom.windows))
+            window_pool=bool(geom.windows),
+            state_pool=bool(geom.state_layers))
         # window pages go by the ids past the full pool's in the prefix
         # cache, which counts the two kinds apart; room for the entries of
         # four whole prompts at least (a prompt registers one a page)
         self._prefix = (PrefixCache(
             page_size, capacity=max(1024, 4 * geom.pages_per_slot),
-            split=geom.num_pages if geom.windows else None)
+            split=geom.num_pages if geom.windows else None,
+            snapshots=geom.state_snapshots)
             if prefix_cache else None)
         self._slot_pins: dict[int, list] = {}   # slot -> pinned page ids
         self._queue: queue.Queue = queue.Queue(self.queue_depth)
@@ -532,6 +560,9 @@ class GenerationEngine:
             # a window engine: the layers' windows, and each pool's layers
             W = geom.windows
             counted = bool(geom.num_experts)
+            # layers with a recurrent state: every admission then carries
+            # where its scan starts and where it leaves a snapshot
+            R = bool(geom.state_layers)
 
             def window_args(extra, w_pin):
                 """A window engine's trailing admission arguments `extra` (the
@@ -544,6 +575,18 @@ class GenerationEngine:
                 return wshared, {"window": (
                     (geom.full_layers, geom.window_layers), wshared, w_from,
                     w_pin)}, ()
+
+            def state_args(slot, extra):
+                """The trailing admission arguments `extra` of an engine with
+                state layers (where the scan starts: a snapshot's place,
+                `SCAN_FROM_ZERO` or `SCAN_FROM_SLOT`; the offset in the pass
+                at which it leaves a snapshot; that snapshot's place, -1 for
+                none) as (what `suffix_prefill` scans by, the place for
+                `put_states`, what is left of `extra`)."""
+                if not R:
+                    return None, -1, extra
+                start, snap_at, snap_to = extra
+                return (slot, start, snap_at), snap_to, ()
 
             # sharding plan: None entries (no mesh) keep today's lowering
             mesh, layout = self._mesh, self._layout
@@ -607,10 +650,12 @@ class GenerationEngine:
 
             model, geometry = self.model, geom
 
-            def target_prefill(params, ids, length):
+            def target_prefill(params, ids, length, *snap_at):
                 out, _ = functional_call(
-                    model, params, (Tensor(ids), length), buffers=buffers,
-                    mutable=False, method="slot_prefill")
+                    model, params, (Tensor(ids), length) + snap_at,
+                    buffers=buffers, mutable=False, method="slot_prefill")
+                if R:       # (k, v, logits, the scan's four states)
+                    return out[:3] + tuple(out[3])
                 if B:
                     # a block engine samples nothing at admission: the
                     # logits go, and the compiler drops the head with them
@@ -663,6 +708,9 @@ class GenerationEngine:
                 # allocated and written (shared_n = 0).  `extra` is the
                 # draft's K/V (speculative) or the opening block (blocks)
                 no_shared = jnp.full((pps,), -1, jnp.int32)
+                ends, snap_to = (), -1
+                if R:       # extra: the cold pass's states, the snapshot's place
+                    ends, snap_to, extra = extra[:4], extra[4], ()
                 if W:       # extra: the first column the window row keeps
                     _, win, extra = window_args((no_shared,) + extra,
                                                 jnp.int32(0))
@@ -675,14 +723,16 @@ class GenerationEngine:
                 state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
                                   do_sample, temp, top_k, stop_pos, eos, pinned,
                                   opening)
-                return state, tok1, row
+                return put_states(state, slot, ends, snap_to), tok1, row
 
             def suffix_prefill(params, dparams, state, ids, shared_ids,
-                               shared_n, length, wshared_ids=None):
+                               shared_n, length, wshared_ids=None, scan=None):
                 # prefill ONLY the suffix, attending over the prefix already
                 # resident in the pool(s) — shared by the prefix-hit admission
                 # path and every prefill chunk.  The suffix tokens sit at
                 # prefix_len + i; only the last real one's logits are wanted.
+                # Returns (k, v, logits, the draft's K/V or (), the state
+                # layers' states for `put_states` or ()).
                 prefix_len = shared_n * ps
                 positions = (prefix_len
                              + jnp.arange(ids.shape[1], dtype=jnp.int32))[None]
@@ -694,18 +744,29 @@ class GenerationEngine:
                         W) if W else PrefixKV.gather(
                             k_pool, v_pool, shared_ids[:pfx_pages], prefix_len,
                             **prefix_kw)
+                    if scan is not None:
+                        # the state layers scan the suffix from where `scan`
+                        # says: (slot, start, the offset of the snapshot)
+                        slot, start, snap_at = scan
+                        prefix = HybridKV(prefix, PromptStates(
+                            *initial_states(state, slot, start),
+                            jnp.asarray(length, jnp.int32) - prefix_len,
+                            jnp.asarray(snap_at, jnp.int32),
+                            chunk=geom.state_chunk, pack=geom.state_pack))
                     (lg, kv), _ = functional_call(
                         m, p, (ids, positions, prefix, last),
                         buffers=b, mutable=False, method="slot_step")
-                    return kv.suffix_kv(), lg[0, 0]
+                    if scan is not None:
+                        return kv.kv.suffix_kv(), lg[0, 0], kv.states.ends()
+                    return kv.suffix_kv(), lg[0, 0], ()
 
-                (k_suf, v_suf), logits = suffix(model, params, buffers,
-                                                state["kp"], state["vp"])
+                (k_suf, v_suf), logits, ends = suffix(
+                    model, params, buffers, state["kp"], state["vp"])
                 if draft is None:
-                    return k_suf, v_suf, logits, ()
-                draft_kv, _ = suffix(draft, dparams, dbuffers,
-                                     state["dkp"], state["dvp"])
-                return k_suf, v_suf, logits, draft_kv
+                    return k_suf, v_suf, logits, (), ends
+                draft_kv, _, _ = suffix(draft, dparams, dbuffers,
+                                        state["dkp"], state["dvp"])
+                return k_suf, v_suf, logits, draft_kv, ends
 
             def _insert_prefix(params, dparams, state, slot, ids, shared_ids,
                                shared_n, length, seed, resume_pos, do_sample,
@@ -713,18 +774,23 @@ class GenerationEngine:
                 # prefix-hit admission: the shared pages are never
                 # recomputed; the suffix pages in at the (page-aligned)
                 # boundary
+                # an engine with state layers scans the suffix from a
+                # restored snapshot (or zero), hands the slot the state after
+                # the last token and the pool the one at the page boundary the
+                # pass crosses: all inside this one executable
+                scan, snap_to, opening = state_args(slot, opening)
                 # a window engine's own pages start where the shared ones end
                 wshared, win, opening = window_args(opening, shared_n)
-                k_suf, v_suf, logits, draft_kv = suffix_prefill(
+                k_suf, v_suf, logits, draft_kv, ends = suffix_prefill(
                     params, dparams, state, ids, shared_ids, shared_n,
-                    length, wshared)
+                    length, wshared, scan)
                 state, row = write_prompt(state, slot, k_suf, v_suf, length,
                                           shared_ids, shared_n, *draft_kv,
                                           **win)
                 state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
                                   do_sample, temp, top_k, stop_pos, eos, pinned,
                                   opening)
-                return state, tok1, row
+                return put_states(state, slot, ends, snap_to), tok1, row
 
             if draft is None:
                 def insert_prefix_step(params, state, *a):
@@ -744,12 +810,15 @@ class GenerationEngine:
                 # chunk page — the stale-pinned leak this executable exists
                 # to prevent; the final chunk raises it to pin_final to
                 # protect the pages about to be registered as shared.
+                # an engine with state layers: every chunk but the first
+                # scans on from the slot's own state (`SCAN_FROM_SLOT`)
+                scan, snap_to, opening = state_args(slot, opening)
                 # a window engine: what an earlier chunk wrote (index >=
                 # pin_now) and the window has passed goes back
                 wshared, win, opening = window_args(opening, pin_now)
-                k_suf, v_suf, logits, draft_kv = suffix_prefill(
+                k_suf, v_suf, logits, draft_kv, ends = suffix_prefill(
                     params, dparams, state, ids, shared_ids, shared_n,
-                    length, wshared)
+                    length, wshared, scan)
                 state, row = write_prompt(state, slot, k_suf, v_suf, length,
                                           shared_ids, shared_n, *draft_kv,
                                           **win)
@@ -758,7 +827,7 @@ class GenerationEngine:
                 state, tok1 = arm(state, slot, logits, length, seed, resume_pos,
                                   do_sample, temp, top_k, stop_pos, eos, pinned,
                                   opening, active=arm_now)
-                return state, tok1, row
+                return put_states(state, slot, ends, snap_to), tok1, row
 
             if draft is None:
                 def chunk_step(params, state, *a):
@@ -791,14 +860,24 @@ class GenerationEngine:
                     win = dict(wk_pages=state["wkp"], wv_pages=state["wvp"],
                                wrows=wtab, windows=W)
                 # (2) one paged-attention token per lane
+                source = PagedKV(state["kp"], state["vp"], ptab, pos, active,
+                                 seq_cap, **win)
+                if R:
+                    # the state layers update the live lanes' state in place
+                    # (the live lanes first: a dead lane's is never read)
+                    n_live = active.sum(dtype=jnp.int32)
+                    source = HybridKV(source, LaneStates(
+                        state["ssm"], state["conv"], active,
+                        jnp.argsort(~active, stable=True).astype(jnp.int32),
+                        n_live))
                 out, _ = functional_call(
                     model, params,
-                    (state["tok"][:, None], pos[:, None],
-                     PagedKV(state["kp"], state["vp"], ptab, pos, active,
-                             seq_cap, **win)),
+                    (state["tok"][:, None], pos[:, None], source),
                     dict(live=active) if counted else {},
                     buffers=buffers, mutable=False, method="slot_step")
                 logits, kv = out[0], out[1]
+                if R:
+                    kv, held = kv.kv, kv.states
                 logits, kp, vp = logits[:, 0], kv.k_pages, kv.v_pages
                 pair = jax.vmap(jax.random.split)(state["rng"])
                 new_keys, subs = pair[:, 0], pair[:, 1]
@@ -824,6 +903,10 @@ class GenerationEngine:
                                  tok=toks, pos=new_pos, rng=new_keys,
                                  active=active & ~finished)
                 report = (toks, finished)
+                if R:
+                    # the report gains how many lanes' state the step updated
+                    new_state.update(ssm=held.ssm, conv=held.conv)
+                    report += (n_live,)
                 if W:
                     # (4) the window pool: what lies wholly behind the next
                     # query's window leaves the row (the lane's own pages go
@@ -1169,7 +1252,8 @@ class GenerationEngine:
                 ids = sds((1, sp), np.int32)
                 with boot.executable(f"genserve/build/prefill.{sp}"):
                     self._prefill_execs[sp] = inference.aot_compile(
-                        prefill_step, (pspec,) + dpre + (ids, i32),
+                        prefill_step,
+                        (pspec,) + dpre + (ids, i32) + ((i32,) if R else ()),
                         out_shardings=(kv_sh, kv_sh, rep)
                         if mesh is not None else None)
                 # insert takes what prefill gives, in the model's own
@@ -1181,13 +1265,16 @@ class GenerationEngine:
                 # a block engine's admissions carry the opening block, a
                 # window engine's the window pool's shared ids and the
                 # first column its row keeps
+                # an engine with state layers' where the scan starts, the
+                # snapshot's offset and its place in the pool
                 opening = (sds((B,), np.int32), i32) if B else \
-                    (pvec, i32) if W else ()
+                    (pvec, i32) if W else (i32, i32, i32) if R else ()
                 with boot.executable(f"genserve/build/insert.{sp}"):
                     self._insert_execs[sp] = inference.aot_compile(
                         insert_step,
                         (sspec, i32, kv, kv, lg, i32, i32, i32, b1, f32, i32,
-                         i32, i32, i32) + dkv_in + opening[1 if W else 0:],
+                         i32, i32, i32) + dkv_in
+                        + opening[2 if R else 1 if W else 0:],
                         donate_argnums=(0,), out_shardings=outs(rep, rep))
                 tail = (i32, ids, pvec, i32, i32, i32, i32, b1, f32, i32,
                         i32, i32, i32)
@@ -1518,6 +1605,8 @@ class GenerationEngine:
             req = self._backlog[0]
             j_hit, shared = (self._prefix.lookup(req.prompt)
                              if self._prefix is not None else (0, ()))
+            if self.geometry.state_layers:
+                j_hit, shared = self._plan_scan(req, j_hit, shared)
             need = self.geometry.pages_for(
                 len(req.prompt) + req.max_new_tokens) - j_hit
             if self.geometry.windows:
@@ -1552,11 +1641,55 @@ class GenerationEngine:
         except Exception as e:  # noqa: BLE001 - fail THIS request,
             # keep the decode loop alive for the others
             logger.exception("generation admission failed")
+            if req.snap_pages:      # the snapshot its pass was to leave
+                self._prefix.drop_snapshot_of(req.prompt, req.snap_pages)
             self.metrics.count("errors")
             self._host_retire(slot)
             req.end_spans("error")
             req.handle._finish(e)
         return True
+
+    def _plan_scan(self, req: _GenRequest, j_match: int, shared):
+        """An engine with state layers: a prefix hit is worth only as deep
+        as a snapshot of the state lies.  Of the ``j_match`` pages the
+        lookup matched, the admission shares those up to the deepest
+        snapshot (none: the scan starts from zero, and the prompt pass
+        computes the pages again); where the match is deeper than the
+        snapshot, the pass leaves a snapshot at the matched depth, so the
+        next request with this prefix restores there.  Returns the (hit
+        pages, shared ids) the admission goes on with."""
+        with self.timers.scope("admit/restore"):
+            j, place = (self._prefix.lookup_state(req.prompt, j_match)
+                        if j_match else (0, -1))
+            req.restore = place if j else SCAN_FROM_ZERO
+            req.snap_pages = j_match if j_match > j else 0
+        return j, shared[:j]
+
+    def _scan_args(self, req: _GenRequest, cur: int, end: int, first=True):
+        """The trailing arguments of a suffix pass over tokens [cur, end)
+        of an engine with state layers, () for any other: where the scan
+        starts (the snapshot to restore or ``SCAN_FROM_ZERO``; behind an
+        earlier chunk ``SCAN_FROM_SLOT``, the slot's own state), the offset
+        in the pass of the snapshot it leaves, and the snapshot's place in
+        the pool (-1: none)."""
+        if not self.geometry.state_layers:
+            return ()
+        start = req.restore if first else SCAN_FROM_SLOT
+        at = req.snap_pages * self.geometry.page_size
+        snap_at, snap_to = 0, -1
+        if req.snap_pages and cur < at <= end:
+            with self.timers.scope("admit/snapshot"):
+                snap_at = at - cur
+                snap_to = self._prefix.take_snapshot(req.prompt,
+                                                     req.snap_pages)
+        self.metrics.observe_scan(end - cur, restored=first and start >= 0)
+        return np.int32(start), np.int32(snap_at), np.int32(snap_to)
+
+    def _sync_snapshots(self):
+        if self.geometry.state_layers and self._prefix is not None:
+            self.metrics.set_state_snapshots(
+                self._prefix.snapshots_taken, self._prefix.snapshots_evicted,
+                self._prefix.snapshots_live)
 
     def _admit(self, req: _GenRequest, slot: int, j_hit: int, shared):
         """Prefill + insert: map the slot's cache pages (reusing any
@@ -1581,6 +1714,7 @@ class GenerationEngine:
                 if self.draft_model is not None else ())
         opening = self._opening(req.prompt)
         scope = self.timers.scope
+        scan = self._scan_args(req, j_hit * geom.page_size, L)
         with scope("prefill"):
             if j_hit > 0:
                 # prefix hit: prefill ONLY the suffix
@@ -1597,12 +1731,12 @@ class GenerationEngine:
                     np.bool_(req.do_sample),
                     np.float32(req.temperature), np.int32(req.top_k),
                     stop, np.int32(req.eos), np.int32(pinned), *opening,
-                    *win)
+                    *win, *scan)
             else:
                 ids = np.zeros((1, req.bucket), np.int32)
                 ids[0, :L] = req.prompt
                 out = self._prefill_execs[req.bucket](
-                    self._params, *dpre, ids, np.int32(L))
+                    self._params, *dpre, ids, np.int32(L), *scan[1:2])
                 k_new, v_new, logits = out[:3]
                 state, tok1, row = self._insert_execs[req.bucket](
                     self._state, np.int32(slot), k_new, v_new, logits,
@@ -1610,7 +1744,8 @@ class GenerationEngine:
                     np.int32(req.resume_pos),
                     np.bool_(req.do_sample), np.float32(req.temperature),
                     np.int32(req.top_k), stop, np.int32(req.eos),
-                    np.int32(pinned), *out[3:], *opening, *win[1:])
+                    np.int32(pinned), *out[3:], *opening, *win[1:],
+                    *scan[2:])
         self._state = state
         with scope("admit/fetch"), host_fetch():
             # blocks until the device has run the prefill and insert
@@ -1628,6 +1763,7 @@ class GenerationEngine:
                 self._reclaim(self._prefix.register(req.prompt, row_np,
                                                     j_hit, j_reg))
                 self._sync_resident()
+                self._sync_snapshots()
         with scope("admit/push"):
             if sp_prefill is not None:
                 sp_prefill.end(status="ok")
@@ -1749,6 +1885,8 @@ class GenerationEngine:
         dpre = ((self._draft_params,)
                 if self.draft_model is not None else ())
         scope = self.timers.scope
+        scan = self._scan_args(req, cur, end,
+                               first=cur == req.j_hit * geom.page_size)
         with scope("prefill_chunk"):
             state, tok1, row = self._chunk_execs[sb](
                 self._params, *dpre, self._state, np.int32(slot), ids,
@@ -1759,7 +1897,7 @@ class GenerationEngine:
                 np.int32(req.top_k),
                 np.int32(L + req.max_new_tokens), np.int32(req.eos),
                 np.int32(req.j_hit), np.int32(req.pin_final),
-                np.bool_(arm), *self._opening(req.prompt), *win)
+                np.bool_(arm), *self._opening(req.prompt), *win, *scan)
         self._state = state
         with scope("chunk/fetch"), host_fetch():
             t1 = int(np.array(tok1, copy=True))
@@ -1791,6 +1929,7 @@ class GenerationEngine:
             self._reclaim(self._prefix.register(req.prompt, row_np,
                                                 j_hit, j_reg))
             self._sync_resident()
+            self._sync_snapshots()
         if req.span_decode is not None:
             req.span_decode.end(status="ok")
             req.span_decode = None
@@ -2078,10 +2217,16 @@ class GenerationEngine:
         if proposed:
             self.metrics.observe_spec(accepted, proposed)
 
-    def _distribute(self, it, lanes, toks_np, fin_np, pools_np=None):
+    def _distribute(self, it, lanes, toks_np, fin_np, *more):
+        """`more`: what the step's report adds for an engine with state
+        layers (how many lanes' state it updated), then for one with window
+        layers (the pools' registers)."""
         now = time.monotonic()
-        if pools_np is not None:
-            self.metrics.observe_pools(*(int(x) for x in pools_np))
+        if self.geometry.state_layers:
+            n_live, *more = more
+            self.metrics.observe_state_step(int(n_live))
+        if more:
+            self.metrics.observe_pools(*(int(x) for x in more[0]))
         self.metrics.observe_tokens(len(lanes))
         for slot, req in lanes:
             if req.t_last_token is not None:

@@ -36,6 +36,20 @@ refcount drops to zero.  The free-list discipline assumes the host
 admits only requests whose worst-case page demand is reserved
 (serving/scheduler.py) — ``take_pages`` underflows silently otherwise.
 
+A model whose ``cfg`` declares ``state_layers`` (layers that hold a
+recurrent state and no page: a state-space mixer) gets a second kind of
+per-slot state in the same pytree: ``ssm`` and ``conv``, every slot's
+state and convolution tail a state layer, and beside them a pool of
+snapshots (``snap_ssm``, ``snap_conv``).  The pool of pages then holds the
+OTHER layers' planes alone.  A slot's state is zero at a cold admission,
+restored from a snapshot at a prefix hit (a page id maps K/V back, nothing
+maps a state back: a hit is worth only as deep as a snapshot lies), both
+inside the admission's own executable; the decode step rewrites the live
+lanes' states in place (donated and aliased through the kernel: no second
+copy) and never reads a dead lane's.  The layers meet it through the state
+sources below (``PromptStates``, ``LaneStates``; ``HybridKV`` carries both
+kinds), as attention meets its keys through ``PagedKV`` and ``PrefixKV``.
+
 This module is layout + traced transitions only; scheduling policy lives
 in serving/scheduler.py and the compiled-executable lifecycle in
 serving/generation.py.  The pool's layout is indexed here and in
@@ -49,14 +63,15 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import fused
 from ..tensor import unwrap
 
-__all__ = ["CacheGeometry", "PagedKV", "PrefixKV", "make_state",
-           "state_specs", "take_pages", "push_pages", "write_prompt",
-           "admit_slot", "release_slots", "slide_window",
-           "reclaim_pages"]
+__all__ = ["CacheGeometry", "PagedKV", "PrefixKV", "HybridKV", "LaneStates",
+           "PromptStates", "make_state", "state_specs", "take_pages",
+           "push_pages", "write_prompt", "admit_slot", "release_slots",
+           "slide_window", "reclaim_pages", "initial_states", "put_states"]
 
 
 @dataclass(frozen=True)
@@ -100,10 +115,37 @@ class CacheGeometry:
     # free list of their own (``window_pages`` pages, derived below): a
     # lane maps there only the pages that meet its window
     windows: tuple = ()
+    # layers that hold no page but a recurrent state (a state-space mixer):
+    # their indices, and one lane's state (float32) and convolution tail in
+    # one such layer.  () = every layer holds pages, and the state is what
+    # it always was.  With state layers the pool has a plane for each OTHER
+    # layer only, every slot a state and a tail for each of these, and a
+    # pool of ``state_snapshots`` snapshots stands beside them, from which
+    # a prefix hit restores (a page id maps K/V back; nothing maps a state
+    # back)
+    state_layers: tuple = ()
+    state_shape: tuple = ()
+    conv_shape: tuple = ()
+    # the prompt pass scans in chunks of ``state_chunk`` tokens, and a state
+    # is held ``state_pack`` heads to a row (``fused.ssm_pack_state``)
+    state_chunk: int = 0
+    state_pack: int = 1
 
     def __post_init__(self):
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {self.page_size}")
+        object.__setattr__(self, "state_layers",
+                           tuple(int(i) for i in self.state_layers))
+        if self.state_layers and (
+                any(self.windows) or self.draft_layers or self.block_length
+                or not self.state_shape or not self.conv_shape
+                or self.state_chunk < 1
+                or len(set(self.state_layers)) == self.num_layers):
+            raise ValueError(
+                "layers with a recurrent state are held beside at least one "
+                "layer with pages, with their state's and tail's shapes and "
+                "the scan's chunk given, and without window layers, a draft model or "
+                "generation by blocks (none of those paths is written)")
         object.__setattr__(self, "windows",
                            tuple(int(w) for w in self.windows))
         if not any(self.windows):
@@ -149,8 +191,28 @@ class CacheGeometry:
     @property
     def full_layers(self) -> tuple:
         """The model's layers whose K/V the full pool holds, in order."""
+        held = set(self.state_layers)
         return tuple(i for i in range(self.num_layers)
-                     if not (self.windows and self.windows[i]))
+                     if not (self.windows and self.windows[i])
+                     and i not in held)
+
+    @property
+    def state_snapshots(self) -> int:
+        """Snapshots of a lane's whole recurrent state the engine can keep
+        for prefix hits to restore from: one for every six slots (0 without
+        state layers).  The pool's one sizing rule: where it is short the
+        oldest snapshot goes, and a later hit on its prefix scans from a
+        shallower one or from zero."""
+        return max(1, self.max_slots // 6) if self.state_layers else 0
+
+    def state_bytes(self) -> int:
+        """Bytes of ONE lane's recurrent state over all state layers (a
+        snapshot costs the same): float32 states, tails in ``dtype``."""
+        import numpy as np
+
+        return len(self.state_layers) * (
+            4 * int(np.prod(self.state_shape))
+            + np.dtype(self.dtype).itemsize * int(np.prod(self.conv_shape)))
 
     @property
     def window_layers(self) -> tuple:
@@ -173,7 +235,7 @@ class CacheGeometry:
         that meets the window, to the position's)."""
         ps, cols = self.page_size, self.pages_per_slot
         n_win = len(self.window_layers)
-        n_full = self.num_layers - n_win
+        n_full = len(self.full_layers)
         win_cols = min(cols, (self.window - 2) // ps + 2) if n_win else 0
         slots = self.max_slots * (n_full * cols + n_win * win_cols)
         live = 0
@@ -275,6 +337,14 @@ def make_state(geom: CacheGeometry):
     window layers' pool: ``wkp``/``wvp``, ``wtab``, ``wfree_stack``/
     ``wfree_count`` (``kp``/``vp`` then hold the other layers alone) and
     ``w_released``, the pages let go behind the window since start.
+    With ``state_layers``: ``ssm`` [state layers, slots, *state_shape]
+    float32 and ``conv`` [state layers, slots, prod(conv_shape)], every slot's
+    recurrent state and convolution tail (``kp``/``vp`` then hold the other
+    layers alone), and ``snap_ssm``/``snap_conv``, the same for
+    ``state_snapshots`` snapshots.  A slot's state is written whole at
+    every admission (zero, restored or scanned), updated in place by the
+    decode step for the live lanes, and never read for a dead one: release
+    has nothing to give back.
     """
     S = geom.max_slots
     key_shape = jax.random.PRNGKey(0).shape  # (2,) for threefry
@@ -316,6 +386,21 @@ def make_state(geom: CacheGeometry):
                                           dtype=jnp.int32)
         state["wfree_count"] = jnp.int32(geom.window_pages)
         state["w_released"] = jnp.int32(0)
+    if geom.state_layers:
+        # every slot's recurrent state and convolution tail, layer by
+        # layer, and the pool of snapshots beside them
+        n, snaps = len(geom.state_layers), geom.state_snapshots
+        dt = jnp.dtype(geom.dtype)
+        state["ssm"] = jnp.zeros((n, S) + geom.state_shape, jnp.float32)
+        # a tail is held flat, [d_conv - 1, conv_dim] as one row: three rows
+        # of a [3, 4352] tile are padded to sixteen, and the compiler then
+        # keeps the array "compressed" and copies all of it around every
+        # layer's use (1.75 s of a 12 s window: PERF.md section 6, PR 41)
+        tail = (int(np.prod(geom.conv_shape)),)
+        state["conv"] = jnp.zeros((n, S) + tail, dt)
+        state["snap_ssm"] = jnp.zeros((n, snaps) + geom.state_shape,
+                                      jnp.float32)
+        state["snap_conv"] = jnp.zeros((n, snaps) + tail, dt)
     if geom.draft_layers:
         # draft-model KV pool, same page ids as kp/vp: one page-table
         # row addresses both models' cache for a lane
@@ -624,6 +709,187 @@ class PrefixKV:
         """(k, v) [layers, Ss, nkv, hd] of the suffix, for ``write_prompt``."""
         return (jnp.stack([k for k, _ in self.suffix]),
                 jnp.stack([v for _, v in self.suffix]))
+
+
+# -- state sources: what a model's recurrent layers run over ----------------
+# Two calls a layer, in this order: ``window(plane, xBC) -> (past, source')``
+# lays the new tokens' convolution inputs xBC [B, S, C] behind the d_conv - 1
+# that came before them ([B, S + d_conv - 1, C]) and takes the new tail in;
+# ``scan(plane, x, dt, A, B, C) -> (y, source')`` runs H_t = exp(dt_t A)
+# H_{t-1} + dt_t x_t B_t^T, y_t = H_t C_t over x [B, S, H, P], dt [B, S, H]
+# (after the softplus), B and C [B, S, N], and takes the new state in.
+# ``plane`` is the layer's number among the state layers.  The states are
+# held in ``fused.ssm_pack_state``'s layout.
+
+@jax.tree_util.register_dataclass
+@dataclass
+class PromptStates:
+    """ONE request's prompt pass (cold, behind a prefix hit, or one chunk of
+    a chunked prefill): the scan starts from ``ssm0``/``conv0`` [state
+    layers, ...] (zero, a restored snapshot, or the slot's own state so
+    far; a tail is held flat, as the decode state holds it), runs over the first ``length`` tokens of the bucket (a padded
+    position leaves state and tail untouched) in chunks of ``chunk``, and
+    keeps of every layer the state and tail after the last token and after
+    token ``snap_at`` - 1 (a shared page boundary the pass crosses)."""
+    ssm0: Any
+    conv0: Any
+    length: Any
+    snap_at: Any
+    chunk: int = field(metadata=dict(static=True))
+    pack: int = field(metadata=dict(static=True))
+    tails: tuple = ()
+    states: tuple = ()
+
+    @classmethod
+    def zeros(cls, cfg, length, snap_at=0):
+        """A pass from the zero state for a model's ``cfg`` (state_layers,
+        state_shape, conv_shape, state_pack, state_chunk)."""
+        n = len(cfg.state_layers)
+        return cls(jnp.zeros((n,) + tuple(cfg.state_shape), jnp.float32),
+                   jnp.zeros((n, int(np.prod(cfg.conv_shape))), jnp.float32),
+                   jnp.asarray(unwrap(length), jnp.int32),
+                   jnp.asarray(unwrap(snap_at), jnp.int32),
+                   chunk=int(cfg.state_chunk), pack=int(cfg.state_pack))
+
+    def window(self, plane, xBC):
+        tail = self.conv0[plane].reshape(-1, xBC.shape[-1])   # held flat
+        past = jnp.concatenate([tail[None].astype(xBC.dtype), xBC], axis=1)
+
+        def at(n):      # the tail after token n - 1: inputs [n - k, n)
+            return jax.lax.dynamic_slice_in_dim(
+                past[0], n, tail.shape[0], 0).reshape(-1)
+
+        return past, replace(self, tails=self.tails + (
+            (at(self.length), at(self.snap_at)),))
+
+    def scan(self, plane, x, dt, A, B, C):
+        live = jnp.arange(x.shape[1])[:, None] < self.length
+        d = jnp.where(live, dt[0], 0.0)
+        y, end, starts = fused.ssd_chunk_scan(
+            x[0], d, A, B[0], C[0],
+            fused.ssm_unpack_state(self.ssm0[plane], self.pack), self.chunk)
+        snap = fused.ssd_state_at(x[0], d, A, B[0], starts, self.snap_at,
+                                  self.chunk)
+        return y[None], replace(self, states=self.states + (
+            (fused.ssm_pack_state(end, self.pack),
+             fused.ssm_pack_state(snap, self.pack)),))
+
+    def ends(self):
+        """(state after the last token [state layers, ...], its tail, the
+        state after token ``snap_at`` - 1, its tail), for ``put_states``."""
+        return (jnp.stack([e for e, _ in self.states]),
+                jnp.stack([t for t, _ in self.tails]),
+                jnp.stack([s for _, s in self.states]),
+                jnp.stack([t for _, t in self.tails]))
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class LaneStates:
+    """The decode step: one token a lane over every slot's state.  ``ssm``
+    [state layers, slots, ...] and ``conv`` are the WHOLE arrays of the
+    decode state: a layer rewrites its plane in place, the live lanes' part
+    of it alone (``live`` [slots] bool; ``lanes`` [slots] lists the live
+    lanes first, ``n_live`` how many they are: ``fused.ssm_decode_update``).
+    A dead lane's state and tail stay as they are: a slot between two
+    chunks of its prompt holds there what its next chunk scans on from."""
+    ssm: Any
+    conv: Any
+    live: Any
+    lanes: Any
+    n_live: Any
+
+    def window(self, plane, xBC):
+        slots, _, width = xBC.shape
+        held = self.conv[plane]                               # held flat
+        past = jnp.concatenate(
+            [held.reshape(slots, -1, width).astype(xBC.dtype), xBC], axis=1)
+        new = past[:, 1:].reshape(slots, -1).astype(held.dtype)
+        return past, replace(self, conv=self.conv.at[plane].set(
+            jnp.where(self.live[:, None], new, held)))
+
+    def scan(self, plane, x, dt, A, B, C):
+        y, ssm = fused.ssm_decode_update(
+            self.ssm, plane, self.lanes, self.n_live, x[:, 0], dt[:, 0], A,
+            B[:, 0], C[:, 0])
+        return y[:, None], replace(self, ssm=ssm)
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class HybridKV:
+    """Both kinds of state as one source: ``kv`` (``PagedKV`` or
+    ``PrefixKV``) for the layers that attend, ``states`` (``LaneStates`` or
+    ``PromptStates``) for the layers that scan."""
+    kv: Any
+    states: Any
+
+    def attend(self, plane, q, k, v, head_axis=None):
+        ctx, kv = self.kv.attend(plane, q, k, v, head_axis)
+        return ctx, replace(self, kv=kv)
+
+    def window(self, plane, xBC):
+        past, states = self.states.window(plane, xBC)
+        return past, replace(self, states=states)
+
+    def scan(self, plane, x, dt, A, B, C):
+        y, states = self.states.scan(plane, x, dt, A, B, C)
+        return y, replace(self, states=states)
+
+
+# where a prompt pass starts its scan, beside a snapshot's place (>= 0)
+SCAN_FROM_ZERO, SCAN_FROM_SLOT = -1, -2
+
+
+def initial_states(state, slot, start):
+    """Where one prompt pass starts its scan, (ssm0, conv0) [state layers,
+    ...]: snapshot ``start`` of the pool (>= 0: a prefix hit restores), the
+    zero state (``SCAN_FROM_ZERO``: a cold pass), or slot ``slot``'s own
+    state so far (``SCAN_FROM_SLOT``: the next chunk of a chunked prefill).
+    Traced."""
+    start = jnp.asarray(start, jnp.int32)
+    slot = jnp.asarray(slot, jnp.int32)
+    snap = jnp.clip(start, 0, state["snap_ssm"].shape[1] - 1)
+
+    def pick(snaps, own):
+        return jnp.where(start >= 0, snaps[:, snap],
+                         jnp.where(start == SCAN_FROM_SLOT, own[:, slot], 0))
+
+    return pick(state["snap_ssm"], state["ssm"]), \
+        pick(state["snap_conv"], state["conv"])
+
+
+def put_states(state, slot, ends, snap_to):
+    """Hand a prompt pass's states (``PromptStates.ends``) over: the state
+    and tail after its last token to slot ``slot``, those at the shared
+    page boundary it crossed to snapshot ``snap_to`` of the pool (-1: it
+    leaves none, and the place written is handed what it held).  Each a
+    slice updated in place: a scatter that may drop its update makes the
+    compiler copy the pool.  ``ends`` () (a model without state layers ran
+    the pass): ``state`` as it came."""
+    if not ends:
+        return state
+    ssm_end, conv_end, ssm_snap, conv_snap = ends
+    slot = jnp.asarray(slot, jnp.int32)
+    keep = jnp.asarray(snap_to, jnp.int32) >= 0
+    to = jnp.clip(snap_to, 0, state["snap_ssm"].shape[1] - 1).astype(
+        jnp.int32)
+
+    def put(held, at, new, unless=None):
+        new = new.astype(held.dtype)[:, None]
+        zero = (jnp.int32(0),) * (held.ndim - 2)
+        if unless is not None:
+            new = jnp.where(unless, new, jax.lax.dynamic_slice(
+                held, (jnp.int32(0), at) + zero, new.shape))
+        return jax.lax.dynamic_update_slice(held, new,
+                                            (jnp.int32(0), at) + zero)
+
+    return dict(
+        state,
+        ssm=put(state["ssm"], slot, ssm_end),
+        conv=put(state["conv"], slot, conv_end),
+        snap_ssm=put(state["snap_ssm"], to, ssm_snap, keep),
+        snap_conv=put(state["snap_conv"], to, conv_snap, keep))
 
 
 # -- in-graph free-list register ops ----------------------------------------

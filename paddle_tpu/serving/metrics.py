@@ -241,13 +241,31 @@ class GenerationMetrics:
                                              kv_pages_mapped summed over
                                              the steps: the mean a step
                                              is a ratio of two counters
+    and, for an engine whose model has layers with a recurrent state
+    (snapshots of it beside the page pool, serving/kv_cache.py):
+      paddle_genserve_state_restores_total   admissions whose scan started
+                                             from a restored snapshot
+      paddle_genserve_state_snapshots_total  snapshots prompt passes left
+      paddle_genserve_state_snapshot_evictions_total
+                                             snapshots that went: their
+                                             place reused, or with their
+                                             entry's pages
+      paddle_genserve_state_snapshots_live   snapshots the pool holds
+      paddle_genserve_state_lane_steps_total live lanes summed over the
+                                             decode steps: the lane
+                                             states the steps updated
+      paddle_genserve_state_scans_total      prompt passes (admissions
+                                             and chunks) that scanned
+      paddle_genserve_state_scan_tokens_total
+                                             the true (unpadded) tokens
+                                             they scanned
     """
 
     WINDOW_S = 60.0
     RESERVOIR = 4096
 
     def __init__(self, max_slots: int = 1, num_pages: int = 1,
-                 window_pool: bool = False):
+                 window_pool: bool = False, state_pool: bool = False):
         self.registry = MetricsRegistry()
         self._lock = self.registry._lock
         self.started_at = time.monotonic()
@@ -377,6 +395,34 @@ class GenerationMetrics:
                 "paddle_genserve_kv_mapped_page_steps_total",
                 "kv_pages_mapped summed over the decode steps",
                 label="pool", preset=("full", "window"), fixed=True)
+        self._state = None
+        if state_pool:
+            self._state = {"live": 0}
+            reg.gauge("paddle_genserve_state_snapshots_live",
+                      "state snapshots the pool holds",
+                      fn=lambda: self._state["live"])
+            self._state_restores = reg.counter(
+                "paddle_genserve_state_restores_total",
+                "admissions whose scan started from a restored snapshot")
+            self._state_snapshots = reg.counter(
+                "paddle_genserve_state_snapshots_total",
+                "state snapshots prompt passes left at a shared page "
+                "boundary")
+            self._state_evictions = reg.counter(
+                "paddle_genserve_state_snapshot_evictions_total",
+                "state snapshots that went: their place reused for a newer "
+                "one, or with their entry's pages")
+            self._state_lane_steps = reg.counter(
+                "paddle_genserve_state_lane_steps_total",
+                "live lanes summed over the decode steps: the lane states "
+                "the steps updated, as each step's report counted them")
+            self._state_scans = reg.counter(
+                "paddle_genserve_state_scans_total",
+                "prompt passes (admissions and prefill chunks) that "
+                "scanned")
+            self._state_scan_tokens = reg.counter(
+                "paddle_genserve_state_scan_tokens_total",
+                "true (unpadded) tokens the prompt passes scanned")
         # the last RESERVOIR samples of the trailing WINDOW_S seconds
         self._ttft = Reservoir(self.RESERVOIR, self._lock, self.WINDOW_S)
         self._gaps = Reservoir(self.RESERVOIR, self._lock, self.WINDOW_S)
@@ -468,6 +514,26 @@ class GenerationMetrics:
         self._mapped_steps.inc("full", mapped)
         self._mapped_steps.inc("window", w_mapped)
 
+    def observe_scan(self, tokens: int, restored: bool):
+        """One prompt pass of an engine with state layers: the true tokens
+        it scans, and whether its scan starts from a restored snapshot."""
+        self._state_scans.inc()
+        self._state_scan_tokens.inc(tokens)
+        if restored:
+            self._state_restores.inc()
+
+    def observe_state_step(self, lanes: int):
+        """One decode step of an engine with state layers: the live lanes
+        whose state it updated."""
+        self._state_lane_steps.inc(lanes)
+
+    def set_state_snapshots(self, taken: int, evicted: int, live: int):
+        """The prefix cache's running counts of its snapshots."""
+        self._state_snapshots.inc(taken - self._state_snapshots.value)
+        self._state_evictions.inc(evicted - self._state_evictions.value)
+        with self._lock:
+            self._state["live"] = int(live)
+
     def set_compile_count(self, n: int):
         with self._lock:
             self.compile_count = int(n)
@@ -534,6 +600,15 @@ class GenerationMetrics:
                         self._window_released.value,
                     "kv_mapped_page_steps":
                         dict(self._mapped_steps.values)}),
+                **({} if self._state is None else {
+                    "state_restores": self._state_restores.value,
+                    "state_snapshots": self._state_snapshots.value,
+                    "state_snapshot_evictions":
+                        self._state_evictions.value,
+                    "state_snapshots_live": self._state["live"],
+                    "state_lane_steps": self._state_lane_steps.value,
+                    "state_scans": self._state_scans.value,
+                    "state_scan_tokens": self._state_scan_tokens.value}),
             }
 
     def prometheus_text(self) -> str:

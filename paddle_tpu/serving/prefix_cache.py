@@ -21,6 +21,15 @@ entry containing it plus every active slot pinned to it.  LRU eviction
 (bounded entry count) and slot release decrement; pages reaching zero
 are handed back to the engine, which returns them to the device free
 stack through the ``reclaim`` executable.
+
+For a model whose layers hold a recurrent state beside their pages
+(serving/kv_cache.py ``state_layers``) a hit is worth only as deep as a
+SNAPSHOT of that state lies: page ids map K/V back, nothing maps a state
+back.  The cache then also keeps which of its entries has a snapshot in
+the device's pool of ``snapshots`` places (``take_snapshot``), finds the
+deepest one under a match (``lookup_state``), reuses the place of the one
+used longest ago when the pool is full, and lets a snapshot go with its
+entry's pages: ``snapshots_live`` x one lane's state is what they hold.
 """
 from __future__ import annotations
 
@@ -33,7 +42,7 @@ class PrefixCache:
     """Refcounted read-only shared KV pages keyed by prompt prefix."""
 
     def __init__(self, page_size: int, capacity: int = 1024,
-                 split: int | None = None):
+                 split: int | None = None, snapshots: int = 0):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if capacity < 1:
@@ -47,6 +56,12 @@ class PrefixCache:
         # of each pool, and rows are [2, pages_per_slot]
         self.split = None if split is None else int(split)
         self.resident_high = 0                     # resident ids >= split
+        # state snapshots: entry key -> its place in the device's pool,
+        # least recently restored first
+        self._snaps = collections.OrderedDict()
+        self._snap_free = list(range(int(snapshots) - 1, -1, -1))
+        self.snapshots_taken = 0
+        self.snapshots_evicted = 0
 
     # -- introspection -----------------------------------------------------
     def __len__(self) -> int:
@@ -57,6 +72,10 @@ class PrefixCache:
         """Pages currently held resident by entries and/or slot pins —
         the scheduler subtracts these from the allocatable pool."""
         return len(self._rc)
+
+    @property
+    def snapshots_live(self) -> int:
+        return len(self._snaps)
 
     def shareable_pages(self, prompt_len: int) -> int:
         """Max pages of an L-token prompt that may ever be shared."""
@@ -90,6 +109,47 @@ class PrefixCache:
                 return j, pages
         return 0, ()
 
+    def lookup_state(self, prompt, j_max: int):
+        """The deepest page count ``j <= j_max`` at which the cache holds a
+        snapshot of ``prompt``'s state, and its place in the pool: (j,
+        place), or (0, -1).  Touches the snapshot (the pool reuses the
+        place of the one restored longest ago)."""
+        for j in range(int(j_max), 0, -1):
+            key = self._key(prompt, j)
+            place = self._snaps.get(key)
+            if place is not None:
+                self._snaps.move_to_end(key)
+                return j, place
+        return 0, -1
+
+    def take_snapshot(self, prompt, j: int) -> int:
+        """A place in the pool for the state after ``prompt``'s first ``j``
+        pages (whose entry the cache holds): a free one, else the place of
+        the snapshot restored longest ago, which is dropped."""
+        key = self._key(prompt, j)
+        place = self._snaps.pop(key, None)
+        if place is None:
+            if self._snap_free:
+                place = self._snap_free.pop()
+            else:
+                _, place = self._snaps.popitem(last=False)
+                self.snapshots_evicted += 1
+        self._snaps[key] = place
+        self.snapshots_taken += 1
+        return place
+
+    def drop_snapshot_of(self, prompt, j: int):
+        """The snapshot of ``prompt``'s first ``j`` pages goes: the pass
+        that was to write it failed."""
+        self.drop_snapshot(self._key(prompt, j))
+
+    def drop_snapshot(self, key):
+        """The snapshot of entry ``key`` goes with the entry's pages."""
+        place = self._snaps.pop(key, None)
+        if place is not None:
+            self._snap_free.append(place)
+            self.snapshots_evicted += 1
+
     def register(self, prompt, row, j_hit: int, j_reg: int):
         """Register entries for every unshared full-page prefix of an
         admitted prompt: prefix page counts ``j_hit+1 .. j_reg`` map to
@@ -105,7 +165,8 @@ class PrefixCache:
             self._entries[key] = pages
             self.pin(pages)
             while len(self._entries) > self.capacity:
-                _, old = self._entries.popitem(last=False)
+                gone, old = self._entries.popitem(last=False)
+                self.drop_snapshot(gone)
                 reclaim.extend(self._unref(old))
         return reclaim
 
@@ -124,7 +185,8 @@ class PrefixCache:
         (entry-count capacity never trips on a small pool)."""
         reclaim = []
         while self._entries and len(reclaim) < n_pages:
-            _, old = self._entries.popitem(last=False)
+            gone, old = self._entries.popitem(last=False)
+            self.drop_snapshot(gone)
             reclaim.extend(self._unref(old))
         return reclaim
 

@@ -22,7 +22,8 @@ import pytest
 import paddle_tpu  # noqa: F401 - the package decides the process's x64 mode
 from paddle_tpu.ops.pallas import (bias_gelu as bg, flash_attention as fa,
                                    layer_norm as ln, moe_gmm as mg,
-                                   paged_attention as pa, softmax_xent as sx)
+                                   paged_attention as pa, softmax_xent as sx,
+                                   ssm)
 
 B, S, NH, HD, H, FFN, V = 8, 1024, 12, 64, 768, 3072, 50304
 SLOTS, PAGE = 16, 16
@@ -171,6 +172,36 @@ def _gmm_case(assignments):
                ((rows // tm,), I32), ((), I32)]
 
 
+# the selective scan as granite-4.0-h-micro.toolchat calls it: 64 heads of
+# [64, 128] float32 a layer; the prompt pass's buckets of 256 and 1280
+# tokens in chunks of 256 (bf16 operands); the one-token update over the
+# whole array of held states, 36 layers x 48 slots x [32, 128, 128]
+# (two heads side by side on the lanes), rewritten in place
+SSM_H, SSM_P, SSM_N, SSM_CHUNK, SSM_LAYERS, SSM_SLOTS = 64, 64, 128, 256, 36, 48
+SSM_HELD = (SSM_LAYERS, SSM_SLOTS, SSM_H // 2, SSM_N, 2 * SSM_P)
+
+
+def _ssd_case(tokens):
+    def f(x, dt, a, b, c, s0):
+        return ssm.ssd_chunk_scan(x, dt, a, b, c, s0, SSM_CHUNK,
+                                  interpret=False)
+
+    return f, [((tokens, SSM_H, SSM_P), BF16), ((tokens, SSM_H), F32),
+               ((SSM_H,), F32), ((tokens, SSM_N), BF16),
+               ((tokens, SSM_N), BF16), ((SSM_H, SSM_P, SSM_N), F32)]
+
+
+def _ssm_update(held, lanes, n_live, decay, dtx, b, c):
+    return ssm.ssm_decode_update(held, 7, lanes, n_live, decay, dtx, b, c,
+                                 interpret=False)
+
+
+SSM_UPDATE_ARGS = [(SSM_HELD, F32), ((SSM_SLOTS,), I32), ((), I32),
+                   ((SSM_SLOTS, SSM_H // 2, 2 * SSM_P), F32),
+                   ((SSM_SLOTS, SSM_H // 2, 2 * SSM_P), F32),
+                   ((SSM_SLOTS, SSM_N), F32), ((SSM_SLOTS, SSM_N), F32)]
+
+
 def _bwd(f, n):
     return jax.grad(_sum32(f), argnums=tuple(range(n)))
 
@@ -203,6 +234,9 @@ CASES = {
     "paged_gqa_block_step": (_gqa_block, GQA_BLOCK_ARGS),
     "moe_gmm_block_step_512": _gmm_case(512),
     "moe_gmm_prefill_6144": _gmm_case(6144),
+    "ssd_chunk_scan_256": _ssd_case(256),
+    "ssd_chunk_scan_1280": _ssd_case(1280),
+    "ssm_decode_update": (_ssm_update, SSM_UPDATE_ARGS),
 }
 
 
@@ -245,6 +279,8 @@ KERNEL_NAMES = {
     "paddle_paged_decode_fwd": "paged_decode",
     "paddle_paged_gqa_decode_fwd": "paged_gqa_decode_window",
     "paddle_moe_gmm": "moe_gmm_block_step_512",
+    "paddle_ssd_chunk_scan": "ssd_chunk_scan_256",
+    "paddle_ssm_decode_update": "ssm_decode_update",
 }
 
 
@@ -314,6 +350,35 @@ def test_flash_roofline_patterns_find_the_compiled_calls(v5e):
     assert len(calls) == 3, calls
     _assert_patterns_find("flash_roofline",
                           {"BH": FLASH_B * NH, "S": S, "HD": HD}, calls)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("metric, case", [
+    ("ssm_decode_roofline", "ssm_decode_update"),
+    ("ssd_prefill_roofline", "ssd_chunk_scan_256"),
+    ("ssd_prefill_roofline", "ssd_chunk_scan_1280")])
+def test_ssm_roofline_patterns_find_the_compiled_calls(v5e, metric, case):
+    """The benchmark knows both calls of the selective scan by their
+    `pallas_call` names: each metric's pattern matches the one call of its
+    compiled case."""
+    calls = _compiled_calls(v5e, case)
+    assert len(calls) == 1, calls
+    _assert_patterns_find(metric, {}, calls)
+
+
+@pytest.mark.kernels
+def test_ssm_decode_update_rewrites_the_held_states_in_place(v5e):
+    """The whole array of held states (3.6 GB at the cell's size) is the
+    call's operand and, aliased, its result: donated, the compiled program
+    holds no second copy of it."""
+    f, args = CASES["ssm_decode_update"]
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    mem = jax.jit(f, donate_argnums=(0,)).lower(*shapes).compile() \
+        .memory_analysis()
+    held = 4 * SSM_LAYERS * SSM_SLOTS * SSM_H * SSM_P * SSM_N
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < held // SSM_LAYERS
 
 
 @pytest.mark.kernels
