@@ -1506,9 +1506,10 @@ def _naive_causal_attention(q, k, v):
 
 def body_kernels(on_tpu):
     """Validate every Pallas kernel (masked flash fwd+bwd, paged decode,
-    softmax-xent, bias-gelu, layer_norm) against the plain-XLA path on
-    the REAL device, then time one flag-on vs flag-off masked training
-    step with per-op attribution (monitor.perf op_report).
+    softmax-xent, layer_norm) and the fused bias-gelu composite (jnp with
+    a polynomial erf; XLA fuses it into the FFN's products) against the
+    plain-XLA path on the REAL device, then time one flag-on vs flag-off
+    masked training step with per-op attribution (monitor.perf op_report).
 
     Numerics hygiene: under jax_enable_x64 a bare numpy scalar promotes
     the XLA reference to f64 while the kernels accumulate in f32 — every
@@ -1520,7 +1521,6 @@ def body_kernels(on_tpu):
     import numpy as np
 
     from paddle_tpu.ops import fused as _fused
-    from paddle_tpu.ops.pallas.bias_gelu import bias_gelu as pl_bias_gelu
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.layer_norm import layer_norm as fused_layer_norm
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
@@ -1638,17 +1638,20 @@ def body_kernels(on_tpu):
         jax.jit(jax.grad(lambda z: softmax_xent(z, lab).sum()))(z),
         jax.jit(jax.grad(lambda z: xent_ref(z).sum()))(z))
 
-    # bias-gelu
+    # bias-gelu: ops/fused's composite, polynomial erf in float32
+    def fused_bias_gelu(x, b):
+        return _fused.unwrap(_fused.bias_gelu(x, b))
+
     xg = jnp.asarray(rs.randn(256, 1024 if on_tpu else 256), jnp.float32)
     bg = jnp.asarray(rs.randn(xg.shape[-1]), jnp.float32)
 
     def bg_ref(x, b):
         return jax.nn.gelu(x + b, approximate=False)
 
-    errs["bias_gelu_fwd"] = _err(jax.jit(pl_bias_gelu)(xg, bg),
+    errs["bias_gelu_fwd"] = _err(jax.jit(fused_bias_gelu)(xg, bg),
                                  jax.jit(bg_ref)(xg, bg))
     gb1 = jax.jit(jax.grad(
-        lambda x, b: (pl_bias_gelu(x, b) ** 2).mean(), (0, 1)))(xg, bg)
+        lambda x, b: (fused_bias_gelu(x, b) ** 2).mean(), (0, 1)))(xg, bg)
     gb2 = jax.jit(jax.grad(
         lambda x, b: (bg_ref(x, b) ** 2).mean(), (0, 1)))(xg, bg)
     errs["bias_gelu_bwd"] = max(_err(a, b) for a, b in zip(gb1, gb2))

@@ -42,7 +42,7 @@ REAL = dict(
     # the paged kernel at GPT-2's heads (pages no DMA can cut out of the
     # pool: the grid walks them) and at heads whose pages the kernel copies
     # itself in a loop of its own (16 of 128, the chat cell's)
-    kernels=dict(B=8, S=1024, NH=12, HD=64, H=768, FFN=3072, V=50304,
+    kernels=dict(B=8, S=1024, NH=12, HD=64, H=768, V=50304,
                  slots=16, page=16, walk_NH=16, walk_HD=128),
     serve=dict(slots=16, max_seq_len=1024, page=16, buckets="128,256,512",
                new_tokens=64, prompt_len=128, long_len=384, prefix_len=256))
@@ -50,7 +50,7 @@ TINY = dict(
     model=dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=2,
                max_position_embeddings=128),
     batch=4, seq=64, steps=4, lr=3e-3,
-    kernels=dict(B=1, S=64, NH=2, HD=32, H=128, FFN=256, V=384,
+    kernels=dict(B=1, S=64, NH=2, HD=32, H=128, V=384,
                  slots=2, page=8, walk_NH=2, walk_HD=128),
     serve=dict(slots=4, max_seq_len=128, page=8, buckets="16,64",
                new_tokens=8, prompt_len=16, long_len=48, prefix_len=32))
@@ -147,7 +147,6 @@ def phase_kernels(args, jax, sizes):
     import numpy as np
 
     from paddle_tpu.ops.pallas import interpret_default
-    from paddle_tpu.ops.pallas.bias_gelu import bias_gelu
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.layer_norm import layer_norm
     from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
@@ -157,8 +156,7 @@ def phase_kernels(args, jax, sizes):
     check(args.rehearse or interpret is False,
           "kernels would run interpreted on this backend")
     k = sizes["kernels"]
-    B, S, NH, HD, H, FFN, V = (k[n] for n in
-                               ("B", "S", "NH", "HD", "H", "FFN", "V"))
+    B, S, NH, HD, H, V = (k[n] for n in ("B", "S", "NH", "HD", "H", "V"))
     slots, page = k["slots"], k["page"]
     pps = S // page
     PLANE = 1   # the paged kernel reads one plane of the stacked pools
@@ -186,10 +184,6 @@ def phase_kernels(args, jax, sizes):
         mu = x.mean(-1, keepdims=True)
         var = jnp.square(x - mu).mean(-1, keepdims=True)
         return (x - mu) * jax.lax.rsqrt(var + 1e-5) * w + b
-
-    def ref_bias_gelu(x, b):
-        x, b = up(x, b)
-        return jax.nn.gelu(x + b, approximate=False)
 
     def ref_xent(z, lab):
         lp = jax.nn.log_softmax(z.astype(f32), -1)
@@ -240,9 +234,6 @@ def phase_kernels(args, jax, sizes):
             layer_norm, ref_layer_norm,
             [rand((B, S, H), f32), rand((H,), f32), rand((H,), f32)],
             (0, 1, 2), 1e-4),
-        "bias_gelu": (
-            bias_gelu, ref_bias_gelu,
-            [rand((B, S, FFN), bf16), rand((FFN,), bf16)], (0, 1), 2e-2),
         "softmax_xent": (
             softmax_xent, ref_xent,
             [rand((B * S, V), bf16), labels], (0,), 2e-2),

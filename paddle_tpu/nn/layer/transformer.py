@@ -150,7 +150,7 @@ class TransformerEncoderLayer(Layer):
         if self.normalize_before:
             src = self.norm2(src)
         if self.activation == "gelu":
-            # expansion matmul with the Pallas-fused bias+GeLU epilogue
+            # expansion matmul with the bias+GeLU epilogue in its fusion
             # (ops/fused.py; exact erf, same as F.gelu's default)
             h = _fused.linear_bias_gelu(src, self.linear1.weight,
                                         self.linear1.bias)

@@ -781,25 +781,101 @@ def ssm_decode_update(ssm, plane, lanes, n_live, x, dt, A, B, C):
 
 
 # ---------------------------------------------------------------------------
-# fused bias + GeLU (fused_gemm_epilogue intent): matmul stays with XLA's
-# MXU scheduling, the bias-add + exact-erf GeLU epilogue runs as one Pallas
-# pass (forward and backward) instead of separate elementwise HLOs
+# fused bias + GeLU (fused_gemm_epilogue intent): the bias-add and the
+# exact-erf GeLU are plain jnp under a custom_vjp, so XLA places them in
+# the fusion of the product before them (forward) and of the product that
+# makes their cotangent (backward, with db's row sum): the [rows, F]
+# activation crosses HBM once a direction, and the MXU and the vector unit
+# work in the same fusion.  A Pallas pass between the two products cannot
+# have that: on a v5e at bf16[16384, 3072] it cost a layer 1.99 ms forward
+# and backward where this costs 0.57 (PERF.md section 6, PR 42), so there
+# is one form, on every backend and under every mesh.
+#
+# All arithmetic in float32 with one cast at the end (in float64 for a
+# float64 input, with `lax.erf`).  erf is XLA's own float32 rational
+# polynomial written out (max abs error 4.5e-7 against float64 erf);
+# `lax.erf` in its place measured the same within 1%.  The
+# backward recomputes from the saved pre-activation: the residuals are
+# (x, b) alone, no erf or cdf kept at the activation's width.
 # ---------------------------------------------------------------------------
-def _sharded_bias_gelu(v, b, mesh, batch, tp):
-    """Pallas bias_gelu under shard_map so GSPMD keeps the FFN activation
-    sharded (rows over dp/fsdp, feature columns over mp/tp) instead of
-    gathering it around an opaque custom call."""
-    from jax.sharding import PartitionSpec as P
+_INV_SQRT2 = 0.7071067811865476
+_INV_SQRT_2PI = 0.3989422804014327
+# erf(x) ~= x * P(x^2) / Q(x^2) on [-4, 4] (|erf| is 1 to float32 beyond)
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04,
+          -2.95459980854025e-03, -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
 
-    from .pallas import bias_gelu as bg
 
-    tp = _axis_if_divides(v.shape[-1], mesh, tp)
-    lead = [_rows_entry(v, mesh, batch)] + [None] * (v.ndim - 2) \
-        if v.ndim >= 2 else []
-    vspec = P(*lead, tp)
-    return jax.shard_map(bg.bias_gelu, mesh=mesh,
-                         in_specs=(vspec, P(tp)), out_specs=vspec,
-                         check_vma=False)(v, b)
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _erf_f32(x):
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    return x * _horner(x2, _ERF_P) / _horner(x2, _ERF_Q)
+
+
+def _erf(x):
+    # the polynomial is float32's; float64 (x64 is on on the CPU) keeps
+    # its own precision
+    return _erf_f32(x) if x.dtype == jnp.float32 else jax.lax.erf(x)
+
+
+def _gelu(u):
+    return 0.5 * u * (1.0 + _erf(u * _INV_SQRT2))
+
+
+def _dgelu(u):
+    cdf = 0.5 * (1.0 + _erf(u * _INV_SQRT2))
+    pdf = jnp.exp(-0.5 * u * u) * _INV_SQRT_2PI
+    return cdf + u * pdf
+
+
+def _preact(x, b):
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    return x.astype(ct) + b.astype(ct)
+
+
+# jitted so that a program traces and lowers each body once a shape, not
+# once a layer (a GPT step calls them 12 times each): XLA inlines the
+# call before it fuses
+@jax.jit
+def _bias_gelu_value(x, b):
+    return _gelu(_preact(x, b)).astype(x.dtype)
+
+
+@jax.jit
+def _bias_gelu_grads(x, b, dy):
+    u = _preact(x, b)
+    dx = dy.astype(u.dtype) * _dgelu(u)
+    # d/db == d/dx elementwise (y = gelu(x + b)), so db is dx's row sum
+    db = dx.sum(tuple(range(x.ndim - 1))).reshape(b.shape)
+    return dx.astype(x.dtype), db.astype(b.dtype)
+
+
+@jax.custom_vjp
+def _bias_gelu(x, b):
+    """gelu(x + b) over the last dim: x [..., F], b [F]; result of x's
+    dtype."""
+    return _bias_gelu_value(x, b)
+
+
+def _bias_gelu_fwd(x, b):
+    return _bias_gelu_value(x, b), (x, b)
+
+
+def _bias_gelu_bwd(res, dy):
+    return _bias_gelu_grads(*res, dy)
+
+
+_bias_gelu.defvjp(_bias_gelu_fwd, _bias_gelu_bwd)
 
 
 def _dropout(y, dropout_p, training):
@@ -817,24 +893,10 @@ def _dropout(y, dropout_p, training):
 
 
 def bias_gelu(x, bias, dropout_p=0.0, training=True):
-    """gelu(x + bias) (exact erf form), optionally followed by dropout
-    threaded through the per-step rng.  Pallas-fused on TPU."""
-    if _use_pallas():
-        from .pallas import bias_gelu as bg
-
-        mesh, batch, tp = _mesh_axes()
-
-        def pf(v, b):
-            if mesh is not None:
-                return _sharded_bias_gelu(v, b, mesh, batch, tp)
-            return bg.bias_gelu(v, b)
-
-        out = _kernel_or_none("bias_gelu", lambda: apply(pf, x, bias))
-        if out is not None:
-            return _dropout(out, dropout_p, training)
-    y = apply(lambda v, b: jax.nn.gelu(v + b.astype(v.dtype),
-                                       approximate=False), x, bias)
-    return _dropout(y, dropout_p, training)
+    """gelu(x + bias) (exact erf form; float32 arithmetic, float64 for a
+    float64 input), optionally followed by dropout threaded through the
+    per-step rng."""
+    return _dropout(apply(_bias_gelu, x, bias), dropout_p, training)
 
 
 def linear_bias_gelu(x, weight, bias, dropout_p=0.0, training=True):

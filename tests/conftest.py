@@ -54,8 +54,8 @@ def pytest_configure(config):
         "distributed.goodput) — run via tools/obs_smoke.sh")
     config.addinivalue_line(
         "markers", "kernels: Pallas fused-kernel parity/dispatch test "
-        "(masked flash, paged decode, softmax-xent, bias-gelu; CPU "
-        "interpret mode) — run via tools/kernels_smoke.sh")
+        "(masked flash, paged decode, softmax-xent; the bias-gelu "
+        "composite; CPU interpret mode) — run via tools/kernels_smoke.sh")
     config.addinivalue_line(
         "markers", "pod: multi-process pod test (N real OS processes via "
         "distributed.podtest — coordinated jax.distributed bring-up or "

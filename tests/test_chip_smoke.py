@@ -76,8 +76,8 @@ def _device(ln):
 def _kernels(ln):
     assert ln["interpret"] is True      # no chip: never "compiled"
     assert set(ln["kernels"]) == {
-        "flash_causal", "flash_masked", "layer_norm", "bias_gelu",
-        "softmax_xent", "paged_decode", "paged_decode_walk"}
+        "flash_causal", "flash_masked", "layer_norm", "softmax_xent",
+        "paged_decode", "paged_decode_walk"}
     assert all(k["fwd_err"] <= k["tol"] for k in ln["kernels"].values())
 
 
@@ -261,12 +261,6 @@ def _dispatch_paged():
         PagedKV(pool, pool, rows, pos, jnp.asarray([True, True]), 16))[0]
 
 
-def _dispatch_bias_gelu():
-    from paddle_tpu.ops import fused
-
-    return fused.bias_gelu(paddle.randn([8, 16]), paddle.zeros([16]))
-
-
 DISPATCH = {
     # counter label: (kernel module, function in it, a call that reaches it)
     "layer_norm": ("layer_norm", "layer_norm", _dispatch_layer_norm),
@@ -275,7 +269,6 @@ DISPATCH = {
                         _dispatch_flash),
     "paged_attention": ("paged_attention", "paged_decode_attention",
                         _dispatch_paged),
-    "bias_gelu": ("bias_gelu", "bias_gelu", _dispatch_bias_gelu),
 }
 
 

@@ -20,10 +20,9 @@ import jax.numpy as jnp
 import pytest
 
 import paddle_tpu  # noqa: F401 - the package decides the process's x64 mode
-from paddle_tpu.ops.pallas import (bias_gelu as bg, flash_attention as fa,
-                                   layer_norm as ln, moe_gmm as mg,
-                                   paged_attention as pa, softmax_xent as sx,
-                                   ssm)
+from paddle_tpu.ops.pallas import (flash_attention as fa, layer_norm as ln,
+                                   moe_gmm as mg, paged_attention as pa,
+                                   softmax_xent as sx, ssm)
 
 B, S, NH, HD, H, FFN, V = 8, 1024, 12, 64, 768, 3072, 50304
 SLOTS, PAGE = 16, 16
@@ -65,7 +64,6 @@ PAD_MASK = ((FLASH_B, 1, 1, S), jnp.bool_)
 CHAT_BUCKETS, CHAT_NH, CHAT_HD = (256, 512, 768), 16, 128
 LN_F32 = [((B, S, H), F32), ((H,), F32), ((H,), F32)]      # fit, autocast
 LN_BF16 = [((SLOTS, 1, H), BF16), ((H,), BF16), ((H,), BF16)]  # bf16 decode
-GELU_ARGS = [((B, S, FFN), BF16), ((FFN,), BF16)]
 XENT_BF16 = [((B * S, V), BF16), ((B * S,), I32)]
 # the loss as gpt2-124m.train calls it: 16 x 1024 rows a step
 XENT_N = 16 * S
@@ -97,10 +95,6 @@ def _flash(causal):
 
 def _ln(x, w, b):
     return ln.layer_norm(x, w, b, interpret=False)
-
-
-def _gelu(x, b):
-    return bg.bias_gelu(x, b, interpret=False)
 
 
 def _xent(z, lab):
@@ -217,8 +211,6 @@ CASES = {
     "layer_norm_f32_fwd": (_ln, LN_F32),
     "layer_norm_f32_bwd": (_bwd(_ln, 3), LN_F32),
     "layer_norm_bf16_decode_rows": (_ln, LN_BF16),
-    "bias_gelu_fwd": (_gelu, GELU_ARGS),
-    "bias_gelu_bwd": (_bwd(_gelu, 2), GELU_ARGS),
     "softmax_xent_bf16_fwd": (_xent, XENT_BF16),
     "softmax_xent_bf16_bwd": (_bwd(_xent, 1), XENT_BF16),
     "softmax_xent_train_fwd": (_xent, XENT_TRAIN),
@@ -257,6 +249,32 @@ def _compiled_text(v5e, name):
     return _compiled(v5e, name).as_text()
 
 
+# opcodes that only name a buffer another instruction produced
+NAMES_A_BUFFER = ("parameter", "tuple", "get-tuple-element", "bitcast")
+
+
+def _outputs_holding(text, *shapes):
+    """(opcode, line) of every instruction of the HLO `text` whose result
+    (or an element of whose tuple result) is an array of one of `shapes`,
+    each given as its dimensions in brackets."""
+    import re
+
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
+        if m and any(s in m.group(1) for s in shapes):
+            found.append((m.group(2), line.strip()))
+    return found
+
+
+def _fusion_body(text, fusion_line):
+    """The text of the computation a fusion instruction calls."""
+    import re
+
+    called = re.search(r"calls=(%[\w.-]+)", fusion_line).group(1)
+    return text.split(f"\n{called} (", 1)[1].split("\n}", 1)[0]
+
+
 @pytest.mark.kernels
 @pytest.mark.parametrize("name", list(CASES))
 def test_kernel_compiles_for_v5e(v5e, name):
@@ -274,8 +292,6 @@ KERNEL_NAMES = {
     "paddle_softmax_xent_fwd": "softmax_xent_bf16_fwd",
     "paddle_softmax_xent_bwd": "softmax_xent_bf16_bwd",
     "paddle_layer_norm_fwd": "layer_norm_f32_fwd",
-    "paddle_bias_gelu_fwd": "bias_gelu_fwd",
-    "paddle_bias_gelu_bwd": "bias_gelu_bwd",
     "paddle_paged_decode_fwd": "paged_decode",
     "paddle_paged_gqa_decode_fwd": "paged_gqa_decode_window",
     "paddle_moe_gmm": "moe_gmm_block_step_512",
@@ -472,6 +488,49 @@ def test_paged_call_walks_pages_no_copy_can_cut_out_by_the_grid(v5e):
     assert call.count(f"bf16[{pool}]") == 2, call
 
 
+# -- the FFN's bias-GELU rides in its products' fusions ------------------------
+# `fused.linear_bias_gelu` writes gelu(x @ w1 + b1) as jnp under a custom_vjp
+# that keeps (pre-activation, bias): the chip's compiler puts the forward's
+# arithmetic into fc1's fusion and the backward's, with db's row sum, into
+# the fusion of the product that makes its cotangent.  As a Pallas pass of
+# its own it was 13% of the train cell's step (PERF.md section 6, PR 42).
+@pytest.mark.kernels
+def test_ffn_bias_gelu_rides_in_the_products_fusions(v5e):
+    """An FFN layer forward and backward at the train cell's 16384 rows,
+    768 -> 3072 -> 768 (float32 weights under bf16 autocast): no
+    `paddle_bias_gelu` custom call, no Mosaic call at all, and every
+    instruction of the entry computation whose result holds a
+    [16384, 3072] array is a fusion that contains a convolution: the
+    activation crosses HBM only as a product's result, never in a pass of
+    its own."""
+    from paddle_tpu import amp
+    from paddle_tpu.ops import fused
+
+    rows = 16 * S
+
+    def ffn(x, w1, b1, w2, b2, ct):
+        with amp.auto_cast(dtype="bfloat16"):
+            h = fused.unwrap(fused.linear_bias_gelu(x, w1, b1))
+            y = jnp.matmul(*amp.white_cast(h, w2)) + b2.astype(BF16)
+        return (y.astype(F32) * ct.astype(F32)).sum()
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in [
+        ((rows, H), BF16), ((H, FFN), F32), ((FFN,), F32), ((FFN, H), F32),
+        ((H,), F32), ((rows, H), BF16)]]
+    text = jax.jit(jax.grad(ffn, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*shapes).compile().as_text()
+    assert "paddle_bias_gelu" not in text
+    assert "tpu_custom_call" not in text
+    wide = [(op, line) for op, line in _outputs_holding(
+        text[text.index("\nENTRY"):], f"[{rows},{FFN}]")
+        if op not in NAMES_A_BUFFER]
+    assert len(wide) >= 2, wide              # forward and backward
+    for op, line in wide:
+        assert op == "fusion", line[:200]
+        assert " convolution(" in _fusion_body(text, line), line[:200]
+
+
 @pytest.mark.kernels
 def test_kernels_compose_with_a_2x2_mesh(v5e):
     """GSPMD cannot partition a Mosaic kernel (the lowering raises
@@ -563,25 +622,16 @@ def _pool_shaped_outputs(text, pool):
     """(opcode, line) of every instruction of the optimised HLO whose
     result (or an element of whose tuple result) is a whole pool or one
     layer's plane of it."""
-    import re
-
     dims = ",".join(map(str, pool))
-    shapes = (f"[{dims}]", f"[{dims.split(',', 1)[1]}]")
-    found = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
-        if m and any(s in m.group(1) for s in shapes):
-            found.append((m.group(2), line.strip()))
-    return found
+    return _outputs_holding(text, f"[{dims}]", f"[{dims.split(',', 1)[1]}]")
 
 
 def _fusion_root_opcode(text, fusion_line):
     """The opcode of the root of the computation a fusion calls."""
     import re
 
-    called = re.search(r"calls=(%[\w.-]+)", fusion_line).group(1)
-    body = text.split(f"\n{called} (", 1)[1].split("\n}", 1)[0]
-    return re.search(r"\n\s*ROOT %\S+ = .*? ([\w-]+)\(", body).group(1)
+    return re.search(r"\n\s*ROOT %\S+ = .*? ([\w-]+)\(",
+                     _fusion_body(text, fusion_line)).group(1)
 
 
 def _assert_pools_stay_in_place(compiled, pool, itemsize):
@@ -592,9 +642,8 @@ def _assert_pools_stay_in_place(compiled, pool, itemsize):
     # scatters' fusions) and go out through the scatters; beside what only
     # names a buffer, nothing else may produce a pool or a plane: no
     # slice, copy, dynamic-update-slice, concatenate or broadcast of one
-    names_a_buffer = ("parameter", "tuple", "get-tuple-element", "bitcast")
     extra = [line for op, line in outputs
-             if op not in names_a_buffer + ("scatter", "fusion")]
+             if op not in NAMES_A_BUFFER + ("scatter", "fusion")]
     assert not extra, [line[:200] for line in extra]
     for line in (ln for op, ln in outputs if op == "fusion"):
         assert _fusion_root_opcode(text, line) == "scatter", line[:200]

@@ -1,7 +1,9 @@
 #!/usr/bin/env bash
 # Kernels smoke: proves the Pallas hot path (masked flash attention,
-# paged decode attention, softmax-xent, bias-gelu) in CPU interpret
-# mode end to end:
+# paged decode attention, softmax-xent) in CPU interpret mode end to
+# end, and the fused bias-gelu beside it (no kernel since PR 42: a jnp
+# composite that the products' fusions hold, checked against
+# jax.nn.gelu like the kernels):
 #
 #   1. bench.py --config kernels — per-kernel fwd/bwd parity vs XLA
 #      (references cast to the kernel compute dtype, per-kernel
