@@ -19,8 +19,12 @@ Design (flash attention v2 style):
   (BH, blocks owned, resident spans), the last axis 1 unless the walked
   sequence outgrows VMEM; accumulators live in VMEM scratch across it.
 - forward: a step owns block_q queries and walks the keys; the running
-  max `m` and normalizer `l` are [block_q, 1] loop carries, the output
-  accumulator is scratch; output + logsumexp written once a step.
+  max `m` and normalizer `l` are [block_q, 128] scratch beside the output
+  accumulator, read and written a part at a time (`m` the same in every
+  lane, `l` as lane-by-lane partial sums that are added up once a step:
+  a tile pays one cross-lane reduction, the max; as loop carries their
+  128 vregs were spilled and filled around every trip); output +
+  logsumexp written once a step.
 - backward: two kernels recomputing p = exp(s - lse) per tile, FLOPs
   ~ 2.5x fwd.  dq owns block_q queries and walks the keys; dkv owns
   block_k keys and walks the queries, so the lane-broadcast lse/delta
@@ -30,6 +34,28 @@ Design (flash attention v2 style):
   span wholly above it is not fetched (its index map repeats the last
   span that has work).  Only sub-blocks the diagonal crosses build the
   iota mask; those wholly under it run the unmasked body.
+- the tile on the diagonal: where the call is causal over equal sequences
+  in square tiles (`_strip_rows`; the train step's 1024 x 1024 in tiles
+  of 512 has two such tiles of three a head), the one masked tile of an
+  owned block starts on the diagonal, and its body walks it in the same
+  loop trip as static strips of 128 owned rows, each against the part of
+  the walked tile it can see: a strip of queries the keys up to its own
+  end, a strip of keys the queries from its own start on.  A tile of 512
+  computes 10 of its 16 squares of 128 and masks the four on the
+  diagonal, by one `row >= col` of the square.  A strip keeps its own
+  rows of the statistics and accumulators, so there is no further pass,
+  loop trip or grid step.  The body writes every strip's score products
+  before any strip's exponentials: the compiler schedules in the
+  source's order, and written a strip at a time the strips' chains
+  (product, reduction, exponentials, product) do not overlap and the
+  saved work buys nothing.  Where the whole walked axis is resident that
+  one trip is no loop but straight-line code (`_walk`), so it runs under
+  the end of the clear loop and the step's end.  Every other call
+  computes the tile whole.
+- the scale: a power of two (`_folds_scale`: 1/8 at a head of 64) is
+  folded into the owned operand once a grid step and, in the backward,
+  applied to the float32 accumulators once at the end, exactly; any other
+  scale multiplies every score tile.
 - mask: an additive bias broadcastable to [B, H, S_q, S_k] (bool masks are
   converted to 0 / -1e30 by the wrapper) streamed tile-by-tile into the
   score matmul of all three kernels — the padding / attention-mask path of
@@ -51,6 +77,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +101,8 @@ _TILE_SIDES = (512, 256, 128)
 _VMEM_BUDGET = 12 * 1024 * 1024
 # bytes a row of a lane-broadcast float32 row vector takes
 _ROW_BYTES = 128 * 4
+# owned rows of a strip of a diagonal tile (`_strip_rows`)
+_STRIP = 128
 
 
 # ---------------------------------------------------------------------------
@@ -85,7 +114,11 @@ def _vmem_bytes(own, tile, span, d, has_bias, itemsize):
     other resident, walking them `tile` rows at a time: per row two
     [*, d] operands and two row vectors (double-buffered, as are the
     outputs and the bias block), the float32 accumulators and statistics,
-    and six live float32 score tiles."""
+    and six live float32 score tiles.  The six are the whole-tile body's
+    (scores, mask, p, dp, ds and a cast); a tile walked by strips
+    (`_strip_rows`) holds every strip's two score products at once, five
+    eighths of a tile each, and the rest a strip at a time, the widest a
+    quarter of a tile, so the bound covers it."""
     per_row = 2 * d * itemsize + 2 * _ROW_BYTES
     pipelined = 2 * (2 * own + span) * per_row
     if has_bias:
@@ -117,6 +150,30 @@ def pick_blocks(s_q, s_k, d, has_bias, itemsize):
                key=lambda t: (t[0] * t[1], t[1]))
 
 
+def _strip_rows(causal, has_bias, s_own, s_walked, own, tile):
+    """Rows of the strips by which the tile on the diagonal is walked, 0
+    where it is computed whole.  A causal call of equal sequences in
+    square tiles has one masked tile an owned block, the one that starts
+    on the diagonal (q0 == k0), and what each strip of its owned rows can
+    see of it is static: strip r of the queries sees the first (r + 1)
+    strips of keys, strip r of the keys is seen by the queries from strip
+    r on.  Any other call (unequal sequences or tile sides, a bias tile
+    to slice, a tile that is no whole number of strips) keeps the
+    whole-tile body."""
+    if (causal and not has_bias and s_own == s_walked and own == tile
+            and own % _STRIP == 0):
+        return _STRIP
+    return 0
+
+
+def _folds_scale(sm_scale):
+    """Whether `sm_scale` leaves the score tile: a power of two (1/8 at a
+    head of 64) scales the owned [block, d] operand once a grid step and
+    the backward's float32 accumulators once at the end, exactly; any
+    other scale multiplies the scores as before."""
+    return sm_scale > 0 and math.frexp(sm_scale)[0] == 0.5
+
+
 def _span(s, own, tile, d, has_bias, itemsize):
     """Rows of the walked axis (length `s`, walked `tile` at a time) that a
     grid step owning `own` rows of the other axis keeps resident: the
@@ -141,11 +198,14 @@ _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
 
 
-def _rows(ref, t, size):
-    """Rows [t*size, (t+1)*size) of the block in `ref` ([1, rows, n])."""
+def _rows(ref, t, size, lo=0, hi=None):
+    """Rows [lo, hi) of sub-block t, `size` rows, of the block in `ref`
+    ([1, rows, n]); the whole sub-block by default."""
+    hi = size if hi is None else hi
     if ref.shape[1] == size:
-        return ref[0]
-    return ref[0, pl.ds(pl.multiple_of(t * size, size), size), :]
+        return ref[0, lo:hi]
+    start = pl.multiple_of(t * size + lo, math.gcd(size, lo))
+    return ref[0, pl.ds(start, hi - lo), :]
 
 
 def _lanes(x, n):
@@ -166,16 +226,47 @@ def _cols(ref, t, size):
     return ref[0, :, pl.ds(pl.multiple_of(t * size, size), size)]
 
 
-def _scores(q, k, bias, q0, k0, masked, sm_scale, keys_first=False):
+def _times(x, c):
+    """x * c, and x itself, no multiply traced, where c is 1."""
+    return x if c == 1.0 else x * c
+
+
+def _lane_sums(p):
+    """[rows, 128] partial sums of p [rows, n], lane by lane, whose lanes
+    add up to the rows' sums: vector adds alone, no cross-lane reduction.
+    A p that is no whole vregs wide (a sequence shorter than 128) gives
+    its row sums in lane 0."""
+    n = p.shape[1]
+    if n % 128:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (p.shape[0], 128), 1)
+        return jnp.where(lane == 0, jnp.sum(p, axis=-1, keepdims=True), 0.0)
+    return sum(p[:, c:c + 128] for c in range(0, n, 128))
+
+
+def _scores(q, k, bias, q0, k0, masked, sm_scale, keys_first=False,
+            strip=0):
     """The score tile [queries, keys], or transposed if `keys_first`:
     scale, additive mask ([queries, keys] either way), and on a tile the
     diagonal crosses the causal mask (q0, k0: the sequence positions of
-    the tile's first query and key)."""
-    s = (_dot(k, q, _NT) if keys_first else _dot(q, k, _NT)) * sm_scale
+    the tile's first query and key).  With `strip`, the tile is a strip
+    of a tile that starts on the diagonal: its `strip` owned rows against
+    what they see of the walked axis, of which only the `strip` columns
+    on the diagonal (the last of the keys, the first of the queries) are
+    masked, by their place in that square alone."""
+    s = _times(_dot(k, q, _NT) if keys_first else _dot(q, k, _NT), sm_scale)
     if bias is not None:
         bias = bias.astype(jnp.float32)
         s = s + (bias.T if keys_first else bias)
-    if masked:
+    if masked and strip:
+        at = 0 if keys_first else s.shape[1] - strip
+        square = (strip, strip)
+        seen = (jax.lax.broadcasted_iota(jnp.int32, square, int(keys_first))
+                >= jax.lax.broadcasted_iota(jnp.int32, square,
+                                            int(not keys_first)))
+        parts = [s[:, :at], jnp.where(seen, s[:, at:at + strip], _NEG_INF),
+                 s[:, at + strip:]]
+        s = jnp.concatenate([x for x in parts if x.shape[1]], axis=1)
+    elif masked:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                               int(keys_first))
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
@@ -184,13 +275,33 @@ def _scores(q, k, bias, q0, k0, masked, sm_scale, keys_first=False):
     return s
 
 
-def _walk(tile, carry, ranges):
-    """Run `tile(t, carry, masked)` over the sub-blocks t of a resident
-    span: `ranges` lists (first, end, masked) in the order to walk them."""
+def _parts(strip, block, from_diagonal):
+    """How the body of a score tile walks it: [(owned rows, (lo, hi) of
+    the walked tile's rows)].  Whole by default; a tile that starts on
+    the diagonal by strips of `strip` owned rows, each against the part
+    of the walked tile it sees: the keys up to its own end for a strip of
+    queries, the queries from its own start (`from_diagonal`) for a strip
+    of keys."""
+    if not strip:
+        return [(slice(None), (0, block))]
+    return [(slice(r, r + strip),
+             (r, block) if from_diagonal else (0, r + strip))
+            for r in range(0, block, strip)]
+
+
+def _walk(tile, ranges, once=False):
+    """Run `tile(t, masked)` over the sub-blocks t of a resident span:
+    `ranges` lists (first, end, masked) in the order to walk them.
+    `once`: the masked range is known to hold exactly one sub-block
+    (`_strip_rows` with the whole walked axis resident), which then is no
+    loop but straight-line code between its neighbours, so the scheduler
+    runs it under the end of the loop before it and the step's end."""
     for lo, hi, masked in ranges:
-        carry = jax.lax.fori_loop(
-            lo, hi, lambda t, c, masked=masked: tile(t, c, masked), carry)
-    return carry
+        if masked and once:
+            tile(lo, True)
+        else:
+            jax.lax.fori_loop(
+                lo, hi, lambda t, _, masked=masked: tile(t, masked), None)
 
 
 def _k_ranges(causal, q0, block_q, k0, span, block_k):
@@ -230,11 +341,14 @@ def _split(refs, n_in, has_bias):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
+def _fwd_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k, strip,
+                once, fold):
     (q_ref, k_ref, v_ref), bias_ref, \
         (o_ref, lse_ref, acc_ref, m_ref, l_ref) = _split(refs, 3, has_bias)
     span = k_ref.shape[1]
     q0, k0 = pl.program_id(1) * block_q, pl.program_id(2) * span
+    own_scale, scale = (sm_scale, 1.0) if fold else (1.0, sm_scale)
+    q = _times(q_ref[0], own_scale)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -242,31 +356,40 @@ def _fwd_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def tile(t, carry, masked):
-        m_prev, l_prev = carry      # [bq, 128] float32, every lane the same
+    # m_ref, l_ref [bq, 128] float32: every lane of m the same, l's lanes
+    # partial sums that `_finish` adds up (alpha is the same in every lane)
+    def tile(t, masked):
         bias = _cols(bias_ref, t, block_k) if has_bias else None
-        s = _scores(q_ref[0], _rows(k_ref, t, block_k), bias,
-                    q0, k0 + t * block_k, masked, sm_scale)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - _lanes(m_new, block_k))    # [bq, bk]
-        alpha = jnp.exp(m_prev - m_new)
-        v = _rows(v_ref, t, block_k)
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) + _dot(
-            p.astype(v.dtype), v, _NN)
-        return m_new, alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        parts = _parts(masked and strip, block_k, False)
+        # every part's scores before any part's softmax: the compiler
+        # schedules in this order, and the products then run under the
+        # exponentials of the part before
+        ss = [_scores(q[rows], _rows(k_ref, t, block_k, lo, hi), bias,
+                      q0, k0 + t * block_k, masked, scale, strip=strip)
+              for rows, (lo, hi) in parts]
+        for (rows, (lo, hi)), s in zip(parts, ss):
+            m_prev = m_ref[rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, hi - lo))
+            alpha = jnp.exp(m_prev - m_new)
+            v = _rows(v_ref, t, block_k, lo, hi)
+            acc_ref[rows, :] = (
+                acc_ref[rows, :] * _lanes(alpha, acc_ref.shape[1])
+                + _dot(p.astype(v.dtype), v, _NN))
+            m_ref[rows, :] = m_new
+            l_ref[rows, :] = alpha * l_ref[rows, :] + _lane_sums(p)
 
-    m, l = _walk(tile, (m_ref[...], l_ref[...]),
-                 _k_ranges(causal, q0, block_q, k0, span, block_k))
-    m_ref[...] = m
-    l_ref[...] = l
+    _walk(tile, _k_ranges(causal, q0, block_q, k0, span, block_k), once)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
-        l_safe = jnp.where(l == 0.0, 1.0, l)
+        total = jnp.broadcast_to(       # of `_lane_sums`' partial sums
+            jnp.sum(l_ref[...], axis=-1, keepdims=True), l_ref.shape)
+        l_safe = jnp.where(total == 0.0, 1.0, total)
         o_ref[0, ...] = (acc_ref[...] / _lanes(l_safe, acc_ref.shape[1])
                          ).astype(o_ref.dtype)
         # lse broadcast over a 128-lane minor dim (TPU tiling-friendly)
-        lse_ref[0, ...] = m + jnp.log(l_safe)
+        lse_ref[0, ...] = m_ref[...] + jnp.log(l_safe)
 
 
 def _bias_group(bh: int, bias) -> int:
@@ -280,14 +403,20 @@ def _layout(name, bh, s_own, own, s_walked, tile, d, has_bias, itemsize,
     """Grid and block specs of a kernel whose grid step (b, i, j) owns
     block i (`own` rows) of one sequence axis and walks resident span j of
     the other, `tile` rows at a time.  Returns (grid, span, the index map's
-    span for (i, j), spec(width, walked)).  Causal steps wholly above the
+    span for (i, j), spec(width, walked), how the kernel walks the tile on
+    the diagonal: `strip` by `_strip_rows`, `once` for `_walk`).  Causal
+    steps wholly above the
     diagonal repeat the span of the nearest step that has work (the last
     one, or the first when the walk starts from the diagonal as the keys'
     does), so nothing is fetched for them."""
     span = _span(s_walked, own, tile, d, has_bias, itemsize)
     grid = (bh, s_own // own, s_walked // span)
+    strip = _strip_rows(causal, has_bias, s_own, s_walked, own, tile)
+    diagonal = dict(strip=strip, once=bool(strip) and grid[2] == 1)
     _log.debug("%s: owns %d of %d rows, walks %d by %d in spans of %d, "
-               "grid %s", name, own, s_own, s_walked, tile, span, grid)
+               "grid %s, the tile on the diagonal %s", name, own, s_own,
+               s_walked, tile, span, grid,
+               f"by strips of {strip} owned rows" if strip else "whole")
     if not causal:
         def walked_j(i, j):
             return j
@@ -304,7 +433,7 @@ def _layout(name, bh, s_own, own, s_walked, tile, d, has_bias, itemsize,
                 (1, span, width), _im(lambda b, i, j: (b, walked_j(i, j), 0)))
         return pl.BlockSpec((1, own, width), _im(lambda b, i, j: (b, i, 0)))
 
-    return grid, span, walked_j, spec
+    return grid, span, walked_j, spec, diagonal
 
 
 _PARAMS = pltpu.CompilerParams(
@@ -321,7 +450,7 @@ _PARAMS = pltpu.CompilerParams(
 def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
     bh, s_q, d = q.shape
     has_bias = bias is not None
-    grid, span, kj, spec = _layout(
+    grid, span, kj, spec, diagonal = _layout(
         "paddle_flash_fwd", bh, s_q, block_q, k.shape[1], block_k, d,
         has_bias, q.dtype.itemsize, causal, False)
     in_specs = [spec(d), spec(d, True), spec(d, True)]
@@ -335,7 +464,8 @@ def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                           has_bias=has_bias, block_q=block_q,
-                          block_k=block_k),
+                          block_k=block_k, fold=_folds_scale(sm_scale),
+                          **diagonal),
         name="paddle_flash_fwd",
         grid=grid,
         in_specs=in_specs,
@@ -360,39 +490,53 @@ def _fwd_call(q, k, v, bias, causal, sm_scale, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _dq_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
+def _dq_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k, strip,
+               once, fold):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
         (dq_ref, acc_ref) = _split(refs, 6, has_bias)
     span = k_ref.shape[1]
     q0, k0 = pl.program_id(1) * block_q, pl.program_id(2) * span
+    own_scale, scale = (sm_scale, 1.0) if fold else (1.0, sm_scale)
+    q = _times(q_ref[0], own_scale)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(t, carry, masked):
+    def tile(t, masked):
         bias = _cols(bias_ref, t, block_k) if has_bias else None
-        k = _rows(k_ref, t, block_k)
-        s = _scores(q_ref[0], k, bias, q0, k0 + t * block_k, masked,
-                    sm_scale)
-        p = jnp.exp(s - _lanes(lse_ref[0], block_k))   # [bq, bk] float32
-        dp = _dot(do_ref[0], _rows(v_ref, t, block_k), _NT)
-        ds = p * (dp - _lanes(delta_ref[0], block_k)) * sm_scale
-        acc_ref[...] += _dot(ds.astype(k.dtype), k, _NN)
-        return carry
+        parts = _parts(masked and strip, block_k, False)
+        # both products of every part before any part's exponentials (the
+        # order the compiler schedules in)
+        dots = []
+        for rows, (lo, hi) in parts:
+            k = _rows(k_ref, t, block_k, lo, hi)
+            dots.append((
+                k, _scores(q[rows], k, bias, q0, k0 + t * block_k, masked,
+                           scale, strip=strip),
+                _dot(do_ref[0, rows, :], _rows(v_ref, t, block_k, lo, hi),
+                     _NT)))
+        for (rows, (lo, hi)), (k, s, dp) in zip(parts, dots):
+            p = jnp.exp(s - _lanes(lse_ref[0, rows, :], hi - lo))
+            ds = _times(p * (dp - _lanes(delta_ref[0, rows, :], hi - lo)),
+                        scale)
+            acc_ref[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _walk(tile, None, _k_ranges(causal, q0, block_q, k0, span, block_k))
+    _walk(tile, _k_ranges(causal, q0, block_q, k0, span, block_k), once)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
-        dq_ref[0, ...] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, ...] = _times(acc_ref[...], own_scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
+def _dkv_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k, strip,
+                once, fold):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
         (dk_ref, dv_ref, dk_acc, dv_acc) = _split(refs, 6, has_bias)
     span = q_ref.shape[1]
     k0, q0 = pl.program_id(1) * block_k, pl.program_id(2) * span
+    own_scale, scale = (sm_scale, 1.0) if fold else (1.0, sm_scale)
+    k = _times(k_ref[0], own_scale)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -401,24 +545,31 @@ def _dkv_kernel(*refs, sm_scale, causal, has_bias, block_q, block_k):
 
     # scores transposed, [keys, queries], so that no product contracts
     # over rows; the queries' row vectors become rows of lanes
-    def tile(t, carry, masked):
-        q = _rows(q_ref, t, block_q)
-        do = _rows(do_ref, t, block_q)
+    def tile(t, masked):
         bias = _rows(bias_ref, t, block_q) if has_bias else None
-        s = _scores(q, k_ref[0], bias, q0 + t * block_q, k0, masked,
-                    sm_scale, keys_first=True)         # [bk, bq]
-        p = jnp.exp(s - _rows(lse_ref, t, block_q).T[:1])
-        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
-        dp = _dot(v_ref[0], do, _NT)
-        ds = p * (dp - _rows(delta_ref, t, block_q).T[:1]) * sm_scale
-        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
-        return carry
+        lse = _rows(lse_ref, t, block_q).T[:1]
+        delta = _rows(delta_ref, t, block_q).T[:1]
+        parts = _parts(masked and strip, block_q, True)
+        dots = []
+        for rows, (lo, hi) in parts:
+            q = _rows(q_ref, t, block_q, lo, hi)
+            do = _rows(do_ref, t, block_q, lo, hi)
+            dots.append((
+                q, do, _scores(q, k[rows], bias, q0 + t * block_q, k0,
+                               masked, scale, keys_first=True,
+                               strip=strip),                  # [bk, bq]
+                _dot(v_ref[0, rows, :], do, _NT)))
+        for (rows, (lo, hi)), (q, do, s, dp) in zip(parts, dots):
+            p = jnp.exp(s - lse[:, lo:hi])
+            dv_acc[rows, :] += _dot(p.astype(do.dtype), do, _NN)
+            ds = _times(p * (dp - delta[:, lo:hi]), scale)
+            dk_acc[rows, :] += _dot(ds.astype(q.dtype), q, _NN)
 
-    _walk(tile, None, _q_ranges(causal, k0, block_k, q0, span, block_q))
+    _walk(tile, _q_ranges(causal, k0, block_k, q0, span, block_q), once)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0, ...] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0, ...] = _times(dk_acc[...], own_scale).astype(dk_ref.dtype)
         dv_ref[0, ...] = dv_acc[...].astype(dv_ref.dtype)
 
 
@@ -440,10 +591,11 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
     operands = [q, k, v, do, lse_r, delta_r] + ([bias] if has_bias else [])
     g = _bias_group(bh, bias) if has_bias else 1
     kwargs = dict(sm_scale=sm_scale, causal=causal, has_bias=has_bias,
-                  block_q=block_q, block_k=block_k)
+                  block_q=block_q, block_k=block_k,
+                  fold=_folds_scale(sm_scale))
 
     # dq: a step owns block_q queries and walks the keys
-    grid, span, kj, spec = _layout(
+    grid, span, kj, spec, diagonal = _layout(
         "paddle_flash_dq", bh, s_q, block_q, s_k, block_k, d, has_bias,
         itemsize, causal, False)
     in_specs = [spec(d), spec(d, True), spec(d, True), spec(d), spec(128),
@@ -452,7 +604,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
         in_specs.append(pl.BlockSpec(
             (1, block_q, span), _im(lambda b, i, j: (b // g, i, kj(i, j)))))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kwargs),
+        functools.partial(_dq_kernel, **diagonal, **kwargs),
         name="paddle_flash_dq",
         grid=grid,
         in_specs=in_specs,
@@ -465,7 +617,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
 
     # dk, dv: a step owns block_k keys and walks the queries, so the
     # lane-broadcast rows are fetched once a key block, not once a tile
-    grid, span, qj, spec = _layout(
+    grid, span, qj, spec, diagonal = _layout(
         "paddle_flash_dkv", bh, s_k, block_k, s_q, block_q, d, has_bias,
         itemsize, causal, True)
     in_specs = [spec(d, True), spec(d), spec(d), spec(d, True),
@@ -474,7 +626,7 @@ def _bwd_call(q, k, v, o, lse, do, bias, causal, sm_scale, block_q, block_k,
         in_specs.append(pl.BlockSpec(
             (1, span, block_k), _im(lambda b, i, j: (b // g, qj(i, j), i))))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kwargs),
+        functools.partial(_dkv_kernel, **diagonal, **kwargs),
         name="paddle_flash_dkv",
         grid=grid,
         in_specs=in_specs,

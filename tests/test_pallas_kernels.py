@@ -182,9 +182,10 @@ class TestFusedLinearCrossEntropy:
 # PR 15 kernel suite (tools/kernels_smoke.sh): masked flash + VJP, paged
 # decode, softmax-xent, bias-gelu, GSPMD composition, dispatch telemetry
 # ===========================================================================
-def _attn_ref_masked(q, k, v, causal=False, mask=None):
+def _attn_ref_masked(q, k, v, causal=False, mask=None, sm_scale=None):
     qh, kh, vh = [jnp.swapaxes(x, 1, 2) for x in (q, k, v)]
-    s = jnp.einsum("bhsd,bhtd->bhst", qh, kh) / np.sqrt(q.shape[-1])
+    s = jnp.einsum("bhsd,bhtd->bhst", qh, kh) * (
+        sm_scale or 1 / np.sqrt(q.shape[-1]))
     if causal:
         m = jnp.tril(jnp.ones(s.shape[-2:], bool))
         s = jnp.where(m, s, -1e30)
@@ -237,7 +238,10 @@ def test_flash_attention_masked_fwd_bwd(causal, kind):
 
 
 # the shapes `pick_blocks` tells apart: (s_q, s_k, d, causal, padding mask,
-# explicit block_q = block_k or None)
+# explicit block_q = block_k or None[, sm_scale]).  The tile on the diagonal
+# is walked by strips of 128 owned rows (`_strip_rows`) where the call is
+# causal over equal sequences in square tiles of whole strips and has no
+# mask to slice; every other call computes it whole.
 FLASH_SHAPES = {
     "s1024_d64_causal": (1024, 1024, 64, True, False, None),
     "s768_d128_causal_512_does_not_divide": (768, 768, 128, True, False,
@@ -249,6 +253,23 @@ FLASH_SHAPES = {
     "sq512_sk256_causal": (512, 256, 64, True, False, None),
     "s512_d64_causal_blocks_of_128": (512, 512, 64, True, False, 128),
     "s384_d64_causal_mask_blocks_of_128": (384, 384, 64, True, True, 128),
+    # by strips: one, two and four tiles a side, of one, two and four strips
+    "s256_d64_causal_one_tile_of_two_strips": (256, 256, 64, True, False,
+                                               None),
+    "s512_d128_causal_one_tile_of_four_strips": (512, 512, 128, True, False,
+                                                 None),
+    "s512_d64_causal_two_tiles_a_side": (512, 512, 64, True, False, 256),
+    "s1024_d128_causal_four_tiles_a_side": (1024, 1024, 128, True, False,
+                                            256),
+    "s384_d128_causal_three_tiles_of_one_strip": (384, 384, 128, True, False,
+                                                  None),
+    "s256_d64_causal_odd_scale_stays_on_the_scores": (256, 256, 64, True,
+                                                      False, None, 0.2),
+    # whole, though causal in square tiles: unequal sequences, a mask
+    "sq256_sk512_causal_square_tiles": (256, 512, 64, True, False, 256),
+    "sq512_sk256_causal_square_tiles": (512, 256, 64, True, False, 256),
+    "s512_d64_causal_mask_square_tiles": (512, 512, 64, True, True, 256),
+    "s512_d64_full_square_tiles": (512, 512, 64, False, False, 256),
 }
 
 
@@ -257,7 +278,8 @@ FLASH_SHAPES = {
 def test_flash_attention_parity_over_block_choices(shape):
     """Forward, dQ, dK and dV against the plain float32 reference at each
     kind of shape the block function separates, one head a call."""
-    s_q, s_k, d, causal, padded, block = FLASH_SHAPES[shape]
+    s_q, s_k, d, causal, padded, block, *scale = FLASH_SHAPES[shape]
+    sm_scale = scale[0] if scale else None
     rs = np.random.RandomState(11)
     q = jnp.asarray(rs.randn(1, s_q, 1, d), jnp.float32)
     k, v = [jnp.asarray(rs.randn(1, s_k, 1, d), jnp.float32)
@@ -270,10 +292,10 @@ def test_flash_attention_parity_over_block_choices(shape):
 
     def kernel(*a):
         return flash_attention(*a, causal=causal, mask=mask, block_q=block,
-                               block_k=block)
+                               block_k=block, sm_scale=sm_scale)
 
     def ref(*a):
-        return _attn_ref_masked(*a, causal, mask)
+        return _attn_ref_masked(*a, causal, mask, sm_scale)
 
     np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
                                np.asarray(ref(q, k, v)),
@@ -284,6 +306,138 @@ def test_flash_attention_parity_over_block_choices(shape):
     for name, a, bb in zip(("dq", "dk", "dv"), g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                    rtol=1e-3, atol=1e-4, err_msg=name)
+
+
+def _assert_matches_reference(out, grads, ref, w, q, k, v):
+    """The kernel's weighted sum `out` and its (dq, dk, dv) against the
+    float32 reference's, at the tolerances of the parity tests above."""
+    ref_out, ref_grads = jax.value_and_grad(
+        lambda *a: (ref(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(out), float(ref_out), rtol=1e-4)
+    for name, a, bb in zip(("dq", "dk", "dv"), grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
+                                   rtol=1e-3, atol=1e-4, err_msg=name)
+
+
+# which body a call takes and whether its scale leaves the score tile, both
+# static facts of the call: (s_q, s_k, heads, d, causal, padding mask,
+# block_q, block_k, sm_scale or None) -> (rows of a strip or 0, folded).
+# Heads of 3 and 5: shapes no other test of this file traces, so that the
+# jitted calls are traced here and the kernels' arguments seen.
+FLASH_CHOICES = {
+    "hd64_one_tile": ((256, 256, 3, 64, True, False, None, None, None),
+                      (128, True)),
+    "hd128_scale_is_no_power_of_two": (
+        (256, 256, 3, 128, True, False, None, None, None), (128, False)),
+    "hd64_odd_scale_of_the_caller": (
+        (256, 256, 5, 64, True, False, None, None, 0.2), (128, False)),
+    "hd32_scale_of_the_caller_is_a_quarter": (
+        (256, 256, 3, 32, True, False, None, None, 0.25), (128, True)),
+    "two_tiles_a_side": ((512, 512, 3, 64, True, False, 256, 256, None),
+                         (128, True)),
+    "unequal_tile_sides": ((512, 512, 3, 64, True, False, 256, 128, None),
+                           (0, True)),
+    "unequal_sequences": ((256, 512, 3, 64, True, False, 256, 256, None),
+                          (0, True)),
+    "not_causal": ((256, 256, 5, 64, False, False, None, None, None),
+                   (0, True)),
+    "padding_mask": ((256, 256, 3, 64, True, True, None, None, None),
+                     (0, True)),
+    "tile_of_no_whole_strip": ((64, 64, 3, 64, True, False, None, None, None),
+                               (0, True)),
+}
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("case", list(FLASH_CHOICES))
+def test_flash_static_choices(case, monkeypatch, caplog):
+    """The diagonal's walk and the scale's place are read off the call:
+    all three kernels are handed the same `strip` and `fold`, `_layout`'s
+    debug line says which walk it is, and either way the call holds to
+    the float32 reference."""
+    import logging
+
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    (s_q, s_k, h, d, causal, padded, bq, bk, sm_scale), (strip, fold) = \
+        FLASH_CHOICES[case]
+    assert fa._folds_scale(sm_scale or 1 / np.sqrt(d)) == fold
+    seen = {}
+    for name in ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"):
+        def spy(*a, _name=name, _kernel=getattr(fa, name), **kw):
+            seen[_name] = (kw["strip"], kw["fold"])
+            return _kernel(*a, **kw)
+        monkeypatch.setattr(fa, name, spy)
+
+    rs = np.random.RandomState(5)
+    q = jnp.asarray(rs.randn(1, s_q, h, d), jnp.float32)
+    k, v = [jnp.asarray(rs.randn(1, s_k, h, d), jnp.float32)
+            for _ in range(2)]
+    mask = None
+    if padded:
+        mask = jnp.asarray(rs.rand(1, 1, 1, s_k) > 0.3).at[..., :8].set(True)
+    w = jnp.asarray(rs.randn(1, s_q, h, d), jnp.float32)
+
+    def kernel(*a):
+        return flash_attention(*a, causal=causal, mask=mask, block_q=bq,
+                               block_k=bk, sm_scale=sm_scale)
+
+    def ref(*a):
+        return _attn_ref_masked(*a, causal, mask, sm_scale)
+
+    with caplog.at_level(logging.DEBUG, logger=fa.__name__):
+        out, g1 = jax.value_and_grad(
+            lambda *a: (kernel(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    assert seen == dict.fromkeys(
+        ("_fwd_kernel", "_dq_kernel", "_dkv_kernel"), (strip, fold))
+    walks = [r.getMessage().rsplit("the tile on the diagonal ", 1)[1]
+             for r in caplog.records if "the tile on the diagonal" in
+             r.getMessage()]
+    assert walks == [f"by strips of {strip} owned rows" if strip
+                     else "whole"] * 3
+    _assert_matches_reference(out, g1, ref, w, q, k, v)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("walk", ["by_strips", "whole", "whole_under_a_mask"])
+def test_flash_attention_parity_over_resident_spans(walk, monkeypatch,
+                                                    caplog):
+    """A walked axis that outgrows the VMEM budget is kept resident a span
+    at a time (here: 512 rows in two spans of 256, under a budget cut for
+    the test): the statistics and accumulators pass from span to span,
+    spans above the diagonal have no work, and the tile on the diagonal
+    lies in one span of several."""
+    import logging
+
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_VMEM_BUDGET", 2_500_000)
+    rs = np.random.RandomState(17)
+    q, k, v, w = [jnp.asarray(rs.randn(1, 512, 7, 64), jnp.float32)
+                  for _ in range(4)]
+    mask = None
+    if walk == "whole_under_a_mask":
+        mask = jnp.asarray(rs.rand(1, 1, 1, 512) > 0.3).at[..., :8].set(True)
+
+    def kernel(*a):
+        return flash_attention(*a, causal=True, mask=mask, block_q=128,
+                               block_k=128 if walk != "whole" else 64)
+
+    def ref(*a):
+        return _attn_ref_masked(*a, True, mask)
+
+    with caplog.at_level(logging.DEBUG, logger=fa.__name__):
+        out, g1 = jax.value_and_grad(
+            lambda *a: (kernel(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    lines = [r.getMessage() for r in caplog.records
+             if "the tile on the diagonal" in r.getMessage()]
+    # forward and dQ at least (dK/dV owns the tile's other side)
+    assert len(lines) == 3 and all(", 2)" in line for line in lines[:2]), \
+        lines
+    assert all(line.endswith("by strips of 128 owned rows"
+                             if walk == "by_strips" else "whole")
+               for line in lines), lines
+    _assert_matches_reference(out, g1, ref, w, q, k, v)
 
 
 @pytest.mark.kernels
