@@ -1,6 +1,6 @@
 """Serving: StableHLO AOT export + Predictor, ONNX interchange, and the
 adaptive-batching ServingEngine (concurrent clients, zero steady-state
-compiles, responses bitwise-identical to single-request runs).
+compiles, responses equal to single-request runs to a few ulp).
 
 Run: python examples/bert_serving.py   (add JAX_PLATFORMS=cpu off-TPU)
 """
@@ -40,9 +40,11 @@ def main():
         print("StableHLO predictor OK (batch 4 and 1 from one artifact)")
 
         # 2) ServingEngine: N concurrent client threads through the
-        # adaptive batcher; every response must be BITWISE-identical to
-        # a direct single-request Predictor.run, with zero compiles
-        # after the startup warmup
+        # adaptive batcher, with zero compiles after the startup warmup.
+        # A request coalesced into a batch of 2, 4 or 8 runs another
+        # executable than its direct run at batch 1, so a response equals
+        # the direct run to a few float32 ulp of the output's size (1e-6
+        # of it), not bitwise
         engine = serving.ServingEngine(pred, batch_timeout_ms=2,
                                        buckets="1,2,4,8x16")
         engine.start()
@@ -67,13 +69,16 @@ def main():
         assert len(outs) == n_clients * per_client
         for req, got in outs.values():
             direct, *_ = pred.run([req[None]])
-            assert np.array_equal(got, direct[0]), "serving != direct run"
+            np.testing.assert_allclose(
+                got, direct[0], rtol=1e-6,
+                atol=1e-6 * np.abs(direct[0]).max(),
+                err_msg="serving != direct run")
         assert pred.compile_count == compiles_after_warmup, \
             "serving recompiled after warmup"
         snap = engine.metrics.snapshot()
         print(f"ServingEngine OK ({snap['responses']} responses, "
               f"mean batch {snap['mean_batch_size']}, "
-              f"p99 {snap['p99_ms']}ms, all bitwise == direct run, "
+              f"p99 {snap['p99_ms']}ms, all == direct run to 1e-6, "
               f"0 recompiles)")
 
         # 3) ONNX artifact with a dynamic batch dim

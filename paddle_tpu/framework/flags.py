@@ -168,12 +168,12 @@ define_flag("FLAGS_telemetry_rotate_mb", 64.0,
             "(old segments keep a bounded .N suffix chain)")
 define_flag("FLAGS_device_peak_flops", 0.0,
             "per-device peak FLOP/s for the MFU gauge; 0 = look the "
-            "device kind up in monitor.PEAK_FLOPS (TPU generations + a "
-            "nominal CPU entry so smoke runs read a nonzero MFU)")
+            "device kind up in monitor.PEAKS (TPU generations + a "
+            "nominal CPU row so CPU runs read a nonzero MFU)")
 define_flag("FLAGS_device_peak_bw", 0.0,
             "per-device HBM bytes/s for the op-table roofline "
             "(monitor/perf.py); 0 = look the device kind up in "
-            "perf.PEAK_BW (TPU generations + a nominal CPU entry)")
+            "monitor.PEAKS (TPU generations + a nominal CPU row)")
 define_flag("FLAGS_perf_ops_top", 48,
             "op-table rows kept before rolling the tail into one "
             "'(other)' row (sums stay exact); /debug/perf and "

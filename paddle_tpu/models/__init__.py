@@ -1,7 +1,8 @@
 """Model zoo: language models (GPT/BERT/ERNIE-style) + hybrid-parallel GPT.
 
 The reference ships vision models only (python/paddle/vision/models); its
-language workloads (BERT/ERNIE/GPT-3 in BASELINE.md) live in external repos.
+language workloads (BERT/ERNIE/GPT-3, SURVEY.md section 6) live in external
+repos.
 Here they are first-class: these are the flagship models the benchmarks and
 the multi-chip dryrun drive.
 """

@@ -1,6 +1,6 @@
 """BERT/ERNIE-style bidirectional encoder + pretraining heads.
 
-Workload parity: BASELINE.md configs 3 (BERT-base Fleet) and 4 (ERNIE AMP).
+Workload parity: SURVEY.md section 6, BERT-base Fleet and ERNIE AMP-O2.
 Built on the same nn.TransformerEncoder the reference exposes
 (python/paddle/nn/layer/transformer.py:404,541); ERNIE shares the
 architecture (segment embeddings + MLM/NSP heads), so `ErnieModel` is the

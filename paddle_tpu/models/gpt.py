@@ -1,6 +1,6 @@
 """GPT decoder-only language model, tensor-parallel-ready.
 
-Workload parity: BASELINE.md config 5 (GPT-3 1.3B with TP+PP).  The reference
+Workload parity: SURVEY.md section 6, GPT-3 1.3B with TP+PP.  The reference
 tree has no GPT implementation (it lives in PaddleNLP); this is the TPU-native
 flagship: GSPMD tensor parallelism via the meta_parallel layers (weights carry
 PartitionSpecs; XLA inserts the Megatron collectives), optional

@@ -1,6 +1,6 @@
 """GPT with explicit 3D hybrid parallelism: dp x pp x mp in ONE SPMD program.
 
-Workload parity: BASELINE.md config 5 (GPT-3 1.3B, TP+PP+DP — the reference
+Workload parity: SURVEY.md section 6 (GPT-3 1.3B, TP+PP+DP — the reference
 composes fleet meta-optimizers PipelineOptimizer + split() TP + DP rings,
 SURVEY.md §2.10).  TPU-native equivalent: a single shard_map over a
 (dp, pp, mp) mesh combining

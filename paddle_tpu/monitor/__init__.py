@@ -26,15 +26,16 @@ from ..utils.metrics import default_registry
 from . import flightrec, perf, tracing
 from .flightrec import FlightRecorder
 from .server import MonitorServer, runtime_health
-from .telemetry import (PEAK_FLOPS, JsonlWriter, TrainTelemetry,
+from .telemetry import (PEAKS, JsonlWriter, TrainTelemetry,
                         device_memory_stats, install_sigusr1,
-                        peak_flops_per_device)
+                        peak_bw_per_device, peak_flops_per_device)
 from .tracing import NullSpan, Span, Tracer, default_tracer
 
 logger = logging.getLogger("paddle_tpu.monitor")
 
-__all__ = ["TrainTelemetry", "MonitorServer", "JsonlWriter", "PEAK_FLOPS",
-           "peak_flops_per_device", "device_memory_stats",
+__all__ = ["TrainTelemetry", "MonitorServer", "JsonlWriter", "PEAKS",
+           "peak_flops_per_device", "peak_bw_per_device",
+           "device_memory_stats",
            "install_sigusr1", "default_registry", "fit_monitor",
            "get_monitor_server", "get_telemetry", "reset",
            "runtime_health",

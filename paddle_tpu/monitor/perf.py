@@ -49,10 +49,10 @@ import re
 
 from ..framework import flags as _flags
 from . import flightrec as _flightrec
-from .telemetry import PEAK_FLOPS, peak_flops_per_device
+from .telemetry import peak_bw_per_device, peak_flops_per_device
 
 __all__ = [
-    "PEAK_BW", "peak_bw_per_device", "parse_hlo", "op_table",
+    "parse_hlo", "op_table",
     "build_report", "load_trace_op_times", "register_provider",
     "unregister_provider", "collect_reports", "register_owner",
     "unregister_owner", "buffer_census", "hbm_stats", "is_oom",
@@ -60,37 +60,6 @@ __all__ = [
 ]
 
 logger = logging.getLogger("paddle_tpu.monitor")
-
-# Per-chip HBM bandwidth (bytes/s) by device kind, the roofline's other
-# axis (PEAK_FLOPS in telemetry.py is the first).  The "cpu" entry is
-# NOMINAL, like its PEAK_FLOPS counterpart: CPU-smoke classifications
-# are comparable run-over-run, not absolute.
-PEAK_BW = {
-    "v2": 700e9, "v3": 900e9, "v4": 1228e9,
-    "v5 lite": 819e9, "v5e": 819e9, "v5p": 2765e9, "v5": 2765e9,
-    "v6 lite": 1640e9, "v6e": 1640e9,
-    "cpu": 5e10,
-}
-
-
-def peak_bw_per_device(device=None) -> float:
-    """HBM bytes/s for one device: FLAGS_device_peak_bw when set, else
-    the longest device-kind match in PEAK_BW; an unknown device kind
-    raises (mirrors telemetry.peak_flops_per_device)."""
-    override = float(_flags.flag("FLAGS_device_peak_bw") or 0.0)
-    if override > 0:
-        return override
-    import jax
-
-    d = device if device is not None else jax.devices()[0]
-    kind = (getattr(d, "device_kind", "") or "").lower()
-    for k, v in sorted(PEAK_BW.items(), key=lambda kv: -len(kv[0])):
-        if k in kind:
-            return v
-    raise KeyError(
-        f"no peak bytes/s known for device_kind {kind!r}; add it to "
-        f"PEAK_BW or set FLAGS_device_peak_bw")
-
 
 # ---------------------------------------------------------------------------
 # HLO text parsing + analytic per-op costs
@@ -147,6 +116,7 @@ _CALLED_RE = re.compile(
     r"false_computation|select|scatter)=%([\w.\-]+)")
 _CDIMS_RE = re.compile(r"lhs_contracting_dims=\{([0-9,\s]*)\}")
 _DIMLBL_RE = re.compile(r"dim_labels=([\w?]+)_([\w?]+)->([\w?]+)")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 
 
 def _shape_stats(text):
@@ -181,8 +151,12 @@ def parse_hlo(text: str):
     """Parse HLO module text into ``(computations, entry_name)`` where
     computations maps name -> [_Instr].  Only the structure the cost
     model needs — result/operand shapes, opcode, attributes — no full
-    grammar."""
+    grammar.  Where the text names an operand without its shape
+    (``dot(%x.1, %w1.1)``, jax 0.9's printer) ``args`` gets the shape
+    the operand was defined with, so every cost that reads an operand's
+    shape reads the same text either way."""
     comps, entry, cur = {}, None, None
+    shapes = {}                # instruction name -> result type text
     for line in text.splitlines():
         if not line:
             continue
@@ -200,6 +174,7 @@ def parse_hlo(text: str):
         if not m:
             continue
         name, shape, opcode = m.groups()
+        shapes[name] = shape
         # operand list: scan from the opcode's '(' to its matching ')'
         start = m.end()            # index just past the '('
         depth, i = 1, start
@@ -214,6 +189,11 @@ def parse_hlo(text: str):
                                  line[start:i - 1], line[i:]))
     if entry is None:
         raise ValueError("no ENTRY computation in HLO text")
+    for instrs in comps.values():
+        for ins in instrs:
+            if not _SHAPE_RE.search(ins.args):
+                ins.args = ", ".join(
+                    shapes.get(n, "") for n in _OPERAND_RE.findall(ins.args))
     return comps, entry
 
 

@@ -16,7 +16,7 @@ instruments every step through a `TrainTelemetry`, which writes
 MFU comes from XLA's own cost model: the engine's `lower_step()` gives
 the compiled train step's PER-DEVICE flops (the same numbers the dp
 scaling tests assert on), divided by measured step wall time and the
-device's peak FLOP/s from `PEAK_FLOPS` (overridable via
+device's peak FLOP/s from `PEAKS` (overridable via
 `FLAGS_device_peak_flops`).  Memory comes from the PJRT device's
 `memory_stats()` — gracefully None on backends that lack it (CPU).
 
@@ -46,39 +46,55 @@ from ..utils.metrics import default_registry
 
 logger = logging.getLogger("paddle_tpu.monitor")
 
-__all__ = ["PEAK_FLOPS", "peak_flops_per_device", "device_memory_stats",
+__all__ = ["PEAKS", "peak_flops_per_device", "peak_bw_per_device",
+           "device_memory_stats",
            "TrainTelemetry", "JsonlWriter", "install_sigusr1"]
 
-# Per-chip peak FLOP/s by device kind (bf16 systolic peak for TPU
-# generations — the BASELINE.md table bench.py uses); the "cpu" entry is
-# a NOMINAL figure so CPU smoke runs report a nonzero, comparable-run-
-# over-run MFU instead of dividing by zero — absolute CPU MFU is not
-# meaningful and README says so.
-PEAK_FLOPS = {
-    "v2": 45e12, "v3": 123e12, "v4": 275e12,
-    "v5 lite": 197e12, "v5e": 197e12, "v5p": 459e12, "v5": 459e12,
-    "v6 lite": 918e12, "v6e": 918e12,
-    "cpu": 1e11,
+# One chip's (bf16 FLOP/s, HBM byte/s) by device kind: the two axes of
+# the roofline (MFU reads the first, monitor/perf.py's op table both).
+# The v5e row is benchmarks/peaks.json's, whose source is named there;
+# tests/test_monitor.py holds the two equal.  The "cpu" row is NOMINAL so
+# that a CPU run reports a nonzero MFU and a classification that compare
+# run over run — absolute CPU figures are not meaningful and README says
+# so.
+PEAKS = {
+    "v2": (45e12, 700e9), "v3": (123e12, 900e9), "v4": (275e12, 1228e9),
+    "v5 lite": (197e12, 819e9), "v5e": (197e12, 819e9),
+    "v5p": (459e12, 2765e9), "v5": (459e12, 2765e9),
+    "v6 lite": (918e12, 1640e9), "v6e": (918e12, 1640e9),
+    "cpu": (1e11, 5e10),
 }
 
 
-def peak_flops_per_device(device=None) -> float:
-    """Peak FLOP/s for one device: FLAGS_device_peak_flops when set,
-    else the longest device-kind match in PEAK_FLOPS.  A device kind the
-    table does not know raises: no other chip's figure stands in."""
-    override = float(_flags.flag("FLAGS_device_peak_flops") or 0.0)
-    if override > 0:
-        return override
+def _peaks(device=None):
+    """The PEAKS row of the longest kind that `device`'s kind contains.
+    A device kind the table does not know raises: no other chip's figure
+    stands in."""
     import jax
 
     d = device if device is not None else jax.devices()[0]
     kind = (getattr(d, "device_kind", "") or "").lower()
-    for k, v in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
+    for k in sorted(PEAKS, key=len, reverse=True):
         if k in kind:
-            return v
+            return PEAKS[k]
     raise KeyError(
-        f"no peak FLOP/s known for device_kind {kind!r}; add it to "
-        f"PEAK_FLOPS or set FLAGS_device_peak_flops")
+        f"no peak FLOP/s and bytes/s known for device_kind {kind!r}; add "
+        f"it to PEAKS or set FLAGS_device_peak_flops and "
+        f"FLAGS_device_peak_bw")
+
+
+def peak_flops_per_device(device=None) -> float:
+    """Peak FLOP/s for one device: FLAGS_device_peak_flops when set,
+    else its PEAKS row's."""
+    override = float(_flags.flag("FLAGS_device_peak_flops") or 0.0)
+    return override if override > 0 else _peaks(device)[0]
+
+
+def peak_bw_per_device(device=None) -> float:
+    """HBM bytes/s for one device: FLAGS_device_peak_bw when set, else
+    its PEAKS row's."""
+    override = float(_flags.flag("FLAGS_device_peak_bw") or 0.0)
+    return override if override > 0 else _peaks(device)[1]
 
 
 def device_memory_stats(device=None):

@@ -3,8 +3,9 @@
 Reference parity: paddle/fluid/operators/fused/ — multihead_matmul_op.cu
 (BERT attention), skip_layernorm_op.cu (residual+LN), layer_norm_op.cu fused
 kernels, softmax_with_cross_entropy_op.cu (fused loss), and
-math/bert_encoder_functor.cu.  BASELINE.json additionally names
-fused_attention / fused_feedforward / fused_multi_transformer as intent.
+math/bert_encoder_functor.cu.  The seed additionally named
+fused_attention / fused_feedforward / fused_multi_transformer as intent
+(SURVEY.md section 2).
 
 TPU-native: each fused op has an XLA composite implementation (XLA fuses the
 elementwise pieces into the matmuls on its own) and, for the hot ones, a

@@ -3,8 +3,8 @@
 The fused-attention op of the framework (reference analogs:
 paddle/fluid/operators/fused/multihead_matmul_op.cu and
 math/bert_encoder_functor.cu — those are inference-only CUDA fusions; this
-kernel is the training-grade TPU replacement named as intent by
-BASELINE.json's fused_attention).
+kernel is the training-grade TPU replacement that the seed named as
+intent, fused_attention: SURVEY.md section 2).
 
 Design (flash attention v2 style):
 - public entry takes paddle layout [B, S, H, D]; internally folds to

@@ -3,7 +3,7 @@
 The reference has NO long-context support (SURVEY.md §5: no ring attention,
 no sequence parallel anywhere in tree; sequence length is bounded by one
 device's memory).  This module is the TPU-native capability that fills that
-gap, required for the GPT-3-class configs in BASELINE.md:
+gap, required for the GPT-3-class workloads of SURVEY.md section 6:
 
 * ring_attention — blockwise attention with the KV shards rotating around
   the `sp` mesh axis via `lax.ppermute` over ICI (Ring Attention, Liu et al.

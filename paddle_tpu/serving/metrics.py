@@ -27,7 +27,7 @@ __all__ = ["Histogram", "ServingMetrics", "GenerationMetrics",
 class ServingMetrics:
     """All engine/server observability state, rendered as Prometheus text.
 
-    Exposes (scraped by tools/serve_smoke.sh and read by bench.py):
+    Exposes (scraped by tools/serve_smoke.sh):
       paddle_serving_qps                    completions/s over the window
       paddle_serving_p50_ms / _p99_ms       request latency order stats
       paddle_serving_batch_size             batch-size histogram
@@ -153,7 +153,7 @@ class ServingMetrics:
                 if self.batch_slots_total else 0.0)
 
     def snapshot(self) -> dict:
-        """Programmatic view (bench.py serving fields, tests)."""
+        """Programmatic view (tests, examples)."""
         with self._lock:
             return {
                 "qps": round(self._qps_locked(), 2),
@@ -177,9 +177,8 @@ class GenerationMetrics:
     engine (same private-registry pattern as ServingMetrics, so several
     engines coexist in one process).
 
-    Exposes (scraped by tools/serve_smoke.sh; `snapshot()` is read by
-    bench.py's genserve body and, for counters and slot occupancy, by
-    benchmarks/adapters/gpt.py; the quantiles are an operator's view —
+    Exposes (scraped by tools/serve_smoke.sh; `snapshot()` is read, for
+    counters and slot occupancy, by benchmarks/adapters/gpt.py; the quantiles are an operator's view —
     the benchmark times at the client).  "window" is the trailing
     WINDOW_S seconds, so a scrape after warm-up stops reporting it:
       paddle_genserve_decode_tokens_per_sec  tokens streamed / s (window)
@@ -558,7 +557,7 @@ class GenerationMetrics:
         return live / span
 
     def snapshot(self) -> dict:
-        """Programmatic view (bench.py genserve fields, tests)."""
+        """Programmatic view (benchmarks/adapters/gpt.py, tests)."""
         with self._lock:
             return {
                 "decode_tokens_per_sec": round(self._tps_locked(), 2),

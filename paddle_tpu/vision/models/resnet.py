@@ -1,6 +1,6 @@
 """ResNet family. Reference parity: python/paddle/vision/models/resnet.py
 (BasicBlock/BottleneckBlock/ResNet, resnet18..152) — the ResNet-50 dygraph
-DataParallel workload of BASELINE.md config 2."""
+DataParallel workload of SURVEY.md section 6."""
 from __future__ import annotations
 
 from ... import nn

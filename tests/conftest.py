@@ -27,8 +27,8 @@ def pytest_configure(config):
         "markers", "chaos: deterministic fault-injection test of the "
         "resilience runtime (run via tools/chaos.sh)")
     config.addinivalue_line(
-        "markers", "perf: performance regression test (persistent compile "
-        "cache, step-time) — run via tools/perf_smoke.sh")
+        "markers", "perf: performance introspection test (persistent compile "
+        "cache, op table, buffer census, /debug/perf) — runs in tier-1")
     config.addinivalue_line(
         "markers", "serving: adaptive-batching serving engine test "
         "(paddle_tpu.serving) — run via tools/serve_smoke.sh")
@@ -55,7 +55,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "kernels: Pallas fused-kernel parity/dispatch test "
         "(masked flash, paged decode, softmax-xent; the bias-gelu "
-        "composite; CPU interpret mode) — run via tools/kernels_smoke.sh")
+        "composite; CPU interpret mode) — tests/test_pallas_kernels.py")
     config.addinivalue_line(
         "markers", "pod: multi-process pod test (N real OS processes via "
         "distributed.podtest — coordinated jax.distributed bring-up or "

@@ -442,23 +442,32 @@ class TestFlapDamping:
 
     def test_probe_loop_staggers(self):
         """The probe loop spaces per-replica probes at interval/N —
-        one replica at a time, never the whole fleet as a herd."""
+        one replica at a time, never the whole fleet as a herd.  The
+        loop's only clock is its stop event's `wait(step)`: a stand-in
+        that records each wait shows the order and the spacing asked
+        for, whatever the host's load."""
         router = FleetRouter(["http://127.0.0.1:1", "http://127.0.0.1:2"],
                              probe_interval_s=0.2,
                              install_signal_handlers=False)
-        times = []
-        router._probe_one = \
-            lambda rep: times.append((time.monotonic(), rep.name))
-        t = threading.Thread(target=router._probe_loop, daemon=True)
-        t.start()
-        time.sleep(0.55)
-        router._stop_probe.set()
-        t.join(2.0)
-        assert len(times) >= 4
-        assert [n for _, n in times[:4]] == ["r0", "r1", "r0", "r1"]
-        gaps = [b[0] - a[0] for a, b in zip(times, times[1:])]
-        assert all(g >= 0.05 for g in gaps), \
-            f"probes fired back-to-back: {gaps}"
+        log = []
+
+        class _StopAfterFourProbes:
+            def is_set(self):
+                return sum(what == "probe" for what, _ in log) >= 4
+
+            def wait(self, step):
+                log.append(("wait", step))
+                return self.is_set()
+
+        router._stop_probe = _StopAfterFourProbes()
+        router._probe_one = lambda rep: log.append(("probe", rep.name))
+        router._probe_loop()
+        assert [x for what, x in log if what == "probe"] \
+            == ["r0", "r1", "r0", "r1"]
+        # a wait of interval/N before every probe, none back-to-back
+        assert [what for what, _ in log] == ["wait", "probe"] * 4
+        assert all(x == pytest.approx(0.1) for what, x in log
+                   if what == "wait")
 
 
 # ---------------------------------------------------------------------------
@@ -503,7 +512,15 @@ class TestRetryBudget:
             assert total <= n_req + 2, \
                 f"dispatches {total} exceed requests+budget"
             assert total >= n_req
+            # the router answers a request before it counts its outcome
+            # (ROADMAP.md Design 20): read the metrics until the last one
+            # is counted
+            deadline = time.monotonic() + 10.0
             snap = router.metrics.snapshot()
+            while snap["requests_failed"] < n_req \
+                    and time.monotonic() < deadline:
+                time.sleep(0.02)
+                snap = router.metrics.snapshot()
             assert snap["retry_budget_exhausted"] >= 1
             assert snap["requests_failed"] == n_req
             assert snap["availability_ratio"] == 0.0

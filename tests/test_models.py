@@ -25,8 +25,7 @@ class TestGPT:
     def test_recompute_loss_and_grad_parity(self):
         """GPTConfig.recompute wraps each block in jax.checkpoint; loss
         and EVERY per-parameter gradient must match the non-remat model —
-        this is the path the full-1.3B single-chip measurement relies on
-        (bench.py body_gpt13b)."""
+        this is what lets a 1.3B model train on one chip."""
         import jax
 
         ids_np = np.random.RandomState(1).randint(0, 64, (2, 16))

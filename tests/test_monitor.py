@@ -359,6 +359,42 @@ class TestMfuAndMeters:
         assert ca.get("flops", 0) > 0
 
 
+# -- the peak table ---------------------------------------------------------
+class _Kind:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+class TestPeaks:
+    """One table states what a chip can do (monitor/telemetry.py PEAKS):
+    the MFU's denominator and the op table's roofline read the same
+    row."""
+
+    @pytest.mark.parametrize(
+        "kind", ["TPU v4", "TPU v5 lite", "TPU v5p", "TPU v6e"])
+    def test_one_row_gives_both_axes(self, kind):
+        from paddle_tpu.monitor import (PEAKS, peak_bw_per_device,
+                                        peak_flops_per_device)
+
+        got = (peak_flops_per_device(_Kind(kind)),
+               peak_bw_per_device(_Kind(kind)))
+        assert got == PEAKS[kind[len("TPU "):]]
+        assert got[0] > got[1] > 0
+
+    def test_v5e_row_is_the_benchmarks(self):
+        """The package does not read benchmarks/peaks.json (a lower layer
+        imports no higher one); the ledger's rooflines and the package's
+        MFU still divide by the same numbers."""
+        from paddle_tpu.monitor import (peak_bw_per_device,
+                                        peak_flops_per_device)
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(repo, "benchmarks", "peaks.json")) as fh:
+            (kind, row), = json.load(fh)["devices"].items()
+        assert peak_flops_per_device(_Kind(kind)) == row["bf16_flops_per_s"]
+        assert peak_bw_per_device(_Kind(kind)) == row["hbm_bytes_per_s"]
+
+
 # -- JSONL event log --------------------------------------------------------
 class TestJsonl:
     def test_schema_and_rotation(self, tmp_path):

@@ -179,7 +179,7 @@ class TestFusedLinearCrossEntropy:
 
 
 # ===========================================================================
-# PR 15 kernel suite (tools/kernels_smoke.sh): masked flash + VJP, paged
+# PR 15 kernel suite: masked flash + VJP, paged
 # decode, softmax-xent, bias-gelu, GSPMD composition, dispatch telemetry
 # ===========================================================================
 def _attn_ref_masked(q, k, v, causal=False, mask=None, sm_scale=None):
@@ -1529,8 +1529,8 @@ def test_gpt_mlp_and_encoder_ffn_route_fused(monkeypatch):
 def test_masked_training_step_through_kernels(monkeypatch):
     """End-to-end flag-on masked+causal training step: grads flow through
     the flash kernel, the xent kernel, and bias-gelu with ZERO fallbacks
-    recorded — the op_report/fallback contract of tools/kernels_smoke.sh
-    at unit scale."""
+    recorded — the op_report/fallback contract at unit scale (at GPT-2
+    124M's scale on the chip: chip_smoke.py's kernels and train phases)."""
     import paddle_tpu.nn.functional as F
     from paddle_tpu.ops import fused
     from paddle_tpu.ops.pallas.flash_attention import flash_attention

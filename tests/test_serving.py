@@ -145,7 +145,10 @@ class TestAdaptiveBatching:
 
     def test_seq_padding_unpads_to_original_length(self, exported_mlp):
         """A seq-3 request padded into the seq-4 bucket comes back
-        sliced to 3 tokens, bitwise-equal to its unpadded direct run."""
+        sliced to 3 tokens and equal to its unpadded direct run to a few
+        ulp (rtol 1e-6), not bitwise: the bucket's executable has another
+        shape than the direct run's, and the compiler may order a
+        product's sums differently in each."""
         eng = ServingEngine(exported_mlp, batch_timeout_ms=2,
                             buckets="1,2x4")
         with eng:
@@ -154,7 +157,7 @@ class TestAdaptiveBatching:
         assert out.shape == (3, 3)
         pred = inference.create_predictor(inference.Config(exported_mlp))
         direct, = pred.run([s[None]])
-        np.testing.assert_array_equal(out, direct[0])
+        np.testing.assert_allclose(out, direct[0], rtol=1e-6)
         assert eng.metrics.snapshot()["padding_waste_ratio"] > 0
 
     def test_oversized_seq_rejected_at_submit(self, exported_mlp):

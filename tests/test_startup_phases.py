@@ -312,19 +312,22 @@ class TestFitStartup:
         assert boot.parents["cost_analysis/lower"] \
             == boot.parents["cost_analysis/compile"] == "cost_analysis"
         # the window line carries each phase's longest run of the
-        # epoch where it fell in that window, beside the means
+        # epoch where it fell in that window, beside the means.  What
+        # the host's clock decides (whether step two outlasted step
+        # one, compile and all) is not asserted: what holds on any host
+        # is the order.  An epoch starts anew, so its first window has
+        # every step phase's first run as the longest so far; its second
+        # carries a phase only with a longer run than the first showed
         windows = [json.loads(x) for x in open(tmp_path / "events.jsonl")]
         windows = [w for w in windows if w["event"] == "window"]
         assert len(windows) == 4
-        for w in windows:
-            assert set(w["phase_max_ms"]) <= set(w["phase_ms"])
-            assert all(w["phase_max_ms"][n] >= 0.0
-                       for n in w["phase_max_ms"])
-        # epoch 0: the first step compiles, so step two's window has no
-        # longer dispatch; epoch 1 starts anew
-        assert "dispatch" in windows[0]["phase_max_ms"]
-        assert "dispatch/call" not in windows[1]["phase_max_ms"]
-        assert "dispatch" in windows[2]["phase_max_ms"]
+        for first, second in (windows[0:2], windows[2:4]):
+            assert {"data", "dispatch", "dispatch/call", "sync"} \
+                <= set(first["phase_max_ms"]) <= set(first["phase_ms"])
+            later = second.get("phase_max_ms", {})
+            assert set(later) <= set(second["phase_ms"])
+            assert all(later[n] >= first["phase_max_ms"][n] >= 0.0
+                       for n in later)
 
     def test_the_epoch_log_carries_each_phases_longest_run(self, caplog):
         with caplog.at_level(logging.INFO, logger="paddle_tpu.hapi"):

@@ -11,8 +11,7 @@ Pins the three contracts the engine introduces:
     the donated engine are bitwise-identical to the legacy non-donated
     `train_batch` loop.
   * persistent compilation cache — FLAGS_jit_cache_dir makes a second
-    PROCESS skip XLA compilation (perf marker; run via
-    tools/perf_smoke.sh).
+    PROCESS skip XLA compilation (perf marker; in tier-1).
 """
 import json
 import os
